@@ -482,8 +482,9 @@ async def run(args: argparse.Namespace) -> dict:
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--model", default=None,
-                        help="llama config name or 'tiny' (default: "
-                        "llama32_3b on TPU, tiny elsewhere)")
+                        help="llama config name (default llama32_3b; a "
+                        "device measurement, needs a TPU) or 'tiny' (a "
+                        "functional run on any backend, stamped with it)")
     parser.add_argument("--quant", default=None,
                         help="int8 or none (default: int8 for real models)")
     parser.add_argument("--kv-dtype", default="bf16")
@@ -496,9 +497,15 @@ def main() -> int:
 
     import jax
 
-    on_tpu = jax.devices()[0].platform == "tpu"
     if args.model is None:
-        args.model = "llama32_3b" if on_tpu else "tiny"
+        args.model = "llama32_3b"
+    platform = jax.devices()[0].platform
+    if args.model != "tiny" and platform != "tpu":
+        parser.error(
+            f"--model {args.model} is a device measurement and needs a TPU "
+            f"(found platform {platform!r}); say --model tiny for a "
+            "functional run"
+        )
     if args.quant is None:
         args.quant = "int8" if args.model.startswith("llama3") else "none"
     if args.isl is None:

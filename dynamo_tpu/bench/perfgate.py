@@ -2,8 +2,8 @@
 artifacts.
 
 The repo commits a pile of benchmark artifacts (PROFILE_DECODE.json,
-DISAGG_BENCH.json, SCENARIO_SOAK.json, KERNEL_PERF.json,
-PREFETCH_BENCH.json, MIGRATION_BENCH.json) but, before this gate, nothing
+DISAGG_BENCH.json, SCENARIO_SOAK.json, PREFETCH_BENCH.json,
+MIGRATION_BENCH.json) but, before this gate, nothing
 diffed them across PRs — a perf regression was silent while a lint finding
 failed tier-1.  This module is the missing ratchet, modeled exactly on
 ``scripts/dynlint.py`` + ``ANALYSIS_BASELINE.json``:
@@ -42,7 +42,6 @@ ARTIFACTS = (
     "PROFILE_DECODE.json",
     "DISAGG_BENCH.json",
     "SCENARIO_SOAK.json",
-    "KERNEL_PERF.json",
     "PREFETCH_BENCH.json",
     "MIGRATION_BENCH.json",
 )
@@ -108,11 +107,6 @@ METRICS: tuple[MetricSpec, ...] = (
         "scenario_soak.worst_burn_rate", "SCENARIO_SOAK.json",
         "slo.worst_burn_rate", "lower", 0.0, abs_slack=0.5,
         doc="worst SLO burn rate observed across the soak"),
-    # -- kernels (scripts/bench_kernels.py, compiled on real hardware) -------
-    MetricSpec(
-        "kernel_perf.max_tflops", "KERNEL_PERF.json",
-        "max:rows[].tflops", "higher", 0.25,
-        doc="best kernel throughput row (loose band: hardware noise)"),
     # -- predictive prefetch (scripts/prefetch_bench.py) ---------------------
     MetricSpec(
         "prefetch_bench.ttft_p50_speedup", "PREFETCH_BENCH.json",

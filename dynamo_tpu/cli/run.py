@@ -52,14 +52,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     run.add_argument("--context-length", type=int, default=None)
     run.add_argument("--tensor-parallel-size", type=int, default=1)
     run.add_argument("--warmup", action="store_true",
-                     help="pre-compile every serving program before registering")
-    run.add_argument("--compilation-cache", default=None, metavar="DIR",
-                     help="persistent JAX compilation cache directory "
-                          "(default: DYN_COMPILE_CACHE_DIR, else "
-                          "~/.cache/dynamo_tpu/jax_cache; set "
-                          "DYN_COMPILE_CACHE_DIR='' to disable); with "
-                          "--warmup the serving programs also AOT-compile "
-                          "in parallel (cold restarts reuse the cache)")
+                     help="pre-compile every serving program before "
+                          "registering (in parallel, through the persistent "
+                          "compile cache: JAX_COMPILATION_CACHE_DIR, else "
+                          "<checkout>/.jax_cache)")
     run.add_argument("--speculative", choices=["ngram"], default=None,
                      help="speculative decoding (ngram = prompt-lookup "
                           "self-drafting with exact greedy verification)")
@@ -99,19 +95,12 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 async def _run(args) -> int:
     configure_logging()
-    if args.compilation_cache:
-        import jax
+    if args.output == "jax":
+        from dynamo_tpu.parallel.mesh import device_summary
+        from dynamo_tpu.utils.compile_cache import ensure_compile_cache
 
-        jax.config.update("jax_compilation_cache_dir", args.compilation_cache)
-    else:
-        # default-on persistence: the engine would resolve this itself at
-        # init, but doing it here covers out=echo/mocker spawns too and
-        # logs the resolved dir once at startup
-        from dynamo_tpu.engine.engine import _ensure_compile_cache
-
-        resolved = _ensure_compile_cache()
-        if resolved:
-            logger.info("persistent compile cache: %s", resolved)
+        logger.info("persistent compile cache: %s", ensure_compile_cache())
+        logger.info("jax devices: %s", json.dumps(device_summary()))
     control_plane = args.control_plane or "memory"
     runtime = await DistributedRuntime.create(
         RuntimeConfig(control_plane=control_plane, namespace=args.namespace)

@@ -124,14 +124,8 @@ class DeviceStorage(Storage):
             self._data = jax.device_put(self._data, device)
         # on TPU the Pallas block-copy kernels move blocks with pipelined
         # HBM↔VMEM DMAs (the block_copy.cu replacement, SURVEY.md §2.2);
-        # XLA gather/scatter is the portable fallback
-        use_pallas = False
-        if sharding is None:
-            try:
-                use_pallas = jax.default_backend() == "tpu"
-            except Exception:  # wedged plugin: portable path
-                use_pallas = False
-        if use_pallas:
+        # XLA gather/scatter serves other backends and sharded pools
+        if sharding is None and jax.default_backend() == "tpu":
             from dynamo_tpu.ops.pallas.block_copy import gather_blocks, scatter_blocks
 
             self._write = lambda pool, ids, blocks: scatter_blocks(

@@ -155,8 +155,10 @@ class ForwardPassMetrics:
                 str(reason): int(count)
                 for reason, count in (stats.get("unified_fallbacks") or {}).items()
             },
-            mfu_perc=stats.get("mfu_perc", 0.0),
-            bandwidth_util_perc=stats.get("bandwidth_util_perc", 0.0),
+            # None = device without a published peak (observability/perf.py);
+            # the wire struct and its gauges carry 0 for "not known"
+            mfu_perc=stats.get("mfu_perc") or 0.0,
+            bandwidth_util_perc=stats.get("bandwidth_util_perc") or 0.0,
             goodput_tokens_per_second=stats.get("goodput_tokens_per_second", 0.0),
             prefill_tokens_per_second=stats.get("prefill_tokens_per_second", 0.0),
             prefill_tokens_total=stats.get("prefill_tokens_total", 0),

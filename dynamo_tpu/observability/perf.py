@@ -30,26 +30,39 @@ and consumed by the planner and ``scripts/dyn_top.py``.
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from dynamo_tpu.utils import knobs
 from collections import deque
 from dataclasses import dataclass
 
-# nominal (bf16 peak FLOPs, HBM bytes/s) per device kind — matched as a
-# lowercase substring of jax's device_kind.  Order matters: first hit wins.
-NOMINAL_PEAKS: tuple[tuple[str, float, float], ...] = (
-    ("v6e", 918e12, 1640e9),
-    ("v6", 918e12, 1640e9),
-    ("v5p", 459e12, 2765e9),
-    ("v5e", 197e12, 819e9),
-    ("v5 lite", 197e12, 819e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("cpu", 0.5e12, 50e9),
-)
-_FALLBACK_PEAKS = (0.5e12, 50e9)
+# THE peak table: (bf16 peak FLOP/s, HBM bytes/s) of one chip, keyed by the
+# ``device_kind`` jax reports.  Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v5e": 197 TFLOP/s bf16,
+# 819 GB/s HBM).  A kind that is not here has no peak: device_peaks raises,
+# and the always-on tracker reports its utilizations as unknown (None).
+DEVICE_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
+}
+
+
+def device_peaks(device_kind: str) -> tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) of ``device_kind``; LookupError when the
+    table has no such device — a device metric never gets an assumed peak."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no published peak for device_kind {device_kind!r}: add it to "
+            "observability.perf.DEVICE_PEAKS with its source"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "float8_e4m3fn": 1, "float8_e5m2": 1, "fp8": 1, "float8": 1,
@@ -163,24 +176,20 @@ def model_cost(
     )
 
 
-def detect_peaks() -> tuple[float, float]:
-    """(peak FLOPs/s, peak bytes/s) for this host: env override →
-    device-kind table → conservative fallback."""
+def detect_peaks() -> tuple[float, float] | None:
+    """(peak FLOP/s, peak bytes/s) for this host: DYN_PEAK_TFLOPS /
+    DYN_PEAK_GBPS override the table entry of jax's device_kind.  None when
+    the device is not in the table and the operator named no peaks."""
     env_tflops = knobs.get("DYN_PEAK_TFLOPS")
     env_gbps = knobs.get("DYN_PEAK_GBPS")
-    kind = ""
-    if not (env_tflops and env_gbps):
-        try:
-            import jax
+    if env_tflops and env_gbps:
+        return env_tflops * 1e12, env_gbps * 1e9
+    import jax
 
-            kind = jax.devices()[0].device_kind.lower()
-        except Exception:  # noqa: BLE001
-            kind = ""
-    flops, gbps = _FALLBACK_PEAKS
-    for needle, f, b in NOMINAL_PEAKS:
-        if needle in kind:
-            flops, gbps = f, b
-            break
+    known = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    if known is None:
+        return None
+    flops, gbps = known
     if env_tflops:
         flops = env_tflops * 1e12
     if env_gbps:
@@ -218,13 +227,17 @@ class UtilizationTracker:
     ):
         self.cost = cost
         if peak_flops is None or peak_bytes_per_s is None:
-            detected_f, detected_b = detect_peaks()
+            detected_f, detected_b = detect_peaks() or (None, None)
             peak_flops = peak_flops if peak_flops is not None else detected_f
             peak_bytes_per_s = (
                 peak_bytes_per_s if peak_bytes_per_s is not None else detected_b
             )
-        self.peak_flops = max(float(peak_flops), 1.0)
-        self.peak_bytes_per_s = max(float(peak_bytes_per_s), 1.0)
+        # None = a device with no published peak: mfu/bandwidth utilization
+        # are then reported as None (unknown), never against a guess
+        self.peak_flops = peak_flops and max(float(peak_flops), 1.0)
+        self.peak_bytes_per_s = peak_bytes_per_s and max(
+            float(peak_bytes_per_s), 1.0
+        )
         if window_s is None:
             window_s = knobs.get("DYN_UTIL_WINDOW_S")
         self.window_s = max(window_s, 0.1)
@@ -288,9 +301,11 @@ class UtilizationTracker:
         with self._lock:
             self._prune(t)
             samples = list(self._samples)
+        unknown_f, unknown_b = not self.peak_flops, not self.peak_bytes_per_s
         if not samples:
             return {
-                "mfu_perc": 0.0, "bandwidth_util_perc": 0.0,
+                "mfu_perc": None if unknown_f else 0.0,
+                "bandwidth_util_perc": None if unknown_b else 0.0,
                 "goodput_tokens_per_second": 0.0,
                 "prefill_tokens_per_second": 0.0,
                 "tokens_per_second": 0.0,
@@ -301,8 +316,13 @@ class UtilizationTracker:
         emitted = sum(s.emitted_tokens for s in samples)
         computed = sum(s.prefill_tokens + s.decode_tokens for s in samples)
         return {
-            "mfu_perc": min(flops / span / self.peak_flops, 1.0),
-            "bandwidth_util_perc": min(moved / span / self.peak_bytes_per_s, 1.0),
+            "mfu_perc": (
+                None if unknown_f else min(flops / span / self.peak_flops, 1.0)
+            ),
+            "bandwidth_util_perc": (
+                None if unknown_b
+                else min(moved / span / self.peak_bytes_per_s, 1.0)
+            ),
             "goodput_tokens_per_second": emitted / span,
             "prefill_tokens_per_second": sum(
                 s.prefill_tokens for s in samples

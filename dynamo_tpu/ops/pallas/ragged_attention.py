@@ -62,6 +62,35 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# Scalar memory of one TensorCore (v5e: 1 MiB).  Everything the kernel
+# scalar-prefetches lives there for the whole call, and Mosaic pads a 2-D
+# int32 operand to (8, 128) tiles — so the three [token blocks, page_slots]
+# worklists are the footprint that matters.  The wrapper never hands one
+# pallas_call more token blocks than fit (worklist_rows_per_call); the
+# reserve is what the compiler keeps for its own scalars.
+SMEM_BYTES = 1 << 20
+SMEM_RESERVE_BYTES = 128 << 10
+
+
+def worklist_rows_per_call(page_slots: int, tb_tokens: int) -> int:
+    """Token blocks one kernel launch may carry so that its scalar-prefetched
+    metadata (three [rows, page_slots] worklists + per-token lane/pos +
+    per-row count) fits scalar memory.  A multiple of 8 (the SMEM row
+    tile).  Raises ValueError when even one row tile does not fit: the
+    engine calls this at init so such a config is a construction error,
+    not a compiler refusal at first dispatch."""
+    cols = -(-page_slots // 128) * 128
+    per_row = 4 * (3 * cols + 2 * tb_tokens + 1)
+    rows = (SMEM_BYTES - SMEM_RESERVE_BYTES) // per_row // 8 * 8
+    if rows < 8:
+        raise ValueError(
+            f"ragged attention worklist width page_slots={page_slots} "
+            f"(tb_tokens={tb_tokens}) needs {8 * per_row} bytes of scalar "
+            f"memory per launch; the chip has {SMEM_BYTES - SMEM_RESERVE_BYTES}"
+            " usable — lower the context length or the token-block size"
+        )
+    return rows
+
 
 def pack_page_meta(
     token_lane,     # [T] int — owning lane per token (OOB / pos<0 = pad)
@@ -312,20 +341,6 @@ def ragged_paged_attention(
             pl.BlockSpec((1, rows, d), m),
             pl.BlockSpec((1, rows, d), m),
         ]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(num_tb, page_slots // pps),
-        in_specs=[
-            pl.BlockSpec((1, tbh, d), lambda t, j, *_: (t, 0, 0)),
-            *kv_specs,
-        ],
-        out_specs=pl.BlockSpec((1, tbh, d), lambda t, j, *_: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tbh, 128), jnp.float32),
-            pltpu.VMEM((tbh, 128), jnp.float32),
-            pltpu.VMEM((tbh, d), jnp.float32),
-        ],
-    )
     kernel = functools.partial(
         _ragged_kernel,
         block_size=bs,
@@ -342,14 +357,57 @@ def ragged_paged_attention(
     kv_args = []
     for _ in range(pps):
         kv_args += [k_flat, v_flat]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_tb, tbh, d), q.dtype),
-        interpret=interpret,
-    )(
-        token_lane, token_pos, page_phys, page_lane, page_ord, page_count,
-        q.reshape(num_tb, tbh, d),
-        *kv_args,
+
+    def launch(blocks, tl, tp, pp, pln, po, pc, q_rows):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(blocks, page_slots // pps),
+            in_specs=[
+                pl.BlockSpec((1, tbh, d), lambda t, j, *_: (t, 0, 0)),
+                *kv_specs,
+            ],
+            out_specs=pl.BlockSpec((1, tbh, d), lambda t, j, *_: (t, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((tbh, 128), jnp.float32),
+                pltpu.VMEM((tbh, 128), jnp.float32),
+                pltpu.VMEM((tbh, d), jnp.float32),
+            ],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((blocks, tbh, d), q.dtype),
+            interpret=interpret,
+        )(tl, tp, pp, pln, po, pc, q_rows, *kv_args)
+
+    # token blocks are independent (each owns its softmax state), so the
+    # token axis splits into launches whose worklists fit scalar memory;
+    # one launch when everything fits
+    calls = -(-num_tb // worklist_rows_per_call(page_slots, tb_tokens))
+    blocks = -(-num_tb // calls)
+    q_rows = q.reshape(num_tb, tbh, d)
+    if calls == 1:
+        out = launch(
+            num_tb, token_lane, token_pos, page_phys, page_lane, page_ord,
+            page_count, q_rows,
+        )
+        return out.reshape(t_pad, h, d)
+    pad = calls * blocks - num_tb  # < calls dead blocks: count 0, pos -1
+
+    def split(x, per_row, fill=0):
+        if pad:
+            x = jnp.pad(
+                x, ((0, pad * per_row),) + ((0, 0),) * (x.ndim - 1),
+                constant_values=fill,
+            )
+        return x.reshape(calls, blocks * per_row, *x.shape[1:])
+
+    out = jax.lax.map(
+        lambda xs: launch(blocks, *xs),
+        (
+            split(token_lane, tb_tokens), split(token_pos, tb_tokens, -1),
+            split(page_phys, 1), split(page_lane, 1, -1), split(page_ord, 1),
+            split(page_count, 1), split(q_rows, 1),
+        ),
     )
-    return out.reshape(t_pad, h, d)
+    return out.reshape(calls * blocks * tb_tokens, h, d)[:t_pad]

@@ -60,9 +60,27 @@ class MemoryKV(KeyValueStore):
             self._reaper = spawn_logged(self._reap_loop())
 
     async def _reap_loop(self) -> None:
+        tick = 0.2
         while self._leases:
-            await asyncio.sleep(0.2)
+            before = time.monotonic()
+            await asyncio.sleep(tick)
             now = time.monotonic()
+            stall = now - before - tick
+            if stall > tick:
+                # this loop did not run for ``stall`` seconds, so neither
+                # did any holder's keep-alive (same process, same loop): a
+                # lease must not die of that.  Seen on the chip: warmup at
+                # real widths starved the loop past the 3 s instance lease
+                # and the worker lost its only instance for good.
+                if stall > 1.0:
+                    logger.warning(
+                        "event loop stalled %.1fs; extending %d lease(s)",
+                        stall, len(self._leases),
+                    )
+                self._leases = {
+                    lid: (lease, deadline + stall)
+                    for lid, (lease, deadline) in self._leases.items()
+                }
             expired = [lid for lid, (_, deadline) in self._leases.items() if deadline < now]
             for lid in expired:
                 await self._expire_lease(lid)

@@ -173,9 +173,11 @@ def plan_resource_envs(
 
     ``services`` is a list of @service-decorated classes (sdk/graph.py).
     Services without a ``tpu`` resource get empty overlays.  Returns {} for
-    every service when allocation is disabled or no chips are visible —
-    processes then see whatever the parent saw, exactly like the reference
-    with DYN_DISABLE_AUTO_GPU_ALLOCATION set."""
+    every service when allocation is disabled, or on a CPU-only host
+    (``JAX_PLATFORMS=cpu``: nothing to claim) — processes then see whatever
+    the parent saw.  A host that may have chips but gives no chip count
+    (``TPU_VISIBLE_CHIPS`` / ``DYN_TPU_CHIP_COUNT``) raises: unassigned
+    replicas would each claim every chip and the second would hang."""
     env = os.environ if env is None else env
     if knobs.get(DISABLE_ENV, env=env):
         return {}
@@ -188,10 +190,7 @@ def plan_resource_envs(
     if not requested:
         return {}
     if not inventory.chips:
-        logger.warning(
-            "services %s request TPU chips but none are visible on this "
-            "host; skipping chip allocation", sorted(requested),
-        )
+        _require_cpu_host(env, f"services {sorted(requested)}")
         return {}
     allocator = ResourceAllocator(inventory)
     return {
@@ -201,3 +200,33 @@ def plan_resource_envs(
         )
         for name, config in requested.items()
     }
+
+
+def _require_cpu_host(env, who: str) -> None:
+    """No chip count is known: fine on a CPU-only host, an error anywhere
+    a chip might be (the planner must not guess, and must not touch jax)."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        logger.info("%s: CPU-only host (JAX_PLATFORMS=cpu), no chips to assign", who)
+        return
+    raise ResourceError(
+        f"{who} need TPU chips but this host's chip count is unknown: set "
+        f"DYN_TPU_CHIP_COUNT (or {VISIBLE_CHIPS_ENV}) so each process gets "
+        "its own chips — unassigned, every process claims every chip and "
+        f"the second one hangs.  (JAX_PLATFORMS=cpu runs the graph on the "
+        f"CPU; {DISABLE_ENV}=1 leaves placement to you.)"
+    )
+
+
+def one_chip_each(workers: int, who: str, env: dict | None = None) -> list[dict[str, str]]:
+    """Env overlays giving each of ``workers`` single-chip processes its own
+    chip, for launchers that build their ProcessSpecs by hand."""
+    env = os.environ if env is None else env
+    if knobs.get(DISABLE_ENV, env=env):
+        return [{} for _ in range(workers)]
+    inventory = ChipInventory.detect(env)
+    if not inventory.chips:
+        _require_cpu_host(env, who)
+        return [{} for _ in range(workers)]
+    return ResourceAllocator(inventory).replica_envs(
+        tpu=1, workers=workers, service_name=who
+    )

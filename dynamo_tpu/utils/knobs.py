@@ -245,12 +245,6 @@ K_KERNEL_PERF = register(
     "DYN_KERNEL_PERF", type="str", default=None,
     doc="explicit path to a KERNEL_PERF.json kernel-choice table (default: "
         "the repo-root artifact, purely advisory)", section=PERF)
-K_COMPILE_CACHE_DIR = register(
-    "DYN_COMPILE_CACHE_DIR", type="str", default=None,
-    doc="persistent JAX compile cache dir (unset: "
-        "`~/.cache/dynamo_tpu/jax_cache`; empty string disables; an "
-        "explicitly set `jax_compilation_cache_dir` always wins)",
-    section=PERF)
 K_AUTOTUNE = register(
     "DYN_AUTOTUNE", type="bool", default=True,
     doc="consult KERNEL_PERF.json autotune rows for ragged-kernel tunables "

@@ -78,9 +78,20 @@ async def orchestrate(args: argparse.Namespace) -> int:
         # workers first: the frontend's model watcher picks the model up
         # whenever registration lands, so strict ordering is not required —
         # but starting engines early overlaps their compile time
-        sup.add_watcher(ProcessSpec(name="decode", cmd=_role_cmd(args, "decode")))
+        # one chip per engine process, planned here from an explicit chip
+        # count (DYN_TPU_CHIP_COUNT / TPU_VISIBLE_CHIPS): a chip belongs to
+        # one process, so unassigned workers would hang on each other
+        from dynamo_tpu.sdk.allocator import one_chip_each
+
+        chips = one_chip_each(1 + args.prefill_workers, "disagg_router workers")
+        sup.add_watcher(ProcessSpec(
+            name="decode", cmd=_role_cmd(args, "decode"), replica_env=chips[:1],
+        ))
         sup.add_watcher(
-            ProcessSpec(name="prefill", cmd=_role_cmd(args, "prefill")),
+            ProcessSpec(
+                name="prefill", cmd=_role_cmd(args, "prefill"),
+                replica_env=chips[1:],
+            ),
             replicas=args.prefill_workers,
         )
         sup.add_watcher(ProcessSpec(name="frontend", cmd=_role_cmd(args, "frontend")))

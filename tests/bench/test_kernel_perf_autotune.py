@@ -247,54 +247,3 @@ def test_engine_rejects_tuned_tb_that_breaks_buckets(tmp_path, monkeypatch):
         assert kc["pages_per_step"] == 1
     finally:
         engine.stop()
-
-
-# ------------------------------------------- per-shape attention_impl=auto
-
-
-def _shape_table(tmp_path, monkeypatch, rows, **header):
-    from dynamo_tpu.engine.engine import _measured_attention_preference
-
-    table = {"platform": "tpu", "interpret": False, **header, "rows": rows}
-    path = tmp_path / "perf.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setenv("DYN_KERNEL_PERF", str(path))
-    return _measured_attention_preference
-
-
-def test_attention_auto_per_shape_routing(tmp_path, monkeypatch):
-    """attention_impl=auto honors the measured row NEAREST to this
-    engine's (batch, ctx): batch-16 engines route to the XLA twin where
-    batch-16 rows show Pallas losing, while batch-64 engines still get
-    the kernel — same table, different shapes."""
-    rows = [
-        {"bench": "paged_attention_decode", "batch": 16, "ctx": 1024,
-         "pallas_speedup": 0.81},
-        {"bench": "paged_attention_decode", "batch": 32, "ctx": 2048,
-         "pallas_speedup": 0.82},
-        {"bench": "paged_attention_decode", "batch": 64, "ctx": 1024,
-         "pallas_speedup": 1.41},
-    ]
-    pref = _shape_table(tmp_path, monkeypatch, rows)
-    assert pref(batch=16, ctx=1024) == "jax"
-    assert pref(batch=32, ctx=2048) == "jax"
-    assert pref(batch=64, ctx=1024) == "pallas"
-    # shapes off the measured grid snap to the nearest row in log space
-    assert pref(batch=48, ctx=1024) == "pallas"   # log-nearer 64 than 32
-    assert pref(batch=8, ctx=512) == "jax"
-    # no shape → median over all rows (legacy whole-table decision)
-    assert pref() == "jax"
-
-
-def test_attention_auto_table_gates_still_hold(tmp_path, monkeypatch):
-    rows = [{"bench": "paged_attention_decode", "batch": 16, "ctx": 1024,
-             "pallas_speedup": 0.5}]
-    # interpret-mode tables say nothing about hardware
-    pref = _shape_table(tmp_path, monkeypatch, rows, interpret=True)
-    assert pref(batch=16, ctx=1024) is None
-    # a table from a different chip generation is ignored when kind known
-    pref = _shape_table(
-        tmp_path, monkeypatch, rows, device_kind="TPU v4",
-    )
-    assert pref("TPU v5 lite", batch=16, ctx=1024) is None
-    assert pref("TPU v4", batch=16, ctx=1024) == "jax"

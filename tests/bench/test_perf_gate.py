@@ -115,11 +115,11 @@ def test_unbaselined_metric_fails(tmp_path):
     _copy_pile(tmp_path)
     _edit_json(
         tmp_path / perfgate.BASELINE_NAME,
-        lambda d: d["metrics"].pop("kernel_perf.max_tflops"),
+        lambda d: d["metrics"].pop("prefetch_bench.prefetch_hits"),
     )
     findings = perfgate.check(tmp_path)
     assert [(f.kind, f.metric) for f in findings] == [
-        ("unbaselined", "kernel_perf.max_tflops")
+        ("unbaselined", "prefetch_bench.prefetch_hits")
     ]
 
 
@@ -140,9 +140,9 @@ def test_incompatible_provenance_is_refused_not_diffed(tmp_path):
 
 def test_missing_artifact_is_a_finding(tmp_path):
     _copy_pile(tmp_path)
-    (tmp_path / "KERNEL_PERF.json").unlink()
+    (tmp_path / "PREFETCH_BENCH.json").unlink()
     kinds = {(f.kind, f.metric) for f in perfgate.check(tmp_path)}
-    assert ("missing-artifact", "KERNEL_PERF.json") in kinds
+    assert ("missing-artifact", "PREFETCH_BENCH.json") in kinds
 
 
 def test_provenance_stamp_matches_gate_generation():

@@ -148,57 +148,101 @@ def test_warmup_uses_aot_when_cache_configured(tmp_path):
         _reset_cache()
 
 
-def test_ensure_compile_cache_resolution(tmp_path, monkeypatch):
-    """Default-on persistence knob chain: explicit jax config > DYN_COMPILE_
-    CACHE_DIR > ~/.cache default; empty string opts out.  Pure resolution —
-    no engine, no compiles."""
-    import jax
+def test_aot_precompile_lowers_on_the_calling_thread():
+    """Lowering is sequential, in a fixed order, on the caller's thread;
+    only compiles fan out.  A Pallas kernel's bytecode carries the source
+    lines of whichever kernel first traced the jitted jnp helpers they
+    share, so the order of first traces decides the cache keys: racing
+    threads made them random (seen on the chip as a warm restart compiling
+    the five programs its traffic needed)."""
+    import threading
 
-    from dynamo_tpu.engine.engine import _ensure_compile_cache
+    engine = JaxLlmEngine(
+        EngineConfig(
+            model=LlamaConfig.tiny(), num_blocks=32, block_size=4,
+            max_batch_size=2, prefill_buckets=(16,), max_model_len=32,
+        )
+    )
+    where = {"lower": set(), "order": [], "compile": 0}
 
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        # an explicitly configured dir always wins
-        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "explicit"))
-        monkeypatch.setenv("DYN_COMPILE_CACHE_DIR", str(tmp_path / "knob"))
-        assert _ensure_compile_cache() == str(tmp_path / "explicit")
+    class Program:
+        def compile(self):
+            where["compile"] += 1
 
-        # knob path: resolved, created, and installed
-        jax.config.update("jax_compilation_cache_dir", None)
-        assert _ensure_compile_cache() == str(tmp_path / "knob")
-        assert (tmp_path / "knob").is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "knob")
+    class Jit:
+        def lower(self, *avals):
+            where["lower"].add(threading.current_thread())
+            where["order"].append(avals[0])
+            return Program()
 
-        # empty string = explicit opt-out
-        jax.config.update("jax_compilation_cache_dir", None)
-        monkeypatch.setenv("DYN_COMPILE_CACHE_DIR", "")
-        assert _ensure_compile_cache() is None
-        assert not jax.config.jax_compilation_cache_dir
+    engine._aot_jobs = lambda lens: {("fake", i): (Jit(), (i,)) for i in range(6)}
+    assert engine.aot_precompile([8], parallel=4) == 6
+    assert where["lower"] == {threading.current_thread()}
+    assert where["order"] == list(range(6))
+    assert where["compile"] == 6
 
-        # unset -> per-user default under $HOME
-        monkeypatch.delenv("DYN_COMPILE_CACHE_DIR")
-        monkeypatch.setenv("HOME", str(tmp_path / "home"))
-        expected = str(tmp_path / "home" / ".cache" / "dynamo_tpu" / "jax_cache")
-        assert _ensure_compile_cache() == expected
-        assert os.path.isdir(expected)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
-        _reset_cache()
+
+def _resolve_in_child(env_value):
+    """Resolve the cache dir in a FRESH interpreter (JAX reads
+    JAX_COMPILATION_CACHE_DIR once, at import) with every
+    jax_compilation_cache_dir update recorded."""
+    import json
+    import subprocess
+    import sys
+
+    code = (
+        "import json, jax\n"
+        "updates = []\n"
+        "real = jax.config.update\n"
+        "def spy(name, value):\n"
+        "    updates.append(name)\n"
+        "    return real(name, value)\n"
+        "jax.config.update = spy\n"
+        "from dynamo_tpu.utils.compile_cache import ensure_compile_cache\n"
+        "path = ensure_compile_cache()\n"
+        "print(json.dumps({'path': path, 'updates': updates,\n"
+        "                  'configured': jax.config.jax_compilation_cache_dir}))\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=root, check=True,
+        capture_output=True, text=True, timeout=120,
+    )
+    return json.loads(out.stdout.strip().splitlines()[-1]), root
+
+
+def test_compile_cache_placed_from_outside(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is exactly there and the
+    program never calls jax.config.update("jax_compilation_cache_dir")."""
+    where = str(tmp_path / "from-outside")
+    got, _ = _resolve_in_child(where)
+    assert got["path"] == where and got["configured"] == where
+    assert "jax_compilation_cache_dir" not in got["updates"]
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    """Unset: <checkout>/.jax_cache — a fixed path (never under $HOME, a
+    temporary, or anything pid- or time-derived)."""
+    got, root = _resolve_in_child(None)
+    assert got["path"] == os.path.join(root, ".jax_cache")
+    assert got["configured"] == got["path"]
+    assert got["updates"].count("jax_compilation_cache_dir") == 1
 
 
 @pytest.mark.slow
 def test_second_engine_init_compiles_nothing_fresh(tmp_path, monkeypatch):
     """Restart survival: a SECOND engine init + warmup against a warm
-    DYN_COMPILE_CACHE_DIR (the knob, not an explicit jax config) performs
-    zero fresh compilations — every serving program is a persistent-cache
-    hit."""
+    cache directory performs zero fresh compilations — every serving
+    program is a persistent-cache hit."""
     import jax
 
     cache_dir = tmp_path / "jcache"
-    monkeypatch.setenv("DYN_COMPILE_CACHE_DIR", str(cache_dir))
     prev = jax.config.jax_compilation_cache_dir
     prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _reset_cache()
 
@@ -227,7 +271,7 @@ def test_second_engine_init_compiles_nothing_fresh(tmp_path, monkeypatch):
         }
 
     try:
-        # the engine ctor itself resolves the knob and installs the dir
+        # the engine ctor leaves an already-configured dir alone
         first = cold_start()
         assert jax.config.jax_compilation_cache_dir == str(cache_dir)
         assert _step_entries(cache_dir)

@@ -358,19 +358,20 @@ async def test_preemption_preserves_penalty_state():
         assert len(set(tokens)) == len(tokens)  # penalty still blocks repeats
 
 
-async def test_pallas_failure_falls_back_to_xla_attention():
-    """A Pallas attention kernel that cannot compile (Mosaic geometry
-    limits, remote-compile 500s) must degrade the engine to the portable
-    XLA attention path, not fail every in-flight sequence.  On CPU the
-    TPU pallas kernel always fails to lower, so forcing
-    ``attention_impl="pallas"`` exercises exactly that recovery."""
+async def test_pallas_failure_is_loud_not_a_quiet_xla_rebuild():
+    """A Pallas kernel the compiler refuses is a start-up failure, not a
+    mid-serve rebuild onto the XLA twin that then reports success: warmup
+    raises, a request fails with an error, and the engine still says
+    ``pallas``.  On CPU the TPU kernel never lowers, so forcing
+    ``attention_impl="pallas"`` exercises exactly that."""
     engine = make_engine(attention_impl="pallas")
     try:
-        prompt = [5, 6, 7, 8, 9, 10]
-        tokens, finish = await collect(engine, request(prompt, max_tokens=6))
-        assert engine.attention_impl == "jax"  # fallback happened
-        assert finish in (FinishReason.LENGTH, FinishReason.STOP)
-        assert tokens == greedy_reference(prompt, len(tokens))
+        with pytest.raises(Exception, match="(?i)pallas|mosaic|interpret|lower"):
+            await engine.warmup()
+        tokens, finish = await collect(engine, request([5, 6, 7, 8], max_tokens=4))
+        assert finish is FinishReason.ERROR and not tokens
+        assert engine.attention_impl == "pallas"
+        assert not hasattr(engine, "_attention_fallback")
     finally:
         engine.stop()
 
@@ -483,6 +484,7 @@ async def test_warmup_compiles_decode_at_max_len_bucket():
             traced["n"] += 1
             return orig(*a, **k)
 
+        counting.lower = orig.lower  # warmup AOT-compiles through .lower
         engine._jit_decode = counting
         await engine.warmup()
         assert traced["n"] >= 1  # decode ran (hence compiled) during warmup
@@ -646,91 +648,40 @@ async def test_single_device_mesh_offset_pins_device():
         engine.stop()
 
 
-def test_measured_attention_preference(monkeypatch, tmp_path):
-    """attention_impl=auto consults KERNEL_PERF.json: real-TPU tables
-    decide pallas-vs-jax by median measured speedup; interpret-mode and
-    foreign-platform tables are ignored."""
+def test_resident_arrays_sit_uncommitted_on_the_default_device():
+    """Params, cache, rope tables and penalty counts are built on the host
+    CPU backend and must end up on the DEFAULT device, uncommitted: an
+    array left on the CPU backend is uploaded again every step, and a
+    committed one marks its argument in every lowered module, so the
+    programs stop matching their AOT twins (both seen on the chip)."""
+    engine = make_engine()
+    leaves = jax.tree.leaves(
+        (engine.params, engine.cache, engine.cos, engine.sin,
+         engine._gen_counts, engine._prompt_counts)
+    )
+    assert leaves and all(not x.committed for x in leaves)
+    assert all(x.devices() == {jax.devices()[0]} for x in leaves)
+
+
+def test_no_table_routes_a_tpu_engine_off_the_kernel(monkeypatch, tmp_path):
+    """attention_impl=auto on a TPU IS the Pallas path.  A kernel-perf table
+    — however it claims the kernel loses, from whatever device — cannot
+    switch it off (it only ever feeds the ragged kernel's autotune rows)."""
     import json
 
-    from dynamo_tpu.engine.engine import _measured_attention_preference
-
-    def table(rows, platform="tpu", interpret=False):
-        p = tmp_path / "perf.json"
-        p.write_text(json.dumps(
-            {"platform": platform, "interpret": interpret, "rows": rows}
-        ))
-        monkeypatch.setenv("DYN_KERNEL_PERF", str(p))
-
-    row = lambda s: {"bench": "paged_attention_decode", "pallas_speedup": s}
-
-    table([row(1.4), row(2.1), row(0.9)])          # median 1.4 → pallas
-    assert _measured_attention_preference() == "pallas"
-    table([row(0.6), row(0.8), row(1.2)])          # median 0.8 → jax
-    assert _measured_attention_preference() == "jax"
-    table([row(2.0)], interpret=True)              # interpret → ignored
-    assert _measured_attention_preference() is None
-    table([row(2.0)], platform="cpu")              # wrong platform → ignored
-    assert _measured_attention_preference() is None
-    table([])                                      # no attention rows
-    assert _measured_attention_preference() is None
-    monkeypatch.setenv("DYN_KERNEL_PERF", str(tmp_path / "absent.json"))
-    assert _measured_attention_preference() is None
-
-
-def test_measured_attention_preference_robust(monkeypatch, tmp_path):
-    """The perf table is advisory: malformed content, wrong device kind,
-    and even-length row sets must never crash or mis-decide."""
-    import json
-
-    from dynamo_tpu.engine.engine import _measured_attention_preference
-
-    def table(rows, **extra):
-        p = tmp_path / "perf.json"
-        p.write_text(json.dumps({"platform": "tpu", "interpret": False,
-                                 "rows": rows, **extra}))
-        monkeypatch.setenv("DYN_KERNEL_PERF", str(p))
-
-    row = lambda s: {"bench": "paged_attention_decode", "pallas_speedup": s}
-
-    # true median on even-length lists: [0.4, 0.6, 1.05, 1.1] → 0.825 → jax
-    table([row(0.4), row(1.05), row(1.1), row(0.6)])
-    assert _measured_attention_preference() == "jax"
-    # malformed values degrade to None, never crash
-    table([row("not-a-number")])
-    assert _measured_attention_preference() is None
-    (tmp_path / "perf.json").write_text("[1, 2, 3]")  # not even a dict
-    assert _measured_attention_preference() is None
-    # different TPU generation → ignored when current kind is known
-    table([row(2.0)], device_kind="TPU v4")
-    assert _measured_attention_preference("TPU v5e") is None
-    assert _measured_attention_preference("TPU v4") == "pallas"
-    assert _measured_attention_preference() == "pallas"  # kind unknown: accept
-    # calibration gate: a table whose own known-FLOPs/known-bytes rows
-    # exceeded device peaks recorded calib_ok=false — nothing in it is
-    # trustworthy (calib_ok absent or true: accepted as before)
-    table([row(2.0)], calib_ok=False)
-    assert _measured_attention_preference() is None
-    table([row(2.0)], calib_ok=True)
-    assert _measured_attention_preference() == "pallas"
-    table([row(2.0)], calib_ok=None)
-    assert _measured_attention_preference() == "pallas"
-
-
-def test_host_bounce_cross_backend():
-    """device_put of a cross-backend jax.Array re-stages per execution on
-    some PJRT runtimes; host_bounce converts exactly those leaves."""
-    import jax
-    import numpy as np
-
-    from dynamo_tpu.parallel.mesh import host_bounce
-
-    cpu_arr = jax.numpy.zeros((4,), jax.numpy.int32)  # tests run on cpu
-    out = host_bounce(cpu_arr, "tpu")  # foreign target → ndarray
-    assert isinstance(out, np.ndarray)
-    same = host_bounce(cpu_arr, "cpu")  # same backend → untouched
-    assert same is cpu_arr
-    nd = np.zeros((4,), np.int32)  # plain ndarrays always pass through
-    assert host_bounce(nd, "tpu") is nd
+    table = tmp_path / "perf.json"
+    table.write_text(json.dumps({
+        "platform": "tpu", "interpret": False, "device_kind": "TPU v5 lite",
+        "rows": [{"bench": "paged_attention_decode", "batch": 4, "ctx": 128,
+                  "pallas_speedup": 0.5}],
+    }))
+    monkeypatch.setenv("DYN_KERNEL_PERF", str(table))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engine = make_engine()
+    assert engine.attention_impl == "pallas"
+    assert engine.stats()["attention_impl"] == "pallas"
+    monkeypatch.undo()
+    assert make_engine().attention_impl == "jax"  # the CPU backend's path
 
 
 async def test_sampling_tail_upload_cache():

@@ -3,6 +3,7 @@ geometry, rolling MFU/MBU/goodput math, and the engine integration — after a
 real generate, stats() must carry nonzero utilization and token totals."""
 
 import jax
+import pytest
 
 from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.mixtral import MixtralConfig
@@ -120,11 +121,35 @@ def test_detect_peaks_env_override(monkeypatch):
     assert bw == 456e9
 
 
-async def test_engine_stats_export_utilization():
+def test_unknown_device_kind_has_no_peak(monkeypatch):
+    """One table keyed by device_kind: a kind missing from it raises in the
+    device-metric path, and the always-on tracker reports "unknown" (None)
+    instead of a utilization against an assumed peak."""
+    from dynamo_tpu.observability.perf import DEVICE_PEAKS, device_peaks
+
+    assert device_peaks("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(LookupError, match="TPU v9 imaginary"):
+        device_peaks("TPU v9 imaginary")
+    assert "cpu" not in DEVICE_PEAKS
+    monkeypatch.delenv("DYN_PEAK_TFLOPS", raising=False)
+    monkeypatch.delenv("DYN_PEAK_GBPS", raising=False)
+    assert detect_peaks() is None  # tests run on the CPU backend
+    t = UtilizationTracker(ModelCost(10, 20, 20, 4, 8), window_s=10.0)
+    t.observe_step(duration_s=0.1, decode_tokens=4, attn_ctx_tokens=8,
+                   weight_streams=1.0, emitted_tokens=4, now=1.0)
+    r = t.rates(now=1.0)
+    assert r["mfu_perc"] is None and r["bandwidth_util_perc"] is None
+    assert r["goodput_tokens_per_second"] > 0.0
+
+
+async def test_engine_stats_export_utilization(monkeypatch):
     """End to end on a real tiny engine: a generate must leave nonzero
     token totals, rolling rates, and the wasted-work counters in stats()."""
     from tests.engine.test_jax_engine import collect, make_engine, request
 
+    # the CPU backend has no published peak; name one like an operator would
+    monkeypatch.setenv("DYN_PEAK_TFLOPS", "0.5")
+    monkeypatch.setenv("DYN_PEAK_GBPS", "50")
     engine = make_engine()
     try:
         tokens, _finish = await collect(engine, request([2, 3, 4, 5], max_tokens=4))

@@ -1,0 +1,185 @@
+"""Ask the TPU's compiler, with no TPU: the main path's Pallas kernels at
+real widths, compiled for a DESCRIBED v5e chip (on-chip-measurement guide,
+section 2).  Interpret mode cannot see what Mosaic refuses — scalar-memory
+footprints, tilings of 1-byte types, VMEM scratch — and these cases are what
+keeps `chip_smoke.py` from finding it out on the chip.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every xdist worker imports this file.
+Everything lives in this one file so one worker owns the library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.engine import EngineConfig, JaxLlmEngine
+from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.ops.pallas.block_copy import gather_blocks, scatter_blocks
+from dynamo_tpu.ops.pallas.paged_attention import (
+    paged_attention_decode,
+    paged_window_attention_decode,
+)
+from dynamo_tpu.ops.pallas.ragged_attention import (
+    ragged_paged_attention,
+    worklist_rows_per_call,
+)
+
+# head geometries: what chip_smoke.py serves on one chip (Llama-3.2-3B) and
+# the repo's headline (Llama-3-8B; also one tp=4 shard's KV width times 4)
+GEOMETRY = {"llama32_3b": (24, 8, 128), "llama3_8b": (32, 8, 128)}
+KV_DTYPES = {"bf16": jnp.bfloat16, "fp8": jnp.float8_e4m3fn}
+BLOCK = 16
+NUM_BLOCKS = 1024
+MAX_LEN = 4096                      # chip_smoke.py --context-length
+MAX_BLOCKS = MAX_LEN // BLOCK
+LANES = 8                           # chip_smoke.py --max-batch-size
+# the engine's unified kernel config for that EngineConfig (tb = gcd(16, 8),
+# full worklist width tb * max_blocks_per_seq, one page per grid step) and
+# every unified bucket it builds: the default prefill buckets up to max_len
+TB, PAGE_SLOTS, PAGES_PER_STEP = 8, 8 * MAX_BLOCKS, 1
+SMOKE_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import compilation_cache, topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable can be written to the persistent cache
+    # but not read back without a chip: keep these compiles out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.compilation_cache.reset_cache()
+
+
+def _sds(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cache(s, kv_dtype, kvh, d):
+    return s((NUM_BLOCKS, BLOCK, kvh, d), KV_DTYPES[kv_dtype])
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
+@pytest.mark.parametrize("model", ["llama32_3b", "llama3_8b"])
+def test_paged_decode_compiles(one_chip, model, kv_dtype):
+    h, kvh, d = GEOMETRY[model]
+    s = _sds(one_chip)
+    cache = _cache(s, kv_dtype, kvh, d)
+    paged_attention_decode.lower(
+        s((LANES, h, d), jnp.bfloat16), cache, cache,
+        s((LANES, MAX_BLOCKS), jnp.int32), s((LANES,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
+def test_window_verify_compiles(one_chip, kv_dtype):
+    h, kvh, d = GEOMETRY["llama3_8b"]
+    s = _sds(one_chip)
+    cache = _cache(s, kv_dtype, kvh, d)
+    paged_window_attention_decode.lower(
+        s((LANES, 5, h, d), jnp.bfloat16), cache, cache,  # spec_tokens + 1
+        s((LANES, MAX_BLOCKS), jnp.int32), s((LANES,), jnp.int32),
+    ).compile()
+
+
+def _ragged_case(s, bucket, model, kv_dtype):
+    h, kvh, d = GEOMETRY[model]
+    cache = _cache(s, kv_dtype, kvh, d)
+    ntb = bucket // TB
+    worklist = s((ntb, PAGE_SLOTS), jnp.int32)
+    tok = s((bucket,), jnp.int32)
+    return ragged_paged_attention.lower(
+        s((bucket, h, d), jnp.bfloat16), cache, cache, tok, tok,
+        worklist, worklist, worklist, s((ntb,), jnp.int32),
+        tb_tokens=TB, pages_per_step=PAGES_PER_STEP,
+    )
+
+
+@pytest.mark.parametrize("bucket", SMOKE_BUCKETS)
+def test_ragged_compiles_at_every_smoke_bucket(one_chip, bucket):
+    """Every (bucket, tb_tokens, page_slots, pages_per_step) the smoke's
+    engine can build.  Before the per-launch worklist tiling, every bucket
+    from 512 up was refused: 'Ran out of memory in memory space smem'."""
+    _ragged_case(_sds(one_chip), bucket, "llama32_3b", "bf16").compile()
+
+
+@pytest.mark.parametrize("bucket", [SMOKE_BUCKETS[0], 528, SMOKE_BUCKETS[-1]])
+def test_ragged_fp8_compiles(one_chip, bucket):
+    """fp8 KV at the headline geometry: smallest, a chunk+lanes mixed bucket
+    (512 + 16 lanes — 66 token blocks, not a multiple of the launch size),
+    largest."""
+    _ragged_case(_sds(one_chip), bucket, "llama3_8b", "fp8").compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
+def test_block_gather_compiles(one_chip, kv_dtype):
+    s = _sds(one_chip)
+    gather_blocks.lower(
+        _cache(s, kv_dtype, 8, 128), s((32,), jnp.int32)
+    ).compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
+def test_block_scatter_compiles(one_chip, kv_dtype):
+    s = _sds(one_chip)
+    scatter_blocks.lower(
+        _cache(s, kv_dtype, 8, 128),
+        s((32, BLOCK, 8, 128), KV_DTYPES[kv_dtype]), s((32,), jnp.int32),
+    ).compile()
+
+
+def test_kernel_bytes_do_not_depend_on_the_call_stack(one_chip):
+    """A program's persistent-cache key covers the Pallas kernel's bytecode,
+    locations included.  Once the cache resolver ran, lowering the same
+    kernel from two different Python call stacks gives identical bytes — so
+    the AOT-compiled twin IS the program the device thread dispatches, and
+    a restart finds it whoever traced first.  (With JAX's default full
+    tracebacks the two differ; seen on the chip as every step program
+    compiling twice.)"""
+    from dynamo_tpu.utils.compile_cache import ensure_compile_cache
+
+    prev_dir = jax.config.jax_compilation_cache_dir
+    ensure_compile_cache()
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+
+    def text():
+        jax.clear_caches()  # a fresh trace each time, like a fresh process
+        lowered = _ragged_case(_sds(one_chip), 64, "llama32_3b", "bf16")
+        return lowered.compiler_ir().operation.get_asm(enable_debug_info=False)
+
+    def from_a_deeper_stack():
+        return (lambda: text())()
+
+    assert text() == from_a_deeper_stack()
+
+
+def test_smoke_worklist_fits_one_launch_of_32_blocks():
+    """The bound the kernel wrapper and the engine share, at the smoke's
+    width: 3 worklists x 32 rows x 2048 slots x 4 B = 768 KiB of the 1 MiB."""
+    assert worklist_rows_per_call(PAGE_SLOTS, TB) == 32
+
+
+def test_engine_rejects_worklists_beyond_scalar_memory():
+    """A context so long that ONE row tile of the full-width worklist
+    (tb_tokens * max_blocks_per_seq slots) exceeds scalar memory is a
+    construction error computed from shapes — not a compiler refusal at the
+    first long prompt."""
+    import dataclasses
+
+    cfg = dataclasses.replace(LlamaConfig.tiny(), max_position_embeddings=32768)
+    common = dict(model=cfg, block_size=16, max_batch_size=2,
+                  attention_impl="pallas_interpret")
+    with pytest.raises(ValueError, match="scalar memory"):
+        JaxLlmEngine(EngineConfig(num_blocks=2048, max_model_len=32768, **common))
+    # the same engine at a context whose worklists fit constructs fine
+    engine = JaxLlmEngine(EngineConfig(num_blocks=256, max_model_len=4096, **common))
+    assert engine.stats()["kernel_config"]["blocks_per_launch"] == 32

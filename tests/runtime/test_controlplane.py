@@ -146,6 +146,33 @@ async def test_lease_ttl_expiry_without_keepalive():
     assert lease.revoked
 
 
+async def test_lease_survives_an_event_loop_stall():
+    """In-process plane: holder keep-alive and reaper share one loop, so
+    time the loop did not run must not count against a lease (on the chip,
+    warmup at real widths starved the loop past the 3 s instance lease and
+    the worker silently lost its only instance).  A lease nobody renews
+    still lapses."""
+    import time
+
+    plane = MemoryControlPlane()
+    lease = await plane.kv.grant_lease(0.6)
+    await plane.kv.put("inst/3", b"alive", lease_id=lease.id)
+
+    async def keepalive():
+        while not lease.revoked:
+            await asyncio.sleep(0.2)
+            await plane.kv.keep_alive(lease)
+
+    task = asyncio.ensure_future(keepalive())
+    await asyncio.sleep(0.3)
+    time.sleep(1.5)  # the whole loop stalls for 2.5x the TTL
+    await asyncio.sleep(0.5)
+    assert (await plane.kv.get("inst/3")).value == b"alive" and not lease.revoked
+    task.cancel()
+    await asyncio.sleep(1.2)  # holder gone: the lease lapses as before
+    assert await plane.kv.get("inst/3") is None and lease.revoked
+
+
 async def test_bus_pubsub_and_queue_groups(plane_factory):
     plane, server = await make_plane(plane_factory)
     try:

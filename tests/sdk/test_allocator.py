@@ -83,8 +83,13 @@ def test_plan_skips_when_disabled_or_no_chips(monkeypatch):
     monkeypatch.setenv("DYN_DISABLE_AUTO_TPU_ALLOCATION", "1")
     assert plan_resource_envs([W], inventory=ChipInventory(chips=(0,))) == {}
     monkeypatch.delenv("DYN_DISABLE_AUTO_TPU_ALLOCATION")
-    # no chips visible: warn-and-skip, never fail the deployment plan
-    assert plan_resource_envs([W], inventory=ChipInventory(chips=())) == {}
+    # no chip count known: a CPU-only host plans nothing...
+    empty = ChipInventory(chips=())
+    assert plan_resource_envs([W], inventory=empty, env={"JAX_PLATFORMS": "cpu"}) == {}
+    # ...but where a chip might be, replicas must not be left to claim
+    # every chip each (the second would hang): fail at plan time
+    with pytest.raises(ResourceError, match="DYN_TPU_CHIP_COUNT"):
+        plan_resource_envs([W], inventory=empty, env={})
 
 
 def test_inventory_detect_prefers_visible_chips_env():
