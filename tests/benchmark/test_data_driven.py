@@ -1,0 +1,110 @@
+"""A configuration, a mix, a cell and a per-layer metric are each added as
+new files plus one entry: nothing that is there is edited."""
+
+import json
+from pathlib import Path
+
+from benchmark import run as runner
+from benchmark import shapes
+
+from .helpers import tiny_bench
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+def test_a_new_cell_configuration_mix_and_metric_are_found_by_name(tmp_path):
+    bench_path = tiny_bench(tmp_path)
+    bench = json.loads(bench_path.read_text())
+    # one more mix, one more cell on it, one more metric: files + entries
+    mix = json.loads((tmp_path / "traffic" / "tinychat.json").read_text())
+    mix["prompt_tokens"] = {"dist": "constant", "value": 17}
+    (tmp_path / "traffic" / "fixed17.json").write_text(json.dumps(mix))
+    (tmp_path / "cells" / "tiny.fixed17.json").write_text(json.dumps(
+        {"rate_rps": 7.5, "serving_args": ["--max-batch-size", 2]}))
+    bench["workloads"].append({"name": "tiny.fixed17", "config": "tiny", "traffic": "fixed17",
+                               "chips": 1, "why": "test"})
+    bench["per_layer"].append({"name": "preemptions.new", "unit": "count", "better": "lower",
+                               "source": "program_counter", "layer": "engine scheduler + KV manager",
+                               "moves": "itl_p95_ms", "workloads": ["tiny.fixed17"]})
+    (tmp_path / "metrics" / "preemptions.new.json").write_text(json.dumps(
+        {"reader": "counter_delta", "args": {"counter": "num_preemptions_total"}}))
+
+    loaded = runner.load_cell(bench, "tiny.fixed17", tmp_path)
+    assert loaded["rate"] == 7.5 and loaded["config"]["hidden_size"] == 64
+    assert loaded["own"]["serving_args"] == ["--max-batch-size", 2]
+    from benchmark import traffic
+
+    plan = traffic.schedule(loaded["mix"], loaded["rate"], 4.0)
+    assert {r["prompt_len"] for r in plan} == {17}
+
+    ctx = {"records": [], "seconds": 4.0, "e2e": {}, "hf": {},
+           "stats0": {"stats": {"num_preemptions_total": 2, "compiles_total": 5}},
+           "stats1": {"stats": {"num_preemptions_total": 5, "compiles_total": 5}}}
+    new = runner.read_per_layer(bench, "tiny.fixed17", ctx, tmp_path)
+    assert new["preemptions.new"] == {"value": 3.0, "unit": "count"}
+    old = runner.read_per_layer(bench, "tiny.tinychat", ctx, tmp_path)
+    assert "preemptions.new" not in old and old["window_compiles.chat"]["value"] == 0.0
+
+
+def test_the_real_cells_load_and_size_as_their_files_say():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for w in bench["workloads"]:
+        loaded = runner.load_cell(bench, w["name"])
+        hf = runner.hf_config(loaded["config"])
+        serving = loaded["config"]["serving"]
+        blocks = serving["args"][serving["args"].index("--num-blocks") + 1]
+        assert blocks * 16 == serving["kv_tokens"]
+        assert serving["kv_tokens"] * shapes.kv_bytes_per_token(hf) == serving["kv_bytes"]
+        # weights alone pass a quarter of the chip's 16 GB; weights + two
+        # copies of the cache stay under it
+        assert shapes.weight_bytes(hf) > 4e9
+        assert 2 * shapes.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
+
+
+def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path):
+    """The offered rate is written in one place, the cell's own file: no
+    mix carries one and nothing falls back to a default."""
+    import pytest
+
+    bench_path = tiny_bench(tmp_path)
+    bench = json.loads(bench_path.read_text())
+    assert runner.load_cell(bench, "tiny.tinychat", tmp_path)["rate"] == 3.0
+    (tmp_path / "cells" / "tiny.tinychat.json").write_text(json.dumps({}))
+    with pytest.raises(SystemExit):
+        runner.load_cell(bench, "tiny.tinychat", tmp_path)
+    (tmp_path / "cells" / "tiny.tinychat.json").unlink()
+    with pytest.raises(SystemExit):
+        runner.load_cell(bench, "tiny.tinychat", tmp_path)
+    for mix in (ROOT / "benchmark" / "traffic").glob("*.json"):
+        assert "rate_rps" not in json.loads(mix.read_text()), mix
+
+
+def test_the_command_line_offers_no_other_load_or_serving():
+    import pytest
+
+    for extra in (["--rate", "9"], ["--server-arg", "--quantize"], ["--control", "int8"]):
+        with pytest.raises(SystemExit):
+            runner.parse(["--workload", "x", "--seed", "1", "--seconds", "1", *extra])
+
+
+def test_an_unknown_cell_is_an_error():
+    import pytest
+
+    with pytest.raises(SystemExit):
+        runner.load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()), "no.such-cell")
+
+
+def test_shapes_match_the_published_sizes():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    sizes = {}
+    for c in bench["configs"]:
+        hf = runner.hf_config(json.loads((ROOT / c["file"]).read_text()))
+        sizes[c["name"]] = (shapes.total_params(hf), shapes.kv_bytes_per_token(hf))
+    assert abs(sizes["qwen3-4b"][0] - 4.02e9) < 0.01e9 and sizes["qwen3-4b"][1] == 147456
+    assert abs(sizes["mistral-7b-l16"][0] - 3.75e9) < 0.01e9
+    assert sizes["mistral-7b-l16"][1] == 65536
+    import pytest
+
+    with pytest.raises(KeyError):
+        shapes.load_peaks("cpu")
+    assert shapes.load_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
