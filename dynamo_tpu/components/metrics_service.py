@@ -156,13 +156,12 @@ class MetricsService:
             "Tokens computed that bought nothing a client received (cumulative)",
             ["worker"], registry=self.registry,
         )
-        # engine phase timing (DYN_ENGINE_PHASE_TIMING=1): cumulative wall
-        # seconds per decode/prefill phase — makes the overlap/sync pipeline
-        # difference (decode.retire vs decode.readback) visible in /metrics
+        # engine host-phase accounting (always on): cumulative wall seconds
+        # per step-loop phase (schedule/pack/upload/dispatch/readback/post;
+        # readback = host blocked on the device)
         self.phase_seconds = Gauge(
             f"{PREFIX}_engine_phase_seconds",
-            "Cumulative engine wall seconds per hot-loop phase "
-            "(DYN_ENGINE_PHASE_TIMING=1)",
+            "Cumulative engine wall seconds per step-loop host phase",
             ["worker", "phase"], registry=self.registry,
         )
         # predictive prefetch (prefetch/pager.py via engine stats):

@@ -18,7 +18,6 @@ Architecture:
 from __future__ import annotations
 
 import asyncio
-import contextlib
 import os
 import queue as thread_queue
 import threading
@@ -51,6 +50,7 @@ from dynamo_tpu.models.llama import LlamaConfig
 from dynamo_tpu.models.registry import get_family
 from dynamo_tpu.observability import FlightRecorder, StepTelemetry, get_recorder
 from dynamo_tpu.observability.perf import UtilizationTracker, model_cost
+from dynamo_tpu.observability.step_metrics import KIND_DECODE, KIND_PROMPT, StepRecord
 from dynamo_tpu.robustness.faults import ENGINE_STEP, FAULTS
 from dynamo_tpu.ops.sampling import (
     apply_logit_bias,
@@ -67,6 +67,60 @@ from dynamo_tpu.utils import knobs
 from dynamo_tpu.utils.compile_cache import compile_counts, ensure_compile_cache
 
 logger = get_logger("engine")
+
+# Host phases of one served window, in order; every branch of the step loop
+# books its time under these names (stats()["phase_ms"], "dyn.<phase>" in a
+# profiler trace).
+STEP_PHASES = ("schedule", "pack", "upload", "dispatch", "readback", "post")
+
+# Attention-kernel work counters (cumulative, in stats() from engine start).
+KERNEL_WORK_KEYS = (
+    "ragged_live_pages_total",      # worklist entries with a live page
+    "ragged_page_slots_total",      # grid steps launched: token blocks x page slots
+    "ragged_attn_flops_total",      # QK^T + attention·V over the attended context, all layers
+    "ragged_kv_read_bytes_total",   # whole pages walked x page bytes, all layers
+    "decode_attn_flops_total",
+    "decode_kv_read_bytes_total",
+)
+
+
+def _named(fn, name: str):
+    """``fn`` under the function name a profiler trace (``jit_<name>``) and
+    the compile log will show."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+class _ProgramsByBucket:
+    """``fn`` jitted once per token bucket, each copy under the function
+    name ``<prefix><bucket>``: a profiler trace (and the compile log) then
+    names a program by what it is and its shape, ``jit_dyn_unified_t256``,
+    not ``jit_step``.  Same programs as one shape-polymorphic jit would
+    compile; only the names differ."""
+
+    def __init__(self, fn, prefix: str, token_arg: int, **jit_kwargs):
+        self.__wrapped__ = fn
+        self._prefix = prefix
+        self._token_arg = token_arg
+        self._jit_kwargs = jit_kwargs
+        self._programs: dict[int, Callable] = {}
+
+    def _program(self, args):
+        bucket = args[self._token_arg].shape[0]
+        program = self._programs.get(bucket)
+        if program is None:
+            fn = self.__wrapped__
+            program = self._programs[bucket] = jax.jit(
+                _named(lambda *a: fn(*a), f"{self._prefix}{bucket}"),
+                **self._jit_kwargs,
+            )
+        return program
+
+    def __call__(self, *args):
+        return self._program(args)(*args)
+
+    def lower(self, *args):
+        return self._program(args).lower(*args)
 
 
 def _round_chunk_tokens(chunk_tokens: int, block_size: int) -> int:
@@ -242,6 +296,12 @@ class _InflightWindow:
     active: list              # sequences RUNNING at dispatch, lane order
     lane_ids: list            # their lanes (composition fingerprint)
     steps: int
+    # "prompt" when the window carried a prompt token, else "decode": the
+    # iteration that waits for this window books its time under this kind
+    kind: str = KIND_DECODE
+    # open ``engine.prefill`` spans (``_open_prefill_span``) of the prefill
+    # windows this window's readback covers: closed when it retires
+    prefills: list = field(default_factory=list)
     # sequences whose finish was detected while THIS window was in flight:
     # emitted already, but their lane/blocks are only released when this
     # window retires (a lagged device step may still write into them)
@@ -489,12 +549,19 @@ class JaxLlmEngine:
         self._host_rng = np.random.Generator(np.random.PCG64(config.seed))
         self._lane_keys = np.zeros((lanes, 2), np.uint32)
 
-        # Decode hot-loop phase accounting (DYN_ENGINE_PHASE_TIMING=1):
-        # wall seconds + counts per phase, surfaced via stats() — upload/
-        # dispatch/readback must be separable from device compute to tune
-        # anything.
-        self._phase_timing = knobs.get("DYN_ENGINE_PHASE_TIMING")
-        self.phase_stats: dict[str, list[float]] = {}
+        # Host-phase accounting, always on: wall seconds + counts per phase
+        # of the step loop, surfaced via stats()["phase_ms"] and mirrored as
+        # jax.profiler TraceAnnotations ("dyn.<phase>") so a device trace
+        # shows them on the profiler's clock.  One set of names for every
+        # branch that serves a window; `readback` is the host blocked on
+        # the device.  Two perf_counter reads and one TraceMe a phase.
+        self.phase_stats: dict[str, list[float]] = {
+            name: [0.0, 0] for name in STEP_PHASES
+        }
+        self._sliding_window = getattr(cfg, "sliding_window", None)
+        self._phase_name: str | None = None   # the open phase
+        self._phase_t0 = 0.0
+        self._phase_ann = None
         # Step telemetry: batch occupancy / queue depth / KV pool usage per
         # scheduler iteration, merged into stats() → load-metrics publisher
         # → dyn_worker_* Prometheus gauges (observability.step_metrics).
@@ -518,10 +585,18 @@ class JaxLlmEngine:
         self._step_decode_tokens = 0
         self._step_attn_ctx = 0         # sum of attended context positions
         self._step_weight_streams = 0.0 # full weight passes dispatched
-        # DYN_XPROF_ANNOTATE=1: wrap hot steps in jax.profiler
-        # TraceAnnotation so host-side spans line up with xprof device
-        # traces (adds a TraceMe per step — keep off unless profiling)
-        self._xprof_annotate = knobs.get("DYN_XPROF_ANNOTATE")
+        self._step_lane_steps = 0       # decode lanes x device steps dispatched
+        self._step_readback_s = 0.0     # host blocked on the device
+        # kind of the window this iteration waited for / dispatched (the
+        # iteration's time is booked to the first that is set)
+        self._step_waited_kind: str | None = None
+        self._step_dispatched_kind: str | None = None
+        # open engine.prefill spans of prompt work dispatched that nothing
+        # waits for; the next window dispatched inherits them (_take_unwaited)
+        self._unwaited_prefills: list = []
+        # Attention-kernel work, counted where the worklists are built
+        # (cumulative; stats() carries every key from engine start)
+        self._kernel_work = dict.fromkeys(KERNEL_WORK_KEYS, 0)
         # DYN_PROFILER_TRACE_DIR: set when start() opened a device trace
         self._profiler_trace_dir: str | None = None
         # Sampling-tail upload cache: the per-window device copies of the
@@ -1049,17 +1124,18 @@ class JaxLlmEngine:
             prompt_row = full_row - gen_row
             prompt_counts = prompt_counts.at[lane].set(prompt_row)
             gen_counts = gen_counts.at[lane].set(gen_row)
-            plogits = apply_penalties(
-                logits[None], gen_row[None], prompt_row[None], pres, freq, rep
-            )
-            plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
-            # guided decoding: inadmissible tokens → -inf (all-true row for
-            # unguided sequences)
-            plogits = jnp.where(grow[None], plogits, -jnp.inf)
-            step_key = jax.random.fold_in(key, seq_len)
-            token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
-            lp = token_logprobs(plogits, token[None])[0]
-            tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
+            with jax.named_scope("sample"):
+                plogits = apply_penalties(
+                    logits[None], gen_row[None], prompt_row[None], pres, freq, rep
+                )
+                plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
+                # guided decoding: inadmissible tokens → -inf (all-true row for
+                # unguided sequences)
+                plogits = jnp.where(grow[None], plogits, -jnp.inf)
+                step_key = jax.random.fold_in(key, seq_len)
+                token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
+                lp = token_logprobs(plogits, token[None])[0]
+                tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
             gen_counts = gen_counts.at[lane, token].add(1)
             return token, lp, tk_vals[0], tk_ids[0], cache, gen_counts, prompt_counts
 
@@ -1069,7 +1145,9 @@ class JaxLlmEngine:
 
             repl = NamedSharding(self.mesh, PartitionSpec())
             kwargs["out_shardings"] = (repl, repl, repl, repl, self._cache_sharding, repl, repl)
-        return jax.jit(step, donate_argnums=(1, 2, 3), **kwargs)
+        return _ProgramsByBucket(
+            step, "dyn_prefill_t", 5, donate_argnums=(1, 2, 3), **kwargs
+        )
 
     def _build_prefill_prefix(self):
         """Continued prefill over a resident prefix (prefix-cache hit or a
@@ -1100,15 +1178,16 @@ class JaxLlmEngine:
             )
             prompt_counts = prompt_counts.at[lane].set(prompt_row)
             gen_counts = gen_counts.at[lane].set(gen_row)
-            plogits = apply_penalties(
-                logits[None], gen_row[None], prompt_row[None], pres, freq, rep
-            )
-            plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
-            plogits = jnp.where(grow[None], plogits, -jnp.inf)
-            step_key = jax.random.fold_in(key, total_len)
-            token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
-            lp = token_logprobs(plogits, token[None])[0]
-            tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
+            with jax.named_scope("sample"):
+                plogits = apply_penalties(
+                    logits[None], gen_row[None], prompt_row[None], pres, freq, rep
+                )
+                plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
+                plogits = jnp.where(grow[None], plogits, -jnp.inf)
+                step_key = jax.random.fold_in(key, total_len)
+                token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
+                lp = token_logprobs(plogits, token[None])[0]
+                tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
             # sample_gate=0 for non-final chunks of a chunked prefill: the
             # logits are discarded and no generated count is recorded
             gen_counts = gen_counts.at[lane, token].add(sample_gate)
@@ -1120,7 +1199,9 @@ class JaxLlmEngine:
 
             repl = NamedSharding(self.mesh, PartitionSpec())
             kwargs["out_shardings"] = (repl, repl, repl, repl, self._cache_sharding, repl, repl)
-        return jax.jit(step, donate_argnums=(1, 2, 3), **kwargs)
+        return _ProgramsByBucket(
+            step, "dyn_prefill_prefix_t", 5, donate_argnums=(1, 2, 3), **kwargs
+        )
 
     def _build_prefill_mm(self):
         """Multimodal prefill: input embeddings are vision patch embeddings
@@ -1155,15 +1236,16 @@ class JaxLlmEngine:
             prompt_row = full_row - gen_row
             prompt_counts = prompt_counts.at[lane].set(prompt_row)
             gen_counts = gen_counts.at[lane].set(gen_row)
-            plogits = apply_penalties(
-                logits[None], gen_row[None], prompt_row[None], pres, freq, rep
-            )
-            plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
-            plogits = jnp.where(grow[None], plogits, -jnp.inf)
-            step_key = jax.random.fold_in(key, seq_len)
-            token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
-            lp = token_logprobs(plogits, token[None])[0]
-            tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
+            with jax.named_scope("sample"):
+                plogits = apply_penalties(
+                    logits[None], gen_row[None], prompt_row[None], pres, freq, rep
+                )
+                plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
+                plogits = jnp.where(grow[None], plogits, -jnp.inf)
+                step_key = jax.random.fold_in(key, seq_len)
+                token = sample_tokens(plogits, step_key[None], temp, top_k, top_p, greedy)[0]
+                lp = token_logprobs(plogits, token[None])[0]
+                tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
             gen_counts = gen_counts.at[lane, token].add(1)
             return token, lp, tk_vals[0], tk_ids[0], cache, gen_counts, prompt_counts
 
@@ -1173,7 +1255,7 @@ class JaxLlmEngine:
 
             repl = NamedSharding(self.mesh, PartitionSpec())
             kwargs["out_shardings"] = (repl, repl, repl, repl, self._cache_sharding, repl, repl)
-        return jax.jit(step, donate_argnums=(1, 2, 3), **kwargs)
+        return jax.jit(_named(step, "dyn_prefill_mm"), donate_argnums=(1, 2, 3), **kwargs)
 
     def _build_decode(self):
         cfg = self.config.model
@@ -1231,23 +1313,24 @@ class JaxLlmEngine:
                     params, cache, token_ids, block_tables, context_lens,
                     slot_ids, cos, sin,
                 )
-                logits = apply_penalties(logits, gen_counts, prompt_counts, pres, freq, rep)
-                logits = apply_logit_bias(logits, bias_ids, bias_vals)
-                # guided decoding: each lane's mode id selects its
-                # admissible-token row from the resident table; mode -1 =
-                # unguided (all tokens allowed)
-                rows = gtable[jnp.clip(gmodes, 0, gtable.shape[0] - 1)]
-                allowed = jnp.where((gmodes < 0)[:, None], True, rows)
-                logits = jnp.where(allowed, logits, -jnp.inf)
-                step_keys = jax.vmap(jax.random.fold_in)(keys, context_lens)
-                tokens = sample_tokens(logits, step_keys, temp, top_k, top_p, greedy)
-                lps = token_logprobs(logits, tokens)
-                tk_vals, tk_ids = topk_logprobs(logits, topk_k)
+                with jax.named_scope("sample"):
+                    logits = apply_penalties(logits, gen_counts, prompt_counts, pres, freq, rep)
+                    logits = apply_logit_bias(logits, bias_ids, bias_vals)
+                    # guided decoding: each lane's mode id selects its
+                    # admissible-token row from the resident table; mode -1 =
+                    # unguided (all tokens allowed)
+                    rows = gtable[jnp.clip(gmodes, 0, gtable.shape[0] - 1)]
+                    allowed = jnp.where((gmodes < 0)[:, None], True, rows)
+                    logits = jnp.where(allowed, logits, -jnp.inf)
+                    step_keys = jax.vmap(jax.random.fold_in)(keys, context_lens)
+                    tokens = sample_tokens(logits, step_keys, temp, top_k, top_p, greedy)
+                    lps = token_logprobs(logits, tokens)
+                    tk_vals, tk_ids = topk_logprobs(logits, topk_k)
                 active = (context_lens > 0).astype(jnp.int32)
                 gen_counts = gen_counts.at[lane_idx, tokens].add(active)
                 return tokens, lps, tk_vals, tk_ids, cache, gen_counts
 
-            return jax.jit(step, donate_argnums=(1, 2), **kwargs)
+            return jax.jit(_named(step, "dyn_decode_w1"), donate_argnums=(1, 2), **kwargs)
 
         # Fused multi-step decode: scan `steps` iterations on-device.  The
         # sampled token feeds back without a host roundtrip; per-iteration
@@ -1273,12 +1356,13 @@ class JaxLlmEngine:
                 logits, cache = fwd_decode(
                     params, cache, tokens, block_tables, lens, slots, cos, sin
                 )
-                logits = apply_penalties(logits, gen_counts, prompt_counts, pres, freq, rep)
-                logits = apply_logit_bias(logits, bias_ids, bias_vals)
-                step_keys = jax.vmap(jax.random.fold_in)(keys, lens)
-                tokens = sample_tokens(logits, step_keys, temp, top_k, top_p, greedy)
-                lps = token_logprobs(logits, tokens)
-                tk_vals, tk_ids = topk_logprobs(logits, topk_k)
+                with jax.named_scope("sample"):
+                    logits = apply_penalties(logits, gen_counts, prompt_counts, pres, freq, rep)
+                    logits = apply_logit_bias(logits, bias_ids, bias_vals)
+                    step_keys = jax.vmap(jax.random.fold_in)(keys, lens)
+                    tokens = sample_tokens(logits, step_keys, temp, top_k, top_p, greedy)
+                    lps = token_logprobs(logits, tokens)
+                    tk_vals, tk_ids = topk_logprobs(logits, topk_k)
                 gen_counts = gen_counts.at[lane_idx, tokens].add(active_i)
                 lens = jnp.where(active, lens + 1, lens)
                 return (tokens, cache, gen_counts, lens), (tokens, lps, tk_vals, tk_ids)
@@ -1297,7 +1381,7 @@ class JaxLlmEngine:
             kwargs["out_shardings"] = (
                 repl, repl, repl, repl, repl, self._cache_sharding, repl
             )
-        return jax.jit(multi, donate_argnums=(1, 2), **kwargs)
+        return jax.jit(_named(multi, f"dyn_decode_w{steps}"), donate_argnums=(1, 2), **kwargs)
 
     def _build_unified(self):
         """Ragged unified-batch step: ONE launch computes chunked-prefill
@@ -1346,18 +1430,19 @@ class JaxLlmEngine:
                 seed_prompt, mode="drop"
             )
             gen_counts = gen_counts.at[seed_lanes].set(seed_gen, mode="drop")
-            plogits = apply_penalties(
-                logits, gen_counts, prompt_counts, pres, freq, rep
-            )
-            plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
-            step_keys = jax.vmap(jax.random.fold_in)(keys, context_lens)
-            tokens = sample_tokens(plogits, step_keys, temp, top_k, top_p, greedy)
-            lps = token_logprobs(plogits, tokens)
-            tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
+            with jax.named_scope("sample"):
+                plogits = apply_penalties(
+                    logits, gen_counts, prompt_counts, pres, freq, rep
+                )
+                plogits = apply_logit_bias(plogits, bias_ids, bias_vals)
+                step_keys = jax.vmap(jax.random.fold_in)(keys, context_lens)
+                tokens = sample_tokens(plogits, step_keys, temp, top_k, top_p, greedy)
+                lps = token_logprobs(plogits, tokens)
+                tk_vals, tk_ids = topk_logprobs(plogits, topk_k)
             gen_counts = gen_counts.at[lane_idx, tokens].add(sample_gate)
             return tokens, lps, tk_vals, tk_ids, cache, gen_counts, prompt_counts
 
-        return jax.jit(step, donate_argnums=(1, 2, 3))
+        return _ProgramsByBucket(step, "dyn_unified_t", 4, donate_argnums=(1, 2, 3))
 
     def _build_verify(self):
         """Speculative verification step: one forward over the [lanes, w]
@@ -1433,7 +1518,7 @@ class JaxLlmEngine:
             kwargs["out_shardings"] = (
                 repl, repl, repl, repl, repl, self._cache_sharding, repl
             )
-        return jax.jit(step, donate_argnums=(1, 2), **kwargs)
+        return jax.jit(_named(step, f"dyn_verify_w{w_len}"), donate_argnums=(1, 2), **kwargs)
 
     def _build_extract(self):
         """Gather a sequence's KV blocks (padded to max_blocks_per_seq) for
@@ -1445,7 +1530,7 @@ class JaxLlmEngine:
         def fn(cache, block_ids):
             return jax.tree.map(lambda c: c[:, block_ids], cache)
 
-        return jax.jit(fn)
+        return jax.jit(_named(fn, "dyn_kv_extract"))
 
     def _build_inject(self):
         """Scatter transferred KV blocks into this engine's cache, per cache
@@ -1462,7 +1547,7 @@ class JaxLlmEngine:
         kwargs = {}
         if self.mesh is not None:
             kwargs["out_shardings"] = self._cache_sharding
-        return jax.jit(fn, donate_argnums=(0,), **kwargs)
+        return jax.jit(_named(fn, "dyn_kv_inject"), donate_argnums=(0,), **kwargs)
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
@@ -1478,6 +1563,14 @@ class JaxLlmEngine:
         self._thread.start()
 
     def stop(self) -> None:
+        if self._profiler_trace_dir is not None:
+            # before the device thread goes: the profiler drops the host
+            # events (the dyn.* phases, the program launches) of a thread
+            # that has exited by the time the trace is collected
+            from dynamo_tpu.utils import profiling
+
+            profiling.maybe_stop_trace()
+            self._profiler_trace_dir = None
         self._stop = True
         self._wake.set()
         if self._thread is not None:
@@ -1491,12 +1584,10 @@ class JaxLlmEngine:
                 (d.memory_stats() or {}).get("bytes_in_use")
                 for d in jax.local_devices()
             ]
+            # (the span histograms stay in stats(); a log line is no place
+            # for them)
+            del final["spans"]
             logger.info("engine stopped: %s", json.dumps(final, default=str))
-        if self._profiler_trace_dir is not None:
-            from dynamo_tpu.utils import profiling
-
-            profiling.maybe_stop_trace()
-            self._profiler_trace_dir = None
         if self.host_tier is not None:
             self.host_tier.close()  # release + delete the G3 memmap
 
@@ -1569,9 +1660,12 @@ class JaxLlmEngine:
                  error: str | None = None,
                  logprobs: list[float] | None = None,
                  top_logprobs: list[list[list]] | None = None) -> None:
+            # runs on the device thread; the stamp lets the frontend see
+            # how long the chunk took from here to the socket
             out = LLMEngineOutput(
                 token_ids=tokens, finish_reason=finish, error=error,
                 logprobs=logprobs, top_logprobs=top_logprobs,
+                emitted_ts=time.time(),
             )
             wire = Annotated.from_data(out).to_wire(LLMEngineOutput.to_wire)
             loop.call_soon_threadsafe(out_q.put_nowait, wire)
@@ -1768,6 +1862,7 @@ class JaxLlmEngine:
                 LLMEngineOutput(
                     token_ids=tokens, finish_reason=finish, error=error,
                     logprobs=logprobs, top_logprobs=top_logprobs,
+                    emitted_ts=time.time(),
                 )
             ).to_wire(LLMEngineOutput.to_wire)
             loop.call_soon_threadsafe(out_q.put_nowait, wire)
@@ -2186,13 +2281,18 @@ class JaxLlmEngine:
             out["offload_tiers"] = self.host_tier.tiers_snapshot()
         if self.prefetch_pager is not None:
             out.update(self.prefetch_pager.stats())
-        if self.phase_stats:
-            # snapshot: the device thread inserts keys concurrently
-            out["phase_ms"] = {
-                name: {"total_ms": round(tot * 1e3, 2), "n": n,
-                       "mean_ms": round(tot / n * 1e3, 3)}
-                for name, (tot, n) in list(self.phase_stats.items())
-            }
+        out.update(self._kernel_work)
+        # snapshot: the device thread inserts keys (prefetch.page) and
+        # updates the pairs concurrently
+        phases = {k: tuple(v) for k, v in list(self.phase_stats.items())}
+        out["phase_ms"] = {
+            name: {"total_ms": round(tot * 1e3, 2), "n": n,
+                   "mean_ms": round(tot / n * 1e3, 3) if n else 0.0}
+            for name, (tot, n) in phases.items()
+        }
+        # request-path spans of this process, aggregated (count / total /
+        # max / duration histogram per component and name): recorder.py
+        out["spans"] = get_recorder().aggregate()
         return out
 
     # -- device thread -----------------------------------------------------
@@ -2225,69 +2325,15 @@ class JaxLlmEngine:
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
                     continue
-                t_step = time.perf_counter()
-                emitted_before = self._tokens_emitted
-                self._step_prefill_tokens = 0
-                self._step_decode_tokens = 0
-                self._step_attn_ctx = 0
-                self._step_weight_streams = 0.0
+                t_step, emitted_before = self._begin_step()
                 decision = self.scheduler.schedule()
                 if not (self.unified_batch and self._maybe_run_unified(decision)):
                     self._run_split_step(decision)
-                self._iterations += 1
-                step_duration_s = time.perf_counter() - t_step
-                if step_duration_s > 1.0:
-                    # seconds-long iterations are compiles or giant windows;
-                    # either way the operator wants to know which
-                    logger.info(
-                        "slow step %d: %.1fs (prefill_tokens=%d decode_tokens=%d "
-                        "compiles so far=%d)", self._iterations, step_duration_s,
-                        self._step_prefill_tokens, self._step_decode_tokens,
-                        compile_counts()["compiles_total"],
-                    )
-                self.step_telemetry.observe_step(
-                    iteration=self._iterations,
-                    num_running=self.scheduler.num_running,
-                    num_waiting=self.scheduler.num_waiting,
-                    kv_active_blocks=self.allocator.used_blocks,
-                    kv_total_blocks=self.allocator.num_blocks,
-                    step_duration_s=step_duration_s,
-                    prefill_tokens=self._step_prefill_tokens,
-                    decode_tokens=self._step_decode_tokens,
-                )
-                self.utilization.observe_step(
-                    duration_s=step_duration_s,
-                    prefill_tokens=self._step_prefill_tokens,
-                    decode_tokens=self._step_decode_tokens,
-                    attn_ctx_tokens=self._step_attn_ctx,
-                    weight_streams=self._step_weight_streams,
-                    emitted_tokens=self._tokens_emitted - emitted_before,
-                )
-                if self.flight.enabled:
-                    preempted = self.scheduler.preemptions_total
-                    if preempted > self._flight_preemptions:
-                        self.flight.record_event(
-                            "preemption",
-                            count=preempted - self._flight_preemptions,
-                            total=preempted,
-                        )
-                        self._flight_preemptions = preempted
-                    rates = self.utilization.rates()
-                    self.flight.record_step(
-                        iteration=self._iterations,
-                        num_running=self.scheduler.num_running,
-                        num_waiting=self.scheduler.num_waiting,
-                        kv_usage=self.allocator.usage,
-                        prefill_tokens=self._step_prefill_tokens,
-                        decode_tokens=self._step_decode_tokens,
-                        emitted_tokens=self._tokens_emitted - emitted_before,
-                        step_duration_s=step_duration_s,
-                        mfu=rates["mfu_perc"],
-                        goodput_tok_s=rates["goodput_tokens_per_second"],
-                    )
+                self._end_step(t_step, emitted_before)
             except Exception as exc:  # noqa: BLE001 — scheduler-level bug:
                 # keep the thread alive (callers would hang forever), don't
                 # hot-spin
+                self._phase(None)
                 logger.exception("engine step failed")
                 if self.flight.enabled:
                     self.flight.record_event(
@@ -2301,6 +2347,87 @@ class JaxLlmEngine:
             self._sync_pipeline()
         except Exception:  # noqa: BLE001
             logger.exception("pipeline drain at shutdown failed")
+        self._phase(None)
+
+    def _begin_step(self) -> tuple[float, int]:
+        """Open one iteration of the step loop: reset the per-step scratch
+        and start the `schedule` phase."""
+        self._step_prefill_tokens = 0
+        self._step_decode_tokens = 0
+        self._step_attn_ctx = 0
+        self._step_weight_streams = 0.0
+        self._step_lane_steps = 0
+        self._step_readback_s = 0.0
+        self._step_waited_kind = self._step_dispatched_kind = None
+        self._phase("schedule")
+        return time.perf_counter(), self._tokens_emitted
+
+    def _end_step(self, t_step: float, emitted_before: int) -> None:
+        """Close the iteration: ONE record of it, handed to all three
+        recorders.  Its time is booked to the window the DEVICE was
+        executing: the one this iteration waited for (under overlap, the
+        previous iteration's), else the one it dispatched; an iteration
+        that served no window carried no prompt token."""
+        self._phase(None)
+        self._iterations += 1
+        step_duration_s = time.perf_counter() - t_step
+        if step_duration_s > 1.0:
+            # seconds-long iterations are compiles or giant windows;
+            # either way the operator wants to know which
+            logger.info(
+                "slow step %d: %.1fs (prefill_tokens=%d decode_tokens=%d "
+                "compiles so far=%d)", self._iterations, step_duration_s,
+                self._step_prefill_tokens, self._step_decode_tokens,
+                compile_counts()["compiles_total"],
+            )
+        rec = StepRecord(
+            iteration=self._iterations,
+            kind=(self._step_waited_kind or self._step_dispatched_kind
+                  or KIND_DECODE),
+            duration_s=step_duration_s,
+            readback_wait_s=self._step_readback_s,
+            num_running=self.scheduler.num_running,
+            num_waiting=self.scheduler.num_waiting,
+            kv_active_blocks=self.allocator.used_blocks,
+            kv_total_blocks=self.allocator.num_blocks,
+            prefill_tokens=self._step_prefill_tokens,
+            decode_tokens=self._step_decode_tokens,
+            decode_lane_steps=self._step_lane_steps,
+            attn_ctx_tokens=self._step_attn_ctx,
+            weight_streams=self._step_weight_streams,
+            emitted_tokens=self._tokens_emitted - emitted_before,
+        )
+        self.step_telemetry.observe(rec)
+        self.utilization.observe(rec)
+        if not self.flight.enabled:
+            return
+        preempted = self.scheduler.preemptions_total
+        if preempted > self._flight_preemptions:
+            self.flight.record_event(
+                "preemption",
+                count=preempted - self._flight_preemptions,
+                total=preempted,
+            )
+            self._flight_preemptions = preempted
+        # this step's own utilization (the rolling rates are computed when
+        # stats() asks, not per step)
+        self.flight.record_step(
+            iteration=rec.iteration,
+            kind=rec.kind,
+            num_running=rec.num_running,
+            num_waiting=rec.num_waiting,
+            kv_usage=self.allocator.usage,
+            prefill_tokens=rec.prefill_tokens,
+            decode_tokens=rec.decode_tokens,
+            emitted_tokens=rec.emitted_tokens,
+            step_duration_s=rec.duration_s,
+            readback_wait_s=rec.readback_wait_s,
+            mfu=self.utilization.step_mfu(rec),
+            goodput_tok_s=(
+                rec.emitted_tokens / rec.duration_s
+                if rec.duration_s > 0.0 else 0.0
+            ),
+        )
 
     def _run_split_step(self, decision) -> None:
         """The split prefill/decode step: one dispatch per prefill window
@@ -2312,25 +2439,30 @@ class JaxLlmEngine:
             self._maybe_record_queue_span(seq)
             t_prefill = time.time()
             try:
-                with self._xprof_span("dyn.prefill"):
-                    self._run_prefill(seq)
+                self._run_prefill(seq)
             except Exception as exc:  # noqa: BLE001 — fail THIS
                 # sequence (free blocks, resolve its caller) and
                 # keep serving; retrying would hot-spin on
                 # deterministic failures and skipping the rest of
                 # the batch would leave restore plans unexecuted
                 logger.exception("prefill failed for %s", seq.seq_id)
-                self._record_prefill_span(seq, t_prefill, status="error")
+                self._close_prefill_spans(
+                    [self._open_prefill_span(seq, t_prefill)], status="error"
+                )
                 self._fail_sequence(seq, exc)
             else:
-                self._record_prefill_span(seq, t_prefill)
+                span = self._open_prefill_span(seq, t_prefill)
+                if seq.status is SeqStatus.PREFILLING:
+                    # intermediate chunk: dispatched, nothing waited for it
+                    self._unwaited_prefills.append(span)
+                else:
+                    self._close_prefill_spans([span])
         decodes = [
             s for s in self.scheduler.running if s.status == SeqStatus.RUNNING
         ]
         if decodes:
             try:
-                with self._xprof_span("dyn.decode"):
-                    self._run_decode(decodes)
+                self._run_decode(decodes)
             except Exception as exc:  # noqa: BLE001
                 logger.exception("decode step failed")
                 # a poisoned in-flight window must not feed the next
@@ -2442,8 +2574,7 @@ class JaxLlmEngine:
             s.request.sampling.top_logprobs > 0 for s in prefills + decodes
         )
         try:
-            with self._xprof_span("dyn.unified"):
-                return self._run_unified(spans, decodes, bucket, overlap)
+            return self._run_unified(spans, decodes, bucket, overlap)
         except Exception as exc:  # noqa: BLE001
             logger.exception("unified step failed")
             self._abandon_pipeline(prefills + decodes)
@@ -2464,8 +2595,6 @@ class JaxLlmEngine:
         admitted sequence needs NO pipeline drain here: its prefill tokens
         come from the host while resident decode lanes keep reading the
         previous window's on-device feedback."""
-        timing = self._phase_timing
-        t = time.perf_counter() if timing else 0.0
         lanes = self.config.max_batch_size
         tb = self._unified_tb
         bs = self.config.block_size
@@ -2618,10 +2747,12 @@ class JaxLlmEngine:
         # prefetch — no per-block lane routing).  The worklist width is the
         # engine-fixed self._unified_ps, so every window of this bucket
         # shares ONE compiled program regardless of batch composition.
-        if self.attention_impl.startswith("pallas"):
+        pallas = self.attention_impl.startswith("pallas")
+        if pallas:
             from dynamo_tpu.ops.pallas import pack_page_meta
 
-            sw = getattr(self.config.model, "sliding_window", None)
+            self._phase("pack")
+            sw = self._sliding_window
             try:
                 page_meta = pack_page_meta(
                     token_lane, token_pos, self._bt_host,
@@ -2652,6 +2783,7 @@ class JaxLlmEngine:
                 np.zeros((num_tb, 1), np.int32),
                 np.zeros((num_tb,), np.int32),
             )
+        self._phase("upload")
         sampling_tail = self._device_sampling_tail(emit_seqs, lanes)
         if overlap and prev is not None:
             feedback_in = prev.feedback
@@ -2672,8 +2804,6 @@ class JaxLlmEngine:
                     jnp.zeros((nseed, vocab), jnp.int32),
                 )
             seed_args = self._seed_none
-        if timing:
-            t = self._phase("decode.schedule", t)
         args = (
             jnp.asarray(token_ids), feedback_in, jnp.asarray(use_fb),
             tables, jnp.asarray(context_lens), jnp.asarray(token_pos),
@@ -2682,22 +2812,28 @@ class JaxLlmEngine:
             jnp.asarray(sample_rows), jnp.asarray(sample_gate),
             *seed_args,
         )
-        if timing:
-            t = self._phase("decode.upload", t)
+        # a unified window always carries a span (decode-only iterations
+        # keep the decode program), so it is a prompt window
+        live_pages = int(page_meta[3].sum()) if pallas else 0
+        t_prefill = time.time()
+        self._phase("dispatch", kind=KIND_PROMPT, tokens=cursor, live_pages=live_pages)
         tokens, lps, tkvs, tkis, self.cache, self._gen_counts, self._prompt_counts = self._jit_unified(
             self.params, self.cache, self._gen_counts, self._prompt_counts,
             *args, *sampling_tail, self.cos, self.sin,
         )
-        if timing:
-            t = self._phase("decode.dispatch", t)
+        self._phase("post")
+        self._step_dispatched_kind = KIND_PROMPT
 
         # host bookkeeping (device-ordered: any later program — including
         # another engine's extract over published blocks — sees the writes)
-        t_prefill = time.time()
+        opened = []  # this window's engine.prefill spans: closed by its wait
+        ragged_ctx = 0  # positions the ragged kernel attends (window-clipped)
         for seq, start, end in spans:
             seq.prefilled_tokens = end
             self._step_prefill_tokens += end - start
-            self._step_attn_ctx += (end * (end + 1) - start * (start + 1)) // 2
+            full, seen = self._attended_ctx(start, end)
+            self._step_attn_ctx += full
+            ragged_ctx += seen
             all_tokens = seq.all_token_ids
             if end >= len(all_tokens):
                 if seq.status == SeqStatus.PREFILLING:
@@ -2705,17 +2841,33 @@ class JaxLlmEngine:
                 self.allocator.publish_stored(seq.seq_id, all_tokens)
             else:
                 self.allocator.publish_stored(seq.seq_id, all_tokens[:end])
-            self._record_prefill_span(seq, t_prefill)
+            opened.append(self._open_prefill_span(seq, t_prefill))
         self._step_decode_tokens += len(decodes)
-        self._step_attn_ctx += int(
-            sum(context_lens[s.lane] for s in decodes)
-        )
+        w = self._sliding_window
+        for s in decodes:
+            ctx = int(context_lens[s.lane])
+            self._step_attn_ctx += ctx
+            ragged_ctx += ctx if w is None else min(ctx, w)
         self._step_weight_streams += 1
         self._unified_windows += 1
         if decodes:
             self._decode_steps_total += 1
+            self._step_lane_steps += len(decodes)
+        if pallas:
+            # the kernel's work, from the worklist just built: every live
+            # entry is one whole page DMA'd and one grid step computed
+            cost = self.utilization.cost
+            work = self._kernel_work
+            work["ragged_live_pages_total"] += live_pages
+            work["ragged_page_slots_total"] += page_meta[0].size
+            work["ragged_attn_flops_total"] += cost.attn_flops(ragged_ctx)
+            work["ragged_kv_read_bytes_total"] += (
+                live_pages * bs * cost.kv_bytes_per_token
+            )
 
         if not overlap:
+            _, opened = self._take_unwaited(KIND_PROMPT, opened)
+            self._phase("readback", kind=KIND_PROMPT)
             tokens_h = np.asarray(tokens)
             lps_h = np.asarray(lps)
             want_top = any(
@@ -2723,8 +2875,8 @@ class JaxLlmEngine:
             )
             tkv_h = np.asarray(tkvs) if want_top else None
             tki_h = np.asarray(tkis) if want_top else None
-            if timing:
-                t = self._phase("decode.readback", t)
+            self._phase("post")
+            self._note_wait(KIND_PROMPT, opened)
             self._sync_windows += 1
             for seq in emit_seqs:
                 if seq.status != SeqStatus.RUNNING:
@@ -2735,8 +2887,6 @@ class JaxLlmEngine:
                     seq, int(tokens_h[lane]), float(lps_h[lane]),
                     top=(tkv_h[lane], tki_h[lane]) if want else None,
                 )
-            if timing:
-                self._phase("decode.post", t)
             return True
 
         # overlap: the window retires one iteration from now, while the
@@ -2749,48 +2899,71 @@ class JaxLlmEngine:
             self._inflight = _InflightWindow(
                 tokens=tokens, lps=lps, feedback=tokens,
                 active=emit_seqs, lane_ids=[s.lane for s in emit_seqs],
-                steps=1,
+                steps=1, kind=KIND_PROMPT,
+                prefills=self._take_unwaited(KIND_PROMPT, opened)[1],
             )
         else:
             # a chunk-only window samples nothing worth retiring: nothing
-            # goes in flight (KV writes are device-ordered regardless)
+            # goes in flight (KV writes are device-ordered regardless), and
+            # the next window's wait covers this one's time on the device
             self._inflight = None
+            self._unwaited_prefills += opened
         if prev is not None:
             self._retire_window(prev)
         return True
 
-    def _xprof_span(self, name: str):
-        """jax.profiler.TraceAnnotation around a hot step when
-        DYN_XPROF_ANNOTATE=1, so host spans line up with xprof device
-        traces; a nullcontext otherwise."""
-        if not self._xprof_annotate:
-            return contextlib.nullcontext()
-        return jax.profiler.TraceAnnotation(name)
-
-    def _record_prefill_span(self, seq: Sequence, start_ts: float,
-                             status: str = "ok") -> None:
-        """One span per prefill window (chunked prefills show every chunk).
-        The window that produced the first token carries the engine-side
-        TTFT (arrival → first sample)."""
-        if seq.trace is None:
-            return
+    def _open_prefill_span(self, seq: Sequence, start_ts: float) -> tuple:
+        """One ``engine.prefill`` span per prefill window (chunked prefills
+        show every chunk), opened at dispatch with what only that instant
+        knows.  It closes when the host has waited for the window
+        (``_close_prefill_spans``): dispatch is asynchronous, so closing it
+        here would measure the dispatch, not the window."""
         # intermediate chunks leave the sequence PREFILLING; the final
         # window flips it to RUNNING (or FINISHED for prefill_only)
         final = seq.status is not SeqStatus.PREFILLING
-        attrs = {
+        return seq, start_ts, final, {
             "prefilled_tokens": seq.prefilled_tokens,
             "cached_tokens": seq.cached_tokens,
         }
-        # a preemption-recompute prefill is not a first-token event: TTFT
-        # attaches exactly once per request, on the window that sampled the
-        # first token
-        if final and status == "ok" and not seq.ttft_recorded:
-            seq.ttft_recorded = True
-            attrs["ttft_s"] = max(0.0, time.time() - seq.arrival_ts)
-        get_recorder().record(
-            "engine.prefill", seq.trace, start_ts, time.time(),
-            component="engine", status=status, attrs=attrs,
-        )
+
+    def _close_prefill_spans(self, spans: list, status: str = "ok") -> None:
+        """The host has waited for these prefill windows: record them.  The
+        window that produced the first token carries the engine-side TTFT
+        (arrival → first sample on the host)."""
+        now = time.time()
+        for seq, start_ts, final, attrs in spans:
+            if seq.trace is None:
+                continue
+            # a preemption-recompute prefill is not a first-token event:
+            # TTFT attaches exactly once per request, on the window that
+            # sampled the first token
+            if final and status == "ok" and not seq.ttft_recorded:
+                seq.ttft_recorded = True
+                attrs["ttft_s"] = max(0.0, now - seq.arrival_ts)
+            get_recorder().record(
+                "engine.prefill", seq.trace, start_ts, now,
+                component="engine", status=status, attrs=attrs,
+            )
+
+    def _take_unwaited(self, own: str, prefills: list | None = None) -> tuple[str, list]:
+        """Kind and open prefill spans of the window being dispatched,
+        together with the prompt work dispatched before it that nothing
+        waits for (a chunk-only unified window, a split prefill's
+        intermediate chunk).  On the device that work runs first, so the
+        wait for THIS window covers its time: the window inherits the kind
+        ``prompt`` and the spans."""
+        pending, self._unwaited_prefills = self._unwaited_prefills, []
+        if pending:
+            return KIND_PROMPT, pending + (prefills or [])
+        return own, prefills or []
+
+    def _note_wait(self, kind: str, prefills: list) -> None:
+        """The host has blocked on a window of ``kind``: the heaviest
+        window waited for names the iteration, and the prefill windows the
+        wait covered close their spans."""
+        if self._step_waited_kind != KIND_PROMPT:
+            self._step_waited_kind = kind
+        self._close_prefill_spans(prefills)
 
     def _on_preempt(self, seq: Sequence) -> None:
         """Scheduler preemption hook: close the victim's decode span (the
@@ -3036,7 +3209,7 @@ class JaxLlmEngine:
         progress = False
         moved = 0
         wall0 = time.time()
-        t0 = time.perf_counter()
+        self._phase("prefetch.page")
         while budget > 0:
             job = pager.next_job()
             if job is None:
@@ -3056,8 +3229,7 @@ class JaxLlmEngine:
         # hot-prefix pinning rides the prefetch loop (never the demand
         # path): promote + pin prefixes that keep paging back in
         pinned = self.host_tier.pin_hot()
-        if self._phase_timing:
-            self._phase("prefetch.page", t0)
+        self._phase(None)
         if moved:
             # prefetch work is not tied to any request: spans hang off the
             # engine-lifetime prefetch root trace (one trace id per engine)
@@ -3310,6 +3482,7 @@ class JaxLlmEngine:
             emb_pad[: seq.mm_len] = seq.mm_embeds
             block_ids = np.zeros((self.max_blocks_per_seq,), np.int32)
             block_ids[: len(blocks)] = blocks
+            self._phase("dispatch", kind=KIND_PROMPT, tokens=total)
             token, lp, tkv, tki, self.cache, self._gen_counts, self._prompt_counts = self._jit_prefill_mm(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 jnp.int32(lane), jnp.asarray(emb_pad), jnp.asarray(tok_arr),
@@ -3322,12 +3495,11 @@ class JaxLlmEngine:
             self._step_attn_ctx += total * (total + 1) // 2
             self._step_weight_streams += 1
             want_top = seq.request.sampling.top_logprobs > 0
+            token_h, lp_h = self._read_prefill_sample(token, lp)
             self._process_token(
-                seq, int(token), float(lp), top=(tkv, tki) if want_top else None
+                seq, token_h, lp_h, top=(tkv, tki) if want_top else None
             )
             return
-        timing = self._phase_timing
-        tp = time.perf_counter() if timing else 0.0
         # the continued-prefill jit serves prefix hits AND every chunk (an
         # intermediate first chunk needs its sample gate; start_pos=0 masks
         # the prefix away entirely)
@@ -3349,6 +3521,7 @@ class JaxLlmEngine:
             tail_ids = np.zeros((table_len,), np.int32)
             tail_ids[: len(blocks) - start_blocks] = blocks[start_blocks:]
             prompt_row = self._count_row(seq.request.token_ids)
+            self._phase("dispatch", kind=KIND_PROMPT, tokens=t)
             token, lp, tkv, tki, self.cache, self._gen_counts, self._prompt_counts = self._jit_prefill_prefix(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 jnp.int32(lane), jnp.asarray(padded), jnp.asarray(full_ids),
@@ -3364,18 +3537,15 @@ class JaxLlmEngine:
             padded[:end] = tokens[:end]
             block_ids = np.zeros((self.max_blocks_per_seq,), np.int32)
             block_ids[: len(blocks)] = blocks
+            self._phase("dispatch", kind=KIND_PROMPT, tokens=end)
             token, lp, tkv, tki, self.cache, self._gen_counts, self._prompt_counts = self._jit_prefill(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 jnp.int32(lane), jnp.asarray(padded), jnp.asarray(block_ids),
                 jnp.int32(end), jnp.int32(0), jnp.asarray(gen_row), jnp.asarray(key),
                 *sampling_tail, self._guided_row(seq), self.cos, self.sin,
             )
-        if timing:
-            # opt-in diagnosis only: the forced scalar sync breaks chunk
-            # pipelining, so production never pays it
-            tp = self._phase("prefill.dispatch", tp)
-            np.asarray(token)
-            self._phase("prefill.readback", tp)
+        self._phase("post")
+        self._step_dispatched_kind = KIND_PROMPT
         seq.prefilled_tokens = end
         # utilization accounting: this window computed [start, end) — each
         # position p attends p+1 context positions (causal)
@@ -3389,6 +3559,9 @@ class JaxLlmEngine:
             if seq.prefill_only and seq.on_chunk_done is not None:
                 self._stream_prefill_chunk(seq, blocks, end)
             return
+        # the final chunk's sample is the only readback of a split prefill
+        # (intermediate chunks stay pipelined: nothing waits for them)
+        token_h, lp_h = self._read_prefill_sample(token, lp)
         if seq.status == SeqStatus.PREFILLING:
             seq.status = SeqStatus.RUNNING  # last chunk done → decode
         if seq.prefill_only:
@@ -3408,7 +3581,7 @@ class JaxLlmEngine:
                 tkv_h, tki_h = np.asarray(tkv), np.asarray(tki)
                 k = min(want_top, len(tki_h))
                 top_rows = [[int(tki_h[i]), float(tkv_h[i])] for i in range(k)]
-            result = (int(token), float(lp), top_rows, blocks_out, n_used)
+            result = (token_h, lp_h, top_rows, blocks_out, n_used)
             self.scheduler.finish(seq)
             if seq.on_prefill_done:
                 seq.on_prefill_done(result)
@@ -3417,8 +3590,18 @@ class JaxLlmEngine:
             self.allocator.publish_stored(seq.seq_id, tokens)
         want_top = seq.request.sampling.top_logprobs > 0
         self._process_token(
-            seq, int(token), float(lp), top=(tkv, tki) if want_top else None
+            seq, token_h, lp_h, top=(tkv, tki) if want_top else None
         )
+
+    def _read_prefill_sample(self, token, lp) -> tuple[int, float]:
+        """Read a split prefill's sampled token back (the `readback` phase:
+        the host blocks until the prefill program is done)."""
+        _, prefills = self._take_unwaited(KIND_PROMPT)  # earlier chunks
+        self._phase("readback", kind=KIND_PROMPT)
+        out = int(token), float(lp)
+        self._phase("post")
+        self._note_wait(KIND_PROMPT, prefills)
+        return out
 
     def _ngram_draft(self, tokens: list[int]) -> list[int]:
         """Prompt-lookup drafting: find the most recent earlier occurrence
@@ -3503,7 +3686,12 @@ class JaxLlmEngine:
         if w is None:
             return
         self._inflight = None
+        # back to the caller's phase; outside a step (an abort or clear_kv
+        # from _drain_submissions) that is none, and `post` must not stay
+        # open over the idle wait that follows
+        resume = self._phase_name
         self._retire_window(w)
+        self._phase(resume)
 
     def _abandon_pipeline(self, seqs: list[Sequence]) -> None:
         """Decode-step failure cleanup: drop the in-flight window without
@@ -3523,6 +3711,7 @@ class JaxLlmEngine:
                 jax.block_until_ready(w.tokens)
             except Exception:  # noqa: BLE001 — a failed program still ended
                 pass
+            self._close_prefill_spans(w.prefills, status="error")
             for seq in w.deferred:
                 self.scheduler.finish(seq)
             for seq in w.active:
@@ -3533,18 +3722,18 @@ class JaxLlmEngine:
     def _retire_window(self, w: _InflightWindow) -> None:
         """Readback + emission for one dispatched window.  Runs AFTER the
         next window was dispatched (steady state), so the device computes
-        while the host blocks here — this wait is the new `decode.retire`
-        phase, replacing the old synchronous `decode.readback`."""
-        timing = self._phase_timing
-        t = time.perf_counter() if timing else 0.0
+        while the host blocks here — this wait is the `readback` phase of
+        the iteration AFTER the one that dispatched
+        it, which is why that iteration's time is booked to ``w.kind``."""
+        self._phase("readback", kind=w.kind)
         try:
             tokens_host = np.asarray(w.tokens)
             lps_host = np.asarray(w.lps)
             if tokens_host.ndim == 1:
                 tokens_host = tokens_host[None, :]
                 lps_host = lps_host[None, :]
-            if timing:
-                t = self._phase("decode.retire", t)
+            self._phase("post")
+            self._note_wait(w.kind, w.prefills)
             for seq in w.active:
                 seq.inflight_tokens = max(0, seq.inflight_tokens - w.steps)
             for s in range(tokens_host.shape[0]):
@@ -3564,8 +3753,6 @@ class JaxLlmEngine:
             # would leak the lane and blocks forever)
             for seq in w.deferred:
                 self.scheduler.finish(seq)
-        if timing:
-            self._phase("decode.post", t)
 
     def _finish_decoded(self, seq: Sequence) -> None:
         """Finish a sequence from the decode path.  While an in-flight
@@ -3593,8 +3780,6 @@ class JaxLlmEngine:
             seq.decode_start_ts = time.time()
 
     def _run_overlap_decode(self, seqs: list[Sequence]) -> None:
-        timing = self._phase_timing
-        t = time.perf_counter() if timing else 0.0
         lanes = self.config.max_batch_size
         steps = self.config.decode_steps
         bs = self.config.block_size
@@ -3658,8 +3843,7 @@ class JaxLlmEngine:
             if token_ids is not None:
                 token_ids[lane] = seq.all_token_ids[-1]
         tables = self._decode_tables(active)
-        if timing:
-            t = self._phase("decode.schedule", t)
+        self._phase("upload")
         sampling_tail = self._device_sampling_tail(active, lanes)
         # token feedback: step N+1's input IS step N's on-device output —
         # the host never sees (or waits for) the tokens it dispatches
@@ -3674,8 +3858,7 @@ class JaxLlmEngine:
                 tok_in, tables, lens_dev, jnp.asarray(slot_ids),
                 *sampling_tail, self._guided_table, self._gmodes_unguided,
             )
-            if timing:
-                t = self._phase("decode.upload", t)
+            self._phase("dispatch", kind=KIND_DECODE, tokens=len(active))
             tokens, lps, _tkvs, _tkis, self.cache, self._gen_counts = self._jit_decode(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 *args, self.cos, self.sin,
@@ -3684,14 +3867,14 @@ class JaxLlmEngine:
             w_tokens, w_lps = tokens, lps
         else:
             args = (tok_in, tables, lens_dev, *sampling_tail)
-            if timing:
-                t = self._phase("decode.upload", t)
+            self._phase("dispatch", kind=KIND_DECODE, tokens=len(active) * steps)
             w_tokens, w_lps, _tkvs, _tkis, feedback, self.cache, self._gen_counts = self._jit_decode(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 *args, self.cos, self.sin,
             )
-        if timing:
-            t = self._phase("decode.dispatch", t)
+        self._phase("post")
+        kind, prefills = self._take_unwaited(KIND_DECODE)
+        self._step_dispatched_kind = kind
         # start the device→host copies now; by the time this window is
         # retired (one iteration from now) the transfer may already be done
         for arr in (w_tokens, w_lps):
@@ -3701,13 +3884,10 @@ class JaxLlmEngine:
         self._inflight = _InflightWindow(
             tokens=w_tokens, lps=w_lps, feedback=feedback,
             active=list(active), lane_ids=[s.lane for s in active],
-            steps=steps,
+            steps=steps, kind=kind, prefills=prefills,
         )
         self._overlap_windows += 1
-        self._decode_steps_total += steps
-        self._step_decode_tokens += len(active) * steps
-        self._step_attn_ctx += int(context_lens.sum()) * steps
-        self._step_weight_streams += steps
+        self._count_decode_window(context_lens, len(active), steps)
         if prev is not None:
             self._retire_window(prev)
 
@@ -3751,18 +3931,71 @@ class JaxLlmEngine:
             self._bt_dev = jnp.asarray(self._bt_host)
         return self._bt_dev
 
-    def _phase(self, name: str, t0: float) -> float:
-        """Accumulate wall time since ``t0`` into ``phase_stats[name]`` and
-        return a fresh timestamp (phase-timing mode only)."""
-        t1 = time.perf_counter()
-        s = self.phase_stats.setdefault(name, [0.0, 0])
-        s[0] += t1 - t0
-        s[1] += 1
-        return t1
+    def _attended_ctx(self, start: int, end: int) -> tuple[int, int]:
+        """Context positions the tokens at ``[start, end)`` attend, causally
+        (position p sees p + 1: what the cost model has always booked) and
+        as the kernel sees them (the sliding window's width once past it)."""
+        full = (end * (end + 1) - start * (start + 1)) // 2
+        w = self._sliding_window
+        if w is None or end <= w:
+            return full, full
+        a = max(start, w)   # first position whose view the window clips
+        return full, (a * (a + 1) - start * (start + 1)) // 2 + (end - a) * w
+
+    def _count_decode_window(self, context_lens: np.ndarray, lanes: int, steps: int) -> None:
+        """Book one dispatch of the decode program: the step's token /
+        context / weight-stream facts and the decode kernel's work (per
+        lane the attended positions and the whole pages walked, after the
+        sliding window's clip, all layers)."""
+        ctx_sum = int(context_lens.sum())
+        self._decode_steps_total += steps
+        self._step_lane_steps += lanes * steps
+        self._step_decode_tokens += lanes * steps
+        self._step_attn_ctx += ctx_sum * steps
+        self._step_weight_streams += steps
+        if not self.attention_impl.startswith("pallas"):
+            return
+        bs = self.config.block_size
+        w = self._sliding_window
+        if w is None:
+            attended = ctx_sum
+            pages = int(((context_lens + (bs - 1)) // bs).sum())
+        else:
+            ctx = context_lens.astype(np.int64)
+            attended = int(np.minimum(ctx, w).sum())
+            pages = int(((ctx + (bs - 1)) // bs - np.maximum(ctx - w, 0) // bs).sum())
+        cost = self.utilization.cost
+        work = self._kernel_work
+        work["decode_attn_flops_total"] += cost.attn_flops(attended * steps)
+        work["decode_kv_read_bytes_total"] += (
+            pages * steps * bs * cost.kv_bytes_per_token
+        )
+
+    def _phase(self, name: str | None, **attrs) -> None:
+        """Close the open host phase (book its wall time into
+        ``phase_stats``, end its ``dyn.<phase>`` TraceAnnotation) and open
+        ``name`` (None: just close).  ``attrs`` ride on the annotation —
+        with no profiler session active a TraceMe costs about a
+        microsecond and records nothing."""
+        now = time.perf_counter()
+        cur = self._phase_name
+        if cur is not None:
+            dt = now - self._phase_t0
+            row = self.phase_stats.get(cur)
+            if row is None:
+                row = self.phase_stats[cur] = [0.0, 0]
+            row[0] += dt
+            row[1] += 1
+            if cur == "readback":
+                self._step_readback_s += dt
+            self._phase_ann.__exit__(None, None, None)
+        self._phase_name = name
+        if name is not None:
+            self._phase_t0 = now
+            self._phase_ann = jax.profiler.TraceAnnotation("dyn." + name, **attrs)
+            self._phase_ann.__enter__()
 
     def _run_plain_decode(self, seqs: list[Sequence]) -> None:
-        timing = self._phase_timing
-        t = time.perf_counter() if timing else 0.0
         lanes = self.config.max_batch_size
         steps = self.config.decode_steps
         token_ids = np.zeros((lanes,), np.int32)
@@ -3803,9 +4036,9 @@ class JaxLlmEngine:
         want_top = any(
             seq.request.sampling.top_logprobs > 0 for seq in active
         )
-        if timing:
-            t = self._phase("decode.schedule", t)
+        self._phase("upload")
         sampling_tail = self._device_sampling_tail(active, lanes)
+        kind, prefills = self._take_unwaited(KIND_DECODE)
         if steps <= 1:
             gmodes = np.full((lanes,), -1, np.int32)
             for seq in active:
@@ -3816,14 +4049,12 @@ class JaxLlmEngine:
                 jnp.asarray(context_lens), jnp.asarray(slot_ids),
                 *sampling_tail, self._guided_table, jnp.asarray(gmodes),
             )
-            if timing:
-                t = self._phase("decode.upload", t)
+            self._phase("dispatch", kind=KIND_DECODE, tokens=len(active))
             tokens, lps, tkvs, tkis, self.cache, self._gen_counts = self._jit_decode(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 *args, self.cos, self.sin,
             )
-            if timing:
-                t = self._phase("decode.dispatch", t)
+            self._phase("readback", kind=kind)
             tokens_host = np.asarray(tokens)[None, :]  # [1, lanes]
             lps_host = np.asarray(lps)[None, :]
             tkv_host = np.asarray(tkvs)[None] if want_top else None
@@ -3833,26 +4064,21 @@ class JaxLlmEngine:
                 jnp.asarray(token_ids), tables,
                 jnp.asarray(context_lens), *sampling_tail,
             )
-            if timing:
-                t = self._phase("decode.upload", t)
+            self._phase("dispatch", kind=KIND_DECODE, tokens=len(active) * steps)
             tokens, lps, tkvs, tkis, _feedback, self.cache, self._gen_counts = self._jit_decode(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
                 *args, self.cos, self.sin,
             )
-            if timing:
-                t = self._phase("decode.dispatch", t)
+            self._phase("readback", kind=kind)
             tokens_host = np.asarray(tokens)  # [steps, lanes]
             lps_host = np.asarray(lps)
             tkv_host = np.asarray(tkvs) if want_top else None
             tki_host = np.asarray(tkis) if want_top else None
-        if timing:
-            t = self._phase("decode.readback", t)
+        self._phase("post")
+        self._step_dispatched_kind = KIND_DECODE
+        self._note_wait(kind, prefills)
         self._sync_windows += 1
-        n_steps = int(tokens_host.shape[0])
-        self._decode_steps_total += n_steps
-        self._step_decode_tokens += len(active) * n_steps
-        self._step_attn_ctx += int(context_lens.sum()) * n_steps
-        self._step_weight_streams += n_steps
+        self._count_decode_window(context_lens, len(active), int(tokens_host.shape[0]))
 
         for s in range(tokens_host.shape[0]):
             for seq in active:
@@ -3866,8 +4092,6 @@ class JaxLlmEngine:
                         if want_top else None
                     ),
                 )
-        if timing:
-            self._phase("decode.post", t)
 
     def _warm_verify_step(self) -> None:
         """Compile the verify program: one launch with every lane inactive
@@ -3938,7 +4162,9 @@ class JaxLlmEngine:
                 slot_mat[lane, j] = blocks[pos // bs] * bs + pos % bs
 
         want_top = any(s.request.sampling.top_logprobs > 0 for s in active)
+        self._phase("upload")
         sampling_tail = self._device_sampling_tail(active, lanes)
+        self._phase("dispatch", kind=KIND_DECODE, tokens=len(active) * w)
         tokens, n_accept, lps, tkvs, tkis, self.cache, self._gen_counts = self._jit_verify(
             self.params, self.cache, self._gen_counts, self._prompt_counts,
             jnp.asarray(token_mat), jnp.asarray(block_tables),
@@ -3946,11 +4172,16 @@ class JaxLlmEngine:
             jnp.asarray(spec_ok), *sampling_tail,
             self.cos, self.sin,
         )
+        kind, prefills = self._take_unwaited(KIND_DECODE)
+        self._phase("readback", kind=kind)
         tokens_h = np.asarray(tokens)
         n_h = np.asarray(n_accept)
         lps_h = np.asarray(lps)
         tkv_h = np.asarray(tkvs) if want_top else None
         tki_h = np.asarray(tkis) if want_top else None
+        self._phase("post")
+        self._step_dispatched_kind = KIND_DECODE
+        self._note_wait(kind, prefills)
         # count attempts only after the jit succeeded (an attention-fallback
         # retry re-enters this method for the same step); attempted = the
         # whole window (pads can accept too), so accepted/drafted <= 1

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import time
 import uuid
 from typing import Any
 
@@ -474,6 +475,9 @@ class HttpService:
         )
         await response.prepare(request)
         completion_tokens = 0
+        # per chunk, straight into the span aggregate (no Span object): the
+        # lag from the engine's emit (device thread) to the socket write
+        recorder = get_recorder()
         try:
             async for ann in stream:
                 if ann.is_annotation():
@@ -493,6 +497,11 @@ class HttpService:
                 # on the per-token serving path
                 payload = ann.data.model_dump_json(exclude_none=True)
                 await response.write(sse.encode_event(data=payload).encode())
+                if ann.emitted_ts is not None:
+                    recorder.observe(
+                        "http.emit_lag", time.time() - ann.emitted_ts,
+                        component="frontend",
+                    )
             await response.write(sse.encode_done().encode())
             guard.mark_ok()
         except (ConnectionResetError, asyncio.CancelledError):

@@ -76,8 +76,8 @@ class ForwardPassMetrics:
     # window rides the unified step)
     unified_fallbacks: dict = field(default_factory=dict)
     # utilization accounting (observability.perf): rolling rates + token
-    # totals + wasted-work counters, and the opt-in engine phase timings
-    # (DYN_ENGINE_PHASE_TIMING=1) as {phase: cumulative seconds}
+    # totals + wasted-work counters, and the engine's host-phase
+    # accounting as {phase: cumulative seconds}
     mfu_perc: float = 0.0
     bandwidth_util_perc: float = 0.0
     goodput_tokens_per_second: float = 0.0

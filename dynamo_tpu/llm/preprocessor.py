@@ -11,6 +11,7 @@ preprocessor.rs:61-63).
 
 from __future__ import annotations
 
+import time
 from typing import AsyncIterator
 
 from jinja2.sandbox import ImmutableSandboxedEnvironment
@@ -35,6 +36,7 @@ from dynamo_tpu.llm.protocols.openai import (
     new_request_id,
 )
 from dynamo_tpu.llm.tokenizer import HfTokenizer
+from dynamo_tpu.observability import get_recorder
 from dynamo_tpu.runtime.engine import Context, Operator, ResponseStream
 
 ANNOTATION_FORMATTED_PROMPT = "formatted_prompt"
@@ -142,6 +144,15 @@ class _PreprocessorCore:
             mdc_sum=self.mdc.checksum,
         )
 
+    @staticmethod
+    def record_span(request: Context, start_s: float, prompt_tokens: int) -> None:
+        """The ``preprocess`` span (template + tokenize), a child of the
+        request's root; nothing for an untraced request."""
+        get_recorder().record(
+            "preprocess", request.ctx.trace, start_s, time.time(),
+            component="preprocessor", attrs={"prompt_tokens": prompt_tokens},
+        )
+
 
 class ChatPreprocessor(Operator):
     """ChatCompletionRequest ⇄ PreprocessedRequest/ChatCompletionChunk."""
@@ -156,6 +167,7 @@ class ChatPreprocessor(Operator):
             resolve_image,
         )
 
+        start_s = time.time()
         req = request.data
         prompt = self.core.formatter.render(req)
         token_ids = self.core.tokenizer.encode(prompt)
@@ -183,6 +195,7 @@ class ChatPreprocessor(Operator):
             "model": req.model,
             "response_id": new_request_id("chatcmpl"),
         }
+        self.core.record_span(request, start_s, len(token_ids))
         return request.transfer(ctx_data)
 
     async def postprocess(
@@ -235,7 +248,8 @@ class ChatPreprocessor(Operator):
                                 logprobs=lp_content,
                             )
                         ],
-                    )
+                    ),
+                    emitted_ts=out.emitted_ts,
                 )
             if include_usage:
                 yield Annotated.from_data(
@@ -261,6 +275,7 @@ class CompletionPreprocessor(Operator):
         self.core = _PreprocessorCore(mdc, tokenizer)
 
     async def preprocess(self, request: Context[CompletionRequest]) -> Context[dict]:
+        start_s = time.time()
         req = request.data
         if isinstance(req.prompt, str):
             token_ids = self.core.tokenizer.encode(req.prompt)
@@ -275,6 +290,7 @@ class CompletionPreprocessor(Operator):
             "model": req.model,
             "response_id": new_request_id("cmpl"),
         }
+        self.core.record_span(request, start_s, len(token_ids))
         return request.transfer(pre.to_wire())
 
     async def postprocess(
@@ -335,7 +351,8 @@ class CompletionPreprocessor(Operator):
                                 logprobs=lp_block,
                             )
                         ],
-                    )
+                    ),
+                    emitted_ts=out.emitted_ts,
                 )
             if include_usage:
                 yield Annotated.from_data(

@@ -143,6 +143,12 @@ class LLMEngineOutput:
     # per-token top-k alternatives: list (parallel to token_ids) of
     # [[token_id, logprob], ...] rows
     top_logprobs: list[list[list]] | None = None
+    # unix seconds at which the engine's device thread emitted this step
+    # (JaxLlmEngine always stamps it; left off the wire when unset).  The
+    # HTTP frontend observes now − emitted_ts when it writes the chunk:
+    # ``http.emit_lag``, a lag between two wall clocks, so it means the
+    # path's time only where frontend and engine share a host
+    emitted_ts: float | None = None
 
     def to_wire(self) -> dict:
         d: dict[str, Any] = {"token_ids": self.token_ids}
@@ -160,6 +166,8 @@ class LLMEngineOutput:
             d["logprobs"] = self.logprobs
         if self.top_logprobs is not None:
             d["top_logprobs"] = self.top_logprobs
+        if self.emitted_ts is not None:
+            d["emitted_ts"] = self.emitted_ts
         return d
 
     @classmethod
@@ -174,6 +182,7 @@ class LLMEngineOutput:
             error=d.get("error"),
             logprobs=d.get("logprobs"),
             top_logprobs=d.get("top_logprobs"),
+            emitted_ts=d.get("emitted_ts"),
         )
 
 
@@ -187,10 +196,13 @@ class Annotated(Generic[T]):
     id: str | None = None
     event: str | None = None
     comment: list[str] = field(default_factory=list)
+    # in-process only (never on the wire): the engine's emit stamp of the
+    # LLMEngineOutput this item was made from, for ``http.emit_lag``
+    emitted_ts: float | None = None
 
     @classmethod
-    def from_data(cls, data: T) -> "Annotated[T]":
-        return cls(data=data)
+    def from_data(cls, data: T, emitted_ts: float | None = None) -> "Annotated[T]":
+        return cls(data=data, emitted_ts=emitted_ts)
 
     @classmethod
     def from_annotation(cls, event: str, value: Any) -> "Annotated[T]":

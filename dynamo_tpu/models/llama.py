@@ -311,13 +311,15 @@ def llama_forward_trunk(
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
-        attn = dense_causal_attention(
-            q[None], k[None], v[None], seq_len[None],
-            sliding_window=cfg.sliding_window,
-        )[0]
+        with jax.named_scope("attn"):
+            attn = dense_causal_attention(
+                q[None], k[None], v[None], seq_len[None],
+                sliding_window=cfg.sliding_window,
+            )[0]
         x = x + mm(attn.reshape(s, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, None
 
     x, _ = jax.lax.scan(layer, x, params["layers"])
@@ -325,9 +327,10 @@ def llama_forward_trunk(
 
 
 def _logits(params, cfg, x):
-    if cfg.tie_word_embeddings:
-        return x @ params["embed"].T.astype(x.dtype)
-    return mm(x, params["lm_head"])
+    with jax.named_scope("logits"):
+        if cfg.tie_word_embeddings:
+            return x @ params["embed"].T.astype(x.dtype)
+        return mm(x, params["lm_head"])
 
 
 def llama_forward_prefill(
@@ -393,17 +396,21 @@ def llama_forward_prefill_embeds(
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
-        k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, block_ids, seq_len)
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, block_ids, seq_len)
         if sp_mesh is not None:
-            attn = ring_attention(q[None], k[None], v[None], seq_len, sp_mesh)[0]
+            with jax.named_scope("attn"):
+                attn = ring_attention(q[None], k[None], v[None], seq_len, sp_mesh)[0]
         else:
-            attn = dense_causal_attention(
-                q[None], k[None], v[None], seq_len[None],
-                sliding_window=cfg.sliding_window,
-            )[0]
+            with jax.named_scope("attn"):
+                attn = dense_causal_attention(
+                    q[None], k[None], v[None], seq_len[None],
+                    sliding_window=cfg.sliding_window,
+                )[0]
         x = x + mm(attn.reshape(s, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, (k_layer, v_layer)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -460,7 +467,8 @@ def llama_forward_prefill_with_prefix(
         # gather the resident prefix BEFORE writing the tail (the mask in
         # the attention op drops everything past start_pos anyway)
         k_prefix, v_prefix = gather_prefix_kv(k_layer, v_layer, full_block_ids)
-        k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, tail_block_ids, tail_len)
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, tail_block_ids, tail_len)
         if sp_mesh is not None:
             attn = ring_attention_with_prefix(
                 q[None], k[None], v[None], k_prefix[None], v_prefix[None],
@@ -473,7 +481,8 @@ def llama_forward_prefill_with_prefix(
             )
         x = x + mm(attn.reshape(s, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, (k_layer, v_layer)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -551,11 +560,14 @@ def llama_forward_decode(
         # apply_rope expects a seq axis: insert and drop it
         q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
-        k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slot_ids)
-        attn = attend(q, k_layer, v_layer)
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slot_ids)
+        with jax.named_scope("attn"):
+            attn = attend(q, k_layer, v_layer)
         x = x + mm(attn.reshape(b, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, (k_layer, v_layer)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -629,11 +641,14 @@ def llama_forward_unified(
         k = apply_rope(k, positions, cos, sin)
         # every token writes before anyone reads: span tokens see their own
         # in-window predecessors through the cache (pads scatter-drop)
-        k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, token_slot)
-        attn = attend(q, k_layer, v_layer)
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, token_slot)
+        with jax.named_scope("attn"):
+            attn = attend(q, k_layer, v_layer)
         x = x + mm(attn.reshape(t, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, (k_layer, v_layer)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -686,14 +701,17 @@ def llama_forward_verify(
         q = apply_rope(q.reshape(b, w_len, cfg.num_heads, cfg.head_dim), positions, cos, sin)
         k = apply_rope(k.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim), positions, cos, sin)
         v = v.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim)
-        k_layer, v_layer = write_decode_kv(
-            k_layer, v_layer, k.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
-            v.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim), flat_slots,
-        )
-        attn = attend(q, k_layer, v_layer)
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_decode_kv(
+                k_layer, v_layer, k.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
+                v.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim), flat_slots,
+            )
+        with jax.named_scope("attn"):
+            attn = attend(q, k_layer, v_layer)
         x = x + mm(attn.reshape(b * w_len, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
         return x, (k_layer, v_layer)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -734,11 +752,13 @@ def llama_forward_decode_pp(
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q[:, None], pos_mb[:, None], cos, sin)[:, 0]
         k = apply_rope(k[:, None], pos_mb[:, None], cos, sin)[:, 0]
-        k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slots_mb)
-        attn = paged_decode_attention(
-            q, k_layer, v_layer, tables_mb, lens_mb,
-            sliding_window=cfg.sliding_window,
-        )
+        with jax.named_scope("kv_write"):
+            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slots_mb)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attention(
+                q, k_layer, v_layer, tables_mb, lens_mb,
+                sliding_window=cfg.sliding_window,
+            )
         x_mb = x_mb + mm(attn.reshape(x_mb.shape[0], -1), w["wo"])
         mlp_in = rms_norm(x_mb, w["mlp_norm"], cfg.rms_norm_eps)
         x_mb = x_mb + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)

@@ -21,7 +21,7 @@ One request, one ``trace_id``, visible in every layer it touches:
 See docs/observability.md for the metric families, env vars, and formats.
 """
 
-from dynamo_tpu.observability.flight import FlightRecorder, flight_dir, latest_dump, load_dump
+from dynamo_tpu.observability.flight import FlightRecorder, flight_dir, load_dump
 from dynamo_tpu.observability.perf import ModelCost, UtilizationTracker, model_cost
 from dynamo_tpu.observability.recorder import (
     Span,
@@ -31,7 +31,7 @@ from dynamo_tpu.observability.recorder import (
 )
 from dynamo_tpu.observability.slo import SloConfig, SloObjective, SloTracker
 from dynamo_tpu.observability.step_metrics import StepTelemetry
-from dynamo_tpu.observability.trace import TraceContext, new_span_id, new_trace_id
+from dynamo_tpu.observability.trace import TraceContext
 
 __all__ = [
     "FlightRecorder",
@@ -46,10 +46,7 @@ __all__ = [
     "UtilizationTracker",
     "flight_dir",
     "get_recorder",
-    "latest_dump",
     "load_dump",
     "model_cost",
-    "new_span_id",
-    "new_trace_id",
     "set_recorder",
 ]

@@ -87,16 +87,6 @@ def flight_dir() -> Path:
     return base / "flight"
 
 
-def latest_dump(directory: str | os.PathLike | None = None) -> Path | None:
-    """Newest flight dump in ``directory`` (default :func:`flight_dir`)."""
-    root = Path(directory) if directory is not None else flight_dir()
-    try:
-        dumps = sorted(root.glob("flight-*.jsonl"), key=lambda p: p.stat().st_mtime)
-    except OSError:
-        return None
-    return dumps[-1] if dumps else None
-
-
 def load_dump(path: str | os.PathLike) -> tuple[dict, list[dict]]:
     """(header, records) of one JSONL flight dump."""
     header: dict = {}
@@ -194,13 +184,6 @@ class FlightRecorder:
     def records(self) -> list[dict]:
         with self._lock:
             return [rec for _, rec in self._ring]
-
-    def occupancy(self) -> float:
-        """Ring fullness (bytes used / budget) — the dyn_top FLIGHT column."""
-        if not self.capacity_bytes:
-            return 0.0
-        with self._lock:
-            return self.buffer_bytes / self.capacity_bytes
 
     def stats(self) -> dict:
         """``flight_*`` keys merged into engine ``stats()`` (metrics service

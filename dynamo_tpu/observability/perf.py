@@ -32,9 +32,11 @@ from __future__ import annotations
 
 import threading
 import time
-from dynamo_tpu.utils import knobs
 from collections import deque
 from dataclasses import dataclass
+
+from dynamo_tpu.observability.step_metrics import StepRecord
+from dynamo_tpu.utils import knobs
 
 # THE peak table: (bf16 peak FLOP/s, HBM bytes/s) of one chip, keyed by the
 # ``device_kind`` jax reports.  Source: Google Cloud TPU documentation, the
@@ -97,13 +99,15 @@ class ModelCost:
     attn_flops_per_ctx_token: int   # QK^T + AV FLOPs per attended ctx token
     kv_bytes_per_token: int         # KV cache bytes written per new token
 
+    def attn_flops(self, attn_ctx_tokens: int) -> float:
+        """FLOPs of attention's own products over ``attn_ctx_tokens``
+        attended context positions (QK^T and attention·V, every layer)."""
+        return attn_ctx_tokens * self.attn_flops_per_ctx_token
+
     def flops(self, tokens: int, attn_ctx_tokens: int) -> float:
         """Total FLOPs to compute ``tokens`` new positions that together
         attended ``attn_ctx_tokens`` context positions."""
-        return (
-            tokens * self.linear_flops_per_token
-            + attn_ctx_tokens * self.attn_flops_per_ctx_token
-        )
+        return tokens * self.linear_flops_per_token + self.attn_flops(attn_ctx_tokens)
 
     def bytes_moved(
         self, tokens: int, attn_ctx_tokens: int, weight_streams: float
@@ -249,43 +253,44 @@ class UtilizationTracker:
         self.emitted_tokens_total = 0
         self.flops_total = 0.0
         self.bytes_total = 0.0
-        self.busy_time_total_s = 0.0
 
-    def observe_step(
-        self,
-        *,
-        duration_s: float,
-        prefill_tokens: int = 0,
-        decode_tokens: int = 0,
-        attn_ctx_tokens: int = 0,
-        weight_streams: float = 0.0,
-        emitted_tokens: int = 0,
-        now: float | None = None,
-    ) -> None:
-        tokens = prefill_tokens + decode_tokens
-        flops = self.cost.flops(tokens, attn_ctx_tokens) if tokens else 0.0
+    def observe(self, rec: StepRecord, now: float | None = None) -> None:
+        """Book one engine step; the step's own FLOPs are written back onto
+        ``rec`` for whoever reads the record next (the flight ring's
+        per-step ``mfu``)."""
+        tokens = rec.prefill_tokens + rec.decode_tokens
+        flops = self.cost.flops(tokens, rec.attn_ctx_tokens) if tokens else 0.0
         moved = (
-            self.cost.bytes_moved(tokens, attn_ctx_tokens, weight_streams)
-            if (tokens or weight_streams)
+            self.cost.bytes_moved(tokens, rec.attn_ctx_tokens, rec.weight_streams)
+            if (tokens or rec.weight_streams)
             else 0.0
         )
+        rec.flops = flops
         t = time.monotonic() if now is None else now
         with self._lock:
-            self.prefill_tokens_total += prefill_tokens
-            self.decode_tokens_total += decode_tokens
-            self.emitted_tokens_total += emitted_tokens
+            self.prefill_tokens_total += rec.prefill_tokens
+            self.decode_tokens_total += rec.decode_tokens
+            self.emitted_tokens_total += rec.emitted_tokens
             self.flops_total += flops
             self.bytes_total += moved
-            if tokens:
-                self.busy_time_total_s += duration_s
             self._samples.append(
                 _Sample(
-                    t=t, duration_s=duration_s, flops=flops, bytes_moved=moved,
-                    emitted_tokens=emitted_tokens, prefill_tokens=prefill_tokens,
-                    decode_tokens=decode_tokens,
+                    t=t, duration_s=rec.duration_s, flops=flops, bytes_moved=moved,
+                    emitted_tokens=rec.emitted_tokens,
+                    prefill_tokens=rec.prefill_tokens,
+                    decode_tokens=rec.decode_tokens,
                 )
             )
             self._prune(t)
+
+    def step_mfu(self, rec: StepRecord) -> float | None:
+        """MFU of ONE observed step from its own FLOPs and duration (None
+        when the device has no published peak)."""
+        if not self.peak_flops:
+            return None
+        if rec.duration_s <= 0.0:
+            return 0.0
+        return min(rec.flops / rec.duration_s / self.peak_flops, 1.0)
 
     def _prune(self, now: float) -> None:
         horizon = now - self.window_s
@@ -340,6 +345,5 @@ class UtilizationTracker:
             tokens_emitted_total=self.emitted_tokens_total,
             model_flops_total=self.flops_total,
             model_bytes_total=self.bytes_total,
-            busy_time_total_s=self.busy_time_total_s,
         )
         return out

@@ -21,11 +21,21 @@ Exports:
 Buffer size: ``DYN_TRACE_BUFFER`` (spans, default 4096).  Per-process
 singleton via :func:`get_recorder`; tests may install a fresh one with
 :func:`set_recorder`.
+
+The ring forgets; the aggregate does not.  Every recorded span (and every
+bare duration handed to :meth:`SpanRecorder.observe`) also lands in a
+per-``(component, name)`` running count / total / max and a fixed geometric
+histogram of durations (:data:`HIST_MIN_S` … 1,000 s, ratio
+:data:`HIST_RATIO`).  :meth:`SpanRecorder.aggregate` returns it as plain
+dicts; ``JaxLlmEngine.stats()`` carries it under ``"spans"``, so a reader
+that takes ``stats()`` at a window's two ends gets the window's own
+percentiles from the difference of two histograms.
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
 import threading
 import time
@@ -36,6 +46,21 @@ from dynamo_tpu.observability.trace import TraceContext
 from dynamo_tpu.utils import knobs
 
 _DEFAULT_BUFFER = 4096
+
+# Duration histogram shared by every series: bucket 0 is [0, HIST_MIN_S),
+# bucket i (1 … HIST_BUCKETS) is [HIST_MIN_S·r^(i-1), HIST_MIN_S·r^i), the
+# last bucket is everything above (past 1,000 s).  A percentile read from it is
+# off by at most one ratio step.
+HIST_MIN_S = 5e-5
+HIST_RATIO = 1.2
+HIST_BUCKETS = math.ceil(math.log(1000.0 / HIST_MIN_S) / math.log(HIST_RATIO))
+_LOG_RATIO = math.log(HIST_RATIO)
+
+
+def hist_bucket(duration_s: float) -> int:
+    if duration_s < HIST_MIN_S:
+        return 0
+    return min(1 + int(math.log(duration_s / HIST_MIN_S) / _LOG_RATIO), HIST_BUCKETS + 1)
 
 
 @dataclass
@@ -115,6 +140,8 @@ class SpanRecorder:
             max_spans = knobs.get("DYN_TRACE_BUFFER")
         self._spans: deque[Span] = deque(maxlen=max(max_spans, 1))
         self._lock = threading.Lock()
+        # (component, name) -> [count, total_s, max_s, bucket counts]
+        self._series: dict[tuple[str, str], list] = {}
         self._jsonl_path = jsonl_path or knobs.get("DYN_TRACE_JSONL") or None
         if max_jsonl_bytes is None:
             max_jsonl_bytes = knobs.get("DYN_TRACE_MAX_BYTES")
@@ -181,9 +208,42 @@ class SpanRecorder:
         )
         return ctx
 
+    def _observe_locked(self, component: str, name: str, duration_s: float) -> None:
+        row = self._series.get((component, name))
+        if row is None:
+            row = self._series[(component, name)] = [0, 0.0, 0.0, [0] * (HIST_BUCKETS + 2)]
+        row[0] += 1
+        row[1] += duration_s
+        if duration_s > row[2]:
+            row[2] = duration_s
+        row[3][hist_bucket(duration_s)] += 1
+
+    def observe(self, name: str, duration_s: float, *, component: str) -> None:
+        """A duration with no span around it (per-chunk paths, where a Span
+        object per token would be the cost): aggregate only, no ring entry."""
+        with self._lock:
+            self._observe_locked(component, name, max(0.0, duration_s))
+
+    def aggregate(self) -> dict:
+        """``{"hist": {min_s, ratio, buckets}, "series": {component: {name:
+        {count, total_s, max_s, counts}}}}`` since the recorder was made."""
+        with self._lock:
+            rows = [(key, row[0], row[1], row[2], list(row[3]))
+                    for key, row in self._series.items()]
+        series: dict[str, dict] = {}
+        for (component, name), count, total_s, max_s, counts in rows:
+            series.setdefault(component, {})[name] = {
+                "count": count, "total_s": total_s, "max_s": max_s, "counts": counts,
+            }
+        return {
+            "hist": {"min_s": HIST_MIN_S, "ratio": HIST_RATIO, "buckets": HIST_BUCKETS},
+            "series": series,
+        }
+
     def _record(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
+            self._observe_locked(span.component, span.name, span.duration_s)
         if self._jsonl_path:
             line = json.dumps(span.to_dict(), default=str) + "\n"
             with self._file_lock:
@@ -265,10 +325,6 @@ class SpanRecorder:
         return summary
 
     # -- exporters ---------------------------------------------------------
-    def to_jsonl(self, trace_id: str | None = None) -> str:
-        spans = self.spans_for(trace_id) if trace_id else self.snapshot()
-        return "".join(json.dumps(s.to_dict(), default=str) + "\n" for s in spans)
-
     def export_jsonl(self, path: str, trace_id: str | None = None) -> int:
         spans = self.spans_for(trace_id) if trace_id else self.snapshot()
         with open(path, "w") as f:

@@ -1,17 +1,51 @@
 """Engine step telemetry.
 
-The engine's device loop calls :meth:`StepTelemetry.observe_step` once per
-scheduler iteration (plain Python assignments under the GIL — safe to read
-from the asyncio thread).  The snapshot rides the existing telemetry path:
-``JaxLlmEngine.stats()`` merges it, ``WorkerMetricsPublisher`` ships it as
-``ForwardPassMetrics``, and ``components/metrics_service.py`` exports it as
-``dyn_worker_*`` Prometheus gauges — no new registry, one coherent pipeline.
+The engine's device loop builds one :class:`StepRecord` per scheduler
+iteration and hands the same object to :meth:`StepTelemetry.observe`, to
+``UtilizationTracker.observe`` and to the flight ring (plain Python
+assignments under the GIL — safe to read from the asyncio thread).  The
+snapshot rides the existing telemetry path: ``JaxLlmEngine.stats()`` merges
+it, ``WorkerMetricsPublisher`` ships it as ``ForwardPassMetrics``, and
+``components/metrics_service.py`` exports it as ``dyn_worker_*`` Prometheus
+gauges — no new registry, one coherent pipeline.
+
+Every iteration is booked by KIND: ``prompt`` when the window it is booked
+to carried at least one prompt token, ``decode`` when it carried none.  The
+window an iteration is booked to is the one the DEVICE was executing while
+the iteration ran: under the overlapped pipeline iteration k schedules and
+dispatches window k and then waits for window k-1, so its time belongs to
+window k-1 (the engine passes that window's kind; see
+``JaxLlmEngine._device_loop``).
 """
 
 from __future__ import annotations
 
 import time
 from dataclasses import dataclass
+
+KIND_DECODE, KIND_PROMPT = "decode", "prompt"
+
+
+@dataclass(slots=True)
+class StepRecord:
+    """Everything one engine iteration knows about itself, built once."""
+
+    iteration: int = 0
+    kind: str = KIND_DECODE             # of the window this time is booked to
+    duration_s: float = 0.0
+    readback_wait_s: float = 0.0        # host blocked on the device
+    num_running: int = 0
+    num_waiting: int = 0
+    kv_active_blocks: int = 0
+    kv_total_blocks: int = 0
+    prefill_tokens: int = 0             # prompt tokens computed this step
+    decode_tokens: int = 0              # decode positions computed this step
+    decode_lane_steps: int = 0          # running decode lanes x device steps
+    attn_ctx_tokens: int = 0            # attended context positions
+    weight_streams: float = 0.0         # full weight passes dispatched
+    emitted_tokens: int = 0
+    # filled by UtilizationTracker.observe (the cost model lives there)
+    flops: float = 0.0
 
 
 @dataclass
@@ -39,37 +73,36 @@ class StepTelemetry:
         self.steps_total = 0
         self.busy_steps_total = 0        # steps with at least one running lane
         self.step_time_total_s = 0.0
+        # by kind of the window the time is booked to
+        self.kind_steps_total = {KIND_DECODE: 0, KIND_PROMPT: 0}
+        self.kind_time_total_s = {KIND_DECODE: 0.0, KIND_PROMPT: 0.0}
+        self.host_time_total_s = 0.0     # step time less readback wait
+        self.decode_lane_steps_total = 0
 
-    def observe_step(
-        self,
-        *,
-        iteration: int,
-        num_running: int,
-        num_waiting: int,
-        kv_active_blocks: int,
-        kv_total_blocks: int,
-        step_duration_s: float,
-        prefill_tokens: int = 0,
-        decode_tokens: int = 0,
-    ) -> None:
+    def observe(self, rec: StepRecord) -> None:
         self.snapshot = StepSnapshot(
-            iteration=iteration,
-            num_running=num_running,
-            num_waiting=num_waiting,
-            batch_occupancy_perc=num_running / self.max_batch_size,
+            iteration=rec.iteration,
+            num_running=rec.num_running,
+            num_waiting=rec.num_waiting,
+            batch_occupancy_perc=rec.num_running / self.max_batch_size,
             kv_usage_perc=(
-                kv_active_blocks / kv_total_blocks if kv_total_blocks else 0.0
+                rec.kv_active_blocks / rec.kv_total_blocks
+                if rec.kv_total_blocks else 0.0
             ),
-            kv_active_blocks=kv_active_blocks,
-            step_duration_s=step_duration_s,
+            kv_active_blocks=rec.kv_active_blocks,
+            step_duration_s=rec.duration_s,
             timestamp_s=time.time(),
-            prefill_tokens=prefill_tokens,
-            decode_tokens=decode_tokens,
+            prefill_tokens=rec.prefill_tokens,
+            decode_tokens=rec.decode_tokens,
         )
         self.steps_total += 1
-        if num_running:
+        if rec.num_running:
             self.busy_steps_total += 1
-        self.step_time_total_s += step_duration_s
+        self.step_time_total_s += rec.duration_s
+        self.kind_steps_total[rec.kind] += 1
+        self.kind_time_total_s[rec.kind] += rec.duration_s
+        self.host_time_total_s += max(0.0, rec.duration_s - rec.readback_wait_s)
+        self.decode_lane_steps_total += rec.decode_lane_steps
 
     def stats(self) -> dict:
         """Merged into ``JaxLlmEngine.stats()`` (names stable: the wire
@@ -88,4 +121,10 @@ class StepTelemetry:
             "engine_busy_steps_total": self.busy_steps_total,
             "engine_step_time_total_s": self.step_time_total_s,
             "last_step_duration_s": s.step_duration_s,
+            "engine_decode_steps_total": self.kind_steps_total[KIND_DECODE],
+            "engine_decode_step_time_total_s": self.kind_time_total_s[KIND_DECODE],
+            "engine_prompt_steps_total": self.kind_steps_total[KIND_PROMPT],
+            "engine_prompt_step_time_total_s": self.kind_time_total_s[KIND_PROMPT],
+            "engine_host_time_total_s": self.host_time_total_s,
+            "decode_lane_steps_total": self.decode_lane_steps_total,
         }

@@ -156,12 +156,6 @@ K_PROFILER_PORT = register(
 K_PROFILER_TRACE_DIR = register(
     "DYN_PROFILER_TRACE_DIR", type="str", default=None,
     doc="capture a device trace of the whole engine serve window here", section=OBS)
-K_XPROF_ANNOTATE = register(
-    "DYN_XPROF_ANNOTATE", type="bool", default=False,
-    doc="wrap hot steps in `jax.profiler.TraceAnnotation`", section=OBS)
-K_ENGINE_PHASE_TIMING = register(
-    "DYN_ENGINE_PHASE_TIMING", type="bool", default=False,
-    doc="host-side decode phase timing in `stats()[\"phase_ms\"]`", section=OBS)
 
 # -- utilization / SLO (docs/observability.md) -------------------------------
 K_UTIL_WINDOW_S = register(

@@ -5,18 +5,16 @@ The reference relies on external genai-perf plus ``tracing`` spans
 ``jax.profiler`` so any engine process can expose traces.
 
 - ``start_server(port)``: serve the profiler so TensorBoard/xprof can attach.
-- ``trace(path)``: context manager capturing a trace of the enclosed steps.
 - env ``DYN_PROFILER_PORT``: auto-start the profiler server in serving paths.
 - env ``DYN_PROFILER_TRACE_DIR``: capture a device trace of the whole engine
   serve window (``maybe_start_trace_from_env`` at engine start,
   ``maybe_stop_trace`` at engine stop) — open the result in TensorBoard /
-  xprof, where ``DYN_XPROF_ANNOTATE=1`` span names line up with host spans.
+  xprof, where the engine's ``dyn.<phase>`` annotations (always emitted)
+  sit beside the device planes.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
 
 from dynamo_tpu.utils.logging import get_logger
 from dynamo_tpu.utils import knobs
@@ -79,25 +77,3 @@ def maybe_stop_trace() -> None:
         logger.warning("profiler trace stop failed: %r", exc)
     finally:
         _trace_dir = None
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a device trace: ``with trace('/tmp/tb'): run_steps()``."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("trace written to %s", log_dir)
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named span visible in device traces."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -2,8 +2,8 @@
 
 Builds a const-init engine (same construction as bench.py's rungs), drives
 a fixed batch of greedy requests, and prints one JSON line with per-phase
-wall time from the engine's DYN_ENGINE_PHASE_TIMING accounting
-(decode.schedule / upload / dispatch / readback / retire / post) plus ITL
+wall time from the engine's always-on host-phase accounting
+(schedule / pack / upload / dispatch / readback / post) plus ITL
 and throughput.  Exists to answer "where do the decode milliseconds go":
 host<->device round-trips versus compute (what the fused decode_steps>1
 path, the overlapped decode pipeline, and upload caching exist to
@@ -13,9 +13,9 @@ A/B mode (``--ab``) runs the same workload twice — synchronous decode
 (``decode_overlap=False``) then the overlapped pipeline — and reports
 steps/s plus each mode's per-phase share of decode wall.  Exits nonzero
 when overlap regresses throughput below ``--ab-min-speedup`` (default:
-any regression fails).  In overlap mode the synchronous ``decode.readback``
-phase disappears by construction: the wait moves to ``decode.retire``,
-which runs while the NEXT window computes on device.
+any regression fails).  ``readback`` is the host blocked on the device in
+both modes; in overlap mode that wait is for the PREVIOUS window and runs
+while the next one computes on device.
 
 Mixed A/B mode (``--mixed``) drives a CONTINUOUS ARRIVAL stream — requests
 land every ``--arrival-ms`` while earlier ones decode, with chunked prefill
@@ -50,7 +50,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ["DYN_ENGINE_PHASE_TIMING"] = "1"
 
 
 def _family_setup(args: argparse.Namespace):
@@ -72,12 +71,19 @@ def _family_setup(args: argparse.Namespace):
 
 
 def _decode_phase_shares(phase_ms: dict) -> dict:
-    """Each decode.* phase's share of total decode wall (0..1)."""
-    decode = {k: v["total_ms"] for k, v in phase_ms.items() if k.startswith("decode.")}
-    total = sum(decode.values())
+    """Each step phase's share of the step loop's wall (0..1)."""
+    from dynamo_tpu.engine.engine import STEP_PHASES
+
+    step = {k: phase_ms[k]["total_ms"] for k in STEP_PHASES if k in phase_ms}
+    total = sum(step.values())
     if total <= 0:
         return {}
-    return {k: round(v / total, 4) for k, v in decode.items()}
+    return {k: round(v / total, 4) for k, v in step.items()}
+
+
+def _reset_phase_stats(engine) -> None:
+    for row in engine.phase_stats.values():
+        row[:] = [0.0, 0]
 
 
 async def run(args: argparse.Namespace, *, overlap: bool | None = None) -> dict:
@@ -192,7 +198,7 @@ async def run(args: argparse.Namespace, *, overlap: bool | None = None) -> dict:
     # queued prefill programs and bills them to decode).
     async def clear_at_steady():
         await all_started.wait()
-        engine.phase_stats.clear()
+        _reset_phase_stats(engine)
 
     t0 = time.monotonic()
     results = await asyncio.gather(
@@ -312,7 +318,7 @@ async def run_mixed(args: argparse.Namespace, *, unified: bool) -> dict:
     warm.append(asyncio.ensure_future(drive(make_request())))
     await asyncio.gather(*warm)
     before = engine.stats()
-    engine.phase_stats.clear()
+    _reset_phase_stats(engine)
     t0 = time.monotonic()
     tasks = []
     if getattr(args, "decode_heavy", False):
@@ -438,9 +444,8 @@ async def amain(args: argparse.Namespace) -> tuple[int, dict]:
         "overlap_speedup_steps_s": round(
             over["steps_s"] / sync["steps_s"], 3
         ) if sync["steps_s"] else 0.0,
-        "readback_share_sync": sync["decode_phase_share"].get("decode.readback", 0.0),
-        "readback_share_overlap": over["decode_phase_share"].get("decode.readback", 0.0),
-        "retire_share_overlap": over["decode_phase_share"].get("decode.retire", 0.0),
+        "readback_share_sync": sync["decode_phase_share"].get("readback", 0.0),
+        "readback_share_overlap": over["decode_phase_share"].get("readback", 0.0),
         "sync": sync,
         "overlap": over,
     }

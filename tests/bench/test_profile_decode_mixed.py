@@ -45,8 +45,7 @@ def _assert_mixed_ok(rc, result):
     assert result["unified_speedup_steps_s"] > 0.0
 
 
-async def test_profile_decode_mixed_smoke(monkeypatch):
-    monkeypatch.setenv("DYN_ENGINE_PHASE_TIMING", "1")
+async def test_profile_decode_mixed_smoke():
     from profile_decode import amain
 
     rc, result = await amain(mixed_args())
@@ -54,10 +53,9 @@ async def test_profile_decode_mixed_smoke(monkeypatch):
     assert result["family"] == "llama"
 
 
-async def test_profile_decode_mixed_moe_family(monkeypatch):
+async def test_profile_decode_mixed_moe_family():
     """--family moe: the Mixtral routed-expert unified forward serves the
     same continuous-arrival A/B end to end."""
-    monkeypatch.setenv("DYN_ENGINE_PHASE_TIMING", "1")
     from profile_decode import amain
 
     rc, result = await amain(
@@ -68,10 +66,9 @@ async def test_profile_decode_mixed_moe_family(monkeypatch):
     assert result["model"] == "tiny_moe"
 
 
-async def test_profile_decode_mixed_mla_family(monkeypatch):
+async def test_profile_decode_mixed_mla_family():
     """--family mla: the DeepSeek latent-KV unified forward serves the
     same continuous-arrival A/B end to end."""
-    monkeypatch.setenv("DYN_ENGINE_PHASE_TIMING", "1")
     from profile_decode import amain
 
     rc, result = await amain(
@@ -82,10 +79,9 @@ async def test_profile_decode_mixed_mla_family(monkeypatch):
     assert result["model"] == "tiny_mla"
 
 
-async def test_profile_decode_mixed_decode_heavy(monkeypatch):
+async def test_profile_decode_mixed_decode_heavy():
     """--decode-heavy: burst admission packs the window with decode lanes;
     the unified engine still serves ragged windows and never drains."""
-    monkeypatch.setenv("DYN_ENGINE_PHASE_TIMING", "1")
     from profile_decode import amain
 
     rc, result = await amain(

@@ -1,6 +1,7 @@
 """DYN_PROFILER_TRACE_DIR wires utils.profiling into the engine serve path:
 engine.start() opens a jax profiler trace, engine.stop() writes it — on the
-CPU backend here, so the hook is covered without hardware."""
+CPU backend here, so the hook is covered without hardware.  The trace holds
+the engine's always-on ``dyn.*`` host annotations and its named programs."""
 
 import jax
 
@@ -44,6 +45,19 @@ async def test_profiler_trace_dir_env_captures_serve_window(tmp_path, monkeypatc
     # stop() wrote the capture: xprof traces land under plugins/profile/
     written = list(trace_dir.rglob("*"))
     assert any(p.is_file() for p in written), written
+    # with no switch set, the trace holds the step loop's host phases
+    # ("dyn.<phase>" TraceAnnotations) beside the programs, which are named
+    # by kind and bucket, not "step"
+    from benchmark import host_spans, trace
+
+    planes = host_spans.load_planes(trace.find_xplane(str(trace_dir)))
+    names = {e[0] for lines in planes.values() for events in lines.values() for e in events}
+    assert {"dyn.schedule", "dyn.upload", "dyn.dispatch", "dyn.readback", "dyn.post"} <= names
+    assert any(n.startswith("PjitFunction(dyn_unified_t16") for n in names), sorted(
+        n for n in names if "Pjit" in n)
+    assert any(n.startswith("PjitFunction(dyn_decode_w1") for n in names)
+    assert not any(n.startswith("PjitFunction(step") for n in names)
+    assert host_spans.attribute(planes)["annotations"]["dyn.dispatch"] >= 4
     # the env hook is once-per-process; a second engine must not re-arm it
     # against the (already consumed) global trace state
     engine2 = JaxLlmEngine(

@@ -13,6 +13,7 @@ from dynamo_tpu.observability.perf import (
     detect_peaks,
     model_cost,
 )
+from dynamo_tpu.observability.step_metrics import StepRecord
 
 # tiny geometry chosen so every term is hand-checkable
 TINY = LlamaConfig(
@@ -82,10 +83,8 @@ def test_tracker_rates_are_hand_computable():
         cost, peak_flops=1000.0, peak_bytes_per_s=1000.0, window_s=10.0
     )
     # one step at t=100: 5 tokens, 10 ctx tokens, 1 weight stream, 5 emitted
-    t.observe_step(
-        duration_s=1.0, prefill_tokens=3, decode_tokens=2, attn_ctx_tokens=10,
-        weight_streams=1, emitted_tokens=5, now=100.0,
-    )
+    t.observe(StepRecord(duration_s=1.0, prefill_tokens=3, decode_tokens=2, attn_ctx_tokens=10,
+        weight_streams=1, emitted_tokens=5), now=100.0)
     r = t.rates(now=101.0)
     # flops = 5*10 + 10*2 = 70 over 1s of 1000 peak
     assert abs(r["mfu_perc"] - 0.07) < 1e-9
@@ -94,7 +93,7 @@ def test_tracker_rates_are_hand_computable():
     assert abs(r["goodput_tokens_per_second"] - 5.0) < 1e-9
     assert abs(r["prefill_tokens_per_second"] - 3.0) < 1e-9
     # totals are cumulative and survive window pruning
-    t.observe_step(duration_s=1.0, prefill_tokens=1, now=200.0)
+    t.observe(StepRecord(duration_s=1.0, prefill_tokens=1), now=200.0)
     assert t.prefill_tokens_total == 4
     assert t.decode_tokens_total == 2
     # the window moved on: only the t=200 sample remains
@@ -106,7 +105,7 @@ def test_tracker_idle_gaps_drag_utilization_down():
     cost = ModelCost(100, 200, 10, 2, 4)
     t = UtilizationTracker(cost, peak_flops=1000.0, peak_bytes_per_s=1e12,
                            window_s=100.0)
-    t.observe_step(duration_s=1.0, decode_tokens=10, now=0.0)
+    t.observe(StepRecord(duration_s=1.0, decode_tokens=10), now=0.0)
     # same work, read after 1s vs after 10s of wall clock
     busy = t.rates(now=1.0)["mfu_perc"]
     idle = t.rates(now=10.0)["mfu_perc"]
@@ -135,8 +134,8 @@ def test_unknown_device_kind_has_no_peak(monkeypatch):
     monkeypatch.delenv("DYN_PEAK_GBPS", raising=False)
     assert detect_peaks() is None  # tests run on the CPU backend
     t = UtilizationTracker(ModelCost(10, 20, 20, 4, 8), window_s=10.0)
-    t.observe_step(duration_s=0.1, decode_tokens=4, attn_ctx_tokens=8,
-                   weight_streams=1.0, emitted_tokens=4, now=1.0)
+    t.observe(StepRecord(duration_s=0.1, decode_tokens=4, attn_ctx_tokens=8,
+                   weight_streams=1.0, emitted_tokens=4), now=1.0)
     r = t.rates(now=1.0)
     assert r["mfu_perc"] is None and r["bandwidth_util_perc"] is None
     assert r["goodput_tokens_per_second"] > 0.0

@@ -8,7 +8,7 @@ import json
 import pytest
 
 from dynamo_tpu.observability import flight
-from dynamo_tpu.observability.flight import FlightRecorder, latest_dump, load_dump
+from dynamo_tpu.observability.flight import FlightRecorder, load_dump
 from dynamo_tpu.utils.tasks import spawn_logged
 
 
@@ -79,7 +79,7 @@ def test_dump_roundtrip_and_latest(flight_tmp):
     assert ts == sorted(ts)
     # the ring is NOT cleared by a dump: a later trigger sees the window
     assert len(rec) == 11
-    assert latest_dump(flight_tmp) == path
+    assert sorted(flight_tmp.glob("flight-*.jsonl")) == [path]
     # every line is standalone JSON (the JSONL contract)
     for line in path.read_text().splitlines():
         json.loads(line)

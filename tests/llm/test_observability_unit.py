@@ -4,6 +4,7 @@ semantics, JSONL/Chrome exporters, lifecycle summaries, step telemetry."""
 import json
 
 from dynamo_tpu.observability import SpanRecorder, StepTelemetry, TraceContext
+from dynamo_tpu.observability.step_metrics import StepRecord
 from dynamo_tpu.observability.trace import sanitize_request_id
 
 
@@ -119,14 +120,10 @@ def test_live_jsonl_streaming(tmp_path):
 
 def test_step_telemetry_snapshot_and_counters():
     t = StepTelemetry(max_batch_size=8)
-    t.observe_step(
-        iteration=1, num_running=4, num_waiting=2, kv_active_blocks=32,
-        kv_total_blocks=64, step_duration_s=0.01,
-    )
-    t.observe_step(
-        iteration=2, num_running=0, num_waiting=0, kv_active_blocks=0,
-        kv_total_blocks=64, step_duration_s=0.02,
-    )
+    t.observe(StepRecord(iteration=1, num_running=4, num_waiting=2, kv_active_blocks=32,
+        kv_total_blocks=64, duration_s=0.01))
+    t.observe(StepRecord(iteration=2, num_running=0, num_waiting=0, kv_active_blocks=0,
+        kv_total_blocks=64, duration_s=0.02))
     stats = t.stats()
     assert stats["engine_steps_total"] == 2
     assert stats["engine_busy_steps_total"] == 1
@@ -136,10 +133,8 @@ def test_step_telemetry_snapshot_and_counters():
     assert stats["step_kv_usage_perc"] == 0.0
     assert t.snapshot.kv_usage_perc == 0.0
     # occupancy of the busy step was 0.5
-    t.observe_step(
-        iteration=3, num_running=8, num_waiting=1, kv_active_blocks=64,
-        kv_total_blocks=64, step_duration_s=0.0,
-    )
+    t.observe(StepRecord(iteration=3, num_running=8, num_waiting=1, kv_active_blocks=64,
+        kv_total_blocks=64, duration_s=0.0))
     assert t.stats()["batch_occupancy_perc"] == 1.0
     assert t.stats()["step_kv_usage_perc"] == 1.0
     assert t.snapshot.kv_usage_perc == 1.0
@@ -179,10 +174,8 @@ def test_jsonl_rotation_resumes_from_existing_file(tmp_path):
 
 def test_step_telemetry_token_counts():
     t = StepTelemetry(max_batch_size=8)
-    t.observe_step(
-        iteration=1, num_running=1, num_waiting=0, kv_active_blocks=1,
-        kv_total_blocks=64, step_duration_s=0.01,
-        prefill_tokens=32, decode_tokens=4,
-    )
+    t.observe(StepRecord(iteration=1, num_running=1, num_waiting=0, kv_active_blocks=1,
+        kv_total_blocks=64, duration_s=0.01,
+        prefill_tokens=32, decode_tokens=4))
     assert t.snapshot.prefill_tokens == 32
     assert t.snapshot.decode_tokens == 4

@@ -25,6 +25,7 @@ MODEL_DIR = str(Path(__file__).parent.parent / "data" / "tiny-chat-model")
 # spans the tree must contain, with the layer that records each
 EXPECTED_SPANS = {
     "http.request": "frontend",
+    "preprocess": "preprocessor",
     "router.schedule": "router",
     "dispatch": "frontend",
     "worker.handle": "worker",
@@ -135,6 +136,22 @@ async def test_span_tree_end_to_end(tmp_path):
             assert summary["decode_s"] > 0
             assert summary["ttft_s"] is not None and summary["ttft_s"] >= 0
             assert summary["tokens_out"] == 8
+
+            # the aggregate keeps what the ring forgets: every recorded span
+            # counts under (component, name), and the duration the frontend
+            # observes without a span (engine emit on the device thread ->
+            # chunk written, the stamp having crossed the data plane on the
+            # wire)
+            series = rec.aggregate()["series"]
+            for name, component in EXPECTED_SPANS.items():
+                assert series[component][name]["count"] >= 1, name
+            assert series["frontend"]["http.emit_lag"]["count"] == 8
+            assert 0 <= series["frontend"]["http.emit_lag"]["max_s"] < 5.0
+            pre = next(s for s in spans if s.name == "preprocess")
+            assert by_id[pre.parent_span_id].name == "http.request"
+            assert pre.attrs["prompt_tokens"] > 0
+            # the worker's stats() carries the same aggregate
+            assert "engine.queue" in worker.engine.stats()["spans"]["series"]["engine"]
 
             # frontend /metrics: TTFT + ITL histograms observed samples
             # (8 streamed tokens -> 1 TTFT sample, 7 ITL samples)
