@@ -75,8 +75,8 @@ STEP_PHASES = ("schedule", "pack", "upload", "dispatch", "readback", "post")
 
 # Attention-kernel work counters (cumulative, in stats() from engine start).
 KERNEL_WORK_KEYS = (
-    "ragged_live_pages_total",      # worklist entries with a live page
-    "ragged_page_slots_total",      # grid steps launched: token blocks x page slots
+    "ragged_live_pages_total",      # pages the ragged kernel walked (pack_spans' page_total)
+    "ragged_page_slots_total",      # page iterations it executed: the same count, no dead step
     "ragged_attn_flops_total",      # QK^T + attention·V over the attended context, all layers
     "ragged_kv_read_bytes_total",   # whole pages walked x page bytes, all layers
     "decode_attn_flops_total",
@@ -594,7 +594,7 @@ class JaxLlmEngine:
         # open engine.prefill spans of prompt work dispatched that nothing
         # waits for; the next window dispatched inherits them (_take_unwaited)
         self._unwaited_prefills: list = []
-        # Attention-kernel work, counted where the worklists are built
+        # Attention-kernel work, counted where the page spans are packed
         # (cumulative; stats() carries every key from engine start)
         self._kernel_work = dict.fromkeys(KERNEL_WORK_KEYS, 0)
         # DYN_PROFILER_TRACE_DIR: set when start() opened a device trace
@@ -688,23 +688,18 @@ class JaxLlmEngine:
         self.unified_batch = unified
         self._unified_windows = 0     # mixed windows served by one dispatch
         self._admission_drains = 0    # pipeline drains forced by admission
-        # ragged kernel tunables (token-block size, page-worklist width,
-        # pages per grid step), precedence: explicit knob > tuned
+        # ragged kernel tunable, precedence: explicit knob > tuned
         # KERNEL_PERF.json row (ops/autotune.py) > heuristic default.
         # tb: the flat token axis pads to whole kernel blocks of this many
         # tokens; lanes PACK within a block (per-row routing), so this is
-        # launch-grid granularity only.  ps: static worklist width — ONE
-        # shape per token bucket, so compiles (and AOT warming) never churn
-        # on batch composition; the full width (tb * max_blocks_per_seq)
-        # always fits, a tuned tighter width falls back to it through the
-        # overflow repack ladder in _run_unified.
+        # launch-grid granularity only.  The kernel's page loop runs each
+        # block's live page count, so there is no width to configure and
+        # ONE program per token bucket whatever the batch composition.
         import math as _math
 
         tb_default = _math.gcd(config.block_size, 8) or 1
         tuned = self._resolve_tuned_kernel_config(cfg)
         knob_tb = knobs.get("DYN_AUTOTUNE_TB")
-        knob_ps = knobs.get("DYN_AUTOTUNE_PAGE_SLOTS")
-        knob_pps = knobs.get("DYN_AUTOTUNE_PAGES_PER_STEP")
         # a tb that cannot pack every unified bucket would split-fallback
         # every window: validate tuned/knob choices against the prospective
         # bucket set (chunk + mixed buckets are added below, after this)
@@ -729,56 +724,20 @@ class JaxLlmEngine:
                 tb, sorted(prospective), tb_default,
             )
             tb = tb_default
-        tuned_fits = tuned is not None and int(tuned["tb_tokens"]) == tb
-        pps = int(
-            knob_pps
-            or ((tuned or {}).get("pages_per_step") if tuned_fits else 0)
-            or 1
-        )
-        ps_full = tb * self.max_blocks_per_seq
-        pps = max(1, min(pps, ps_full))
-        ps = int(
-            knob_ps
-            or ((tuned or {}).get("page_slots") if tuned_fits else 0)
-            or ps_full
-        )
-        # kernel contract: page_slots is a positive multiple of
-        # pages_per_step; the overflow ladder's full width too
-        ps = -(-max(pps, min(ps, ps_full)) // pps) * pps
         self._unified_tb = tb
-        self._unified_ps = ps
-        self._unified_pps = pps
-        self._unified_ps_full = -(-ps_full // pps) * pps
-        self._unified_ps_overflows = 0  # windows repacked at full width
-        blocks_per_launch = None
-        if unified and self.attention_impl.startswith("pallas"):
-            # the kernel wrapper tiles the token axis so each launch's
-            # worklists fit scalar memory; a width where not even one row
-            # tile fits is refused HERE (ValueError), from shapes alone
-            from dynamo_tpu.ops.pallas.ragged_attention import (
-                worklist_rows_per_call,
-            )
-
-            blocks_per_launch = worklist_rows_per_call(
-                self._unified_ps_full, tb
-            )
-        if knob_tb or knob_ps or knob_pps:
+        if knob_tb:
             source = "knob"
-        elif tuned_fits:
+        elif tuned is not None and int(tuned["tb_tokens"]) == tb:
             source = "tuned"
         else:
             source = "default"
         self._kernel_config = {
-            "tb_tokens": tb, "page_slots": ps, "pages_per_step": pps,
+            "tb_tokens": tb,
             "source": source,
             "geometry": getattr(self, "_kernel_geometry", None),
-            "blocks_per_launch": blocks_per_launch,
         }
         if source != "default":
-            logger.info(
-                "unified kernel config (%s): tb_tokens=%d page_slots=%d "
-                "pages_per_step=%d", source, tb, ps, pps,
-            )
+            logger.info("unified kernel config (%s): tb_tokens=%d", source, tb)
         self._fb_zero = None          # resident all-zero feedback tokens
         self._seed_none = None        # resident no-op seed scatter args
         # Per-lane block-table host rows, rewritten only for lanes whose
@@ -1386,7 +1345,7 @@ class JaxLlmEngine:
     def _build_unified(self):
         """Ragged unified-batch step: ONE launch computes chunked-prefill
         spans and decode tokens from different sequences (flat token axis +
-        per-token lane/pos metadata + packed page worklist, forward_unified
+        per-token lane/pos metadata + per-block page spans, forward_unified
         → ragged paged attention), then samples one token per lane.  Key-fold, penalty, bias and
         guided-free logits math mirror the split programs bit-for-bit so
         the two paths keep byte-identical outputs:
@@ -1410,8 +1369,8 @@ class JaxLlmEngine:
 
         def step(params, cache, gen_counts, prompt_counts, token_ids,
                  feedback, use_fb, block_tables, context_lens, token_pos,
-                 token_slot, token_lane, page_phys, page_lane, page_ord,
-                 page_count, sample_rows, sample_gate, seed_lanes,
+                 token_slot, token_lane, span_lane, span_first, span_count,
+                 page_total, sample_rows, sample_gate, seed_lanes,
                  seed_prompt, seed_gen, keys, temp, top_k, top_p, greedy,
                  pres, freq, rep, bias_ids, bias_vals, cos, sin):
             lane_c = jnp.clip(token_lane, 0, lanes - 1)
@@ -1421,10 +1380,9 @@ class JaxLlmEngine:
             tok = jnp.where(use_fb, feedback[lane_c], token_ids)
             logits, cache = self.family.forward_unified(
                 params, cfg, tok, cache, block_tables, context_lens,
-                token_pos, token_slot, token_lane, page_phys, page_lane,
-                page_ord, page_count, sample_rows, cos, sin,
+                token_pos, token_slot, token_lane, span_lane, span_first,
+                span_count, page_total, sample_rows, cos, sin,
                 attention=self.attention_impl, tb_tokens=tb,
-                pages_per_step=self._unified_pps,
             )  # [lanes, vocab]
             prompt_counts = prompt_counts.at[seed_lanes].set(
                 seed_prompt, mode="drop"
@@ -2154,13 +2112,12 @@ class JaxLlmEngine:
             # unified compile buckets: every reachable token-axis bucket —
             # bounded by one chunk window plus a full complement of packed
             # decode lanes — gets its mixed program warmed with the exact
-            # avals _run_unified ships (page worklist shapes included), so
+            # avals _run_unified ships (the page spans' shapes included), so
             # the first mixed window after a cold start never compiles on
             # the device thread
             nseed = self._unified_seed_slots
             tb = self._unified_tb
             pallas = self.attention_impl.startswith("pallas")
-            ps = self._unified_ps if pallas else 1
             if self.chunk_tokens is not None:
                 ucap = self._bucket_len(
                     min(self.chunk_tokens + lanes, self.max_len)
@@ -2176,8 +2133,7 @@ class JaxLlmEngine:
                     self._jit_unified,
                     (params_a, cache_a, counts_a, counts_a, tok_a, lanes_i,
                      sds((b,), jnp.bool_), tables_a, lanes_i, tok_a, tok_a,
-                     tok_a, sds((ntb, ps), jnp.int32),
-                     sds((ntb, ps), jnp.int32), sds((ntb, ps), jnp.int32),
+                     tok_a, tok_a, tok_a, tok_a,
                      sds((ntb,), jnp.int32), lanes_i, lanes_i,
                      sds((nseed,), jnp.int32), sds((nseed, vocab), jnp.int32),
                      sds((nseed, vocab), jnp.int32), keys_a, *tail(lanes),
@@ -2242,16 +2198,13 @@ class JaxLlmEngine:
             # reason-slug → count of windows (or the engine init) that fell
             # back from the unified step; each reason also logged once
             "unified_fallbacks": dict(self._unified_fallbacks),
-            # resolved ragged-kernel tunables (source: knob / tuned / default)
+            # resolved ragged-kernel token block (source: knob / tuned / default)
             "kernel_config": dict(self._kernel_config),
             "attention_impl": self.attention_impl,
             "device": dict(self._device_info),
             # programs requested from the compiler / answered by the
             # persistent cache since engine init (utils/compile_cache.py)
             **compile_counts(),
-            # windows whose page worklist outgrew the tuned page_slots and
-            # repacked at the untuned full-size grid (autotune too tight)
-            "unified_ps_overflows_total": self._unified_ps_overflows,
             "decode_steps_total": self._decode_steps_total,
             "guided_requests_total": self._guided_requests,
             "guided_completions_total": self._guided_completions,
@@ -2742,46 +2695,25 @@ class JaxLlmEngine:
             cursor += span
 
         tables = self._decode_tables(decodes + [s for s, _, _ in spans])
-        # packed-lane page worklist: resolve each token block's pages on the
-        # host (the kernel reads physical page ids straight from scalar
-        # prefetch — no per-block lane routing).  The worklist width is the
-        # engine-fixed self._unified_ps, so every window of this bucket
-        # shares ONE compiled program regardless of batch composition.
+        # per token block, the spans of pages its lanes can see: the ragged
+        # kernel loops over exactly those (it resolves physical pages from
+        # the block tables itself), so every window of this bucket shares
+        # ONE compiled program regardless of batch composition.
         pallas = self.attention_impl.startswith("pallas")
         if pallas:
-            from dynamo_tpu.ops.pallas import pack_page_meta
+            from dynamo_tpu.ops.pallas import pack_spans
 
             self._phase("pack")
-            sw = self._sliding_window
-            try:
-                page_meta = pack_page_meta(
-                    token_lane, token_pos, self._bt_host,
-                    tb_tokens=tb, block_size=bs,
-                    page_slots=self._unified_ps,
-                    sliding_window=sw,
-                )
-            except ValueError:
-                if self._unified_ps_full <= self._unified_ps:
-                    raise
-                # tuned page_slots too tight for this window's worklist:
-                # repack at the untuned full-size rung (at most one extra
-                # compiled program per bucket) instead of failing the window
-                self._unified_ps_overflows += 1
-                page_meta = pack_page_meta(
-                    token_lane, token_pos, self._bt_host,
-                    tb_tokens=tb, block_size=bs,
-                    page_slots=self._unified_ps_full,
-                    sliding_window=sw,
-                )
+            page_meta = pack_spans(
+                token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                block_size=bs, sliding_window=self._sliding_window,
+            )
         else:
             # the XLA twin routes per token off token_lane/token_pos and
-            # never reads the worklist: ship minimal fixed-shape dummies
-            num_tb = max(1, bucket // tb)
+            # never reads the spans: ship fixed-shape zeros
+            flat = np.zeros((bucket,), np.int32)
             page_meta = (
-                np.zeros((num_tb, 1), np.int32),
-                np.full((num_tb, 1), -1, np.int32),
-                np.zeros((num_tb, 1), np.int32),
-                np.zeros((num_tb,), np.int32),
+                flat, flat, flat, np.zeros((max(1, bucket // tb),), np.int32)
             )
         self._phase("upload")
         sampling_tail = self._device_sampling_tail(emit_seqs, lanes)
@@ -2854,12 +2786,13 @@ class JaxLlmEngine:
             self._decode_steps_total += 1
             self._step_lane_steps += len(decodes)
         if pallas:
-            # the kernel's work, from the worklist just built: every live
-            # entry is one whole page DMA'd and one grid step computed
+            # the kernel's work, from the spans just built: every page of a
+            # span is one whole page copied and one loop iteration computed,
+            # and the loop runs no other iteration
             cost = self.utilization.cost
             work = self._kernel_work
             work["ragged_live_pages_total"] += live_pages
-            work["ragged_page_slots_total"] += page_meta[0].size
+            work["ragged_page_slots_total"] += live_pages
             work["ragged_attn_flops_total"] += cost.attn_flops(ragged_ctx)
             work["ragged_kv_read_bytes_total"] += (
                 live_pages * bs * cost.kv_bytes_per_token

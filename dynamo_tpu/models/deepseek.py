@@ -532,9 +532,8 @@ def _mla_decode_attn(w, x, cfg: DeepseekConfig, positions, k_layer, v_layer,
 
 def _mla_unified_attn(w, x, cfg: DeepseekConfig, positions, token_pos,
                       token_lane, token_slot, k_layer, v_layer, block_tables,
-                      page_phys, page_lane, page_ord, page_count, cos, sin,
-                      attention: str = "jax", tb_tokens: int = 8,
-                      pages_per_step: int = 1):
+                      span_lane, span_first, span_count, page_total, cos, sin,
+                      attention: str = "jax", tb_tokens: int = 8):
     """Absorbed-form ragged unified-batch MLA attention: the flat token
     axis carries chunked-prefill spans + decode tokens, every token writes
     its latent before anyone reads, scores stay in latent space per token.
@@ -568,8 +567,8 @@ def _mla_unified_attn(w, x, cfg: DeepseekConfig, positions, token_pos,
 
         ctx = ragged_mla_attention(
             q_lat, q_rope, ck3, kr3, token_lane, token_pos,
-            page_phys, page_lane, page_ord, page_count,
-            scale=scale, tb_tokens=tb_tokens, pages_per_step=pages_per_step,
+            block_tables, span_lane, span_first, span_count, page_total,
+            scale=scale, tb_tokens=tb_tokens,
             interpret=attention == "pallas_interpret",
         )
     else:
@@ -796,17 +795,16 @@ def deepseek_forward_unified(
     token_pos,      # [T] int32 absolute position (-1 = pad)
     token_slot,     # [T] int32 flat cache slot (OOB = pad)
     token_lane,     # [T] int32 owning lane (OOB = pad)
-    page_phys,      # [T // tb_tokens, PS] int32 (pack_page_meta)
-    page_lane,      # [T // tb_tokens, PS] int32 owning lane (-1 pad)
-    page_ord,       # [T // tb_tokens, PS] int32 page ordinal
-    page_count,     # [T // tb_tokens] int32 live worklist entries
+    span_lane,      # [T] int32 (pack_spans): block t's span s at t*tb+s
+    span_first,     # [T] int32 first page ordinal of the span
+    span_count,     # [T] int32 pages in the span (0 = unused)
+    page_total,     # [T // tb_tokens] int32 live pages per token block
     sample_rows,    # [lanes] int32 flat index of span's LAST token
     cos,
     sin,
     *,
     attention: str = "jax",     # "jax" | "pallas" | "pallas_interpret"
     tb_tokens: int = 8,
-    pages_per_step: int = 1,
 ):
     """Ragged unified-batch forward for the MLA family: mixed spans +
     decode tokens in one launch against the latent cache (the llama
@@ -820,9 +818,8 @@ def deepseek_forward_unified(
     def attn(w, attn_in, k_layer, v_layer):
         return _mla_unified_attn(
             w, attn_in, cfg, positions, token_pos, token_lane, token_slot,
-            k_layer, v_layer, block_tables, page_phys, page_lane, page_ord,
-            page_count, cos, sin, attention=attention, tb_tokens=tb_tokens,
-            pages_per_step=pages_per_step,
+            k_layer, v_layer, block_tables, span_lane, span_first, span_count,
+            page_total, cos, sin, attention=attention, tb_tokens=tb_tokens,
         )
 
     x, new_cache = _forward(params, cfg, x, kv_cache, attn)

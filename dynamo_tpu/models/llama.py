@@ -588,17 +588,16 @@ def llama_forward_unified(
     token_pos: jnp.ndarray,     # [T] int32 absolute position (-1 = pad)
     token_slot: jnp.ndarray,    # [T] int32 flat cache slot (OOB = pad)
     token_lane: jnp.ndarray,    # [T] int32 owning lane (OOB = pad)
-    page_phys: jnp.ndarray,     # [T // tb_tokens, PS] int32 (pack_page_meta)
-    page_lane: jnp.ndarray,     # [T // tb_tokens, PS] int32 owning lane (-1 pad)
-    page_ord: jnp.ndarray,      # [T // tb_tokens, PS] int32 page ordinal
-    page_count: jnp.ndarray,    # [T // tb_tokens] int32 live worklist entries
+    span_lane: jnp.ndarray,     # [T] int32 (pack_spans): block t's span s at t*tb+s
+    span_first: jnp.ndarray,     # [T] int32 first page ordinal of the span
+    span_count: jnp.ndarray,     # [T] int32 pages in the span (0 = unused)
+    page_total: jnp.ndarray,     # [T // tb_tokens] int32 live pages per token block
     sample_rows: jnp.ndarray,   # [lanes] int32 flat index of span's LAST token
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     *,
     attention: str = "jax",     # "jax" | "pallas" | "pallas_interpret"
     tb_tokens: int = 8,
-    pages_per_step: int = 1,
 ) -> tuple[jnp.ndarray, dict]:
     """Ragged unified-batch forward: one launch computes chunked-prefill
     spans AND decode tokens from different sequences, each token at its own
@@ -622,9 +621,8 @@ def llama_forward_unified(
 
             return ragged_kernel(
                 q, k_layer, v_layer, token_lane, token_pos,
-                page_phys, page_lane, page_ord, page_count,
+                block_tables, span_lane, span_first, span_count, page_total,
                 tb_tokens=tb_tokens,
-                pages_per_step=pages_per_step,
                 interpret=attention == "pallas_interpret",
                 sliding_window=cfg.sliding_window,
             )

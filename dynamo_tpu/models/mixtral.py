@@ -330,17 +330,16 @@ def mixtral_forward_unified(
     token_pos,      # [T] int32 absolute position (-1 = pad)
     token_slot,     # [T] int32 flat cache slot (OOB = pad)
     token_lane,     # [T] int32 owning lane (OOB = pad)
-    page_phys,      # [T // tb_tokens, PS] int32 (pack_page_meta)
-    page_lane,      # [T // tb_tokens, PS] int32 owning lane (-1 pad)
-    page_ord,       # [T // tb_tokens, PS] int32 page ordinal
-    page_count,     # [T // tb_tokens] int32 live worklist entries
+    span_lane,      # [T] int32 (pack_spans): block t's span s at t*tb+s
+    span_first,     # [T] int32 first page ordinal of the span
+    span_count,     # [T] int32 pages in the span (0 = unused)
+    page_total,     # [T // tb_tokens] int32 live pages per token block
     sample_rows,    # [lanes] int32 flat index of span's LAST token
     cos,
     sin,
     *,
     attention: str = "jax",     # "jax" | "pallas" | "pallas_interpret"
     tb_tokens: int = 8,
-    pages_per_step: int = 1,
 ):
     """Ragged unified-batch forward for the sparse-MoE family: the llama
     unified contract (mixed chunked-prefill spans + decode tokens, one
@@ -378,9 +377,9 @@ def mixtral_forward_unified(
 
                 attn_out = ragged_kernel(
                     q, state["kv"][0], state["kv"][1], token_lane, token_pos,
-                    page_phys, page_lane, page_ord, page_count,
+                    block_tables, span_lane, span_first, span_count,
+                    page_total,
                     tb_tokens=tb_tokens,
-                    pages_per_step=pages_per_step,
                     interpret=attention == "pallas_interpret",
                 )
             else:

@@ -1,37 +1,34 @@
 """Kernel autotuner for the ragged packed attention family.
 
-The unified-batch kernels expose a small tunable space that the engine
-historically filled with heuristics:
+The ragged kernels walk exactly the live pages of each token block
+(ops/pallas/ragged_attention.py), so one tunable is left:
 
-- ``tb_tokens`` — token-block size of the packed ragged kernel (was
-  ``gcd(block_size, 8)``);
-- ``page_slots`` — static width of the per-token-block page worklist
-  (was ``tb_tokens * max_blocks_per_seq``, hugely oversized for decode-
-  heavy windows: every step past ``page_count`` is a dead pipeline tick);
-- ``pages_per_step`` — KV pages DMA'd per grid step (ragged kernels) /
-  pages per compute block (``paged_attention`` / ``mla_attention``).
+- ``tb_tokens`` — token-block size of the packed ragged kernel (heuristic
+  default ``gcd(block_size, 8)``): a larger block walks a prompt span's
+  pages fewer times, a smaller one wastes fewer masked rows on the pages
+  of packed decode lanes.
 
-This module sweeps that space per **(model geometry, device_kind,
-dtype)** key.  On CPU the sweep is scored by a deterministic cost model
-over the REAL host packer (``pack_page_meta`` builds the worklists for a
-synthetic decode-heavy + mixed-chunk workload, so packing waste and
-feasibility are exact); on TPU ``scripts/tpu_validate.py --bench`` passes
-a wall-clock ``runner`` and the winner is measured, not modeled.  Winners
-persist as provenance-stamped rows in ``KERNEL_PERF.json`` (same table
-the calibration benches write); the engine resolves them at init with the
-precedence **explicit knob > tuned row > heuristic default**.
+This module sweeps it per **(model geometry, device_kind, dtype)** key.
+On CPU the sweep is scored by a deterministic cost model over the REAL
+host packer (``pack_spans`` builds the span lists for two synthetic
+windows, so the page counts are exact) with per-block / per-page /
+per-row prices read off a TPU v5e (PERF.md section 6, PR 28); on TPU
+``scripts/tpu_validate.py --bench`` passes a wall-clock ``runner`` and the
+winner is measured, not modeled.  Winners persist as provenance-stamped
+rows in ``KERNEL_PERF.json`` (same table the calibration benches write);
+the engine resolves them at init with the precedence **explicit knob >
+tuned row > heuristic default**.
 
-Row schema (version 1)::
+Row schema (version 2)::
 
     {"bench": "autotune_ragged", "geometry": "h4kv2d64-bs4-l4-mb16",
      "device_kind": "any" | "<jax device_kind>", "dtype": "float32",
-     "source": "cost_model" | "measured", "version": 1,
-     "tb_tokens": 4, "page_slots": 16, "pages_per_step": 2,
-     "cost": 123.4, "swept": 18}
+     "source": "cost_model" | "measured", "version": 2,
+     "tb_tokens": 4, "cost": 123.4, "swept": 3}
 
-``source="cost_model"`` rows are hardware-independent layout choices and
-are stamped ``device_kind="any"``; ``source="measured"`` rows are only
-trusted for the device kind that produced them.
+``source="cost_model"`` rows are stamped ``device_kind="any"``;
+``source="measured"`` rows are only trusted for the device kind that
+produced them.
 """
 
 from __future__ import annotations
@@ -44,18 +41,19 @@ import os
 import numpy as np
 
 RAGGED_BENCH = "autotune_ragged"
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 
-# cost-model coefficients (arbitrary units; only ratios matter).  DMA is
-# the dominant real cost of decode attention, per-step overhead is the
-# pipeline bubble each grid step pays, MAC covers the masked score/row
-# waste that grows with tb_tokens, SELECT the per-token routing chain,
-# PAD the dead pipeline tick a deduped pad slot still occupies.
-_C_DMA = 1.0        # per KV byte streamed
-_C_STEP = 4096.0    # per grid step
-_C_MAC = 0.002      # per masked MAC in the score matrix
-_C_SELECT = 64.0    # per select in the routing chain, per live page
-_C_PAD = 256.0      # per dead (pad) worklist slot
+# cost-model prices in nanoseconds, fitted to the live-page kernel alone on
+# a TPU v5e at h32 kv8 d128, block 16, bf16, tb_tokens 2-16 (PERF.md
+# section 6, PR 28): a token block's fixed cost, a page iteration's floor
+# (copy wait + loop), and the page body's cost per score-matrix row at a
+# 128 x 128 page; past 256 rows the softmax state no longer stays in
+# registers and every further row costs several times more.
+_NS_BLOCK = 335.0
+_NS_PAGE = 476.0
+_NS_ROW = 0.855
+_ROWS_IN_REGISTERS = 256
+_NS_SPILLED_ROW = 3.7
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,16 +78,15 @@ class Geometry:
 
 
 def _synthetic_workloads(geom: Geometry, tb: int):
-    """Deterministic (token_lane, token_pos, block_tables) workloads the
-    cost model scores: a full decode window with every lane mid-stream,
-    and a mixed window (decode lanes + one chunked-prefill span).  Both
-    are derived purely from the geometry — no RNG, no wall clock."""
+    """Deterministic (token_lane, token_pos) windows the cost model scores.
+    A unified window always carries a prompt span (decode-only iterations
+    run the decode program), so both do: a whole prompt of a quarter of the
+    context beside the other lanes decoding mid-stream, and a 2-page chunk
+    beside them.  Derived purely from the geometry — no RNG, no wall
+    clock."""
     lanes = geom.lanes
     bs = geom.block_size
     mid = max(bs, (geom.max_blocks_per_seq * bs) // 2)
-    bt = np.arange(
-        lanes * geom.max_blocks_per_seq, dtype=np.int32
-    ).reshape(lanes, geom.max_blocks_per_seq)
 
     def pad_to(arr, fill):
         t_pad = -(-len(arr) // tb) * tb
@@ -97,100 +94,61 @@ def _synthetic_workloads(geom: Geometry, tb: int):
         out[: len(arr)] = arr
         return out
 
-    # decode-heavy: one token per lane, staggered contexts around mid
-    d_lane = np.arange(lanes, dtype=np.int32)
-    d_pos = np.array([mid - 1 + (i % bs) for i in range(lanes)], np.int32)
-    decode = (pad_to(d_lane, lanes), pad_to(d_pos, -1), bt)
-
-    # mixed: a 2-page prefill chunk on lane 0 + the other lanes decoding
-    chunk = 2 * bs
-    m_lane = np.concatenate([
-        np.zeros(chunk, np.int32), np.arange(1, lanes, dtype=np.int32)
-    ])
-    m_pos = np.concatenate([
-        np.arange(chunk, dtype=np.int32),
-        np.array([mid - 1 + (i % bs) for i in range(1, lanes)], np.int32),
-    ])
-    mixed = (pad_to(m_lane, lanes), pad_to(m_pos, -1), bt)
-    return (decode, mixed)
+    # decode lanes first, then the span (the engine's flat-axis order)
+    d_lane = np.arange(1, lanes, dtype=np.int32)
+    d_pos = np.array([mid - 1 + (i % bs) for i in range(1, lanes)], np.int32)
+    windows = []
+    for span in (max(2 * bs, mid // 2), 2 * bs):
+        lane = np.concatenate([d_lane, np.zeros(span, np.int32)])
+        pos = np.concatenate([d_pos, np.arange(span, dtype=np.int32)])
+        windows.append((pad_to(lane, lanes), pad_to(pos, -1)))
+    return windows
 
 
 def _pack_stats(geom: Geometry, tb: int):
-    """Run the real host packer over the synthetic workloads; return
-    (need, per-workload [num_tb, live_pages] pairs).  ``need`` is the
-    tightest page_slots width that fits every workload."""
-    from dynamo_tpu.ops.pallas.ragged_attention import pack_page_meta
+    """Run the real host packer over the synthetic windows; return
+    per-window (token blocks, live pages) pairs."""
+    from dynamo_tpu.ops.pallas.ragged_attention import pack_spans
 
-    need = 1
     stats = []
-    for token_lane, token_pos, bt in _synthetic_workloads(geom, tb):
-        page_phys, _, _, page_count = pack_page_meta(
-            token_lane, token_pos, bt,
+    for token_lane, token_pos in _synthetic_workloads(geom, tb):
+        page_total = pack_spans(
+            token_lane, token_pos, lanes=geom.lanes,
             tb_tokens=tb, block_size=geom.block_size,
-        )
-        need = max(need, page_phys.shape[1])
-        stats.append((page_phys.shape[0], int(page_count.sum())))
-    return need, stats
+        )[3]
+        stats.append((page_total.shape[0], int(page_total.sum())))
+    return stats
 
 
-def cost_model(geom: Geometry, tb: int, ps: int, pps: int,
-               dtype_bytes: int = 4) -> float | None:
-    """Deterministic score (lower is better) for one candidate; None when
-    the candidate cannot hold the synthetic workloads (the engine would
-    hit the overflow-repack ladder on typical traffic)."""
-    need, stats = _pack_stats(geom, tb)
-    if ps < need or ps % pps:
-        return None
-    page_bytes = (
-        2 * geom.block_size * geom.num_kv_heads * geom.head_dim * dtype_bytes
-    )
+def cost_model(geom: Geometry, tb: int) -> float:
+    """Deterministic score (modeled nanoseconds a layer, lower is better)
+    for one token-block size."""
     tbh = tb * geom.num_heads
-    score_cols = geom.block_size * geom.num_kv_heads
-    cost = 0.0
-    for num_tb, live in stats:
-        steps = num_tb * (ps // pps)
-        cost += _C_STEP * steps
-        cost += _C_DMA * live * page_bytes
-        cost += _C_MAC * live * tbh * score_cols
-        cost += _C_SELECT * live * tb
-        cost += _C_PAD * (num_tb * ps - live)
-    return cost
+    # the page body's work per row, relative to the 128 x 128 page the
+    # prices were read at
+    page_scale = (
+        geom.block_size * geom.num_kv_heads * geom.head_dim / (128.0 * 128.0)
+    )
+    per_page = _NS_PAGE + page_scale * (
+        _NS_ROW * tbh + _NS_SPILLED_ROW * max(0, tbh - _ROWS_IN_REGISTERS)
+    )
+    return float(sum(
+        _NS_BLOCK * num_tb + per_page * live
+        for num_tb, live in _pack_stats(geom, tb)
+    ))
 
 
 def candidate_grid(geom: Geometry, buckets: tuple[int, ...] = ()) -> list[dict]:
-    """The swept (tb_tokens, page_slots, pages_per_step) candidates.
-    ``buckets`` (the engine's unified token buckets) constrain tb_tokens:
-    a tb that does not divide every bucket would force the split
-    fallback, so it is never a valid winner."""
+    """The swept tb_tokens candidates.  ``buckets`` (the engine's unified
+    token buckets) constrain them: a tb that does not divide every bucket
+    would force the split fallback, so it is never a valid winner."""
     default_tb = math.gcd(geom.block_size, 8) or 1
-    tbs = sorted({
-        t for t in (1, 2, 4, 8, 16, default_tb)
+    return [
+        {"tb_tokens": t}
+        for t in sorted({1, 2, 4, 8, 16, default_tb})
         if t <= max(geom.lanes, default_tb)
         and all(b % t == 0 for b in buckets)
-    })
-    out = []
-    for tb in tbs:
-        need, _ = _pack_stats(geom, tb)
-        full = tb * geom.max_blocks_per_seq
-        for pps in (1, 2, 4, 8):
-            # round the tight width up to a pps multiple; also sweep a
-            # 2x-slack width and the legacy full width
-            tight = -(-need // pps) * pps
-            for ps in sorted({tight, min(full, 2 * tight), full}):
-                if ps < need or ps % pps:
-                    continue
-                out.append(
-                    {"tb_tokens": tb, "page_slots": ps, "pages_per_step": pps}
-                )
-    # dedup, preserving order
-    seen = set()
-    uniq = []
-    for c in out:
-        k = (c["tb_tokens"], c["page_slots"], c["pages_per_step"])
-        if k not in seen:
-            seen.add(k)
-            uniq.append(c)
-    return uniq
+    ]
 
 
 def sweep(
@@ -206,7 +164,6 @@ def sweep(
     ``callable(candidate) -> wall_us | None`` — when present the sweep is
     *measured* and stamped with the real device kind; otherwise the
     deterministic cost model scores it (``device_kind="any"``)."""
-    dtype_bytes = max(1, np.dtype(dtype).itemsize)
     grid = candidate_grid(geom, buckets)
     if not grid:
         raise ValueError(f"no feasible candidates for {geom.key}")
@@ -215,10 +172,7 @@ def sweep(
         if runner is not None:
             cost = runner(dict(cand))
         else:
-            cost = cost_model(
-                geom, cand["tb_tokens"], cand["page_slots"],
-                cand["pages_per_step"], dtype_bytes,
-            )
+            cost = cost_model(geom, cand["tb_tokens"])
         if cost is None:
             continue
         scored.append((float(cost), cand))
@@ -324,7 +278,7 @@ def resolve(
         and r.get("geometry") == geometry_key
         and r.get("dtype") == str(dtype)
         and r.get("version") == SCHEMA_VERSION
-        and all(k in r for k in ("tb_tokens", "page_slots", "pages_per_step"))
+        and "tb_tokens" in r
     ]
     measured = [
         r for r in rows

@@ -15,7 +15,7 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_window_attention_decode,
 )
 from dynamo_tpu.ops.pallas.ragged_attention import (
-    pack_page_meta,
+    pack_spans,
     ragged_paged_attention,
 )
 from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention
@@ -26,7 +26,7 @@ __all__ = [
     "paged_window_attention_decode",
     "ragged_paged_attention",
     "ragged_mla_attention",
-    "pack_page_meta",
+    "pack_spans",
     "gather_blocks",
     "scatter_blocks",
 ]

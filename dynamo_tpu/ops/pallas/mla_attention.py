@@ -21,6 +21,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.pallas.ragged_attention import (
+    row_routing,
+    softmax_finish,
+    softmax_init,
+    softmax_update,
+    walk_live_pages,
+)
+
 NEG_INF = -1e30
 
 
@@ -303,107 +311,70 @@ def mla_paged_attention_decode(
 def _ragged_kernel(
     token_lane_ref,     # [T] int32 — owning lane per token (OOB = pad)
     token_pos_ref,      # [T] int32 — absolute position per token (-1 = pad)
-    page_phys_ref,      # [num_tb, PS] int32 — physical page per grid step
-    page_lane_ref,      # [num_tb, PS] int32 — lane owning that page
-    page_ord_ref,       # [num_tb, PS] int32 — page ordinal in its lane
-    page_count_ref,     # [num_tb] int32 — live worklist entries
+    block_tables_ref,   # [lanes, max_blocks] int32
+    span_lane_ref,      # [T] int32 — pack_spans
+    span_first_ref,     # [T] int32
+    span_count_ref,     # [T] int32
+    page_total_ref,     # [num_tb] int32 — page iterations per token block
     q_lat_ref,          # [1, TB*H, R]  (token-major fold: row = tok*H + h)
     q_rope_ref,         # [1, TB*H, P]
-    *refs,              # pps × (ck_page [1, bs, R], kr_page [1, bs, P]),
-                        # out [1, TB*H, R], then m/l/acc scratch
+    ck_hbm,             # [N, bs, R] whole latent cache, HBM
+    kr_hbm,             # [N, bs, P]
+    out_ref,            # [1, TB*H, R]
+    ck_buf,             # [2, bs, R] VMEM double buffer
+    kr_buf,             # [2, bs, P]
+    sems,               # DMA semaphores [2, 2]
+    m_ref, l_ref, acc_ref,
+    *,
     block_size: int,
     scale: float,
-    page_slots: int,
     tb_tokens: int,
     num_heads: int,
-    pages_per_step: int,
 ):
-    """Ragged unified-batch MLA: the packed page-worklist loop of
-    ops/pallas/ragged_attention.py applied to the latent cache — two-part
-    scores, latent-space accumulation (decompression outside).  Each grid
-    step folds ``pages_per_step`` consecutive worklist slots into the
-    running softmax (one input stream per slot)."""
-    pps = pages_per_step
-    kv_refs = refs[: 2 * pps]
-    out_ref = refs[2 * pps]
-    m_ref, l_ref, acc_ref = refs[2 * pps + 1:]
+    """Ragged unified-batch MLA: the live-page loop of
+    ops/pallas/ragged_attention.py (walk_live_pages) applied to the latent
+    cache — two-part scores, latent-space accumulation (decompression
+    outside)."""
     t = pl.program_id(0)
-    j = pl.program_id(1)
-    tbh = tb_tokens * num_heads
+    base = t * tb_tokens
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    softmax_init(m_ref, l_ref, acc_ref)
+    q_lat = q_lat_ref[0].astype(jnp.float32)    # [TB*H, R]
+    q_rope = q_rope_ref[0].astype(jnp.float32)  # [TB*H, P]
+    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    row_lane, q_pos = row_routing(
+        token_lane_ref, token_pos_ref, base, tb_tokens=tb_tokens,
+        heads=num_heads,
+    )
 
-    for i in range(pps):
-        slot = j * pps + i
-        page_lane = page_lane_ref[t, slot]
-        page_start = page_ord_ref[t, slot] * block_size
-        ck_page_ref = kv_refs[2 * i]
-        kr_page_ref = kv_refs[2 * i + 1]
-
-        @pl.when(slot < page_count_ref[t])
-        def _compute(
-            ck_page_ref=ck_page_ref, kr_page_ref=kr_page_ref,
-            page_lane=page_lane, page_start=page_start,
-        ):
-            q_lat = q_lat_ref[0].astype(jnp.float32)    # [TB*H, R]
-            q_rope = q_rope_ref[0].astype(jnp.float32)  # [TB*H, P]
-            ck = ck_page_ref[0].astype(jnp.float32)     # [bs, R]
-            kr = kr_page_ref[0].astype(jnp.float32)     # [bs, P]
-            s = (
-                jax.lax.dot_general(
-                    q_lat, ck, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                + jax.lax.dot_general(
-                    q_rope, kr, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            ) * scale                                    # [TB*H, bs]
-            pos = page_start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_size), 1
-            )
-            row = jax.lax.broadcasted_iota(jnp.int32, (tbh, 1), 0)
-            tok_of_row = row // num_heads
-            base = t * tb_tokens
-            q_pos = jnp.full((tbh, 1), -1, jnp.int32)
-            row_lane = jnp.full((tbh, 1), -1, jnp.int32)
-            for rr in range(tb_tokens):
-                q_pos = jnp.where(
-                    tok_of_row == rr, token_pos_ref[base + rr], q_pos
-                )
-                row_lane = jnp.where(
-                    tok_of_row == rr, token_lane_ref[base + rr], row_lane
-                )
-            mask = (row_lane == page_lane) & (pos <= q_pos)
-            s = jnp.where(mask, s, NEG_INF)
-
-            m_prev = m_ref[:, :1]
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, ck, dimension_numbers=(((1,), (0,)), ((), ())),
+    def page_body(slot, page_lane, page_ord):
+        ck = ck_buf[slot].astype(jnp.float32)     # [bs, R]
+        kr = kr_buf[slot].astype(jnp.float32)     # [bs, P]
+        s = (
+            jax.lax.dot_general(
+                q_lat, ck, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            acc_ref[...] = acc_ref[...] * alpha + pv
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            + jax.lax.dot_general(
+                q_rope, kr, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        ) * scale                                    # [TB*H, bs]
+        pos = page_ord * block_size + pos_in_page
+        mask = (row_lane == page_lane) & (pos <= q_pos)
+        softmax_update(s, mask, ck, m_ref, l_ref, acc_ref)
 
-    @pl.when(j == page_slots // pps - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[:, :1], 1e-20)
-        out_ref[0] = (acc_ref[...] / denom).astype(out_ref.dtype)
+    walk_live_pages(
+        base, page_total_ref[t], span_lane_ref, span_first_ref,
+        span_count_ref, block_tables_ref,
+        ((ck_hbm, ck_buf), (kr_hbm, kr_buf)), sems,
+        tb_tokens=tb_tokens, page_body=page_body,
+    )
+    softmax_finish(out_ref, l_ref, acc_ref)
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("scale", "tb_tokens", "pages_per_step", "interpret"),
+    jax.jit, static_argnames=("scale", "tb_tokens", "interpret"),
 )
 def ragged_mla_attention(
     q_lat: jnp.ndarray,         # [T, H, R] flat ragged token batch
@@ -412,22 +383,21 @@ def ragged_mla_attention(
     kr_cache: jnp.ndarray,      # [N, bs, P] rope-key cache
     token_lane: jnp.ndarray,    # [T] int32 owning lane (OOB = pad)
     token_pos: jnp.ndarray,     # [T] int32 absolute position (-1 = pad)
-    page_phys: jnp.ndarray,     # [T // tb_tokens, PS] int32 (pack_page_meta)
-    page_lane: jnp.ndarray,     # [T // tb_tokens, PS] int32
-    page_ord: jnp.ndarray,      # [T // tb_tokens, PS] int32
-    page_count: jnp.ndarray,    # [T // tb_tokens] int32
+    block_tables: jnp.ndarray,  # [lanes, max_blocks] int32
+    span_lane: jnp.ndarray,     # [T] int32 (pack_spans)
+    span_first: jnp.ndarray,    # [T] int32
+    span_count: jnp.ndarray,    # [T] int32
+    page_total: jnp.ndarray,    # [T // tb_tokens] int32
     *,
     scale: float,
     tb_tokens: int = 8,
-    pages_per_step: int = 1,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Ragged unified-batch MLA paged attention with packed lanes: one
     launch over mixed chunked-prefill spans + decode tokens against the
-    latent cache.  Returns the latent-space context [T, H, R] (float32);
-    metadata comes from ragged_attention.pack_page_meta over the latent
-    block tables.  ``pages_per_step`` widens each grid step to DMA that
-    many worklist pages (autotuned; ``page_slots`` must divide evenly)."""
+    latent cache, page iterations = live pages.  Returns the latent-space
+    context [T, H, R] (float32); metadata comes from
+    ragged_attention.pack_spans and the latent block tables."""
     t_pad, h, r = q_lat.shape
     p_dim = q_rope.shape[-1]
     bs = ck_cache.shape[1]
@@ -437,37 +407,29 @@ def ragged_mla_attention(
             f"{tb_tokens}"
         )
     num_tb = t_pad // tb_tokens
-    page_slots = page_phys.shape[1]
-    pps = pages_per_step
-    if pps < 1 or page_slots % pps:
-        raise ValueError(
-            f"page_slots ({page_slots}) must be a positive multiple of "
-            f"pages_per_step ({pps})"
-        )
     tbh = tb_tokens * h
-
-    def kv_map_at(i):
-        def kv_map(t, j, tl, tp, pp, pln, po, pc):
-            return (pp[t, j * pps + i], 0, 0)
-        return kv_map
-
-    kv_specs = []
-    for i in range(pps):
-        m = kv_map_at(i)
-        kv_specs += [
-            pl.BlockSpec((1, bs, r), m),
-            pl.BlockSpec((1, bs, p_dim), m),
-        ]
+    # a page is copied whole by DMA, whose rows must fill the 128-lane
+    # tile: zero-pad a narrower rope part (DeepSeek's 64), which adds
+    # exact zeros to the scores
+    lane_pad = -p_dim % 128
+    if lane_pad:
+        widen = ((0, 0), (0, 0), (0, lane_pad))
+        q_rope, kr_cache = jnp.pad(q_rope, widen), jnp.pad(kr_cache, widen)
+        p_dim += lane_pad
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(num_tb, page_slots // pps),
+        num_scalar_prefetch=7,
+        grid=(num_tb,),
         in_specs=[
-            pl.BlockSpec((1, tbh, r), lambda t, j, *_: (t, 0, 0)),
-            pl.BlockSpec((1, tbh, p_dim), lambda t, j, *_: (t, 0, 0)),
-            *kv_specs,
+            pl.BlockSpec((1, tbh, r), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((1, tbh, p_dim), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, tbh, r), lambda t, j, *_: (t, 0, 0)),
+        out_specs=pl.BlockSpec((1, tbh, r), lambda t, *_: (t, 0, 0)),
         scratch_shapes=[
+            pltpu.VMEM((2, bs, r), ck_cache.dtype),
+            pltpu.VMEM((2, bs, p_dim), kr_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((tbh, 128), jnp.float32),
             pltpu.VMEM((tbh, 128), jnp.float32),
             pltpu.VMEM((tbh, r), jnp.float32),
@@ -477,23 +439,19 @@ def ragged_mla_attention(
         _ragged_kernel,
         block_size=bs,
         scale=scale,
-        page_slots=page_slots,
         tb_tokens=tb_tokens,
         num_heads=h,
-        pages_per_step=pps,
     )
-    kv_args = []
-    for _ in range(pps):
-        kv_args += [ck_cache, kr_cache]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tb, tbh, r), jnp.float32),
         interpret=interpret,
     )(
-        token_lane, token_pos, page_phys, page_lane, page_ord, page_count,
+        token_lane, token_pos, block_tables, span_lane, span_first,
+        span_count, page_total,
         q_lat.reshape(num_tb, tbh, r),
         q_rope.reshape(num_tb, tbh, p_dim),
-        *kv_args,
+        ck_cache, kr_cache,
     )
     return out.reshape(t_pad, h, r)

@@ -241,23 +241,14 @@ K_KERNEL_PERF = register(
         "the repo-root artifact, purely advisory)", section=PERF)
 K_AUTOTUNE = register(
     "DYN_AUTOTUNE", type="bool", default=True,
-    doc="consult KERNEL_PERF.json autotune rows for ragged-kernel tunables "
-        "at engine init; `0` keeps the static heuristic defaults",
+    doc="consult KERNEL_PERF.json autotune rows for the ragged kernel's "
+        "token-block size at engine init; `0` keeps the heuristic default",
     section=PERF)
 K_AUTOTUNE_TB = register(
     "DYN_AUTOTUNE_TB", type="int", default=None,
     doc="force the ragged kernel's token-block size (overrides tuned rows; "
         "must divide every serving bucket or it falls back with a warning)",
     section=PERF)
-K_AUTOTUNE_PAGE_SLOTS = register(
-    "DYN_AUTOTUNE_PAGE_SLOTS", type="int", default=None,
-    doc="force the packed page-worklist width (overflowing windows repack "
-        "at the full-size rung and count in "
-        "`stats()[\"unified_ps_overflows_total\"]`)", section=PERF)
-K_AUTOTUNE_PAGES_PER_STEP = register(
-    "DYN_AUTOTUNE_PAGES_PER_STEP", type="int", default=None,
-    doc="force KV pages fetched per ragged/paged grid step (must divide "
-        "page_slots)", section=PERF)
 
 # -- predictive prefetch (docs/performance.md) -------------------------------
 K_PREFETCH = register(

@@ -329,7 +329,7 @@ def bench_ragged_packed(iters: int) -> list[dict]:
     import jax.numpy as jnp
     import numpy as np
 
-    from dynamo_tpu.ops.pallas import pack_page_meta, ragged_paged_attention
+    from dynamo_tpu.ops.pallas import pack_spans, ragged_paged_attention
 
     rows = []
     tb = 8
@@ -362,20 +362,20 @@ def bench_ragged_packed(iters: int) -> list[dict]:
                 row = lane if packed else lane * tb
                 token_lane[row] = lane
                 token_pos[row] = ctx - 1
-            meta = pack_page_meta(
-                token_lane, token_pos, tables, tb_tokens=tb, block_size=bs
+            meta = pack_spans(
+                token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                block_size=bs,
             )
             q = jnp.asarray(
                 rng.standard_normal((t, qh, d)), jnp.bfloat16
             )
             args = (q, k, v, jnp.asarray(token_lane), jnp.asarray(token_pos),
-                    *(jnp.asarray(a) for a in meta))
+                    jnp.asarray(tables), *(jnp.asarray(a) for a in meta))
             return args, t // tb
 
         fn = jax.jit(
-            lambda q, k, v, tl, tp, pp, pl, po, pc: ragged_paged_attention(
-                q, k, v, tl, tp, pp, pl, po, pc, tb_tokens=tb,
-                interpret=INTERPRET,
+            lambda q, *rest: ragged_paged_attention(
+                q, *rest, tb_tokens=tb, interpret=INTERPRET,
             ).astype(q.dtype)
         )
         chain = lambda a, out: (out,) + a[1:]  # noqa: E731
@@ -414,11 +414,11 @@ AUTOTUNE_GEOMETRIES = (
 
 
 def bench_autotune(iters: int) -> list[dict]:
-    """Ragged-kernel tunable sweep (ops/autotune.py): tb_tokens x
-    page_slots x pages_per_step per geometry.  Off-TPU the deterministic
+    """Ragged-kernel tunable sweep (ops/autotune.py): tb_tokens per
+    geometry.  Off-TPU the deterministic
     cost model scores the grid (hardware-independent rows, device_kind=
     "any"); on real hardware each candidate is additionally WALL-CLOCK
-    timed over a synthetic decode-heavy window and the measured winner is
+    timed over the synthetic prompt window and the measured winner is
     stamped with this chip's device_kind.  The swept grid prints to
     stdout per candidate; only winner rows enter the table."""
     import jax
@@ -426,7 +426,7 @@ def bench_autotune(iters: int) -> list[dict]:
     import numpy as np
 
     from dynamo_tpu.ops import autotune
-    from dynamo_tpu.ops.pallas import pack_page_meta, ragged_paged_attention
+    from dynamo_tpu.ops.pallas import pack_spans, ragged_paged_attention
 
     dev = jax.devices()[0]
     rows = []
@@ -448,40 +448,36 @@ def bench_autotune(iters: int) -> list[dict]:
             continue  # interpret wall clocks say nothing about hardware
 
         # measured sweep at the serving dtype: time the compiled kernel on
-        # this chip over the decode-heavy synthetic window
+        # this chip over the synthetic prompt window
         jdt = jnp.bfloat16
         rng = np.random.default_rng(0)
         pool = lanes * mb + 8
         k = jnp.asarray(rng.standard_normal((pool, bs, kvh, d)), jdt)
         v = jnp.asarray(rng.standard_normal((pool, bs, kvh, d)), jdt)
 
+        bt = jnp.asarray(
+            rng.permutation(pool)[: lanes * mb].reshape(lanes, mb), jnp.int32
+        )
+
         def runner(cand):
             tb = cand["tb_tokens"]
-            ps = cand["page_slots"]
-            pps = cand["pages_per_step"]
-            token_lane, token_pos, bt = autotune._synthetic_workloads(
-                geom, tb
-            )[0]
-            try:
-                meta = pack_page_meta(
-                    token_lane, token_pos, bt, tb_tokens=tb,
-                    block_size=bs, page_slots=ps,
-                )
-            except ValueError:
-                return None  # candidate cannot hold the workload
+            token_lane, token_pos = autotune._synthetic_workloads(geom, tb)[0]
+            meta = pack_spans(
+                token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                block_size=bs,
+            )
             q = jnp.asarray(
                 rng.standard_normal((token_lane.shape[0], h, d)), jdt
             )
             fn = jax.jit(
-                lambda q, k, v, tl, tp, pp, pl, po, pc: ragged_paged_attention(
-                    q, k, v, tl, tp, pp, pl, po, pc, tb_tokens=tb,
-                    pages_per_step=pps, interpret=INTERPRET,
+                lambda q, *rest: ragged_paged_attention(
+                    q, *rest, tb_tokens=tb, interpret=INTERPRET,
                 ).astype(q.dtype)
             )
             chain = lambda a, out: (out,) + a[1:]  # noqa: E731
             us = _time_us(
                 fn, q, k, v,
-                jnp.asarray(token_lane), jnp.asarray(token_pos),
+                jnp.asarray(token_lane), jnp.asarray(token_pos), bt,
                 *(jnp.asarray(a) for a in meta),
                 iters=iters, chain=chain,
             )

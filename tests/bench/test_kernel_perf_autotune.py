@@ -34,31 +34,31 @@ def test_sweep_winner_is_deterministic_and_feasible():
     assert a["dtype"] == "float32"
     assert a["version"] == autotune.SCHEMA_VERSION
     assert a["geometry"] == TINY.key == "h4kv2d16-bs4-l4-mb32"
-    assert a["swept"] == len(grid_a) >= 8
-    # the winner must be feasible: page_slots fits the synthetic
-    # workloads and is a pages_per_step multiple
-    assert a["page_slots"] % a["pages_per_step"] == 0
-    need, _ = autotune._pack_stats(TINY, a["tb_tokens"])
-    assert a["page_slots"] >= need
+    assert a["swept"] == len(grid_a) == 3           # tb_tokens 1, 2, 4
+    # the row carries the one tunable left and nothing of the worklists
+    assert "page_slots" not in a and "pages_per_step" not in a
     # every bucket stays packable at the tuned tb
     assert all(b_ % a["tb_tokens"] == 0 for b_ in (16, 32, 64))
-    # the tuned width beats the legacy full width in the model: the sweep
-    # exists to stop paying dead pad ticks
-    full = a["tb_tokens"] * TINY.max_blocks_per_seq
-    assert a["page_slots"] <= full
+    # a tb that does not divide a bucket is never a candidate
+    assert [c["tb_tokens"] for c in autotune.candidate_grid(TINY, (6, 18))] == [1, 2]
 
 
-def test_cost_model_orders_tight_over_oversized():
-    """An oversized worklist pays _C_PAD per dead slot: for the same
-    (tb, pps) the tight width must never score worse."""
-    tb = 4
-    need, _ = autotune._pack_stats(TINY, tb)
-    tight = autotune.cost_model(TINY, tb, need, 1)
-    full = autotune.cost_model(TINY, tb, tb * TINY.max_blocks_per_seq, 1)
-    assert tight is not None and full is not None
-    assert tight < full
-    # infeasible candidates report None, not a bogus score
-    assert autotune.cost_model(TINY, tb, max(1, need - 1), 1) is None
+def test_cost_model_counts_live_pages_and_the_price_of_rows():
+    """The model's terms, from the packer's own counts: a larger token
+    block walks a prompt span's pages fewer times (fewer live pages, fewer
+    blocks); at the serving geometry a block past 256 score rows pays the
+    spill price, so 16 tokens of 32 heads lose to 8."""
+    stats = {tb: autotune._pack_stats(TINY, tb) for tb in (1, 2, 4)}
+    for small, large in ((1, 2), (2, 4)):
+        for (nb_s, live_s), (nb_l, live_l) in zip(stats[small], stats[large]):
+            assert nb_l < nb_s and live_l < live_s
+    assert autotune.cost_model(TINY, 4) < autotune.cost_model(TINY, 2)
+    serving = autotune.Geometry(
+        num_heads=32, num_kv_heads=8, head_dim=128,
+        block_size=16, lanes=16, max_blocks_per_seq=256,
+    )
+    assert autotune.cost_model(serving, 8) < autotune.cost_model(serving, 4)
+    assert autotune.cost_model(serving, 8) < autotune.cost_model(serving, 16)
 
 
 def test_tune_persists_and_rerun_is_cache_hit(tmp_path):
@@ -70,8 +70,7 @@ def test_tune_persists_and_rerun_is_cache_hit(tmp_path):
     # the persisted row carries full provenance but not the swept grid
     assert "grid" not in table["rows"][0]
     for key in ("bench", "geometry", "device_kind", "dtype", "source",
-                "version", "tb_tokens", "page_slots", "pages_per_step",
-                "cost", "swept"):
+                "version", "tb_tokens", "cost", "swept"):
         assert key in table["rows"][0], key
     before = path.read_text()
     row2, cached2 = autotune.tune(path, TINY, buckets=(16, 32))
@@ -98,10 +97,10 @@ def test_measured_rows_outrank_cost_model_rows():
     modeled = {
         "bench": autotune.RAGGED_BENCH, "geometry": TINY.key,
         "device_kind": "any", "dtype": "float32", "source": "cost_model",
-        "version": 1, "tb_tokens": 4, "page_slots": 8, "pages_per_step": 1,
+        "version": autotune.SCHEMA_VERSION, "tb_tokens": 4,
     }
     measured = dict(modeled, device_kind="TPU v5 lite", source="measured",
-                    page_slots=16, pages_per_step=4)
+                    tb_tokens=2)
     table = {"rows": [modeled, measured]}
     # exact-kind measured row wins
     got = autotune.resolve(
@@ -122,6 +121,12 @@ def test_measured_rows_outrank_cost_model_rows():
         table, geometry_key="h1kv1d8-bs4-l2-mb4", device_kind=None,
         dtype="float32",
     ) is None
+    # rows of the schema that carried worklist widths no longer bind
+    old = dict(modeled, version=1, page_slots=8, pages_per_step=1)
+    assert autotune.resolve(
+        {"rows": [old]}, geometry_key=TINY.key, device_kind=None,
+        dtype="float32",
+    ) is None
 
 
 def test_measured_runner_stamps_device_kind():
@@ -129,15 +134,15 @@ def test_measured_runner_stamps_device_kind():
 
     def runner(cand):
         calls.append(cand)
-        # pretend pps=2 candidates are fastest on this "hardware"
-        return 10.0 if cand["pages_per_step"] == 2 else 100.0
+        # pretend tb=2 candidates are fastest on this "hardware"
+        return 10.0 if cand["tb_tokens"] == 2 else 100.0
 
     row = autotune.sweep(
         TINY, buckets=(16, 32), runner=runner, device_kind="TPU v5 lite",
     )
     assert row["source"] == "measured"
     assert row["device_kind"] == "TPU v5 lite"
-    assert row["pages_per_step"] == 2
+    assert row["tb_tokens"] == 2
     assert len(calls) == row["swept"]
 
 
@@ -158,7 +163,7 @@ def _tuned_row(**kw):
     row = {
         "bench": autotune.RAGGED_BENCH, "geometry": TINY.key,
         "device_kind": "any", "dtype": "float32", "source": "cost_model",
-        "version": 1, "tb_tokens": 4, "page_slots": 8, "pages_per_step": 2,
+        "version": autotune.SCHEMA_VERSION, "tb_tokens": 2,
     }
     row.update(kw)
     return row
@@ -175,12 +180,8 @@ def test_engine_resolves_tuned_row(tmp_path, monkeypatch):
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "tuned"
         assert kc["geometry"] == TINY.key
-        assert (kc["tb_tokens"], kc["page_slots"], kc["pages_per_step"]) == (4, 8, 2)
-        assert engine._unified_tb == 4
-        assert engine._unified_ps == 8
-        assert engine._unified_pps == 2
-        # the overflow rung stays the full width, pps-aligned
-        assert engine._unified_ps_full == 4 * 32
+        assert kc == {"tb_tokens": 2, "source": "tuned", "geometry": TINY.key}
+        assert engine._unified_tb == 2
     finally:
         engine.stop()
 
@@ -194,16 +195,16 @@ def test_engine_default_without_rows(tmp_path, monkeypatch):
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "default"
         assert kc["tb_tokens"] == 4          # gcd(block_size=4, 8)
-        assert kc["page_slots"] == 4 * 32    # legacy full width
-        assert kc["pages_per_step"] == 1
-        assert engine.stats()["unified_ps_overflows_total"] == 0
+        # the worklist width, its overflow counter and the pages folded
+        # into a grid step went with the static worklists
+        assert set(kc) == {"tb_tokens", "source", "geometry"}
+        assert "unified_ps_overflows_total" not in engine.stats()
     finally:
         engine.stop()
 
 
 def test_engine_knob_outranks_tuned_row(tmp_path, monkeypatch):
-    monkeypatch.setenv("DYN_AUTOTUNE_PAGE_SLOTS", "24")
-    monkeypatch.setenv("DYN_AUTOTUNE_PAGES_PER_STEP", "4")
+    monkeypatch.setenv("DYN_AUTOTUNE_TB", "1")
     engine = _engine(
         tmp_path, monkeypatch, table_rows=[_tuned_row()],
         num_blocks=64, block_size=4, max_batch_size=4, max_model_len=128,
@@ -211,8 +212,7 @@ def test_engine_knob_outranks_tuned_row(tmp_path, monkeypatch):
     try:
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "knob"
-        assert kc["page_slots"] == 24
-        assert kc["pages_per_step"] == 4
+        assert kc["tb_tokens"] == 1
     finally:
         engine.stop()
 
@@ -232,11 +232,10 @@ def test_engine_autotune_opt_out(tmp_path, monkeypatch):
 def test_engine_rejects_tuned_tb_that_breaks_buckets(tmp_path, monkeypatch):
     """A tuned tb that cannot pack every unified bucket must fall back to
     the heuristic default (warn, not wedge every window into the split
-    path) — and the tuned ps/pps are dropped with it (they were chosen
-    FOR that tb)."""
+    path)."""
     engine = _engine(
         tmp_path, monkeypatch,
-        table_rows=[_tuned_row(tb_tokens=16, page_slots=32)],
+        table_rows=[_tuned_row(tb_tokens=16)],
         num_blocks=64, block_size=4, max_batch_size=4, max_model_len=128,
         prefill_buckets=(24, 48),
     )
@@ -244,6 +243,5 @@ def test_engine_rejects_tuned_tb_that_breaks_buckets(tmp_path, monkeypatch):
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "default"
         assert kc["tb_tokens"] == 4
-        assert kc["pages_per_step"] == 1
     finally:
         engine.stop()

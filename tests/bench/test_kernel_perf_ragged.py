@@ -3,7 +3,7 @@
 The ``ragged_packed_decode`` rows record what dense lane packing buys over
 the padded per-lane-block layout.  The packing fields (``blocks_packed`` /
 ``blocks_padded`` / ``block_reduction``) are host-side facts computed by
-``pack_page_meta``'s layout math — hardware-independent, so tier-1 can gate
+``pack_spans``' layout math — hardware-independent, so tier-1 can gate
 on them on any box: if a change to the packer or the engine's flat-axis
 layout silently regresses the block count, the recomputed layout here stops
 matching the artifact and this test fails.  Timing fields are advisory
@@ -109,5 +109,6 @@ def test_artifact_autotune_rows_match_cost_model():
             block_size=bs, lanes=lanes, max_blocks_per_seq=mb,
         )
         fresh = autotune.sweep(geom, dtype=r["dtype"])
-        for key in ("tb_tokens", "page_slots", "pages_per_step", "cost"):
+        assert "page_slots" not in r and "pages_per_step" not in r, r
+        for key in ("tb_tokens", "cost"):
             assert fresh[key] == r[key], (key, fresh[key], r)

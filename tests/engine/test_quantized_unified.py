@@ -171,7 +171,7 @@ def test_fp8_unified_forward_kernel_vs_twin():
         llama_forward_unified,
         make_rope_tables,
     )
-    from dynamo_tpu.ops.pallas import pack_page_meta
+    from dynamo_tpu.ops.pallas import pack_spans
 
     cfg = LlamaConfig.tiny()
     params = init_params(cfg, jax.random.PRNGKey(0))
@@ -202,8 +202,8 @@ def test_fp8_unified_forward_kernel_vs_twin():
         ] * bs + np.clip(token_pos, 0, None) % bs,
         10**6,
     ).astype(np.int32)
-    meta = pack_page_meta(token_lane, token_pos, np.asarray(tables),
-                          tb_tokens=tb, block_size=bs, page_slots=8)
+    meta = pack_spans(token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                      block_size=bs)
     tokens = jnp.asarray(np.arange(3, 3 + t_pad) % cfg.vocab_size, jnp.int32)
     args = (
         params, cfg, tokens, cache, tables, jnp.asarray(ctx),
@@ -215,7 +215,7 @@ def test_fp8_unified_forward_kernel_vs_twin():
         *args, attention="jax", tb_tokens=tb
     )
     out_logits, out_cache = llama_forward_unified(
-        *args, attention="pallas_interpret", tb_tokens=tb, pages_per_step=2
+        *args, attention="pallas_interpret", tb_tokens=tb
     )
     assert ref_cache["k"].dtype == jnp.float8_e4m3fn
     np.testing.assert_allclose(

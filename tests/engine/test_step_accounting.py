@@ -15,7 +15,7 @@ from dynamo_tpu.engine.engine import (
     _InflightWindow,
 )
 from dynamo_tpu.observability.step_metrics import KIND_DECODE, KIND_PROMPT
-from dynamo_tpu.ops.pallas import pack_page_meta
+from dynamo_tpu.ops.pallas import pack_spans
 
 from tests.engine.test_jax_engine import CFG, PARAMS, collect, make_engine, request
 
@@ -264,7 +264,7 @@ async def test_a_chunked_prompt_on_a_real_engine_closes_one_prefill_span_a_chunk
         engine.stop()
 
 
-# -- the ragged kernel's worklist ------------------------------------------
+# -- the ragged kernel's page spans -----------------------------------------
 
 def hand_counted(spans, tb, bs, window=None):
     """Live pages of a flat batch laid out span after span: per token block
@@ -284,7 +284,7 @@ def hand_counted(spans, tb, bs, window=None):
 
 
 @pytest.mark.parametrize("window", [None, 8])
-def test_pack_page_meta_live_pages_and_slots_against_a_hand_count(window):
+def test_pack_spans_page_iterations_against_a_hand_count(window):
     tb, bs, lanes = 8, 4, 3
     spans = [(0, 0, 13), (1, 20, 21), (2, 5, 27)]   # a prompt, a decode token, a later chunk
     tokens = sum(end - start for _, start, end in spans)
@@ -296,12 +296,14 @@ def test_pack_page_meta_live_pages_and_slots_against_a_hand_count(window):
         token_lane[i:i + end - start] = lane
         token_pos[i:i + end - start] = np.arange(start, end)
         i += end - start
-    tables = np.arange(lanes * 16, dtype=np.int32).reshape(lanes, 16)
-    page_phys, _, _, page_count = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=tb, block_size=bs, page_slots=16,
+    span_lane, _, span_count, page_count = pack_spans(
+        token_lane, token_pos, lanes=lanes, tb_tokens=tb, block_size=bs,
         sliding_window=window)
+    # the kernel's loop runs page_count[t] iterations for block t: the sum
+    # of its spans' counts, and nothing for a span that is not there
     assert int(page_count.sum()) == hand_counted(spans, tb, bs, window)
-    assert page_phys.size == (t_pad // tb) * 16          # the grid steps launched
+    assert page_count.tolist() == span_count.reshape(-1, tb).sum(axis=1).tolist()
+    assert (span_count[span_lane < 0] == 0).all()
     if window is None:
         # block by block: [13 of lane 0 -> 2 then 4 pages incl. the decode token's 6], ...
         assert int(page_count.sum()) == 2 + (4 + 6 + 2) + 4 + 6 + 7
@@ -322,8 +324,10 @@ async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
         tb, bs = engine._unified_tb, engine.config.block_size
         assert stats["decode_windows_unified_total"] == 1
         assert stats["ragged_live_pages_total"] == hand_counted([(0, 0, n)], tb, bs)
-        bucket = engine._bucket_len(n)
-        assert stats["ragged_page_slots_total"] == (bucket // tb) * engine._unified_ps
+        # the iterations executed: 3 token blocks of 8 walk 1, 2 and 3
+        # pages of 8 positions; the bucket's fourth block walks none
+        assert engine._bucket_len(n) // tb == 4
+        assert stats["ragged_page_slots_total"] == 1 + 2 + 3
         cost = engine.utilization.cost
         assert stats["ragged_attn_flops_total"] == cost.attn_flops(n * (n + 1) // 2)
         assert stats["ragged_kv_read_bytes_total"] == (
@@ -337,6 +341,38 @@ async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
         assert stats["model_flops_total"] == pytest.approx(
             cost.flops(n + 2, n * (n + 1) // 2 + sum(ctxs)))
         assert stats["phase_ms"]["pack"]["n"] == 1
+    finally:
+        engine.stop()
+
+
+async def test_a_mixed_windows_page_iterations_are_its_live_pages():
+    """A prompt admitted beside a running decode (one unified window with a
+    span and a packed decode lane): the iterations the kernel executed, by
+    hand, and the share of them that walked a live page."""
+    import asyncio
+
+    engine = make_engine(attention_impl="pallas_interpret", block_size=8, num_blocks=64,
+                         decode_overlap=False)
+    try:
+        tb, bs = engine._unified_tb, engine.config.block_size
+        first = asyncio.ensure_future(collect(
+            engine, request(list(range(3, 3 + 12)), max_tokens=24, ignore_eos=True)))
+        while engine.stats()["decode_steps_total"] < 2:
+            await asyncio.sleep(0.01)
+        await collect(engine, request(list(range(40, 40 + 19)), max_tokens=2, ignore_eos=True))
+        await first
+        stats = await settled_stats(engine)
+        assert stats["decode_windows_unified_total"] == 2
+        # window 1: the 12-token prompt alone.  window 2: the decode lane's
+        # token at context c (its pages: ceil(c / 8)) packed in front of
+        # the 19-token prompt, so the blocks of 8 hold [decode + 7], [8], [4]
+        live, slots = stats["ragged_live_pages_total"], stats["ragged_page_slots_total"]
+        first_window = hand_counted([(0, 0, 12)], tb, bs)
+        mixed = {hand_counted([(0, c - 1, c), (1, 0, 19)], tb, bs) for c in range(13, 13 + 24)}
+        assert live - first_window in mixed, (live, first_window, sorted(mixed))
+        assert slots == live and live / slots >= 0.8
+        assert stats["ragged_kv_read_bytes_total"] == (
+            live * bs * engine.utilization.cost.kv_bytes_per_token)
     finally:
         engine.stop()
 

@@ -21,10 +21,7 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_attention_decode,
     paged_window_attention_decode,
 )
-from dynamo_tpu.ops.pallas.ragged_attention import (
-    ragged_paged_attention,
-    worklist_rows_per_call,
-)
+from dynamo_tpu.ops.pallas.ragged_attention import ragged_paged_attention
 
 # head geometries: what chip_smoke.py serves on one chip (Llama-3.2-3B) and
 # the repo's headline (Llama-3-8B; also one tp=4 shard's KV width times 4)
@@ -35,11 +32,13 @@ NUM_BLOCKS = 1024
 MAX_LEN = 4096                      # chip_smoke.py --context-length
 MAX_BLOCKS = MAX_LEN // BLOCK
 LANES = 8                           # chip_smoke.py --max-batch-size
-# the engine's unified kernel config for that EngineConfig (tb = gcd(16, 8),
-# full worklist width tb * max_blocks_per_seq, one page per grid step) and
-# every unified bucket it builds: the default prefill buckets up to max_len
-TB, PAGE_SLOTS, PAGES_PER_STEP = 8, 8 * MAX_BLOCKS, 1
+# the engine's unified token block for that EngineConfig (tb = gcd(16, 8))
+# and every unified bucket it builds: the default prefill buckets up to max_len
+TB = 8
 SMOKE_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
+# the benchmark's two serving shapes (BENCHMARK.json: both models are h32 kv8
+# d128): 8 lanes at the default token block, 16 lanes at the tuned one
+CELL_SHAPES = {"lanes8_tb8": (8, 8), "lanes16_tb4": (16, 4)}
 
 
 @pytest.fixture(scope="module")
@@ -91,33 +90,76 @@ def test_window_verify_compiles(one_chip, kv_dtype):
     ).compile()
 
 
-def _ragged_case(s, bucket, model, kv_dtype):
+def _ragged_case(s, bucket, model, kv_dtype, lanes=LANES, tb=TB,
+                 max_blocks=MAX_BLOCKS, sliding_window=None):
     h, kvh, d = GEOMETRY[model]
     cache = _cache(s, kv_dtype, kvh, d)
-    ntb = bucket // TB
-    worklist = s((ntb, PAGE_SLOTS), jnp.int32)
     tok = s((bucket,), jnp.int32)
     return ragged_paged_attention.lower(
         s((bucket, h, d), jnp.bfloat16), cache, cache, tok, tok,
-        worklist, worklist, worklist, s((ntb,), jnp.int32),
-        tb_tokens=TB, pages_per_step=PAGES_PER_STEP,
+        s((lanes, max_blocks), jnp.int32), tok, tok, tok,
+        s((bucket // tb,), jnp.int32),
+        tb_tokens=tb, sliding_window=sliding_window,
     )
 
 
 @pytest.mark.parametrize("bucket", SMOKE_BUCKETS)
 def test_ragged_compiles_at_every_smoke_bucket(one_chip, bucket):
-    """Every (bucket, tb_tokens, page_slots, pages_per_step) the smoke's
-    engine can build.  Before the per-launch worklist tiling, every bucket
-    from 512 up was refused: 'Ran out of memory in memory space smem'."""
+    """Every (bucket, tb_tokens) the smoke's engine can build.  The scalar
+    memory the kernel needs is seven words a token and the block tables."""
     _ragged_case(_sds(one_chip), bucket, "llama32_3b", "bf16").compile()
 
 
 @pytest.mark.parametrize("bucket", [SMOKE_BUCKETS[0], 528, SMOKE_BUCKETS[-1]])
 def test_ragged_fp8_compiles(one_chip, bucket):
     """fp8 KV at the headline geometry: smallest, a chunk+lanes mixed bucket
-    (512 + 16 lanes — 66 token blocks, not a multiple of the launch size),
-    largest."""
+    (512 + 16 lanes), largest."""
     _ragged_case(_sds(one_chip), bucket, "llama3_8b", "fp8").compile()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
+@pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+def test_ragged_compiles_at_every_bucket_of_the_benchmark_cells(
+    one_chip, shape, kv_dtype
+):
+    """The live-page kernel at every unified bucket of both benchmark
+    serving shapes (h32 kv8 d128, context 4,096), bf16 and fp8 KV, with
+    Mistral's sliding window on the 8-lane shape."""
+    lanes, tb = CELL_SHAPES[shape]
+    for bucket in SMOKE_BUCKETS:
+        _ragged_case(
+            _sds(one_chip), bucket, "llama3_8b", kv_dtype, lanes=lanes, tb=tb,
+            sliding_window=4096 if lanes == 8 else None,
+        ).compile()
+
+
+def test_ragged_compiles_at_a_context_of_32768(one_chip):
+    """One whole 32,768-token window against 16 lanes of 2,048-page block
+    tables: five words a token and 128 KiB of tables fit scalar memory.
+    (The static page worklists refused every context past about 19k.)"""
+    _ragged_case(
+        _sds(one_chip), 32768, "llama3_8b", "bf16", lanes=16, max_blocks=2048,
+    ).compile()
+
+
+@pytest.mark.parametrize("bucket,max_blocks", [(4096, 256), (32768, 2048)])
+def test_ragged_mla_compiles_at_deepseek_v2_lite_widths(one_chip, bucket, max_blocks):
+    """The ragged MLA kernel at DeepSeek-V2-Lite's published widths (16
+    heads, latent 512, rope 64), a whole window of the bucket: the static
+    worklists ran out of scalar memory from bucket 512 up, and the 64-wide
+    rope page needs its lanes padded to be copied whole."""
+    from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention
+
+    s = _sds(one_chip)
+    h, r, p = 16, 512, 64
+    tok = s((bucket,), jnp.int32)
+    ragged_mla_attention.lower(
+        s((bucket, h, r), jnp.float32), s((bucket, h, p), jnp.bfloat16),
+        s((NUM_BLOCKS, BLOCK, r), jnp.bfloat16),
+        s((NUM_BLOCKS, BLOCK, p), jnp.bfloat16),
+        tok, tok, s((LANES, max_blocks), jnp.int32), tok, tok, tok,
+        s((bucket // TB,), jnp.int32), scale=0.1, tb_tokens=TB,
+    ).compile()
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
@@ -162,24 +204,20 @@ def test_kernel_bytes_do_not_depend_on_the_call_stack(one_chip):
     assert text() == from_a_deeper_stack()
 
 
-def test_smoke_worklist_fits_one_launch_of_32_blocks():
-    """The bound the kernel wrapper and the engine share, at the smoke's
-    width: 3 worklists x 32 rows x 2048 slots x 4 B = 768 KiB of the 1 MiB."""
-    assert worklist_rows_per_call(PAGE_SLOTS, TB) == 32
-
-
-def test_engine_rejects_worklists_beyond_scalar_memory():
-    """A context so long that ONE row tile of the full-width worklist
-    (tb_tokens * max_blocks_per_seq slots) exceeds scalar memory is a
-    construction error computed from shapes — not a compiler refusal at the
-    first long prompt."""
+def test_engine_constructs_at_a_context_the_worklists_refused():
+    """Context 32,768 was a construction error while the kernel prefetched
+    [token blocks, tb_tokens * max_blocks_per_seq] worklists (one row tile
+    of them exceeded scalar memory).  The live-page kernel has no such
+    width: the engine constructs, with the unified step on."""
     import dataclasses
 
     cfg = dataclasses.replace(LlamaConfig.tiny(), max_position_embeddings=32768)
-    common = dict(model=cfg, block_size=16, max_batch_size=2,
-                  attention_impl="pallas_interpret")
-    with pytest.raises(ValueError, match="scalar memory"):
-        JaxLlmEngine(EngineConfig(num_blocks=2048, max_model_len=32768, **common))
-    # the same engine at a context whose worklists fit constructs fine
-    engine = JaxLlmEngine(EngineConfig(num_blocks=256, max_model_len=4096, **common))
-    assert engine.stats()["kernel_config"]["blocks_per_launch"] == 32
+    engine = JaxLlmEngine(EngineConfig(
+        model=cfg, block_size=16, max_batch_size=2, num_blocks=2048,
+        max_model_len=32768, attention_impl="pallas_interpret",
+    ))
+    assert engine.unified_batch
+    assert engine.stats()["kernel_config"] == {
+        "tb_tokens": 8, "source": "default",
+        "geometry": engine.stats()["kernel_config"]["geometry"],
+    }

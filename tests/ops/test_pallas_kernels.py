@@ -276,15 +276,27 @@ def ragged_meta(spans, lanes, tb=8, t_pad=None):
     )
 
 
+def span_args(token_lane, token_pos, tables, tb, bs, sliding_window=None):
+    """The ragged kernels' routing arguments after q and the caches:
+    per-token lane/pos, the block tables and pack_spans' four arrays."""
+    from dynamo_tpu.ops.pallas import pack_spans
+
+    meta = pack_spans(
+        token_lane, token_pos, lanes=tables.shape[0], tb_tokens=tb,
+        block_size=bs, sliding_window=sliding_window,
+    )
+    return (
+        jnp.asarray(token_lane), jnp.asarray(token_pos), jnp.asarray(tables),
+        *(jnp.asarray(a) for a in meta),
+    )
+
+
 def run_ragged(spans, q_key=9, lanes=3, tb=8, t_pad=None, sliding_window=None,
-               page_slots=None, pages_per_step=1, cache_dtype=None):
+               cache_dtype=None):
     """Kernel + pure-JAX twin over the shared test cache; returns
     (kernel_out, ref_out, token_pos host array, q)."""
     from dynamo_tpu.ops.attention import ragged_paged_attention as ragged_ref
-    from dynamo_tpu.ops.pallas import (
-        pack_page_meta,
-        ragged_paged_attention as ragged_kernel,
-    )
+    from dynamo_tpu.ops.pallas import ragged_paged_attention as ragged_kernel
 
     rng = jax.random.PRNGKey(0)
     k_cache, v_cache, tables, _ = build_cache(rng)
@@ -292,11 +304,6 @@ def run_ragged(spans, q_key=9, lanes=3, tb=8, t_pad=None, sliding_window=None,
         k_cache = k_cache.astype(cache_dtype)
         v_cache = v_cache.astype(cache_dtype)
     token_lane, token_pos, ctx = ragged_meta(spans, lanes, tb=tb, t_pad=t_pad)
-    page_meta = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=tb,
-        block_size=k_cache.shape[1], sliding_window=sliding_window,
-        page_slots=page_slots,
-    )
     t = token_lane.shape[0]
     q = jax.random.normal(jax.random.fold_in(rng, q_key), (t, 4, 128), jnp.float32)
     ref = ragged_ref(
@@ -304,10 +311,10 @@ def run_ragged(spans, q_key=9, lanes=3, tb=8, t_pad=None, sliding_window=None,
         sliding_window=sliding_window,
     )
     out = ragged_kernel(
-        q, k_cache, v_cache, token_lane, token_pos,
-        *(jnp.asarray(a) for a in page_meta),
+        q, k_cache, v_cache,
+        *span_args(token_lane, token_pos, tables, tb, k_cache.shape[1],
+                   sliding_window),
         tb_tokens=tb, interpret=True, sliding_window=sliding_window,
-        pages_per_step=pages_per_step,
     )
     return np.asarray(out), np.asarray(ref), np.asarray(token_pos), q
 
@@ -376,10 +383,7 @@ def test_ragged_attention_packed_block_reduction_16_lanes():
         ragged_paged_attention as ragged_ref,
         write_prefill_kv,
     )
-    from dynamo_tpu.ops.pallas import (
-        pack_page_meta,
-        ragged_paged_attention as ragged_kernel,
-    )
+    from dynamo_tpu.ops.pallas import ragged_paged_attention as ragged_kernel
 
     lanes, bs, kvh, d, maxb, tb = 16, 8, 2, 128, 4, 8
     rng = jax.random.PRNGKey(3)
@@ -399,14 +403,11 @@ def test_ragged_attention_packed_block_reduction_16_lanes():
     packed_blocks = token_lane.shape[0] // tb
     padded_blocks = lanes  # one-lane-per-block: every decode lane = 1 block
     assert packed_blocks * 4 <= padded_blocks
-    page_meta = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=tb, block_size=bs
-    )
     q = jax.random.normal(keys[2], (token_lane.shape[0], 4, d), jnp.float32)
     ref = ragged_ref(q, k_cache, v_cache, tables, ctx_a, token_lane, token_pos)
     out = ragged_kernel(
-        q, k_cache, v_cache, token_lane, token_pos,
-        *(jnp.asarray(a) for a in page_meta),
+        q, k_cache, v_cache,
+        *span_args(token_lane, token_pos, tables, tb, bs),
         tb_tokens=tb, interpret=True,
     )
     valid = np.asarray(token_pos) >= 0
@@ -415,31 +416,85 @@ def test_ragged_attention_packed_block_reduction_16_lanes():
     )
 
 
-def test_pack_page_meta_pads_repeat_last_page():
-    """Worklist pads repeat the last live physical page (the unchanged
-    BlockSpec index skips their DMA) and empty blocks count zero."""
-    from dynamo_tpu.ops.pallas import pack_page_meta
+def test_pack_spans_lists_each_lane_once_per_block():
+    """One span per lane present in a token block, first-appearance order,
+    live spans first; a lane's pages run from the window's floor to its
+    highest position's page; empty blocks total zero."""
+    from dynamo_tpu.ops.pallas import pack_spans
 
-    token_lane = np.asarray([0, 1, 3, 3, 3, 3, 3, 3], np.int32)
-    token_pos = np.asarray([9, 0, -1, -1, -1, -1, -1, -1], np.int32)
-    tables = np.asarray([[4, 5], [6, 7], [8, 9]], np.int32)
-    phys, lane, ord_, count = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=4, block_size=8,
-        page_slots=4,
+    token_lane = np.asarray([0, 1, 0, 3, 3, 3, 3, 3], np.int32)
+    token_pos = np.asarray([9, 0, 17, -1, -1, -1, -1, -1], np.int32)
+    lane, first, count, total = pack_spans(
+        token_lane, token_pos, lanes=3, tb_tokens=4, block_size=8,
     )
-    # block 0: lane 0 needs pages 0..1 (pos 9), lane 1 page 0 — 3 live
-    assert count.tolist() == [3, 0]
-    assert phys[0].tolist() == [4, 5, 6, 6]   # pad repeats phys page 6
-    assert lane[0].tolist() == [0, 0, 1, -1]
-    assert ord_[0].tolist() == [0, 1, 0, 0]
-    assert phys[1].tolist() == [0, 0, 0, 0]   # empty block -> page 0, gated
+    # block 0: lane 0 sees pages 0..2 (pos 9 and 17), lane 1 page 0; the
+    # out-of-range lane 3 is padding
+    assert lane.tolist() == [0, 1, -1, -1, -1, -1, -1, -1]
+    assert first.tolist() == [0, 0, 0, 0, 0, 0, 0, 0]
+    assert count.tolist() == [3, 1, 0, 0, 0, 0, 0, 0]
+    assert total.tolist() == [4, 0]
+    # a window of 8 positions: lane 0's lowest token (pos 9) sees from
+    # position 2, so its span still starts at page 0; a window of 2 drops it
+    _, first, count, total = pack_spans(
+        token_lane, token_pos, lanes=3, tb_tokens=4, block_size=8,
+        sliding_window=2,
+    )
+    assert (first[:2].tolist(), count[:2].tolist()) == ([1, 0], [2, 1])
+    assert total.tolist() == [3, 0]
+    with pytest.raises(ValueError, match="whole token blocks"):
+        pack_spans(token_lane[:6], token_pos[:6], lanes=3, tb_tokens=4,
+                   block_size=8)
+
+
+def _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window):
+    """pack_spans as the plain loop it replaced (per block a dict by lane,
+    insertion-ordered): the reference for the vectorised packer."""
+    t_pad = len(token_lane)
+    lane_o = np.full(t_pad, -1, np.int32)
+    first_o = np.zeros(t_pad, np.int32)
+    count_o = np.zeros(t_pad, np.int32)
+    total = np.zeros(t_pad // tb, np.int32)
+    for t in range(t_pad // tb):
+        span = {}
+        for i in range(t * tb, (t + 1) * tb):
+            lane, pos = int(token_lane[i]), int(token_pos[i])
+            if pos < 0 or not 0 <= lane < lanes:
+                continue
+            lo, hi = span.get(lane, (pos, pos))
+            span[lane] = (min(lo, pos), max(hi, pos))
+        for s, (lane, (lo, hi)) in enumerate(span.items()):
+            first = 0 if window is None else max(0, lo - (window - 1)) // bs
+            lane_o[t * tb + s] = lane
+            first_o[t * tb + s] = first
+            count_o[t * tb + s] = hi // bs + 1 - first
+            total[t] += hi // bs + 1 - first
+    return lane_o, first_o, count_o, total
+
+
+@pytest.mark.parametrize("window", [None, 5, 40])
+@pytest.mark.parametrize("tb", [1, 4, 8])
+def test_pack_spans_equals_the_plain_loop(tb, window):
+    """Random windows — interleaved lanes, holes, pad rows, out-of-range
+    lanes — pack to exactly what the loop packs."""
+    from dynamo_tpu.ops.pallas import pack_spans
+
+    rng = np.random.default_rng(tb * 100 + (window or 0))
+    for _ in range(20):
+        t_pad, lanes, bs = tb * int(rng.integers(1, 9)), 5, 4
+        token_lane = rng.integers(-1, lanes + 2, t_pad).astype(np.int32)
+        token_pos = rng.integers(-1, 70, t_pad).astype(np.int32)
+        got = pack_spans(token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                         block_size=bs, sliding_window=window)
+        want = _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window)
+        for g, w in zip(got, want):
+            assert g.dtype == np.int32 and g.tolist() == w.tolist()
 
 
 def test_ragged_mla_attention_matches_dense_reference():
     """Packed-lane ragged MLA kernel vs a dense latent-space per-token
     reference: mixed span + decode tokens against the latent cache, causal
     per-row masks, pad rows finite."""
-    from dynamo_tpu.ops.pallas import pack_page_meta, ragged_mla_attention
+    from dynamo_tpu.ops.pallas import ragged_mla_attention
 
     rng = jax.random.PRNGKey(5)
     h, r, p, bs, maxb, nblocks = 4, 32, 16, 8, 4, 16
@@ -450,15 +505,11 @@ def test_ragged_mla_attention_matches_dense_reference():
     scale = 0.17
     spans = [(0, 2, 3), (1, 16, 1), (2, 24, 5)]
     token_lane, token_pos, _ = ragged_meta(spans, 3)
-    page_meta = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=8, block_size=bs
-    )
     t = token_lane.shape[0]
     q_lat = jax.random.normal(keys[0], (t, h, r), jnp.float32)
     q_rope = jax.random.normal(keys[1], (t, h, p), jnp.float32)
     out = np.asarray(ragged_mla_attention(
-        q_lat, q_rope, ck, kr, token_lane, token_pos,
-        *(jnp.asarray(a) for a in page_meta),
+        q_lat, q_rope, ck, kr, *span_args(token_lane, token_pos, tables, 8, bs),
         scale=scale, tb_tokens=8, interpret=True,
     ))
     assert np.isfinite(out).all()
@@ -505,7 +556,7 @@ def test_ragged_attention_chunked_gather_matches_direct():
 
 def test_ragged_attention_sliding_window_matches_fallback():
     """Packed kernel with a sliding window must match the windowed XLA twin;
-    page pruning (pack_page_meta drops pages fully below the window) must
+    page pruning (pack_spans starts a span at the window's floor) must
     not change the result."""
     spans = [(0, 4, 1), (1, 8, 9), (2, 28, 1)]
     for w in (4, 16):
@@ -541,22 +592,6 @@ def test_paged_attention_sliding_window_matches_fallback():
     assert not np.allclose(full, win)
 
 
-def test_ragged_attention_pages_per_step_parity():
-    """Multi-page DMA batching (pages_per_step > 1) is a pure grid
-    relayout: every pps that divides the worklist width must reproduce the
-    pps=1 result byte-for-byte, and the twin within tolerance."""
-    spans = [(0, 4, 1), (1, 8, 9), (2, 28, 1)]
-    base, ref, token_pos, _ = run_ragged(spans, page_slots=8, pages_per_step=1)
-    valid = token_pos >= 0
-    np.testing.assert_allclose(base[valid], ref[valid], rtol=2e-5, atol=2e-5)
-    for pps in (2, 8):
-        out, _, _, _ = run_ragged(spans, page_slots=8, pages_per_step=pps)
-        np.testing.assert_array_equal(out[valid], base[valid])
-    # non-divisible pps is a static-shape error, not silent corruption
-    with pytest.raises(ValueError, match="pages_per_step"):
-        run_ragged(spans, page_slots=12, pages_per_step=8)
-
-
 def test_paged_attention_pages_per_step_parity():
     """Decode kernel: clamped multi-page grid steps match pps=1 exactly,
     including pps values that do not divide (or exceed) max_blocks."""
@@ -575,10 +610,11 @@ def test_paged_attention_pages_per_step_parity():
 
 
 def test_mla_attention_pages_per_step_parity():
-    """MLA decode + ragged MLA kernels under pages_per_step match their
-    pps=1 results exactly."""
+    """MLA decode kernel under pages_per_step matches its pps=1 result
+    exactly; the ragged MLA kernel (no such option: it walks live pages)
+    matches its twin on a mixed chunk + decode window."""
     from dynamo_tpu.ops.attention import ragged_mla_paged_attention
-    from dynamo_tpu.ops.pallas import pack_page_meta, ragged_mla_attention
+    from dynamo_tpu.ops.pallas import ragged_mla_attention
     from dynamo_tpu.ops.pallas.mla_attention import mla_paged_attention_decode
 
     rng = np.random.default_rng(5)
@@ -605,16 +641,11 @@ def test_mla_attention_pages_per_step_parity():
     token_lane, token_pos, _ = ragged_meta(
         [(0, 4, 1), (1, 8, 9), (2, 28, 1)], lanes, tb=tb
     )
-    meta = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=tb, block_size=bs,
-        page_slots=8,
-    )
     t = token_lane.shape[0]
     ql = jnp.asarray(rng.standard_normal((t, h, R)), jnp.float32)
     qr = jnp.asarray(rng.standard_normal((t, h, P)), jnp.float32)
     rbase = np.asarray(ragged_mla_attention(
-        ql, qr, ck, kr, token_lane, token_pos,
-        *(jnp.asarray(a) for a in meta),
+        ql, qr, ck, kr, *span_args(token_lane, token_pos, tables, tb, bs),
         scale=scale, tb_tokens=tb, interpret=True,
     ))
     valid = np.asarray(token_pos) >= 0
@@ -622,13 +653,6 @@ def test_mla_attention_pages_per_step_parity():
         ql, qr, ck, kr, tables, token_lane, token_pos, scale=scale,
     ))
     np.testing.assert_allclose(rbase[valid], rref[valid], rtol=2e-5, atol=2e-5)
-    for pps in (2, 8):
-        rout = np.asarray(ragged_mla_attention(
-            ql, qr, ck, kr, token_lane, token_pos,
-            *(jnp.asarray(a) for a in meta),
-            scale=scale, tb_tokens=tb, interpret=True, pages_per_step=pps,
-        ))
-        np.testing.assert_array_equal(rout[valid], rbase[valid])
 
 
 def test_ragged_attention_fp8_cache():
@@ -651,7 +675,7 @@ def test_ragged_attention_fp8_cache():
 def test_ragged_mla_attention_fp8_cache():
     """fp8 latent+rope cache through the ragged MLA kernel vs its twin."""
     from dynamo_tpu.ops.attention import ragged_mla_paged_attention
-    from dynamo_tpu.ops.pallas import pack_page_meta, ragged_mla_attention
+    from dynamo_tpu.ops.pallas import ragged_mla_attention
 
     fp8 = jnp.float8_e4m3fn
     rng = np.random.default_rng(6)
@@ -663,16 +687,12 @@ def test_ragged_mla_attention_fp8_cache():
     token_lane, token_pos, _ = ragged_meta(
         [(0, 4, 1), (1, 8, 9), (2, 28, 1)], lanes, tb=tb
     )
-    meta = pack_page_meta(
-        token_lane, token_pos, tables, tb_tokens=tb, block_size=bs
-    )
     t = token_lane.shape[0]
     scale = 1.0 / np.sqrt(R + P)
     ql = jnp.asarray(rng.standard_normal((t, h, R)), jnp.float32)
     qr = jnp.asarray(rng.standard_normal((t, h, P)), jnp.float32)
     out = np.asarray(ragged_mla_attention(
-        ql, qr, ck, kr, token_lane, token_pos,
-        *(jnp.asarray(a) for a in meta),
+        ql, qr, ck, kr, *span_args(token_lane, token_pos, tables, tb, bs),
         scale=scale, tb_tokens=tb, interpret=True,
     ))
     ref = np.asarray(ragged_mla_paged_attention(
@@ -680,3 +700,66 @@ def test_ragged_mla_attention_fp8_cache():
     ))
     valid = np.asarray(token_pos) >= 0
     np.testing.assert_allclose(out[valid], ref[valid], rtol=2e-5, atol=2e-5)
+
+
+# The benchmark cells' attention geometry (Qwen3-4B, Mistral-7B: 32 query
+# heads over 8 KV heads of 128, pages of 16) at both token-block sizes the
+# engine resolves there (8 lanes: gcd(16, 8); 16 lanes: the tuned 4).
+CELL_WINDOWS = {
+    # name: (spans [(lane, start, len)], t_pad, sliding_window, cache dtype)
+    "decode_lanes_only": ([(i, 11 + 29 * i, 1) for i in range(8)], None, None, None),
+    "one_long_span": ([(2, 0, 150)], None, None, None),
+    "span_beside_8_packed_decodes": (
+        [(i, 20 + 27 * i, 1) for i in range(8)] + [(8, 64, 40)], None, None, None,
+    ),
+    "lane_holes_and_padding_rows": ([(0, 36, 1), (3, 90, 1), (6, 17, 21)], 64, None, None),
+    "sliding_window_that_cuts": (
+        [(1, 200, 1), (4, 130, 1), (5, 100, 30)], None, 48, None,
+    ),
+    "fp8_kv": ([(0, 77, 1), (1, 8, 25), (2, 140, 1)], None, None, jnp.float8_e4m3fn),
+}
+
+
+@pytest.mark.parametrize("tb", [4, 8])
+@pytest.mark.parametrize("window", sorted(CELL_WINDOWS))
+def test_ragged_attention_cell_geometry_matches_twin(window, tb):
+    """The live-page kernel against the XLA twin at h32 kv8 d128, block 16:
+    every live row agrees, pad rows are finite, and the kernel's page
+    iterations are exactly the pages the window's lanes can see."""
+    from dynamo_tpu.ops.attention import ragged_paged_attention as ragged_ref
+    from dynamo_tpu.ops.pallas import ragged_paged_attention as ragged_kernel
+
+    h, kvh, d, bs, lanes, maxb, nblocks = 32, 8, 128, 16, 9, 16, 40
+    spans, t_pad, sw, cache_dtype = CELL_WINDOWS[window]
+    rng = np.random.default_rng(17)
+    k_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), jnp.float32)
+    v_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), jnp.float32)
+    if cache_dtype is not None:
+        k_cache, v_cache = k_cache.astype(cache_dtype), v_cache.astype(cache_dtype)
+    tables = jnp.asarray(rng.integers(0, nblocks, (lanes, maxb)), jnp.int32)
+    token_lane, token_pos, ctx = ragged_meta(spans, lanes, tb=tb, t_pad=t_pad)
+    q = jnp.asarray(
+        rng.standard_normal((token_lane.shape[0], h, d)), jnp.float32
+    )
+    args = span_args(token_lane, token_pos, tables, tb, bs, sw)
+    out = np.asarray(ragged_kernel(
+        q, k_cache, v_cache, *args, tb_tokens=tb, interpret=True,
+        sliding_window=sw,
+    ))
+    ref = np.asarray(ragged_ref(
+        q, k_cache, v_cache, tables, ctx, token_lane, token_pos,
+        sliding_window=sw,
+    ))
+    valid = np.asarray(token_pos) >= 0
+    np.testing.assert_allclose(out[valid], ref[valid], rtol=2e-5, atol=2e-5)
+    assert np.isfinite(out).all()
+    # hand count: per token block, per lane in it, the pages from the
+    # window's floor under its lowest token to its highest token's page
+    tl, tp = np.asarray(token_lane), np.asarray(token_pos)
+    want = 0
+    for t in range(0, len(tl), tb):
+        for lane in {int(x) for x, p in zip(tl[t:t + tb], tp[t:t + tb]) if p >= 0}:
+            pos = [p for x, p in zip(tl[t:t + tb], tp[t:t + tb]) if x == lane and p >= 0]
+            lo = 0 if sw is None else max(0, min(pos) - sw + 1) // bs
+            want += max(pos) // bs + 1 - lo
+    assert int(np.asarray(args[-1]).sum()) == want
