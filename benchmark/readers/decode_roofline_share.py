@@ -4,7 +4,6 @@ step (%).  Some steps of a window carry a prompt and are compute-bound, so
 this reads a little low; it cannot read high while a step streams the
 weights at least once."""
 
-from benchmark import shapes
 from benchmark.readers import device_idle_share, step_ms
 
 
@@ -12,7 +11,8 @@ def read(ctx):
     step, idle = step_ms.read(ctx), device_idle_share.idle(ctx)
     live = [s["step_kv_active_blocks"] for s in ctx.get("samples") or []
             if s and s.get("step_kv_active_blocks") is not None]
-    if step is None or idle is None or not live or not ctx.get("peaks"):
+    shapes = ctx.get("shapes")   # the configuration's own arithmetic
+    if step is None or idle is None or not live or not ctx.get("peaks") or shapes is None:
         return None
     block = 16
     kv = sum(live) / len(live) * block * shapes.kv_bytes_per_token(ctx["hf"])

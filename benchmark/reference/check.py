@@ -3,14 +3,17 @@ has gone (a chip belongs to one process).
 
     python benchmark/reference/check.py <job.json> <out.json>
 
-The job holds the configuration's ``config.json`` keys, the weights' seed and
-a sample of finished requests: the ids the engine must have been handed and
-the ids it served.  For each, one reference forward over prompt + served
-tokens gives, at every served position, how far the served token's logit
-lies below the reference's best, in units of that row's logit spread (the
-two configurations' logits differ fifty-fold in scale).  ``control``
-(``fp8``) also puts the reference at that precision in the program's place
-and reads the same numbers of it.
+The job holds the configuration's ``config.json`` keys, the file of the
+reference module the configuration names (``benchmark/README.md`` has the
+interface; nothing here knows a reference or a leaf by name), the weights'
+seed and a sample of finished requests: the ids the engine must have been
+handed and the ids it served.  For each, ONE reference trunk over prompt +
+served tokens; its served rows then go through the head ``PAD`` at a time,
+however many there are, and give, at every served position, how far the
+served token's logit lies below the reference's best, in units of that row's
+logit spread (the two configurations' logits differ fifty-fold in scale).
+``control`` (``fp8``) also puts the reference at that precision in the
+program's place and reads the same numbers of it.
 """
 
 from __future__ import annotations
@@ -25,7 +28,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-PAD = 256    # sequences are padded to a multiple of this, rows to exactly this
+PAD = 256    # sequences are padded to a multiple of this; rows go through the head this many at a time
 TOP_K = 20
 
 
@@ -71,6 +74,29 @@ def _topk_err(values, at_top, sigma):
     return np.abs(got - want)[:, 1:] / sigma[:, None]
 
 
+def _slices(s: dict):
+    """A sample's served rows, ``PAD`` at a time: the rows of the trunk's
+    output, the served ids and the probe's first k, each padded to ``PAD``,
+    and how many of them are real."""
+    import numpy as np
+
+    n, m = len(s["prompt_ids"]), len(s["served_ids"])
+    top = s.get("top")
+    k = min((len(r) for r in top), default=0) if top else 0
+    for a in range(0, m, PAD):
+        real = min(PAD, m - a)
+        rows = np.zeros(PAD, np.int32)
+        rows[:real] = np.arange(n - 1 + a, n - 1 + a + real)
+        served = np.zeros(PAD, np.int32)
+        served[:real] = s["served_ids"][a:a + real]
+        top_tokens = np.zeros((PAD, max(k, 1)), np.int32)
+        top_values = np.zeros((PAD, max(k, 1)), np.float32)
+        if k:
+            top_tokens[:real] = [[t for t, _ in r[:k]] for r in top[a:a + real]]
+            top_values[:real] = [[v for _, v in r[:k]] for r in top[a:a + real]]
+        yield real, rows, served, top_tokens, top_values
+
+
 def run(job: dict) -> dict:
     import jax
 
@@ -81,8 +107,9 @@ def run(job: dict) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark.reference import llama_like as ref
+    from benchmark import modules
 
+    ref = modules.load(job["reference"])
     device = jax.devices()[0]
     if job.get("require_platform") and device.platform != job["require_platform"]:
         raise SystemExit(f"reference needs {job['require_platform']}, found {device.platform}")
@@ -92,51 +119,52 @@ def run(job: dict) -> dict:
     jax.block_until_ready(weights)
     print(f"weights after {time.monotonic() - t0:.1f} s", flush=True)
     row_stats, control_stats = jax.jit(_row_stats), jax.jit(_control_stats)
-    # one padded length and one row count for every sample: one set of
-    # programs, whatever the seed drew
+    cat = lambda parts: {a: np.concatenate([p[a] for p in parts]) for a in parts[0]}  # noqa: E731
+    # one padded length for every sample and PAD rows for every slice: one
+    # set of programs, whatever the seed drew and however long an answer is
     longest = max(len(s["prompt_ids"]) + len(s["served_ids"]) for s in job["samples"])
     length = longest + (-longest % PAD)
     out, kept = [], []
     for s in job["samples"]:
         ids = s["prompt_ids"] + s["served_ids"]
         n, m = len(s["prompt_ids"]), len(s["served_ids"])
-        if m > PAD:
-            raise SystemExit(f"request {s['index']} served {m} tokens, more than {PAD} rows")
         padded = ids + [0] * (length - len(ids))
-        rows = list(range(n - 1, n - 1 + m)) + [0] * (PAD - m)
-        served = jnp.asarray(s["served_ids"] + [0] * (PAD - m), jnp.int32)
-        top = s.get("top")
-        k = min((len(r) for r in top), default=0) if top else 0
-        top_tokens = np.zeros((PAD, max(k, 1)), np.int32)
-        top_values = np.zeros((PAD, max(k, 1)), np.float32)
-        if k:
-            top_tokens[:m] = [[t for t, _ in r[:k]] for r in top]
-            top_values[:m] = [[v for _, v in r[:k]] for r in top]
-        logits = ref.forward(weights, hf, padded, rows)
-        st = {a: np.asarray(b)[:m] for a, b in
-              row_stats(logits, served, jnp.asarray(top_tokens)).items()}
+        x = ref.hidden(weights, hf, padded)
+        parts, top_values, held = [], [], []
+        for real, rows, served, top_tokens, values in _slices(s):
+            logits = ref.logits(weights, hf, x[jnp.asarray(rows)])
+            parts.append({a: np.asarray(b)[:real] for a, b in
+                          row_stats(logits, jnp.asarray(served), jnp.asarray(top_tokens)).items()})
+            top_values.append(values[:real])
+            if job.get("control"):
+                # on the host: the chip holds the weights, and a long
+                # answer's logits are gigabytes
+                held.append((real, rows, np.asarray(logits)))
+        st, top_values = cat(parts), np.concatenate(top_values)
         rec = {"index": s["index"], "tokens": m, "gap_max": float(st["gap"].max()),
                "gap_mean": float(st["gap"].mean()),
                "mismatch": int((st["first"] != np.asarray(s["served_ids"])).sum())}
         if s.get("served_logprobs"):
             err = np.abs(np.asarray(s["served_logprobs"], np.float32) - st["logprob"])
             rec["logprob_err_max"], rec["logprob_err_mean"] = float(err.max()), float(err.mean())
-        if k > 1:
-            e = _topk_err(top_values[:m], st["at_top"], st["sigma"])
+        if top_values.shape[1] > 1:
+            e = _topk_err(top_values, st["at_top"], st["sigma"])
             rec["topk_err_max"], rec["topk_err_mean"] = float(e.max()), float(e.mean())
         if job.get("control"):
-            kept.append((rec, padded, rows, m, logits, st["sigma"]))
+            kept.append((rec, padded, held, st["sigma"]))
         out.append(rec)
         print(f"sample {s['index']} ({n}+{m} tokens) after {time.monotonic() - t0:.1f} s", flush=True)
     if job.get("control"):
         # the control's weights take the place of the reference's, leaf by
-        # leaf: the chip does not hold both
-        control, tied = {}, bool(hf.get("tie_word_embeddings", False))
+        # leaf (the chip does not hold both); the module says what a leaf is
+        control = {}
         for name in list(weights):
-            control[name] = ref.quantize({name: weights.pop(name)}, job["control"], tied)[name]
-        for rec, padded, rows, m, logits, sigma in kept:
-            low = ref.forward(control, hf, padded, rows)
-            cs = {a: np.asarray(b)[:m] for a, b in control_stats(logits, low).items()}
+            control.update(ref.quantize({name: weights.pop(name)}, job["control"], hf))
+        for rec, padded, held, sigma in kept:
+            x = ref.hidden(control, hf, padded)
+            cs = cat([{a: np.asarray(b)[:real] for a, b in control_stats(
+                jnp.asarray(logits), ref.logits(control, hf, x[jnp.asarray(rows)])).items()}
+                for real, rows, logits in held])
             ce = _topk_err(cs["top_values"], cs["at_top"], sigma)
             rec.update(control_gap_max=float(cs["gap"].max()), control_gap_mean=float(cs["gap"].mean()),
                        control_logprob_err_max=float(cs["logprob_err"].max()),

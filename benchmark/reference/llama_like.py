@@ -1,5 +1,10 @@
 """The plain reference: a llama-like decoder in straightforward ``jax.numpy``.
 
+One reference module among any number: a configuration names its own in its
+file's ``reference`` key and ``check.py`` calls the four functions of the
+interface (``benchmark/README.md``): ``init_weights``, ``hidden``,
+``logits``, ``quantize``.
+
 float32 activations, every product at precision ``highest``, no cache, no
 kernels, no batching: one sequence in, the logits at every position out.
 It follows the published block (RMSNorm, rotary positions in the split-half
@@ -83,13 +88,13 @@ def _round_fp8(w):
 _ROUND = {"fp8": jax.jit(_round_fp8)}
 
 
-def quantize(weights: dict, kind: str, tied: bool | None = None) -> dict:
-    """A control's weights: every matrix a token multiplies against (the
-    blocks and the output head; a tied head is the embedding) through
-    ``kind`` and back."""
-    if tied is None:
-        tied = "lm_head" not in weights
-    return {k: (v if k == "embed" and not tied else _ROUND[kind](v)) for k, v in weights.items()}
+def quantize(leaves: dict, kind: str, hf: dict) -> dict:
+    """The control's form of ``leaves`` (any subset of the weights, by name):
+    every matrix a token multiplies against (the blocks and the output head;
+    a tied head is the embedding) through ``kind`` and back; an embedding
+    that is only looked up stays."""
+    tied = bool(hf.get("tie_word_embeddings", False))
+    return {k: (v if k == "embed" and not tied else _ROUND[kind](v)) for k, v in leaves.items()}
 
 
 def _rms(x, eps):
@@ -132,10 +137,10 @@ def _head(x, embed, lm_head, c):
     return _mm(x, lm_head)
 
 
-def forward(weights: dict, hf: dict, ids, rows=None):
-    """Logits ``[len(rows), vocab]`` (float32) for the sequence ``ids``, at
-    the positions ``rows`` (default: all).  Layer by layer, so that only one
-    layer's float32 copy is alive at a time."""
+def hidden(weights: dict, hf: dict, ids):
+    """The trunk: the last block's output ``[len(ids), hidden]`` (float32)
+    for the sequence ``ids``.  Layer by layer, so that only one layer's
+    float32 copy is alive at a time."""
     c = dims(hf)
     ids = jnp.asarray(ids, jnp.int32)
     t = ids.shape[0]
@@ -153,9 +158,21 @@ def forward(weights: dict, hf: dict, ids, rows=None):
     names = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
     for layer in range(c["l"]):
         x = _layer_jit(x, {n: weights[n][layer] for n in names}, cos, sin, mask, frozen)
-    if rows is not None:
-        x = x[jnp.asarray(rows, jnp.int32)]
+    return x
+
+
+def logits(weights: dict, hf: dict, x):
+    """Final norm and output head over rows ``x`` of ``hidden``'s output:
+    ``[len(x), vocab]`` float32."""
+    frozen = tuple(sorted((k, v) for k, v in dims(hf).items()))
     return _head_jit(x, weights["embed"], weights.get("lm_head"), frozen)
+
+
+def forward(weights: dict, hf: dict, ids, rows=None):
+    """Logits for the sequence ``ids`` at the positions ``rows`` (default:
+    all): the two halves together, for a caller that wants few rows once."""
+    x = hidden(weights, hf, ids)
+    return logits(weights, hf, x if rows is None else x[jnp.asarray(rows, jnp.int32)])
 
 
 _layer_jit = jax.jit(lambda x, w, cos, sin, mask, frozen: _layer(x, w, cos, sin, mask, dict(frozen)),

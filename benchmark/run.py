@@ -6,10 +6,11 @@
 Looks the cell up in ``BENCHMARK.json``; its ``config`` and ``traffic`` name
 ``benchmark/configs/<config>.json`` and ``benchmark/traffic/<traffic>.json``,
 ``benchmark/cells/<cell>.json`` holds the rate the cell is offered (the one
-place a rate is written), and each
+place a rate is written), the configuration's file names its own
+``reference`` and ``shapes`` modules (``benchmark/modules.py``), and each
 per-layer metric is ``benchmark/metrics/<name>.json`` naming a reader module
 under ``benchmark/readers/``.  Nothing here knows a cell, a configuration, a
-mix or a metric by name.
+reference, a block, a mix or a metric by name.
 
 Set-up (counted in ``setup_s``): write the model directory, start the server
 child (``benchmark/launcher.py`` = the program's ``dynamo_tpu.cli.run`` entry
@@ -26,6 +27,7 @@ from __future__ import annotations
 _T_START = __import__("time").monotonic()
 
 import argparse
+import hashlib
 import importlib
 import json
 import os
@@ -40,7 +42,8 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from benchmark import arith, client, shapes, traffic  # noqa: E402
+from benchmark import arith, client, modules, traffic  # noqa: E402
+from benchmark.shapes import load_peaks  # noqa: E402
 from benchmark.server import BenchFailure, Server, hf_config, write_model_dir  # noqa: E402
 
 BENCH_DIR = ROOT / "benchmark"
@@ -72,7 +75,24 @@ def load_cell(bench: dict, name: str, bench_dir: Path = BENCH_DIR) -> dict:
     if "rate_rps" not in own:
         raise SystemExit(f"cell {name!r} has no offered rate: {cell_file} must give rate_rps")
     return {"cell": cell, "config": config, "mix": mix, "own": own,
-            "rate": float(own["rate_rps"])}
+            "rate": float(own["rate_rps"]),
+            "reference": modules.path_of(config, "reference", bench_dir, cell["config"]),
+            "shapes": modules.load(modules.path_of(config, "shapes", bench_dir, cell["config"]))}
+
+
+def cache_dir() -> Path:
+    """JAX's persistent compilation cache, where the program keeps it too."""
+    return Path(os.environ.get("JAX_COMPILATION_CACHE_DIR") or ROOT / ".jax_cache")
+
+
+def warm_marker(cache: Path, cell: str) -> Path:
+    """Written when a run of ``cell`` from this checkout has reached its end
+    under ``cache``: until then the cell's server and reference compile, and
+    the run gets the long deadline.  (A cache that is warm for another cell,
+    or for another checkout, says nothing: the serving arguments and the
+    reference's shapes are the cell's, and a kernel's key holds the
+    checkout's path.)"""
+    return cache / f"bench-warm.{cell}.{hashlib.sha1(str(ROOT).encode()).hexdigest()[:12]}"
 
 
 def metrics_of(bench: dict, cell: str, group: str) -> list[dict]:
@@ -183,8 +203,17 @@ def parse(argv):
     return p.parse_args(argv)
 
 
+def run_deadline(started: float, marker: Path) -> float:
+    return started + (RUN_DEADLINE_S if marker.exists() else COLD_DEADLINE_S)
+
+
 def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path=None,
-        bench_dir: Path = BENCH_DIR, env_overlay=None) -> tuple[int, dict | None]:
+        bench_dir: Path = BENCH_DIR, env_overlay=None,
+        started: float | None = None) -> tuple[int, dict | None]:
+    """``started`` is the instant set-up is counted from and the deadline
+    runs from: the process's start for ``main``, now for a caller that
+    imported this module long ago."""
+    started = time.monotonic() if started is None else started
     bench = load_json(bench_path or ROOT / "BENCHMARK.json")
     loaded = load_cell(bench, args.workload, bench_dir)
     cell, config, mix = loaded["cell"], loaded["config"], loaded["mix"]
@@ -204,9 +233,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     filled = traffic.fill(plan, mix, hf["vocab_size"], args.seed)
     plan_by_index = {r["index"]: r for r in plan}
     serving = [str(a) for a in [*config["serving"]["args"], *loaded["own"].get("serving_args", [])]]
-    cache = Path(os.environ.get("JAX_COMPILATION_CACHE_DIR") or ROOT / ".jax_cache")
-    cold = not cache.is_dir() or not any(cache.iterdir())
-    deadline = _T_START + (COLD_DEADLINE_S if cold else RUN_DEADLINE_S)
+    marker = warm_marker(cache_dir(), args.workload)
+    deadline = run_deadline(started, marker)
     server = Server(model_dir, serving, weights_seed, work / "server.log", deadline,
                     launcher=launcher, env_overlay=env_overlay)
     holder: dict = {"samples": []}
@@ -219,7 +247,7 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
                   file=sys.stderr)
             return 1, None
         server.wait_for("listening on http://", "the server to listen")
-        ready_s = time.monotonic() - _T_START
+        ready_s = time.monotonic() - started
         warm = warm_up(server.port, filled, hf["vocab_size"], args.seed)
         bad = [r["error"] for r in warm if r["error"]]
         if bad:
@@ -241,7 +269,7 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
                       for s in range(1, int(seconds)) if not t_a - 1 <= s <= t_a + span + 1]
         driven = client.drive(server.port, "bench", plan, filled, seconds,
                               float(mix.get("drain_s", 30.0)), hooks)
-        setup_s = driven["t0"] - _T_START
+        setup_s = driven["t0"] - started
         after = server.ask("/stats")
         trace = server.ask("/trace/reduce", timeout=240.0) if args.trace else None
     finally:
@@ -276,7 +304,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     sample = pick_sample(records + probes, plan_by_index, filled, int(mix.get("check_requests", 6)), args.seed)
     t_ref = time.monotonic()
     check = run_reference(
-        {"hf": hf, "weights_seed": weights_seed, "samples": sample, "control": args.control,
+        {"hf": hf, "reference": str(loaded["reference"]), "weights_seed": weights_seed,
+         "samples": sample, "control": args.control,
          "require_platform": require_platform}, work, deadline) if sample else {}
     ref_s = time.monotonic() - t_ref
     correct, lines = compare(check, {"failed": len(failed), "mismatched": len(mismatched)}, limits)
@@ -291,8 +320,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     if args.trace:
         ctx = {"records": records, "seconds": seconds, "stats0": holder.get("stats0"),
                "stats1": holder.get("stats1"), "samples": holder["samples"], "trace": trace,
-               "hf": hf, "config": config, "mix": mix, "e2e": e2e,
-               "peaks": shapes.load_peaks(device["kind"]) if require_platform else None}
+               "hf": hf, "config": config, "mix": mix, "e2e": e2e, "shapes": loaded["shapes"],
+               "peaks": load_peaks(device["kind"]) if require_platform else None}
         metrics = read_per_layer(bench, args.workload, ctx, bench_dir)
         device_out.update(busy_s=trace["busy_s"], window_s=trace["window_s"])
     else:
@@ -318,13 +347,15 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     print(json.dumps(result), flush=True)
     for line in lines:
         print(line, file=sys.stderr, flush=True)
+    marker.parent.mkdir(parents=True, exist_ok=True)
+    marker.touch()
     return 0, result
 
 
 def main(argv=None) -> int:
     args = parse(argv)
     try:
-        rc, _ = run(args)
+        rc, _ = run(args, started=_T_START)
     except BenchFailure as exc:
         print(f"benchmark failed: {exc}", file=sys.stderr)
         return 1

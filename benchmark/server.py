@@ -17,7 +17,8 @@ from benchmark import traffic
 
 ROOT = Path(__file__).resolve().parents[1]
 # keys of a configuration's file that are the harness's, not config.json's
-NOT_HF = ("source", "reduced", "assumed", "deployment", "serving", "published", "notes")
+NOT_HF = ("source", "reduced", "assumed", "deployment", "serving", "published", "notes",
+          "limits", "reference", "shapes")
 
 
 class BenchFailure(Exception):
@@ -28,11 +29,18 @@ def hf_config(config: dict) -> dict:
     return {k: v for k, v in config.items() if k not in NOT_HF}
 
 
+def serving_context(config: dict) -> int:
+    args = config["serving"]["args"]
+    return int(args[args.index("--context-length") + 1])
+
+
 def write_model_dir(path: Path, config: dict) -> None:
     """config.json + a word-level tokenizer over the whole vocabulary (token
     i is the word ``t<i>``; no special tokens, so a text names every id) +
     the chat template.  No safetensors: the server draws its weights from
-    the engine's seed."""
+    the engine's seed.  ``model_max_length`` is the published
+    ``max_position_embeddings`` where the configuration has one, and the
+    context it is served at otherwise."""
     from tokenizers import Tokenizer
     from tokenizers.models import WordLevel
     from tokenizers.pre_tokenizers import WhitespaceSplit
@@ -47,7 +55,7 @@ def write_model_dir(path: Path, config: dict) -> None:
     (path / "tokenizer_config.json").write_text(json.dumps({
         "model_type": hf.get("model_type", "llama"), "bos_token": "t0", "eos_token": "t1",
         "chat_template": traffic.CHAT_TEMPLATE,
-        "model_max_length": hf["max_position_embeddings"],
+        "model_max_length": hf.get("max_position_embeddings") or serving_context(config),
     }))
 
 

@@ -3,6 +3,13 @@
 The yardstick's own arithmetic (kept here so that no later PR can move it):
 everything is computed from the keys of the published ``config.json`` that a
 configuration's file quotes, never from the program.
+
+``load_peaks`` is the harness's, shared by every configuration.  The rest is
+the arithmetic of ONE kind of model, a llama-like dense decoder with a
+per-token key/value cache, and is reached only through a configuration's
+``shapes`` key (``benchmark/modules.py``); a configuration with experts, a
+latent cache or a recurrent state names a module of its own that offers the
+same functions (``benchmark/README.md``).
 """
 
 from __future__ import annotations
@@ -59,6 +66,15 @@ def weight_bytes(hf: dict) -> int:
 
 def kv_bytes_per_token(hf: dict) -> int:
     return 2 * hf["num_hidden_layers"] * hf["num_key_value_heads"] * head_dim(hf) * BF16_BYTES
+
+
+def cache_bytes(hf: dict, serving: dict) -> int:
+    """Bytes the serving arguments reserve on the device for per-token and
+    per-sequence state: here, ``--num-blocks`` pages of ``--kv-block-size``
+    (the program's default 16) tokens of keys and values, nothing a sequence."""
+    args = serving["args"]
+    block = args[args.index("--kv-block-size") + 1] if "--kv-block-size" in args else 16
+    return int(args[args.index("--num-blocks") + 1]) * int(block) * kv_bytes_per_token(hf)
 
 
 def flops_per_token(hf: dict) -> int:

@@ -28,22 +28,34 @@ TINY_MIX = {
 }
 
 
-def tiny_bench(tmp: Path, hf: dict = TINY_QWEN, limits: dict | None = None) -> Path:
+def tiny_bench(tmp: Path, hf: dict = TINY_QWEN, limits: dict | None = None, *,
+               name: str = "tiny", own_modules: dict | None = None) -> Path:
     """Writes the throwaway benchmark under ``tmp``; returns the path of its
-    BENCHMARK.json.  Its cell is ``tiny.tinychat``."""
-    for d in ("configs", "traffic", "cells", "metrics"):
+    BENCHMARK.json.  Its cell is ``<name>.tinychat``.  The configuration
+    names the llama-like reference and shapes, copied beside it, or, with
+    ``own_modules`` (``{"reference": source, "shapes": source}``), modules
+    that exist nowhere but under ``tmp``."""
+    for d in ("configs", "traffic", "cells", "metrics", "reference"):
         (tmp / d).mkdir(parents=True, exist_ok=True)
-    config = dict(hf, reduced=[], serving={"args": [
+    if own_modules:
+        names = {"reference": f"{name}_reference", "shapes": f"{name}_shapes"}
+        (tmp / "reference" / f"{name}_reference.py").write_text(own_modules["reference"])
+        (tmp / f"{name}_shapes.py").write_text(own_modules["shapes"])
+    else:
+        names = {"reference": "llama_like", "shapes": "shapes"}
+        for rel in ("reference/llama_like.py", "shapes.py"):
+            (tmp / rel).write_text((ROOT / "benchmark" / rel).read_text())
+    config = dict(hf, **names, reduced=[], serving={"args": [
         "--num-blocks", 128, "--max-batch-size", 4, "--context-length", 256]},
         limits=limits or dict(LIMITS))
-    (tmp / "configs" / "tiny.json").write_text(json.dumps(config))
+    (tmp / "configs" / f"{name}.json").write_text(json.dumps(config))
     (tmp / "traffic" / "tinychat.json").write_text(json.dumps(TINY_MIX))
-    (tmp / "cells" / "tiny.tinychat.json").write_text(json.dumps({"rate_rps": 3.0}))
+    (tmp / "cells" / f"{name}.tinychat.json").write_text(json.dumps({"rate_rps": 3.0}))
     real = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench = dict(real)
-    bench["configs"] = [{"name": "tiny", "source": "none", "reduced": [], "why": "test",
-                         "file": str(tmp / "configs" / "tiny.json")}]
-    bench["workloads"] = [{"name": "tiny.tinychat", "config": "tiny", "traffic": "tinychat",
+    bench["configs"] = [{"name": name, "source": "none", "reduced": [], "why": "test",
+                         "file": str(tmp / "configs" / f"{name}.json")}]
+    bench["workloads"] = [{"name": f"{name}.tinychat", "config": name, "traffic": "tinychat",
                            "chips": 1, "why": "test"}]
     bench["end_to_end"] = [{k: v for k, v in m.items() if k != "workloads"}
                            for m in real["end_to_end"]]

@@ -4,6 +4,8 @@ new files plus one entry: nothing that is there is edited."""
 import json
 from pathlib import Path
 
+import pytest
+
 from benchmark import run as runner
 from benchmark import shapes
 
@@ -47,25 +49,75 @@ def test_a_new_cell_configuration_mix_and_metric_are_found_by_name(tmp_path):
 
 
 def test_the_real_cells_load_and_size_as_their_files_say():
+    """Each cell is held to its configuration's OWN shapes module, so a
+    configuration with experts, a latent cache or a recurrent state is sized
+    by the arithmetic it brought."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for w in bench["workloads"]:
         loaded = runner.load_cell(bench, w["name"])
-        hf = runner.hf_config(loaded["config"])
+        hf, own = runner.hf_config(loaded["config"]), loaded["shapes"]
         serving = loaded["config"]["serving"]
+        assert own.cache_bytes(hf, serving) == serving["kv_bytes"]
+        # weights alone pass a quarter of the chip's 16 GB; weights + two
+        # copies of the cache stay under it
+        assert own.weight_bytes(hf) > 4e9
+        assert 2 * own.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
+        assert loaded["reference"].is_file()
+        # nothing of the harness's leaks into the served config.json
+        assert not {"limits", "reference", "shapes", "serving"} & set(hf)
+
+
+def test_the_llama_like_cache_is_blocks_of_tokens():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for c in bench["configs"]:
+        config = json.loads((ROOT / c["file"]).read_text())
+        hf, serving = runner.hf_config(config), config["serving"]
         blocks = serving["args"][serving["args"].index("--num-blocks") + 1]
         assert blocks * 16 == serving["kv_tokens"]
         assert serving["kv_tokens"] * shapes.kv_bytes_per_token(hf) == serving["kv_bytes"]
-        # weights alone pass a quarter of the chip's 16 GB; weights + two
-        # copies of the cache stay under it
-        assert shapes.weight_bytes(hf) > 4e9
-        assert 2 * shapes.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
+        assert shapes.cache_bytes(hf, {"args": ["--num-blocks", 10, "--kv-block-size", 32]}) \
+            == 320 * shapes.kv_bytes_per_token(hf)
+
+
+@pytest.mark.parametrize("key", ["reference", "shapes"])
+@pytest.mark.parametrize("how", ["left out", "not a bare name", "no such file"])
+def test_a_configuration_that_names_no_module_of_its_own_is_an_error(tmp_path, key, how):
+    """Nothing falls back to a default reference or a default block."""
+    bench_path = tiny_bench(tmp_path)
+    bench = json.loads(bench_path.read_text())
+    assert runner.load_cell(bench, "tiny.tinychat", tmp_path)["reference"].name == "llama_like.py"
+    path = tmp_path / "configs" / "tiny.json"
+    config = json.loads(path.read_text())
+    if how == "left out":
+        del config[key]
+    else:
+        config[key] = {"not a bare name": "../reference/llama_like", "no such file": "absent"}[how]
+    path.write_text(json.dumps(config))
+    with pytest.raises(SystemExit, match=f"{key} module"):
+        runner.load_cell(bench, "tiny.tinychat", tmp_path)
+
+
+def test_a_run_is_cold_until_one_of_its_own_cell_has_reached_its_end(tmp_path):
+    """The long deadline is decided by a marker the harness writes itself,
+    per cell and checkout, under the cache directory: a cache that is warm
+    for another cell says nothing."""
+    cache = tmp_path / "cache"
+    cache.mkdir()
+    (cache / "jit_some_program-of-another-cell").write_text("x")
+    mine, other = runner.warm_marker(cache, "a.chat"), runner.warm_marker(cache, "b.chat")
+    assert mine != other and mine.parent == cache
+    other.touch()
+    # a first run of `a` still compiles; one that follows a run that reached its end does not
+    assert runner.run_deadline(100.0, mine) == 100.0 + runner.COLD_DEADLINE_S
+    mine.touch()
+    assert runner.run_deadline(100.0, runner.warm_marker(cache, "a.chat")) == 100.0 + runner.RUN_DEADLINE_S
+    moved = runner.warm_marker(tmp_path / "moved", "a.chat")
+    assert runner.run_deadline(100.0, moved) == 100.0 + runner.COLD_DEADLINE_S
 
 
 def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path):
     """The offered rate is written in one place, the cell's own file: no
     mix carries one and nothing falls back to a default."""
-    import pytest
-
     bench_path = tiny_bench(tmp_path)
     bench = json.loads(bench_path.read_text())
     assert runner.load_cell(bench, "tiny.tinychat", tmp_path)["rate"] == 3.0
@@ -80,16 +132,12 @@ def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path):
 
 
 def test_the_command_line_offers_no_other_load_or_serving():
-    import pytest
-
     for extra in (["--rate", "9"], ["--server-arg", "--quantize"], ["--control", "int8"]):
         with pytest.raises(SystemExit):
             runner.parse(["--workload", "x", "--seed", "1", "--seconds", "1", *extra])
 
 
 def test_an_unknown_cell_is_an_error():
-    import pytest
-
     with pytest.raises(SystemExit):
         runner.load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()), "no.such-cell")
 
@@ -103,8 +151,6 @@ def test_shapes_match_the_published_sizes():
     assert abs(sizes["qwen3-4b"][0] - 4.02e9) < 0.01e9 and sizes["qwen3-4b"][1] == 147456
     assert abs(sizes["mistral-7b-l16"][0] - 3.75e9) < 0.01e9
     assert sizes["mistral-7b-l16"][1] == 65536
-    import pytest
-
     with pytest.raises(KeyError):
         shapes.load_peaks("cpu")
     assert shapes.load_peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9

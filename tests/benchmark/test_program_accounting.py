@@ -201,10 +201,12 @@ def test_a_trace_of_a_program_without_annotations_reads_as_none():
 def test_a_tiny_run_on_the_cpu_reports_the_metrics_from_stats(tmp_path):
     from benchmark import run as runner
 
-    bench = tiny_bench(tmp_path)
+    # a cell name of this file's own: the run's working directory is the
+    # cell's, and test_end_to_end.py drives ``tiny.tinychat`` in another worker
+    bench = tiny_bench(tmp_path, name="acct")
     (tmp_path / "traffic" / "tinychat.json").write_text(json.dumps(TINY_MIX))
     dump = tmp_path / "dump.json"
-    argv = ["--workload", "tiny.tinychat", "--seed", "5", "--seconds", "3", "--trace", "0",
+    argv = ["--workload", "acct.tinychat", "--seed", "5", "--seconds", "3", "--trace", "0",
             "--dump", str(dump)]
     rc, result = runner.run(runner.parse(argv), require_platform=None, bench_path=bench,
                             bench_dir=tmp_path, env_overlay={"JAX_PLATFORMS": "cpu"})

@@ -64,7 +64,8 @@ def job_for(hf, served_shift=0, control=None):
             served.append(int(jnp.argmax(logits[0])))
         served = [(t + served_shift) % hf["vocab_size"] for t in served]
         samples.append({"index": i, "prompt_ids": prompt, "served_ids": served})
-    return {"hf": hf, "weights_seed": 11, "samples": samples, "control": control}
+    return {"hf": hf, "reference": ref.__file__, "weights_seed": 11, "samples": samples,
+            "control": control}
 
 
 @pytest.mark.parametrize("hf", [TINY_QWEN, TINY_MISTRAL], ids=["qwen", "mistral"])
@@ -79,12 +80,51 @@ def test_check_reads_no_gap_for_the_reference_own_tokens_and_a_wide_one_for_alte
 
 def test_fp8_rounding_moves_the_weights_by_a_few_hundredths():
     w = ref.init_weights(TINY_MISTRAL, 2)
-    q = ref.quantize(w, "fp8")
+    q = ref.quantize(w, "fp8", TINY_MISTRAL)
     assert bool(jnp.all(q["embed"] == w["embed"]))  # untied: only a lookup
-    tied = ref.quantize(ref.init_weights(TINY_QWEN, 2), "fp8")
+    tied = ref.quantize(ref.init_weights(TINY_QWEN, 2), "fp8", TINY_QWEN)
     assert not bool(jnp.all(tied["embed"] == ref.init_weights(TINY_QWEN, 2)["embed"]))
 
     err = jnp.abs(q["wq"].astype(jnp.float32) - w["wq"].astype(jnp.float32))
     assert 2e-2 < float(jnp.max(err) / jnp.max(jnp.abs(w["wq"].astype(jnp.float32)))) < 0.1
     with pytest.raises(KeyError):
-        ref.quantize(w, "int8")
+        ref.quantize(w, "int8", TINY_MISTRAL)
+
+
+def test_every_row_of_an_answer_longer_than_a_slice_is_compared():
+    """3 x PAD + 1 served tokens behind a 20-token prompt: one trunk, four
+    slices through the head, and the numbers are those of all 769 rows (the
+    last slice holds one)."""
+    hf = TINY_QWEN
+    rng = np.random.default_rng(7)
+    prompt = rng.integers(8, hf["vocab_size"], 20).tolist()
+    served = rng.integers(8, hf["vocab_size"], 3 * check.PAD + 1).tolist()
+    top = [[[int(t), float(v)] for t, v in zip(rng.integers(8, hf["vocab_size"], 4),
+                                              -np.sort(rng.random(4)))] for _ in served]
+    sample = {"index": 0, "prompt_ids": prompt, "served_ids": served, "top": top,
+              "served_logprobs": [-1.0] * len(served)}
+    job = {"hf": hf, "reference": ref.__file__, "weights_seed": 11, "samples": [sample]}
+    got = check.run(job)
+    assert got["tokens"] == got["probed_tokens"] == 3 * check.PAD + 1
+
+    weights = ref.init_weights(hf, 11)
+    rows = list(range(len(prompt) - 1, len(prompt) - 1 + len(served)))
+    logits = np.asarray(ref.forward(weights, hf, prompt + served, rows=rows))
+    at = logits[np.arange(len(served)), served]
+    gap = (logits.max(axis=-1) - at) / logits.std(axis=-1)
+    assert got["gap_max"] == pytest.approx(float(gap.max()), rel=1e-4)
+    assert got["gap_mean"] == pytest.approx(float(gap.mean()), rel=1e-4)
+    assert got["mismatch"] == int((logits.argmax(axis=-1) != np.asarray(served)).sum())
+    lsm = at - np.log(np.exp(logits - logits.max(-1, keepdims=True)).sum(-1)) - logits.max(-1)
+    assert got["logprob_err_mean"] == pytest.approx(float(np.abs(-1.0 - lsm).mean()), rel=1e-4)
+
+    # the one row of the last slice counts: served there the reference's own
+    # first choice, the sum of the gaps falls by exactly that row's
+    again = dict(sample, served_ids=served[:-1] + [int(logits[-1].argmax())])
+    fewer = check.run(dict(job, samples=[again]))
+    assert fewer["mismatch"] == got["mismatch"] - 1
+    assert (got["gap_mean"] - fewer["gap_mean"]) * len(served) == pytest.approx(float(gap[-1]), rel=1e-3)
+
+    # and so does it in the control's reading, slice by slice
+    low = check.run(dict(job, control="fp8"))
+    assert low["gap_max"] == got["gap_max"] and low["control_topk_err_mean"] > 0.0
