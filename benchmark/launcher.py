@@ -9,7 +9,8 @@ on ``127.0.0.1:<control-port>``:
 - ``GET /stats``       the engine's ``stats()`` and the device's memory
 - ``POST /trace/start`` / ``POST /trace/stop``   ``jax.profiler`` around a
   few seconds of the window (only the process that holds the chip can trace)
-- ``GET /trace/reduce``   the trace just taken, reduced (benchmark/trace.py)
+- ``GET /trace/reduce?keep=<JSON list of patterns>``   the trace just taken,
+  reduced (benchmark/trace.py), no operation a pattern matches dropped
 
 The thread does nothing unless asked.  The engine's seed is handed to
 ``build_jax_engine`` as the ``seed`` override it already accepts
@@ -23,6 +24,7 @@ from __future__ import annotations
 import json
 import sys
 import threading
+import urllib.parse
 from http.server import BaseHTTPRequestHandler, HTTPServer
 from pathlib import Path
 
@@ -77,10 +79,12 @@ class _Control(BaseHTTPRequestHandler):
                 engine = _state["engine"]
                 stats = engine.stats() if engine is not None else None
                 self._reply({"stats": stats, "memory": _memory()})
-            elif self.path == "/trace/reduce":
+            elif self.path.partition("?")[0] == "/trace/reduce":
                 from benchmark import trace
 
-                self._reply(trace.reduce_dir(_state["trace_dir"]))
+                query = urllib.parse.parse_qs(self.path.partition("?")[2])
+                keep = json.loads(query["keep"][0]) if "keep" in query else ()
+                self._reply(trace.reduce_dir(_state["trace_dir"], keep))
             else:
                 self._reply({"error": "unknown path"}, 404)
         except Exception as exc:  # noqa: BLE001 - reported to the harness

@@ -36,6 +36,7 @@ import shutil
 import subprocess
 import sys
 import time
+import urllib.parse
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -100,12 +101,24 @@ def metrics_of(bench: dict, cell: str, group: str) -> list[dict]:
     return [m for m in bench[group] if "workloads" not in m or cell in m["workloads"]]
 
 
+def metric_specs(bench: dict, cell: str, bench_dir: Path = BENCH_DIR) -> list[tuple[dict, dict]]:
+    """``(entry, its own file)`` of each per-layer metric this cell reports."""
+    return [(m, load_json(bench_dir / "metrics" / f"{m['name']}.json"))
+            for m in metrics_of(bench, cell, "per_layer")]
+
+
+def op_patterns(specs) -> list[str]:
+    """The operation names the cell's readers look for in the trace (a
+    reader's argument ``pattern``): the reduction drops none of them."""
+    return sorted({spec["args"]["pattern"] for _, spec in specs
+                   if "pattern" in spec.get("args", {})})
+
+
 def read_per_layer(bench: dict, cell: str, ctx: dict, bench_dir: Path = BENCH_DIR) -> dict:
     """Each per-layer metric through the reader its own file names.  A reader
     that finds nothing to read returns None and the metric is left out."""
     out = {}
-    for m in metrics_of(bench, cell, "per_layer"):
-        spec = load_json(bench_dir / "metrics" / f"{m['name']}.json")
+    for m, spec in metric_specs(bench, cell, bench_dir):
         reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
         value = reader.read(ctx, **spec.get("args", {}))
         if value is not None:
@@ -271,7 +284,11 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
                               float(mix.get("drain_s", 30.0)), hooks)
         setup_s = driven["t0"] - started
         after = server.ask("/stats")
-        trace = server.ask("/trace/reduce", timeout=240.0) if args.trace else None
+        trace = None
+        if args.trace:
+            keep = json.dumps(op_patterns(metric_specs(bench, args.workload, bench_dir)))
+            trace = server.ask("/trace/reduce?" + urllib.parse.urlencode({"keep": keep}),
+                               timeout=240.0)
     finally:
         server.stop()
 

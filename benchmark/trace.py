@@ -99,9 +99,11 @@ def short(name: str, limit: int = 80) -> str:
 
 def reduce_planes(planes: dict, top: int = 10) -> dict:
     """``busy_s`` and ``window_s`` averaged over the device planes that ran
-    anything, time per operation name (seconds, summed over chips), and the
-    longest idle gaps named by the programs on either side."""
+    anything, time per operation name (``ops``: seconds, summed over chips)
+    and how many events that time is of (``op_events``: launches of a
+    kernel), and the longest idle gaps named by the programs on either side."""
     per_op: dict[str, float] = {}
+    events: dict[str, int] = {}
     busy_ns, window_ns, n = 0.0, 0.0, 0
     all_gaps = []
     line_names = {}
@@ -116,13 +118,14 @@ def reduce_planes(planes: dict, top: int = 10) -> dict:
         window_ns += max(s + d for _, s, d in ops) - min(s for _, s, _ in ops)
         for name, _, dur in ops:
             per_op[name] = per_op.get(name, 0.0) + dur
+            events[name] = events.get(name, 0) + 1
         modules = sorted(lines.get(MODULES_LINE, []), key=lambda e: e[1])
         for g0, g1, before, after in gaps:
             a = _module_at(modules, g0 - 1.0) or before
             z = _module_at(modules, g1 + 1.0) or after
             all_gaps.append((f"{short(a or '?', 36)}->{short(z or '?', 36)}", (g1 - g0) / 1e9))
     if n == 0:
-        return {"busy_s": 0.0, "window_s": 0.0, "chips": 0, "ops": {}, "device_ops": [],
+        return {"busy_s": 0.0, "window_s": 0.0, "chips": 0, "ops": {}, "op_events": {}, "device_ops": [],
                 "idle_gaps": [], "gap_kinds": [], "lines": line_names}
     kinds: dict[str, float] = {}
     for name, sec in all_gaps:
@@ -133,6 +136,7 @@ def reduce_planes(planes: dict, top: int = 10) -> dict:
     return {
         "busy_s": busy_ns / n / 1e9, "window_s": window_ns / n / 1e9, "chips": n,
         "ops": {k: v / 1e9 for k, v in ranked},
+        "op_events": {k: events[k] for k, _ in ranked},
         "device_ops": [[short(k), v / 1e9] for k, v in leaves[:top]],
         "idle_gaps": [[k, v] for k, v in sorted(all_gaps, key=lambda g: -g[1])[:top]],
         "gap_kinds": [[k, v] for k, v in sorted(kinds.items(), key=lambda kv: -kv[1])[:top]],
@@ -140,7 +144,15 @@ def reduce_planes(planes: dict, top: int = 10) -> dict:
     }
 
 
-def reduce_dir(trace_dir: str) -> dict:
+def reduce_dir(trace_dir: str, keep=(), largest: int = 200) -> dict:
+    """The reduction of the trace under ``trace_dir``, its per-operation
+    tables cut to the ``largest`` operations by time and every operation
+    whose name one of the ``keep`` patterns matches: what a metric's reader
+    looks for is there however little time it took."""
     out = reduce_planes(load_planes(find_xplane(trace_dir)))
-    out["ops"] = dict(list(out["ops"].items())[:200])
+    wanted = [re.compile(p) for p in keep]
+    names = [name for rank, name in enumerate(out["ops"])
+             if rank < largest or any(rx.search(name) for rx in wanted)]
+    for table in ("ops", "op_events"):
+        out[table] = {name: out[table][name] for name in names}
     return out

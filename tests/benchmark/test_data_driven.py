@@ -1,8 +1,9 @@
 """A configuration, a mix, a cell and a per-layer metric are each added as
-new files plus one entry: nothing that is there is edited."""
+new files plus one entry: nothing that is there is edited.  The tests of the
+real tree run on the repository and on the copy with the next PR's files
+added (``trees.py``)."""
 
 import json
-from pathlib import Path
 
 import pytest
 
@@ -10,8 +11,7 @@ from benchmark import run as runner
 from benchmark import shapes
 
 from .helpers import tiny_bench
-
-ROOT = Path(__file__).resolve().parents[2]
+from .trees import TREES, bench_of, config_of, each
 
 
 def test_a_new_cell_configuration_mix_and_metric_are_found_by_name(tmp_path):
@@ -48,35 +48,40 @@ def test_a_new_cell_configuration_mix_and_metric_are_found_by_name(tmp_path):
     assert "preemptions.new" not in old and old["window_compiles.chat"]["value"] == 0.0
 
 
-def test_the_real_cells_load_and_size_as_their_files_say():
+@pytest.mark.parametrize("tree,cell", each(lambda bench: bench["workloads"]))
+def test_the_real_cells_load_and_size_as_their_files_say(tree, cell, roots):
     """Each cell is held to its configuration's OWN shapes module, so a
     configuration with experts, a latent cache or a recurrent state is sized
     by the arithmetic it brought."""
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for w in bench["workloads"]:
-        loaded = runner.load_cell(bench, w["name"])
-        hf, own = runner.hf_config(loaded["config"]), loaded["shapes"]
-        serving = loaded["config"]["serving"]
-        assert own.cache_bytes(hf, serving) == serving["kv_bytes"]
-        # weights alone pass a quarter of the chip's 16 GB; weights + two
-        # copies of the cache stay under it
-        assert own.weight_bytes(hf) > 4e9
-        assert 2 * own.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
-        assert loaded["reference"].is_file()
-        # nothing of the harness's leaks into the served config.json
-        assert not {"limits", "reference", "shapes", "serving"} & set(hf)
+    loaded = runner.load_cell(bench_of(tree), cell["name"], roots[tree] / "benchmark")
+    hf, own = runner.hf_config(loaded["config"]), loaded["shapes"]
+    serving = loaded["config"]["serving"]
+    assert own.cache_bytes(hf, serving) == serving["kv_bytes"]
+    # weights alone pass a quarter of the chip's 16 GB; weights + two
+    # copies of the cache stay under it
+    assert own.weight_bytes(hf) > 4e9
+    assert 2 * own.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
+    assert loaded["reference"].is_file()
+    # nothing of the harness's leaks into the served config.json
+    assert not {"limits", "reference", "shapes", "serving"} & set(hf)
 
 
-def test_the_llama_like_cache_is_blocks_of_tokens():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for c in bench["configs"]:
-        config = json.loads((ROOT / c["file"]).read_text())
-        hf, serving = runner.hf_config(config), config["serving"]
-        blocks = serving["args"][serving["args"].index("--num-blocks") + 1]
-        assert blocks * 16 == serving["kv_tokens"]
-        assert serving["kv_tokens"] * shapes.kv_bytes_per_token(hf) == serving["kv_bytes"]
-        assert shapes.cache_bytes(hf, {"args": ["--num-blocks", 10, "--kv-block-size", 32]}) \
-            == 320 * shapes.kv_bytes_per_token(hf)
+def llama_like(bench):
+    """The configurations sized by ``benchmark/shapes.py``, a per-token cache
+    of keys and values; one that names a module of its own says there what
+    its cache is made of, and the test above holds it to that."""
+    return [c for c in bench["configs"] if config_of(c)["shapes"] == "shapes"]
+
+
+@pytest.mark.parametrize("tree,entry", each(llama_like))
+def test_the_llama_like_cache_is_blocks_of_tokens(tree, entry, roots):
+    config = json.loads((roots[tree] / entry["file"]).read_text())
+    hf, serving = runner.hf_config(config), config["serving"]
+    blocks = serving["args"][serving["args"].index("--num-blocks") + 1]
+    assert blocks * 16 == serving["kv_tokens"]
+    assert serving["kv_tokens"] * shapes.kv_bytes_per_token(hf) == serving["kv_bytes"]
+    assert shapes.cache_bytes(hf, {"args": ["--num-blocks", 10, "--kv-block-size", 32]}) \
+        == 320 * shapes.kv_bytes_per_token(hf)
 
 
 @pytest.mark.parametrize("key", ["reference", "shapes"])
@@ -115,7 +120,8 @@ def test_a_run_is_cold_until_one_of_its_own_cell_has_reached_its_end(tmp_path):
     assert runner.run_deadline(100.0, moved) == 100.0 + runner.COLD_DEADLINE_S
 
 
-def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path):
+@pytest.mark.parametrize("tree", TREES)
+def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path, tree, roots):
     """The offered rate is written in one place, the cell's own file: no
     mix carries one and nothing falls back to a default."""
     bench_path = tiny_bench(tmp_path)
@@ -127,7 +133,9 @@ def test_a_cell_without_a_rate_of_its_own_is_an_error(tmp_path):
     (tmp_path / "cells" / "tiny.tinychat.json").unlink()
     with pytest.raises(SystemExit):
         runner.load_cell(bench, "tiny.tinychat", tmp_path)
-    for mix in (ROOT / "benchmark" / "traffic").glob("*.json"):
+    mixes = list((roots[tree] / "benchmark" / "traffic").glob("*.json"))
+    assert len(mixes) >= 3
+    for mix in mixes:
         assert "rate_rps" not in json.loads(mix.read_text()), mix
 
 
@@ -137,16 +145,17 @@ def test_the_command_line_offers_no_other_load_or_serving():
             runner.parse(["--workload", "x", "--seed", "1", "--seconds", "1", *extra])
 
 
-def test_an_unknown_cell_is_an_error():
+@pytest.mark.parametrize("tree", TREES)
+def test_an_unknown_cell_is_an_error(tree, roots):
     with pytest.raises(SystemExit):
-        runner.load_cell(json.loads((ROOT / "BENCHMARK.json").read_text()), "no.such-cell")
+        runner.load_cell(bench_of(tree), "no.such-cell", roots[tree] / "benchmark")
 
 
-def test_shapes_match_the_published_sizes():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+@pytest.mark.parametrize("tree", TREES)
+def test_shapes_match_the_published_sizes(tree, roots):
     sizes = {}
-    for c in bench["configs"]:
-        hf = runner.hf_config(json.loads((ROOT / c["file"]).read_text()))
+    for c in llama_like(bench_of(tree)):
+        hf = runner.hf_config(json.loads((roots[tree] / c["file"]).read_text()))
         sizes[c["name"]] = (shapes.total_params(hf), shapes.kv_bytes_per_token(hf))
     assert abs(sizes["qwen3-4b"][0] - 4.02e9) < 0.01e9 and sizes["qwen3-4b"][1] == 147456
     assert abs(sizes["mistral-7b-l16"][0] - 3.75e9) < 0.01e9
