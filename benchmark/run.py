@@ -6,7 +6,8 @@
 Looks the cell up in ``BENCHMARK.json``; its ``config`` and ``traffic`` name
 ``benchmark/configs/<config>.json`` and ``benchmark/traffic/<traffic>.json``,
 ``benchmark/cells/<cell>.json`` holds the rate the cell is offered (the one
-place a rate is written), the configuration's file names its own
+place a rate is written) and, on a mix that opens on a backlog, the range of
+requests whose gaps are its ``itl_p95_ms``, the configuration's file names its own
 ``reference`` and ``shapes`` modules (``benchmark/modules.py``), and each
 per-layer metric is ``benchmark/metrics/<name>.json`` naming a reader module
 under ``benchmark/readers/``.  Nothing here knows a cell, a configuration, a
@@ -245,6 +246,10 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     plan = traffic.schedule(mix, rate, seconds)
     filled = traffic.fill(plan, mix, hf["vocab_size"], args.seed)
     plan_by_index = {r["index"]: r for r in plan}
+    gap_requests = loaded["own"].get("gap_requests")
+    if gap_requests and gap_requests[1] > sum(1 for r in plan if not r["probe"]):
+        raise SystemExit(f"--seconds {seconds:g} offers fewer requests than the cell's "
+                         f"gap_requests {gap_requests}: itl_p95_ms would be another set's")
     serving = [str(a) for a in [*config["serving"]["args"], *loaded["own"].get("serving_args", [])]]
     marker = warm_marker(cache_dir(), args.workload)
     deadline = run_deadline(started, marker)
@@ -311,7 +316,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
                                              "decode_tokens_total", "engine_busy_steps_total")
             if k in s0 and k in s1)
             + f"; waiting at its close {s1.get('num_requests_waiting')}")
-    e2e = arith.end_to_end(records, seconds, miss_ms=(seconds + float(mix.get("drain_s", 30.0))) * 1e3)
+    e2e = arith.end_to_end(records, seconds, miss_ms=(seconds + float(mix.get("drain_s", 30.0))) * 1e3,
+                           gap_requests=gap_requests)
     e2e["setup_s"] = setup_s
     peaks = [m.get("peak_bytes_in_use") or 0 for s in (holder.get("stats1"), after) if s
              for m in s["memory"]]

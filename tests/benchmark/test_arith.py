@@ -95,3 +95,32 @@ def test_split_and_unsplit_agree_over_all_time():
 def test_no_gap_no_itl():
     e = arith.end_to_end([rec(0.0, [(0.5, 1)])], 1.0, miss_ms=1.0)
     assert "itl_p50_ms" not in e and e["ttft_p95_ms"] == pytest.approx(500.0)
+
+
+FIXED = [dict(rec(-5.0, [(-1.0, 1), (-0.7, 1), (0.2, 1)]), index=0),    # lead-in: -0.7 closes outside
+         dict(rec(0.0, [(1.0, 1), (1.1, 1), (9.8, 1), (10.6, 1)]), index=1),   # the last closes in the drain
+         dict(rec(1.0, [(2.0, 1), (2.05, 1)]), index=2),
+         dict(rec(2.0, [(3.0, 1)]), index=3)]
+
+
+def test_gaps_of_a_range_of_requests_count_wherever_they_close():
+    assert arith.gaps_of_requests_ms(FIXED, 0, 2) == pytest.approx([300.0, 900.0, 100.0, 8700.0, 800.0])
+    assert arith.gaps_of_requests_ms(FIXED, 2, 4) == pytest.approx([50.0])
+    assert arith.gaps_of_requests_ms(FIXED, 3, 9) == []
+    # the window's own set leaves out the lead-in's gap and the drain's
+    assert sorted(arith.gaps_ms(FIXED, 10.0)) == pytest.approx([50.0, 100.0, 900.0, 8700.0])
+
+
+@pytest.mark.parametrize("gap_requests, p95", [
+    (None, 7530.0), ([0, 2], 7140.0), ([2, 3], 50.0), ([1, 3], 7515.0)])
+def test_a_cells_range_moves_itl_p95_ms_and_nothing_else(gap_requests, p95):
+    plain = arith.end_to_end(FIXED, 10.0, miss_ms=5e4)
+    got = arith.end_to_end(FIXED, 10.0, 5e4, gap_requests)
+    assert got.pop("itl_p95_ms") == pytest.approx(p95)
+    assert plain.pop("itl_p95_ms") == plain["itl_p95_window_ms"] == pytest.approx(7530.0)
+    assert got == plain          # the median, the window's tail, the rates, the TTFT
+
+
+def test_a_range_that_holds_no_gap_reports_no_tail():
+    e = arith.end_to_end(FIXED, 10.0, 5e4, [3, 9])
+    assert "itl_p95_ms" not in e and e["itl_p95_window_ms"] == pytest.approx(7530.0)

@@ -6,7 +6,8 @@ holds it against the reference.
 - the control (the float8 reference in the program's place) reads above the
   limit on the same seeds at this size;
 - with the timed path broken underneath (the model's logits rolled by one
-  id where they are produced) ``correct`` comes out false.
+  id where they are produced) ``correct`` comes out false;
+- a cell that carries ``gap_requests`` reports the tail of those answers' gaps.
 """
 
 import json
@@ -16,6 +17,7 @@ from pathlib import Path
 
 import pytest
 
+from benchmark import arith
 from benchmark import run as runner
 
 from .helpers import TINY_MISTRAL, TINY_MIX, tiny_bench
@@ -30,14 +32,16 @@ LIMITS = {"gap_max": 0.05, "logprob_err_mean": 0.014, "topk_err_mean": 0.02,
 SEEDS = [11, 2**31 + 5, 987654321]
 
 
-def drive(tmp_path, seed, *, launcher=None, control=None):
+def drive(tmp_path, seed, *, launcher=None, control=None, own=None, seconds=3):
     hf = dict(TINY_MISTRAL, vocab_size=2000)
     bench = tiny_bench(tmp_path, hf, LIMITS)
+    if own:
+        (tmp_path / "cells" / "tiny.tinychat.json").write_text(json.dumps(own))
     mix = dict(TINY_MIX, output_tokens={"dist": "lognormal", "median": 40, "sigma": 0.3,
                                         "min": 24, "max": 64}, check_requests=6)
     (tmp_path / "traffic" / "tinychat.json").write_text(json.dumps(mix))
     dump = tmp_path / "dump.json"
-    argv = ["--workload", "tiny.tinychat", "--seed", str(seed), "--seconds", "3",
+    argv = ["--workload", "tiny.tinychat", "--seed", str(seed), "--seconds", str(seconds),
             "--trace", "0", "--dump", str(dump)] + (["--control", control] if control else [])
     rc, result = runner.run(runner.parse(argv), require_platform=None, launcher=launcher,
                             bench_path=bench, bench_dir=tmp_path,
@@ -81,3 +85,19 @@ def test_a_broken_timed_path_is_not_correct(tmp_path):
     assert result["correct"] is False
     assert dump["check"]["gap_max"] > LIMITS["gap_max"]
     assert dump["check"]["topk_err_mean"] > LIMITS["topk_err_mean"]
+
+
+def test_a_cell_with_a_range_reads_its_tail_over_those_answers(tmp_path):
+    own = {"rate_rps": 3.0, "gap_requests": [2, 7]}
+    rc, result, dump = drive(tmp_path, SEEDS[0], own=own)
+    assert rc == 0 and result["correct"] is True
+    gaps = [(b - a) * 1e3 for index, *_, chunks in dump["timeline"] if 2 <= index < 7
+            for a, b in zip(chunks, chunks[1:])]
+    assert len(gaps) > 100
+    assert result["metrics"]["itl_p95_ms"]["value"] == arith.percentile(gaps, 95) == dump["e2e"]["itl_p95_ms"]
+    inside = [(b - a) * 1e3 for *_, chunks in dump["timeline"] for a, b in zip(chunks, chunks[1:]) if 0 <= b <= 3]
+    assert dump["e2e"]["itl_p95_window_ms"] == arith.percentile(inside, 95)
+    assert set(gaps) != set(inside)
+    # a window that offers fewer requests than the range names is refused, not read over another set
+    with pytest.raises(SystemExit, match="gap_requests"):
+        drive(tmp_path, SEEDS[0], own=dict(own, gap_requests=[2, 70]))
