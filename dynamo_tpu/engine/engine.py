@@ -10,7 +10,10 @@ Architecture:
   drops into the same pipelines as any remote engine;
 - static shapes throughout: prompt lengths round up to buckets (one compiled
   prefill per bucket), decode runs a fixed ``max_batch_size`` lane array;
-- KV cache is donated through every step (no double-buffering in HBM);
+- KV cache is donated through every step and carried through the layer
+  loop as flat pages (models/llama.py ``_scan_layers``): a step program
+  writes the donated buffer in place and returns it, its temporaries are
+  activations only (``stats()["program_temp_bytes_max"]``);
 - the allocator publishes stored/removed block events and load metrics for
   the KV-aware router.
 """
@@ -649,6 +652,8 @@ class JaxLlmEngine:
         # checks fire every iteration and must not spam
         self._unified_fallbacks: dict[str, int] = {}
         self._unified_fallback_logged: set[str] = set()
+        # aot_precompile's job name → that program's temporary bytes
+        self.program_temp_bytes: dict[tuple, int] = {}
         if unified:
             reason = slug = None
             if self.family.forward_unified is None:
@@ -2004,7 +2009,11 @@ class JaxLlmEngine:
         def compile_one(item):
             name, program = item
             t = time.monotonic()
-            program.compile()
+            # what the program allocates beside its arguments and results: a
+            # step program that copies the cache holds a whole cache here
+            analysis = program.compile().memory_analysis()
+            if analysis is not None:  # a backend may report none
+                self.program_temp_bytes[name] = int(analysis.temp_size_in_bytes)
             logger.info("aot_precompile: %s in %.1fs", name, time.monotonic() - t)
             if on_program is not None:
                 on_program(name)
@@ -2205,6 +2214,16 @@ class JaxLlmEngine:
             # programs requested from the compiler / answered by the
             # persistent cache since engine init (utils/compile_cache.py)
             **compile_counts(),
+            # what each step program aot_precompile compiled allocates
+            # beside its arguments and results ("unified_4096": bytes), and
+            # the largest of them (0 before it ran)
+            "program_temp_bytes": {
+                "_".join(map(str, name)): temp
+                for name, temp in sorted(self.program_temp_bytes.items())
+            },
+            "program_temp_bytes_max": max(
+                self.program_temp_bytes.values(), default=0
+            ),
             "decode_steps_total": self._decode_steps_total,
             "guided_requests_total": self._guided_requests,
             "guided_completions_total": self._guided_completions,

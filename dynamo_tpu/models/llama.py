@@ -7,8 +7,13 @@ TPU-first design decisions:
 - tensor parallelism by sharding annotation only: params carry
   ``PartitionSpec``s over mesh axis ``tp``; XLA/GSPMD inserts the
   all-reduces (no hand-written collectives in the model);
-- paged KV cache (``[layers, num_blocks, block_size, kv_heads, head_dim]``)
-  threaded through prefill/decode as scan-carried state;
+- paged KV cache, stored ``[layers, num_blocks, block_size, kv_heads,
+  head_dim]``; a forward views it as flat pages ``[layers * num_blocks,
+  ...]`` and carries THAT through the layer scan (``_scan_layers``): every
+  layer scatters into and attends over the one donated buffer at its own
+  page offset, nothing is copied or stacked back (the one-query decode
+  kernel alone reads a slice of its layer, where that stays in the chip's
+  fast memory: ``_LayerPages.on_chip``);
 - bf16 params/activations, fp32 softmax/norms.
 
 The reference has no model code (engines own it); this replaces the
@@ -109,7 +114,7 @@ class LlamaConfig:
         ``use_sliding_window`` and ``max_window_layers``: layers with index
         >= max_window_layers use the window, layers below it use full
         attention.  This model applies ONE attention pattern to every layer
-        (the layer body is a single ``lax.scan``), so:
+        (one ``lax.scan`` body over the stacked weights, ``_scan_layers``), so:
         - use_sliding_window false, or max_window_layers >= num layers
           (no layer windowed): full attention everywhere;
         - max_window_layers <= 0 (every layer windowed), or the key absent
@@ -333,6 +338,92 @@ def _logits(params, cfg, x):
         return mm(x, params["lm_head"])
 
 
+# what XLA's memory-space assignment will hold of one value in a v5e's
+# 128 MiB of fast memory beside a decode step's activations: 75.5 MB (K and V
+# of a `qwen3-4b` layer at 1,152 blocks) stays there, 168 MB (`mistral-7b` at
+# 2,560) does not (traces of PR 32 and PR 33, PERF.md section 5)
+_ON_CHIP_PAGES_BYTES = 96 << 20
+
+
+@dataclass(frozen=True)
+class _LayerPages:
+    """Where one layer's pages lie in the cache viewed as flat pages
+    ``[layers * num_blocks, block_size, kv_heads, head_dim]``."""
+
+    base: jnp.ndarray   # scalar int32: layer * num_blocks, its first page
+    num_blocks: int     # pages of ONE layer
+    block_size: int
+    num_layers: int
+
+    def blocks(self, ids: jnp.ndarray) -> jnp.ndarray:
+        """A layer's block ids (any shape: a prefill's ids, the lanes'
+        tables) as pages of the flat cache.  Added here, in XLA: the
+        attention kernels see plain page numbers."""
+        return ids + self.base
+
+    def on_chip(self, k_pages, v_pages, block_tables):
+        """What the one-query decode kernel reads: ``(k, v, tables)``.
+
+        That kernel issues a page copy in every grid step and does next to
+        nothing with it, so it runs at the speed its pages arrive: 0.24 ms a
+        launch from the chip's fast memory against 0.47 ms from HBM at
+        ``qwen3-4b.chat``'s shapes (PERF.md section 6, PR 33).  XLA keeps a
+        layer's pages in fast memory when they are a value of their own and
+        fit there, so where K and V of ONE layer fit together the kernel
+        gets a slice of the carry, block tables as they are; where they do
+        not (the slice would go back to HBM and buy nothing) it reads the
+        flat pages in place, tables offset."""
+        layer_bytes = (k_pages.nbytes + v_pages.nbytes) // self.num_layers
+        if layer_bytes > _ON_CHIP_PAGES_BYTES:
+            return k_pages, v_pages, self.blocks(block_tables)
+        take = lambda pages: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            pages, self.base, self.num_blocks
+        )
+        return take(k_pages), take(v_pages), block_tables
+
+    def slots(self, ids: jnp.ndarray) -> jnp.ndarray:
+        """A layer's flat slots as slots of the flat cache.  A slot out of
+        the layer's range (a pad token, an inactive lane) is sent out of
+        range of the WHOLE cache, where the scatter drops it: merely offset
+        it would land in the next layer's first page."""
+        per_layer = self.num_blocks * self.block_size
+        return jnp.where(
+            ids < per_layer, ids + self.base * self.block_size,
+            self.num_layers * per_layer,
+        )
+
+
+def _scan_layers(layer, x, layers: dict, kv_cache: dict):
+    """The layer loop of every forward that takes the stacked KV cache.
+
+    ``kv_cache["k"]`` / ``["v"]`` ``[L, N, bs, kvh, d]`` ride the scan as a
+    CARRY, viewed as flat pages ``[L * N, bs, kvh, d]`` (a reshape of two
+    unsharded leading axes; the ``tp`` spec on the kv-head axis stands), and
+    are reshaped back once after the loop: the buffer a jitted step donates
+    is the buffer it returns.  (As per-layer scan inputs and stacked outputs
+    the cache was copied whole, K and V, in every step program, and each
+    layer sliced out and back.)
+
+    ``layer(x, w, k_pages, v_pages, at) -> (x, k_pages, v_pages)`` writes and
+    reads layer ``l`` through ``at`` (``_LayerPages``): block ids and tables
+    through ``at.blocks``, write slots through ``at.slots``."""
+    k, v = kv_cache["k"], kv_cache["v"]
+    num_layers, num_blocks, block_size = k.shape[:3]
+    flat = (num_layers * num_blocks, *k.shape[2:])
+
+    def body(carry, layer_in):
+        x, k_pages, v_pages = carry
+        w, index = layer_in
+        at = _LayerPages(index * num_blocks, num_blocks, block_size, num_layers)
+        return layer(x, w, k_pages, v_pages, at), None
+
+    (x, k_pages, v_pages), _ = jax.lax.scan(
+        body, (x, k.reshape(flat), v.reshape(flat)),
+        (layers, jnp.arange(num_layers, dtype=jnp.int32)),
+    )
+    return x, {"k": k_pages.reshape(k.shape), "v": v_pages.reshape(v.shape)}
+
+
 def llama_forward_prefill(
     params: dict,
     cfg: LlamaConfig,
@@ -390,14 +481,15 @@ def llama_forward_prefill_embeds(
             )
         from dynamo_tpu.ops.ring_attention import ring_attention
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
+    def layer(x, w, k_pages, v_pages, at):
         attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, block_ids, seq_len)
+            k_pages, v_pages = write_prefill_kv(
+                k_pages, v_pages, k, v, at.blocks(block_ids), seq_len
+            )
         if sp_mesh is not None:
             with jax.named_scope("attn"):
                 attn = ring_attention(q[None], k[None], v[None], seq_len, sp_mesh)[0]
@@ -411,15 +503,13 @@ def llama_forward_prefill_embeds(
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
             x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, (k_layer, v_layer)
+        return x, k_pages, v_pages
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(seq_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), kv_cache
 
 
 def llama_forward_prefill_with_prefix(
@@ -458,17 +548,20 @@ def llama_forward_prefill_with_prefix(
             )
         from dynamo_tpu.ops.ring_attention import ring_attention_with_prefix
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
+    def layer(x, w, k_pages, v_pages, at):
         attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         # gather the resident prefix BEFORE writing the tail (the mask in
         # the attention op drops everything past start_pos anyway)
-        k_prefix, v_prefix = gather_prefix_kv(k_layer, v_layer, full_block_ids)
+        k_prefix, v_prefix = gather_prefix_kv(
+            k_pages, v_pages, at.blocks(full_block_ids)
+        )
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_prefill_kv(k_layer, v_layer, k, v, tail_block_ids, tail_len)
+            k_pages, v_pages = write_prefill_kv(
+                k_pages, v_pages, k, v, at.blocks(tail_block_ids), tail_len
+            )
         if sp_mesh is not None:
             attn = ring_attention_with_prefix(
                 q[None], k[None], v[None], k_prefix[None], v_prefix[None],
@@ -483,15 +576,13 @@ def llama_forward_prefill_with_prefix(
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
             x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, (k_layer, v_layer)
+        return x, k_pages, v_pages
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(tail_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), kv_cache
 
 
 def llama_forward_decode(
@@ -521,11 +612,12 @@ def llama_forward_decode(
     x = _embed(params, cfg, token_ids)  # [b, h]
     positions = jnp.maximum(context_lens - 1, 0)      # this token's position
 
-    def attend(q, k_layer, v_layer):
+    def attend(q, k_pages, v_pages, at):
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import paged_attention_decode
 
             interpret = attention == "pallas_interpret"
+            k_read, v_read, tables = at.on_chip(k_pages, v_pages, block_tables)
             if tp_mesh is not None and tp_mesh.shape.get("tp", 1) > 1:
                 kernel = jax.shard_map(
                     lambda q_, k_, v_, bt, cl: paged_attention_decode(
@@ -543,39 +635,38 @@ def llama_forward_decode(
                     out_specs=P(None, "tp", None),
                     check_vma=False,  # pallas_call outputs carry no vma info
                 )
-                return kernel(q, k_layer, v_layer, block_tables, context_lens)
+                return kernel(q, k_read, v_read, tables, context_lens)
             return paged_attention_decode(
-                q, k_layer, v_layer, block_tables, context_lens,
+                q, k_read, v_read, tables, context_lens,
                 interpret=interpret, sliding_window=cfg.sliding_window,
             )
         return paged_decode_attention(
-            q, k_layer, v_layer, block_tables, context_lens,
+            q, k_pages, v_pages, at.blocks(block_tables), context_lens,
             sliding_window=cfg.sliding_window,
         )
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
+    def layer(x, w, k_pages, v_pages, at):
         attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(attn_in, w, cfg)
         # apply_rope expects a seq axis: insert and drop it
         q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slot_ids)
+            k_pages, v_pages = write_decode_kv(
+                k_pages, v_pages, k, v, at.slots(slot_ids)
+            )
         with jax.named_scope("attn"):
-            attn = attend(q, k_layer, v_layer)
+            attn = attend(q, k_pages, v_pages, at)
         x = x + mm(attn.reshape(b, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
             x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, (k_layer, v_layer)
+        return x, k_pages, v_pages
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x)
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), kv_cache
 
 
 def llama_forward_unified(
@@ -613,26 +704,25 @@ def llama_forward_unified(
     x = _embed(params, cfg, token_ids)  # [t, h]
     positions = jnp.maximum(token_pos, 0)
 
-    def attend(q, k_layer, v_layer):
+    def attend(q, k_pages, v_pages, block_tables):
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import (
                 ragged_paged_attention as ragged_kernel,
             )
 
             return ragged_kernel(
-                q, k_layer, v_layer, token_lane, token_pos,
+                q, k_pages, v_pages, token_lane, token_pos,
                 block_tables, span_lane, span_first, span_count, page_total,
                 tb_tokens=tb_tokens,
                 interpret=attention == "pallas_interpret",
                 sliding_window=cfg.sliding_window,
             )
         return ragged_paged_attention(
-            q, k_layer, v_layer, block_tables, context_lens, token_lane,
+            q, k_pages, v_pages, block_tables, context_lens, token_lane,
             token_pos, sliding_window=cfg.sliding_window,
         )
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
+    def layer(x, w, k_pages, v_pages, at):
         attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q, positions, cos, sin)
@@ -640,22 +730,22 @@ def llama_forward_unified(
         # every token writes before anyone reads: span tokens see their own
         # in-window predecessors through the cache (pads scatter-drop)
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, token_slot)
+            k_pages, v_pages = write_decode_kv(
+                k_pages, v_pages, k, v, at.slots(token_slot)
+            )
         with jax.named_scope("attn"):
-            attn = attend(q, k_layer, v_layer)
+            attn = attend(q, k_pages, v_pages, at.blocks(block_tables))
         x = x + mm(attn.reshape(t, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
             x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, (k_layer, v_layer)
+        return x, k_pages, v_pages
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated
     logits = _logits(params, cfg, rows)
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), kv_cache
 
 
 def llama_forward_verify(
@@ -686,38 +776,36 @@ def llama_forward_verify(
     )  # [b, w]
     flat_slots = slot_ids.reshape(-1)
 
-    def attend(q, k_layer, v_layer):
+    def attend(q, k_pages, v_pages, block_tables):
         return window_attention(
-            attention, q, k_layer, v_layer, block_tables, context_lens,
+            attention, q, k_pages, v_pages, block_tables, context_lens,
             sliding_window=cfg.sliding_window,
         )
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
+    def layer(x, w, k_pages, v_pages, at):
         attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
         q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q.reshape(b, w_len, cfg.num_heads, cfg.head_dim), positions, cos, sin)
         k = apply_rope(k.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim), positions, cos, sin)
         v = v.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim)
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_decode_kv(
-                k_layer, v_layer, k.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
-                v.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim), flat_slots,
+            k_pages, v_pages = write_decode_kv(
+                k_pages, v_pages, k.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
+                v.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
+                at.slots(flat_slots),
             )
         with jax.named_scope("attn"):
-            attn = attend(q, k_layer, v_layer)
+            attn = attend(q, k_pages, v_pages, at.blocks(block_tables))
         x = x + mm(attn.reshape(b * w_len, -1), w["wo"])
         mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
             x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, (k_layer, v_layer)
+        return x, k_pages, v_pages
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x).reshape(b, w_len, -1)
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    return logits.astype(jnp.float32), kv_cache
 
 
 def llama_forward_decode_pp(

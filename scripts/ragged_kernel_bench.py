@@ -2,14 +2,23 @@
 benchmark cells' shapes (h32 kv8 d128, block 16, bf16 cache).
 
     python scripts/ragged_kernel_bench.py [--parent DIR] [--iters N]
+    python scripts/ragged_kernel_bench.py --layers 36 [--num-blocks 1152]
 
 Prints one JSON line per (workload, variant): ms a call (median of
 ``--iters`` timed calls, each ended by block_until_ready), token blocks,
 live pages, page iterations.  With ``--parent DIR`` (a checkout of the commit
 before the kernel walked live pages only) it times THAT tree's kernel at its
 full worklist width and at the tightest width that fits, which prices a dead
-grid step and a live page (PERF.md section 6, PR 28).  Exits 1 off the TPU:
-a CPU time is not a device time.
+grid step and a live page (PERF.md section 6, PR 28).  With ``--layers L`` it
+times both attention kernels as a step program launches them, L times in one
+jitted scan: over ONE layer's pages ``[N, ...]`` with the tables as they are,
+and over the whole cache as flat pages ``[L * N, ...]`` with the tables offset
+by ``layer * N`` (``models/llama.py:_scan_layers``); ms a launch must agree
+(PERF.md section 5, PR 33).  Both operands are a program's arguments, in HBM:
+this prices the operand's SIZE, not where it lives (a layer sliced out inside
+a step program may sit in the chip's fast memory, and the one-query kernel
+then runs twice as fast: ``_LayerPages.on_chip``).  Exits 1 off the TPU: a
+CPU time is not a device time.
 """
 
 from __future__ import annotations
@@ -54,9 +63,9 @@ def _flat(spans, bucket):
     return lane, pos
 
 
-def _tables(lanes, max_blocks, rng):
-    per = min(130, max_blocks, (NUM_BLOCKS - 1) // lanes)
-    perm = rng.permutation(NUM_BLOCKS - 1)[: lanes * per] + 1
+def _tables(lanes, max_blocks, rng, num_blocks=NUM_BLOCKS):
+    per = min(130, max_blocks, (num_blocks - 1) // lanes)
+    perm = rng.permutation(num_blocks - 1)[: lanes * per] + 1
     bt = np.zeros((lanes, max_blocks), np.int32)
     bt[:, :per] = perm.reshape(lanes, per)
     return bt
@@ -89,6 +98,98 @@ def _time(fn, args, iters):
     return statistics.median(ts) * 1e3, min(ts) * 1e3
 
 
+def _decode_workloads():
+    """(name, lanes, max_blocks, [context length of each live lane]): the
+    decode kernel at the cells' lane counts (`qwen3-4b.chat` 16 lanes with
+    about 7 busy on short contexts, the long-prompt cells 8 full lanes)."""
+    return [
+        ("decode.chat.16lanes", 16, 256, [300 + 23 * i for i in range(7)]),
+        ("decode.long.8lanes", 8, 256, [2000 + 7 * i for i in range(8)]),
+    ]
+
+
+def _bench_layers(a, dev, interpret) -> int:
+    """Both kernels, ``--layers`` launches in one jitted scan, at N pages
+    and at L * N pages with offset tables."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.pallas import paged_attention as pa
+    from dynamo_tpu.ops.pallas import ragged_attention as ra
+
+    layers, n = a.layers, a.num_blocks
+    rng = np.random.default_rng(0)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    flat_shape = (layers * n, BS, KVH, D)
+    k_all = jax.random.normal(kk, flat_shape, jnp.bfloat16)
+    v_all = jax.random.normal(kv, flat_shape, jnp.bfloat16)
+    # one layer's pages, as the slice a forward used to take: the LAST
+    # layer's, so that both variants read the same values at the far end
+    k_one, v_one = k_all[-n:], v_all[-n:]
+    index = jnp.arange(layers, dtype=jnp.int32)
+
+    def timed(launch, out_shape, tables, row):
+        """``launch(k, v, tables) -> out`` scanned over the layer index;
+        the outputs are summed so that no launch is dead code."""
+        def scanned(offset):
+            def run(k, v, bt):
+                def body(acc, layer):
+                    # min(layer, 0) is 0 in every iteration, but not to the
+                    # compiler: no launch can be hoisted out of the loop
+                    shift = layer * offset if offset else jnp.minimum(layer, 0)
+                    out = launch(k, v, bt + shift)
+                    return acc + out.astype(jnp.float32), None
+                return jax.lax.scan(body, jnp.zeros(out_shape, jnp.float32), index)[0]
+            return jax.jit(run)
+
+        for variant, fn, k, v in (
+            ("N", scanned(0), k_one, v_one), ("LxN", scanned(n), k_all, v_all),
+        ):
+            med, best = _time(fn, (k, v, tables), a.iters)
+            print(json.dumps({
+                **row, "device": dev.device_kind, "variant": variant,
+                "pages": int(k.shape[0]), "launches": layers,
+                "ms_a_launch": med / layers, "ms_a_launch_min": best / layers,
+            }), flush=True)
+
+    for name, tb, lanes, bucket, max_blocks, spans in _workloads():
+        if interpret:  # rehearsal: same code path, toy extents
+            bucket, max_blocks = 16, 4
+            spans = [(ln, s % 40, s % 40 + min(e - s, 6)) for ln, s, e in spans[-2:]]
+        lane, pos = _flat(spans, bucket)
+        bt = jnp.asarray(_tables(lanes, max_blocks, rng, n))
+        q = jax.random.normal(kq, (bucket, H, D), jnp.bfloat16)
+        meta = ra.pack_spans(lane, pos, lanes=lanes, tb_tokens=tb, block_size=BS)
+        lane_j, pos_j = jnp.asarray(lane), jnp.asarray(pos)
+        meta_j = tuple(jnp.asarray(m) for m in meta)
+        timed(
+            lambda k, v, t: ra.ragged_paged_attention(
+                q, k, v, lane_j, pos_j, t, *meta_j, tb_tokens=tb,
+                interpret=interpret,
+            ),
+            (bucket, H, D), bt,
+            {"workload": name, "kernel": "ragged_paged_attention",
+             "live_pages": int(meta[-1].sum())},
+        )
+    for name, lanes, max_blocks, ctx in _decode_workloads():
+        if interpret:
+            max_blocks, ctx = 4, [min(c % 60 + 1, 60) for c in ctx[:2]]
+        bt = jnp.asarray(_tables(lanes, max_blocks, rng, n))
+        lens = np.zeros((lanes,), np.int32)
+        lens[: len(ctx)] = ctx
+        lens_j = jnp.asarray(lens)
+        q = jax.random.normal(kq, (lanes, H, D), jnp.bfloat16)
+        timed(
+            lambda k, v, t: pa.paged_attention_decode(
+                q, k, v, t, lens_j, interpret=interpret
+            ),
+            (lanes, H, D), bt,
+            {"workload": name, "kernel": "paged_window_attention_decode",
+             "lanes": lanes, "live_lanes": len(ctx)},
+        )
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--parent", default=None)
@@ -99,6 +200,12 @@ def main() -> int:
     ap.add_argument("--check", default=None, metavar="DIR",
                     help="compare this tree's outputs, to the bit, with the "
                          "kernel of the checkout in DIR (page worklists)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="time both attention kernels, this many launches in "
+                         "one jitted scan, over one layer's pages and over "
+                         "the whole cache as flat pages")
+    ap.add_argument("--num-blocks", type=int, default=NUM_BLOCKS,
+                    help="pages of ONE layer (with --layers)")
     ap.add_argument("--allow-cpu", action="store_true", help="rehearsal only")
     a = ap.parse_args()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -111,6 +218,8 @@ def main() -> int:
         print(f"needs a TPU, found {dev.platform}", file=sys.stderr)
         return 1
     interpret = dev.platform != "tpu"
+    if a.layers:
+        return _bench_layers(a, dev, interpret)
     from dynamo_tpu.ops.pallas import ragged_attention as ra
 
     rng = np.random.default_rng(0)

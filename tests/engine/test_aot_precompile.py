@@ -168,6 +168,10 @@ def test_aot_precompile_lowers_on_the_calling_thread():
     class Program:
         def compile(self):
             where["compile"] += 1
+            return self
+
+        def memory_analysis(self):
+            return None
 
     class Jit:
         def lower(self, *avals):
@@ -180,6 +184,63 @@ def test_aot_precompile_lowers_on_the_calling_thread():
     assert where["lower"] == {threading.current_thread()}
     assert where["order"] == list(range(6))
     assert where["compile"] == 6
+
+
+def _big_cache_engine():
+    """A tiny model under a cache that dwarfs its activations (8.4 MB of K
+    and V against well under 1 MB of temporaries): a program that copies
+    the cache cannot hide among them."""
+    return JaxLlmEngine(
+        EngineConfig(
+            model=LlamaConfig.tiny(), num_blocks=4096, block_size=4,
+            max_batch_size=2, prefill_buckets=(16,), max_model_len=32,
+            top_logprobs_k=0,
+        )
+    )
+
+
+def test_step_programs_hold_no_copy_of_the_cache():
+    """The cache rides the layer loop as a carry and is written in place:
+    what a decode or unified program allocates beside its arguments is
+    activations.  (With the cache as per-layer scan inputs and stacked
+    outputs each program held at least one whole cache there.)"""
+    import jax
+
+    engine = _big_cache_engine()
+    assert engine.stats()["program_temp_bytes_max"] == 0  # nothing compiled yet
+    assert engine.aot_precompile([12], parallel=2) >= 3
+    cache_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(engine.cache))
+    temps = engine.program_temp_bytes
+    assert ("decode",) in temps and ("unified", 16) in temps, temps
+    for name, temp in temps.items():
+        assert temp < cache_bytes // 2, (name, temp, cache_bytes)
+    stats = engine.stats()
+    assert stats["program_temp_bytes_max"] == max(temps.values())
+    assert stats["program_temp_bytes"]["decode"] == temps[("decode",)]
+    assert stats["program_temp_bytes"]["unified_16"] == temps[("unified", 16)]
+
+
+@pytest.mark.parametrize("program", [("decode",), ("unified", 16), ("prefill", 16)])
+def test_donated_cache_buffer_is_the_returned_one(program):
+    """One step with the donated cache: K and V come back in the buffers
+    they went in with."""
+    import jax
+    import jax.numpy as jnp
+
+    engine = _big_cache_engine()
+    jit_fn, avals = engine._aot_jobs([12])[program]
+    args = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), avals)
+    cache = jax.tree.map(lambda a: jnp.ones(a.shape, a.dtype), avals[1])
+    try:
+        before = {name: leaf.unsafe_buffer_pointer() for name, leaf in cache.items()}
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"this backend exposes no buffer pointer: {e}")
+    outs = jit_fn(args[0], cache, *args[2:])
+    (new,) = [out for out in outs if isinstance(out, dict)]
+    assert all(leaf.is_deleted() for leaf in cache.values())  # it WAS donated
+    assert {
+        name: leaf.unsafe_buffer_pointer() for name, leaf in new.items()
+    } == before
 
 
 def _resolve_in_child(env_value):

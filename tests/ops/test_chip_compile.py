@@ -179,6 +179,99 @@ def test_block_scatter_compiles(one_chip, kv_dtype):
     ).compile()
 
 
+# the benchmark's two configurations as the cells serve them
+# (benchmark/configs/*.json): layers, blocks of one layer, lanes, the rest
+STEP_CONFIGS = {
+    "qwen3-4b": (LlamaConfig(
+        vocab_size=151936, hidden_size=2560, intermediate_size=9728,
+        num_layers=36, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_position_embeddings=4096, rope_theta=1e6, rms_norm_eps=1e-6,
+        tie_word_embeddings=True, qk_norm=True,
+    ), 1152, 16, 4),
+    "mistral-7b-l16": (LlamaConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+        num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_position_embeddings=4096, rope_theta=1e4, sliding_window=4096,
+    ), 2560, 8, 8),
+}
+
+
+def _compile_forward(one_chip, config, program):
+    """A llama forward at a cell's shapes, cache donated, as a compiled
+    executable for the described chip."""
+    from dynamo_tpu.models import llama
+
+    cfg, num_blocks, lanes, tb = STEP_CONFIGS[config]
+    s = _sds(one_chip)
+    i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    page = (cfg.num_layers, num_blocks, BLOCK, cfg.num_kv_heads, cfg.head_dim)
+    cache = {"k": s(page, jnp.bfloat16), "v": s(page, jnp.bfloat16)}
+    rope = s((MAX_LEN, cfg.head_dim // 2), jnp.float32)
+    tables = i32(lanes, MAX_BLOCKS)
+    if program == "decode":
+        def fn(p, c, tok, bt, cl, sl, cos, sin):
+            return llama.llama_forward_decode(
+                p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
+        args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
+    else:
+        t = int(program.removeprefix("unified_t"))
+        def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
+            return llama.llama_forward_unified(
+                p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
+                cos, sin, attention="pallas", tb_tokens=tb)
+        args = (i32(t), tables, i32(lanes), *(i32(t) for _ in range(6)),
+                i32(t // tb), i32(lanes), rope, rope)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
+    cache_bytes = 2 * 2 * cfg.num_layers * num_blocks * BLOCK * cfg.num_kv_heads * cfg.head_dim
+    return compiled, cache_bytes
+
+
+@pytest.mark.parametrize("program", ["decode", "unified_t128", "unified_t4096"])
+@pytest.mark.parametrize("config", sorted(STEP_CONFIGS))
+def test_step_forward_writes_the_donated_cache_in_place(one_chip, config, program):
+    """The cache rides the layer loop as a carry: the compiled forward
+    aliases the donated K and V to its outputs and holds no copy of them
+    among its temporaries (as per-layer scan inputs and stacked outputs it
+    held a whole cache there, 2.72 / 2.68 GB)."""
+    compiled, cache_bytes = _compile_forward(one_chip, config, program)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < cache_bytes // 10
+
+
+@pytest.mark.parametrize("config,operand", [
+    ("qwen3-4b", r"bf16\[1152,128,128\]\{[^}]*S\(1\)\}"),
+    ("mistral-7b-l16", r"bf16\[40960,128,128\]\{[^}]*\}"),
+])
+def test_decode_kernel_reads_its_pages_from_fast_memory_where_a_layer_fits(
+    one_chip, config, operand,
+):
+    """``_LayerPages.on_chip``: K and V of one ``qwen3-4b`` layer (75.5 MB)
+    are sliced out of the carry and XLA's memory-space assignment keeps the
+    slices in the chip's fast memory (``S(1)`` in the layout), where the
+    one-query kernel runs twice as fast; ``mistral-7b-l16``'s (168 MB) do
+    not fit, and its kernel reads the flat pages in HBM."""
+    import re
+
+    compiled, _ = _compile_forward(one_chip, config, "decode")
+    hlo = compiled.as_text()
+    (call,) = [
+        line for line in hlo.splitlines()
+        if "paged_window_attention_decode" in line and "custom-call(" in line
+    ]
+    k_name, v_name = re.findall(r"%[\w.\-]+", call.split("custom-call(")[1])[3:5]
+    for name in (k_name, v_name):
+        (definition,) = [
+            line for line in hlo.splitlines()
+            if line.strip().startswith(f"{name} = ")
+        ]
+        assert re.search(operand, definition.split(" = ")[1][:120]), definition[:200]
+
+
 def test_kernel_bytes_do_not_depend_on_the_call_stack(one_chip):
     """A program's persistent-cache key covers the Pallas kernel's bytecode,
     locations included.  Once the cache resolver ran, lowering the same
