@@ -811,7 +811,8 @@ def deepseek_forward_unified(
     unified contract).  Every token writes its compressed latent + rope
     key at its cache slot before attention reads, so span tokens see
     their own in-window predecessors through the cache; the MoE stack
-    routes per token exactly as in the mixtral unified forward."""
+    routes per token exactly as the sparse-expert families' FFN does in
+    the llama one."""
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = jnp.maximum(token_pos, 0)
 

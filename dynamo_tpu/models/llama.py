@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 from typing import Any
 
@@ -135,6 +136,20 @@ class LlamaConfig:
             f"num_hidden_layers={config['num_hidden_layers']}) is not "
             "supported: every layer shares one attention pattern"
         )
+
+    # --- what a family supplies to the shared forwards -----------------------
+    def ffn(self, w: dict, x: jnp.ndarray) -> jnp.ndarray:
+        """This family's feed-forward over one layer's weights ``w``: the
+        gated MLP.  The block every forward below runs (``_block``) asks the
+        config for it, so a family whose FFN differs overrides this method
+        (models/mixtral.py) and shares the forwards as they are."""
+        return _mlp(x, w["w_gate"], w["w_up"], w["w_down"], self.mlp_activation)
+
+    def for_microbatches(self, count: int) -> "LlamaConfig":
+        """The config whose ``ffn`` sees one of ``count`` equal slices of the
+        batch at a time (the pipelined decode streams microbatches through
+        its stages).  A per-token FFN gives the same answer either way."""
+        return self
 
     # --- presets (geometries for serving + bench; weights are loaded or
     # random-initialized — no checkpoints ship with the framework) ---------
@@ -297,6 +312,29 @@ def _qkv(attn_in, w, cfg: LlamaConfig):
     return q, k, v
 
 
+def _block(cfg: LlamaConfig, attend, x: jnp.ndarray, w: dict, *cache):
+    """THE transformer block of the llama-geometry families, dense and
+    sparse-expert alike: every forward below runs this one body per layer.
+
+    ``attend(q, k, v, *cache) -> (attn, *pages)`` is the forward's own, the
+    only thing that differs between them: it rotates q and k at its
+    positions (in the shape it has them: decode inserts a sequence axis,
+    verify folds ``[batch, window]``), writes K and V into what the layer
+    loop handed this layer (``cache``: ``_scan_layers``' pages and where they
+    lie; nothing for a forward without a cache) and calls its attention.
+    ``q/k/v`` come in and ``attn`` goes out token-major ``[tokens, heads,
+    head_dim]``; the written pages are handed back behind the new ``x``.
+    The FFN is the family's (``cfg.ffn``)."""
+    attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
+    q, k, v = _qkv(attn_in, w, cfg)
+    attn, *pages = attend(q, k, v, *cache)
+    x = x + mm(attn.reshape(x.shape[0], -1), w["wo"])
+    mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("mlp"):
+        x = x + cfg.ffn(w, mlp_in)
+    return x, *pages
+
+
 def llama_forward_trunk(
     params: dict,
     cfg: LlamaConfig,
@@ -307,13 +345,10 @@ def llama_forward_trunk(
 ) -> jnp.ndarray:
     """Trunk-only forward (no KV cache, no LM head): final hidden states
     [seq_pad, hidden].  Used by the embedding engine."""
-    s = token_ids.shape[0]
     x = _embed(params, cfg, token_ids)
-    positions = jnp.arange(s, dtype=jnp.int32)
+    positions = jnp.arange(token_ids.shape[0], dtype=jnp.int32)
 
-    def layer(x, w):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v):
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         with jax.named_scope("attn"):
@@ -321,13 +356,11 @@ def llama_forward_trunk(
                 q[None], k[None], v[None], seq_len[None],
                 sliding_window=cfg.sliding_window,
             )[0]
-        x = x + mm(attn.reshape(s, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, None
+        return (attn,)
 
-    x, _ = jax.lax.scan(layer, x, params["layers"])
+    x, _ = jax.lax.scan(
+        lambda x, w: (*_block(cfg, attend, x, w), None), x, params["layers"]
+    )
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
 
 
@@ -481,30 +514,24 @@ def llama_forward_prefill_embeds(
             )
         from dynamo_tpu.ops.ring_attention import ring_attention
 
-    def layer(x, w, k_pages, v_pages, at):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v, k_pages, v_pages, at):
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         with jax.named_scope("kv_write"):
-            k_pages, v_pages = write_prefill_kv(
+            pages = write_prefill_kv(
                 k_pages, v_pages, k, v, at.blocks(block_ids), seq_len
             )
-        if sp_mesh is not None:
-            with jax.named_scope("attn"):
+        with jax.named_scope("attn"):
+            if sp_mesh is not None:
                 attn = ring_attention(q[None], k[None], v[None], seq_len, sp_mesh)[0]
-        else:
-            with jax.named_scope("attn"):
+            else:
                 attn = dense_causal_attention(
                     q[None], k[None], v[None], seq_len[None],
                     sliding_window=cfg.sliding_window,
                 )[0]
-        x = x + mm(attn.reshape(s, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, k_pages, v_pages
+        return attn, *pages
 
+    layer = partial(_block, cfg, attend)
     x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(seq_len - 1, 0)]
@@ -548,9 +575,7 @@ def llama_forward_prefill_with_prefix(
             )
         from dynamo_tpu.ops.ring_attention import ring_attention_with_prefix
 
-    def layer(x, w, k_pages, v_pages, at):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v, k_pages, v_pages, at):
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         # gather the resident prefix BEFORE writing the tail (the mask in
@@ -559,7 +584,7 @@ def llama_forward_prefill_with_prefix(
             k_pages, v_pages, at.blocks(full_block_ids)
         )
         with jax.named_scope("kv_write"):
-            k_pages, v_pages = write_prefill_kv(
+            pages = write_prefill_kv(
                 k_pages, v_pages, k, v, at.blocks(tail_block_ids), tail_len
             )
         if sp_mesh is not None:
@@ -572,12 +597,9 @@ def llama_forward_prefill_with_prefix(
                 q, k, v, k_prefix, v_prefix, start_pos, tail_len,
                 sliding_window=cfg.sliding_window,
             )
-        x = x + mm(attn.reshape(s, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, k_pages, v_pages
+        return attn, *pages
 
+    layer = partial(_block, cfg, attend)
     x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(tail_len - 1, 0)]
@@ -608,11 +630,10 @@ def llama_forward_decode(
     with their kv heads) — and GSPMD handles everything around it.
     "jax" is the portable gather-based fallback.
     """
-    b = token_ids.shape[0]
     x = _embed(params, cfg, token_ids)  # [b, h]
     positions = jnp.maximum(context_lens - 1, 0)      # this token's position
 
-    def attend(q, k_pages, v_pages, at):
+    def paged(q, k_pages, v_pages, at):
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import paged_attention_decode
 
@@ -645,24 +666,16 @@ def llama_forward_decode(
             sliding_window=cfg.sliding_window,
         )
 
-    def layer(x, w, k_pages, v_pages, at):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v, k_pages, v_pages, at):
         # apply_rope expects a seq axis: insert and drop it
         q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
         k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
         with jax.named_scope("kv_write"):
-            k_pages, v_pages = write_decode_kv(
-                k_pages, v_pages, k, v, at.slots(slot_ids)
-            )
+            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(slot_ids))
         with jax.named_scope("attn"):
-            attn = attend(q, k_pages, v_pages, at)
-        x = x + mm(attn.reshape(b, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, k_pages, v_pages
+            return paged(q, *pages, at), *pages
 
+    layer = partial(_block, cfg, attend)
     x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x)
@@ -700,11 +713,10 @@ def llama_forward_unified(
     per sequence regardless of how many tokens it contributed.  One weight
     stream from HBM serves the whole mixed batch — the dispatch-count win
     that removes the engine's prefill/decode phase split."""
-    t = token_ids.shape[0]
     x = _embed(params, cfg, token_ids)  # [t, h]
     positions = jnp.maximum(token_pos, 0)
 
-    def attend(q, k_pages, v_pages, block_tables):
+    def ragged(q, k_pages, v_pages, block_tables):
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import (
                 ragged_paged_attention as ragged_kernel,
@@ -722,25 +734,17 @@ def llama_forward_unified(
             token_pos, sliding_window=cfg.sliding_window,
         )
 
-    def layer(x, w, k_pages, v_pages, at):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v, k_pages, v_pages, at):
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
         # every token writes before anyone reads: span tokens see their own
         # in-window predecessors through the cache (pads scatter-drop)
         with jax.named_scope("kv_write"):
-            k_pages, v_pages = write_decode_kv(
-                k_pages, v_pages, k, v, at.slots(token_slot)
-            )
+            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(token_slot))
         with jax.named_scope("attn"):
-            attn = attend(q, k_pages, v_pages, at.blocks(block_tables))
-        x = x + mm(attn.reshape(t, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, k_pages, v_pages
+            return ragged(q, *pages, at.blocks(block_tables)), *pages
 
+    layer = partial(_block, cfg, attend)
     x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated
@@ -776,32 +780,25 @@ def llama_forward_verify(
     )  # [b, w]
     flat_slots = slot_ids.reshape(-1)
 
-    def attend(q, k_pages, v_pages, block_tables):
-        return window_attention(
-            attention, q, k_pages, v_pages, block_tables, context_lens,
-            sliding_window=cfg.sliding_window,
-        )
-
-    def layer(x, w, k_pages, v_pages, at):
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
+    def attend(q, k, v, k_pages, v_pages, at):
+        # rotate as [batch, window]; K and V go to their slots flat
         q = apply_rope(q.reshape(b, w_len, cfg.num_heads, cfg.head_dim), positions, cos, sin)
         k = apply_rope(k.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim), positions, cos, sin)
         v = v.reshape(b, w_len, cfg.num_kv_heads, cfg.head_dim)
         with jax.named_scope("kv_write"):
-            k_pages, v_pages = write_decode_kv(
+            pages = write_decode_kv(
                 k_pages, v_pages, k.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
                 v.reshape(b * w_len, cfg.num_kv_heads, cfg.head_dim),
                 at.slots(flat_slots),
             )
         with jax.named_scope("attn"):
-            attn = attend(q, k_pages, v_pages, at.blocks(block_tables))
-        x = x + mm(attn.reshape(b * w_len, -1), w["wo"])
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        with jax.named_scope("mlp"):
-            x = x + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x, k_pages, v_pages
+            attn = window_attention(
+                attention, q, *pages, at.blocks(block_tables), context_lens,
+                sliding_window=cfg.sliding_window,
+            )
+        return attn, *pages
 
+    layer = partial(_block, cfg, attend)
     x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x).reshape(b, w_len, -1)
@@ -826,29 +823,28 @@ def llama_forward_decode_pp(
     axis (parallel/pipeline.py): stage s holds layers [s*L/S, (s+1)*L/S)
     and their KV-cache slice; microbatches stream through the stages over
     ICI.  Embedding and the LM head run replicated outside the pipeline.
-    Matches llama_forward_decode exactly (same layer body)."""
-    b = token_ids.shape[0]
+    Matches llama_forward_decode exactly (same block)."""
     x = _embed(params, cfg, token_ids)
     positions = jnp.maximum(context_lens - 1, 0)
+    # the FFN runs once per MICROBATCH here, not once per batch
+    cfg = cfg.for_microbatches(microbatches or pp_mesh.shape["pp"])
 
-    def body(x_mb, aux_mb, w, layer_cache):
-        k_layer, v_layer = layer_cache
+    def attend(q, k, v, k_layer, v_layer, aux_mb):
         pos_mb, slots_mb, tables_mb, lens_mb = aux_mb
-        attn_in = rms_norm(x_mb, w["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(attn_in, w, cfg)
         q = apply_rope(q[:, None], pos_mb[:, None], cos, sin)[:, 0]
         k = apply_rope(k[:, None], pos_mb[:, None], cos, sin)[:, 0]
         with jax.named_scope("kv_write"):
-            k_layer, v_layer = write_decode_kv(k_layer, v_layer, k, v, slots_mb)
+            layer_kv = write_decode_kv(k_layer, v_layer, k, v, slots_mb)
         with jax.named_scope("attn"):
             attn = paged_decode_attention(
-                q, k_layer, v_layer, tables_mb, lens_mb,
+                q, *layer_kv, tables_mb, lens_mb,
                 sliding_window=cfg.sliding_window,
             )
-        x_mb = x_mb + mm(attn.reshape(x_mb.shape[0], -1), w["wo"])
-        mlp_in = rms_norm(x_mb, w["mlp_norm"], cfg.rms_norm_eps)
-        x_mb = x_mb + _mlp(mlp_in, w["w_gate"], w["w_up"], w["w_down"], cfg.mlp_activation)
-        return x_mb, (k_layer, v_layer)
+        return attn, *layer_kv
+
+    def body(x_mb, aux_mb, w, layer_cache):
+        x_mb, *layer_kv = _block(cfg, attend, x_mb, w, *layer_cache, aux_mb)
+        return x_mb, tuple(layer_kv)
 
     from dynamo_tpu.parallel.pipeline import pipeline_layer_stack
 

@@ -1,9 +1,13 @@
 """Mixtral-class sparse-MoE model (Mixtral 8x7B geometry and kin).
 
-Same attention trunk as the llama family; the dense MLP is replaced by a
-top-2-of-E MoE (dynamo_tpu/ops/moe.py).  Expert parallelism is sharding
-annotation only: expert-stacked weights carry ``P(None, "ep", ...)`` and
-GSPMD emits the dispatch/combine all-to-alls over ICI.
+The llama family's block and step programs (models/llama.py: ``_block``, the
+``llama_forward_*``) with ONE thing of its own: the FFN.  ``MixtralConfig``
+is a ``LlamaConfig`` whose ``ffn`` is a top-k-of-E MoE (dynamo_tpu/ops/moe.py)
+over expert-stacked weights; this file holds that config, its parameters
+and loader, and the one forward whose token order the experts dictate
+(``mixtral_forward_verify``).  Expert parallelism is sharding annotation
+only: expert-stacked weights carry ``P(None, "ep", ...)`` and GSPMD emits
+the dispatch/combine all-to-alls over ICI.
 
 (The reference serves wide-EP MoE through SGLang+DeepEP —
 examples/sglang/README.md:105; here the MoE engine is native.)
@@ -12,29 +16,28 @@ examples/sglang/README.md:105; here the MoE engine is native.)
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
+from functools import partial
 from pathlib import Path
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models.llama import (
+    LlamaConfig,
+    _block,
+    _embed,
+    _logits,
+    _scan_layers,
+)
 from dynamo_tpu.ops.attention import (
-    dense_causal_attention,
-    gather_prefix_kv,
-    paged_decode_attention,
     position_major_to_batch,
-    prefill_attention_with_prefix,
-    ragged_paged_attention,
     window_attention,
     write_decode_kv,
-    write_prefill_kv,
 )
 from dynamo_tpu.ops.moe import moe_ffn
 from dynamo_tpu.ops.norms import rms_norm
-from dynamo_tpu.ops.quant import mm
 from dynamo_tpu.ops.rope import apply_rope
 
 
@@ -51,10 +54,11 @@ class MixtralConfig(LlamaConfig):
     norm_topk_prob: bool = True
 
     def __post_init__(self):
-        # inherited field from LlamaConfig that NO mixtral-family forward
-        # honors (prefill/decode/verify all run full attention) — refuse
-        # rather than silently ignoring the window; from_hf_config parses
-        # the HF window fields specifically so this fires on checkpoints
+        # inherited from LlamaConfig, and the shared forwards would honor it;
+        # mixtral_forward_verify does not, and no windowed MoE has been
+        # checked against its reference — refuse rather than serve it half
+        # windowed; from_hf_config parses the HF window fields specifically
+        # so this fires on checkpoints
         if self.sliding_window is not None:
             raise NotImplementedError(
                 "mixtral-family attention has no sliding-window mask"
@@ -63,6 +67,28 @@ class MixtralConfig(LlamaConfig):
     @property
     def expert_intermediate_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    def ffn(self, w: dict, x: jnp.ndarray) -> jnp.ndarray:
+        """The family's FFN for the shared block: route each token to its
+        top-k experts, each with room for ``capacity_factor`` times its even
+        share of the tokens in ``x``."""
+        return moe_ffn(
+            x, w["w_router"], w["w_gate"], w["w_up"], w["w_down"],
+            top_k=self.experts_per_token,
+            capacity_factor=self.capacity_factor,
+            norm_topk_prob=self.norm_topk_prob,
+        )
+
+    def for_microbatches(self, count: int) -> "MixtralConfig":
+        """Routing per MICROBATCH (the pipelined decode): capacity counts
+        from the tokens ``ffn`` sees, so the factor is scaled back up by the
+        microbatch count and each expert's per-call capacity equals what
+        full-batch routing would allocate.  Tokens then only compete for
+        slots within their own microbatch — outputs match the plain decode
+        exactly whenever no drops occur (the served regime capacity_factor
+        is sized for), and under extreme routing skew the pipelined path
+        drops no earlier than full-batch routing would."""
+        return replace(self, capacity_factor=self.capacity_factor * float(count))
 
     @classmethod
     def mixtral_8x7b(cls) -> "MixtralConfig":
@@ -178,306 +204,15 @@ def param_specs(cfg: MixtralConfig) -> dict:
     return specs
 
 
-def _block(cfg: MixtralConfig, w, x, attn_fn, *, capacity_scale: float = 1.0):
-    # capacity_scale: callers that split the batch before routing (the
-    # pp-pipelined decode routes per MICROBATCH) scale the factor back up
-    # so per-expert capacity matches what full-batch routing would allocate
-    attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-    x = x + attn_fn(attn_in)
-    mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-    moe_out = moe_ffn(
-        mlp_in, w["w_router"], w["w_gate"], w["w_up"], w["w_down"],
-        top_k=cfg.experts_per_token,
-        capacity_factor=cfg.capacity_factor * capacity_scale,
-        norm_topk_prob=cfg.norm_topk_prob,
-    )
-    return x + moe_out
-
-
-def _prefill_trunk(params, cfg: MixtralConfig, token_ids, kv_cache,
-                   positions, cos, sin, attend, last_idx):
-    """Shared prefill scaffold: embed → layer scan (qkv+rope handled here,
-    the caller supplies only the attention math via ``attend``) → final
-    norm → last-token logits.  Keeps the plain and continued-prefill paths
-    from drifting apart."""
-    s = token_ids.shape[0]
-    x = params["embed"][token_ids].astype(cfg.dtype)
-
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
-        state = {}
-
-        def attn(attn_in):
-            q = mm(attn_in, w["wq"]).reshape(s, cfg.num_heads, cfg.head_dim)
-            k = mm(attn_in, w["wk"]).reshape(s, cfg.num_kv_heads, cfg.head_dim)
-            v = mm(attn_in, w["wv"]).reshape(s, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:  # Qwen3-MoE: per-head RMSNorm pre-rope
-                q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q, positions, cos, sin)
-            k = apply_rope(k, positions, cos, sin)
-            attn_out, state["kv"] = attend(q, k, v, k_layer, v_layer)
-            return mm(attn_out.reshape(s, -1), w["wo"])
-
-        x = _block(cfg, w, x, attn)
-        return x, state["kv"]
-
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = x[jnp.maximum(last_idx - 1, 0)]
-    logits = (
-        last[None] @ params["embed"].T.astype(x.dtype)
-        if cfg.tie_word_embeddings
-        else mm(last[None], params["lm_head"])
-    )[0]
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
-
-
-def mixtral_forward_prefill(
-    params, cfg: MixtralConfig, token_ids, kv_cache, block_ids, seq_len, start_pos, cos, sin
-):
-    positions = start_pos + jnp.arange(token_ids.shape[0], dtype=jnp.int32)
-
-    def attend(q, k, v, k_layer, v_layer):
-        kv = write_prefill_kv(k_layer, v_layer, k, v, block_ids, seq_len)
-        out = dense_causal_attention(q[None], k[None], v[None], seq_len[None])[0]
-        return out, kv
-
-    return _prefill_trunk(
-        params, cfg, token_ids, kv_cache, positions, cos, sin, attend, seq_len
-    )
-
-
-def mixtral_forward_prefill_with_prefix(
-    params, cfg: MixtralConfig, token_ids, kv_cache, full_block_ids,
-    tail_block_ids, tail_len, start_pos, cos, sin
-):
-    """Continued prefill over a reused prefix for the MoE family: tail
-    queries attend to the resident prefix KV plus themselves, MoE FFN on the
-    tail activations only (same contract as
-    llama_forward_prefill_with_prefix)."""
-    positions = start_pos + jnp.arange(token_ids.shape[0], dtype=jnp.int32)
-
-    def attend(q, k, v, k_layer, v_layer):
-        k_prefix, v_prefix = gather_prefix_kv(k_layer, v_layer, full_block_ids)
-        kv = write_prefill_kv(k_layer, v_layer, k, v, tail_block_ids, tail_len)
-        out = prefill_attention_with_prefix(
-            q, k, v, k_prefix, v_prefix, start_pos, tail_len
-        )
-        return out, kv
-
-    return _prefill_trunk(
-        params, cfg, token_ids, kv_cache, positions, cos, sin, attend, tail_len
-    )
-
-
-def mixtral_forward_decode(
-    params, cfg: MixtralConfig, token_ids, kv_cache, block_tables, context_lens, slot_ids,
-    cos, sin, *, attention: str = "jax",
-):
-    b = token_ids.shape[0]
-
-    def paged_attn(q, k_layer, v_layer):
-        if attention.startswith("pallas"):
-            from dynamo_tpu.ops.pallas import paged_attention_decode
-
-            return paged_attention_decode(
-                q, k_layer, v_layer, block_tables, context_lens,
-                interpret=attention == "pallas_interpret",
-            )
-        return paged_decode_attention(q, k_layer, v_layer, block_tables, context_lens)
-
-    x = params["embed"][token_ids].astype(cfg.dtype)
-    positions = jnp.maximum(context_lens - 1, 0)
-
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
-        state = {}
-
-        def attn(attn_in):
-            q = mm(attn_in, w["wq"]).reshape(b, cfg.num_heads, cfg.head_dim)
-            k = mm(attn_in, w["wk"]).reshape(b, cfg.num_kv_heads, cfg.head_dim)
-            v = mm(attn_in, w["wv"]).reshape(b, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:  # Qwen3-MoE: per-head RMSNorm pre-rope
-                q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
-            k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
-            state["kv"] = write_decode_kv(k_layer, v_layer, k, v, slot_ids)
-            attn_out = paged_attn(q, state["kv"][0], state["kv"][1])
-            return mm(attn_out.reshape(b, -1), w["wo"])
-
-        x = _block(cfg, w, x, attn)
-        return x, state["kv"]
-
-    x, (new_k, new_v) = jax.lax.scan(layer, x, (params["layers"], kv_cache["k"], kv_cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = (
-        x @ params["embed"].T.astype(x.dtype)
-        if cfg.tie_word_embeddings
-        else mm(x, params["lm_head"])
-    )
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
-
-
-def mixtral_forward_unified(
-    params,
-    cfg: MixtralConfig,
-    token_ids,      # [T] int32 — flat ragged token batch
-    kv_cache,
-    block_tables,   # [lanes, max_blocks] int32
-    context_lens,   # [lanes] int32 incl. each lane's span end
-    token_pos,      # [T] int32 absolute position (-1 = pad)
-    token_slot,     # [T] int32 flat cache slot (OOB = pad)
-    token_lane,     # [T] int32 owning lane (OOB = pad)
-    span_lane,      # [T] int32 (pack_spans): block t's span s at t*tb+s
-    span_first,     # [T] int32 first page ordinal of the span
-    span_count,     # [T] int32 pages in the span (0 = unused)
-    page_total,     # [T // tb_tokens] int32 live pages per token block
-    sample_rows,    # [lanes] int32 flat index of span's LAST token
-    cos,
-    sin,
-    *,
-    attention: str = "jax",     # "jax" | "pallas" | "pallas_interpret"
-    tb_tokens: int = 8,
-):
-    """Ragged unified-batch forward for the sparse-MoE family: the llama
-    unified contract (mixed chunked-prefill spans + decode tokens, one
-    launch, per-token absolute positions) with the dense MLP swapped for
-    the top-k MoE FFN.  Expert routing is already per-token (ops/moe.py),
-    so it composes with the ragged layout unchanged — each token routes on
-    its own activations regardless of which lane owns it, and in the
-    no-drop regime capacity_factor is sized for, per-token expert outputs
-    are independent of batch composition (the split-vs-unified byte-parity
-    contract).  Pad rows route too and are discarded at the sample gather."""
-    t = token_ids.shape[0]
-    x = params["embed"][token_ids].astype(cfg.dtype)
-    positions = jnp.maximum(token_pos, 0)
-
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
-        state = {}
-
-        def attn(attn_in):
-            q = mm(attn_in, w["wq"]).reshape(t, cfg.num_heads, cfg.head_dim)
-            k = mm(attn_in, w["wk"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
-            v = mm(attn_in, w["wv"]).reshape(t, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:  # Qwen3-MoE: per-head RMSNorm pre-rope
-                q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q, positions, cos, sin)
-            k = apply_rope(k, positions, cos, sin)
-            # every token writes before anyone reads: span tokens see their
-            # own in-window predecessors through the cache
-            state["kv"] = write_decode_kv(k_layer, v_layer, k, v, token_slot)
-            if attention.startswith("pallas"):
-                from dynamo_tpu.ops.pallas import (
-                    ragged_paged_attention as ragged_kernel,
-                )
-
-                attn_out = ragged_kernel(
-                    q, state["kv"][0], state["kv"][1], token_lane, token_pos,
-                    block_tables, span_lane, span_first, span_count,
-                    page_total,
-                    tb_tokens=tb_tokens,
-                    interpret=attention == "pallas_interpret",
-                )
-            else:
-                attn_out = ragged_paged_attention(
-                    q, state["kv"][0], state["kv"][1], block_tables,
-                    context_lens, token_lane, token_pos,
-                )
-            return mm(attn_out.reshape(t, -1), w["wo"])
-
-        x = _block(cfg, w, x, attn)
-        return x, state["kv"]
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated
-    logits = (
-        rows @ params["embed"].T.astype(rows.dtype)
-        if cfg.tie_word_embeddings
-        else mm(rows, params["lm_head"])
-    )
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
-
-
-def mixtral_forward_decode_pp(
-    params, cfg: MixtralConfig, token_ids, kv_cache, block_tables,
-    context_lens, slot_ids, cos, sin, *, pp_mesh, microbatches: int | None = None,
-):
-    """Batched MoE decode with the layer stack pipelined over the ``pp``
-    mesh axis (parallel/pipeline.py), composing with expert parallelism:
-    the pp axis is manual inside the pipeline runner's partial-manual
-    shard_map while the expert-stacked weights keep their ``P(..., "ep",
-    ...)`` shardings — GSPMD inserts the expert all-to-alls INSIDE each
-    stage exactly as it does for tp in the llama path
-    (llama_forward_decode_pp).  BASELINE.json's Mixtral-on-v5p config
-    implies this composition.
-
-    MoE drop semantics vs the non-pp decode: routing runs per MICROBATCH,
-    with capacity_factor scaled by the microbatch count so each expert's
-    per-call capacity equals what full-batch routing would allocate.
-    Tokens therefore only compete for slots within their own microbatch —
-    outputs match the plain decode exactly whenever no drops occur (the
-    served regime capacity_factor is sized for), and under extreme routing
-    skew the pp path drops no earlier than full-batch routing would."""
-    b = token_ids.shape[0]
-    x = params["embed"][token_ids].astype(cfg.dtype)
-    positions = jnp.maximum(context_lens - 1, 0)
-    m_count = microbatches or pp_mesh.shape["pp"]
-
-    def body(x_mb, aux_mb, w, layer_cache):
-        k_layer, v_layer = layer_cache
-        pos_mb, slots_mb, tables_mb, lens_mb = aux_mb
-        bmb = x_mb.shape[0]
-        state = {}
-
-        def attn(attn_in):
-            q = mm(attn_in, w["wq"]).reshape(bmb, cfg.num_heads, cfg.head_dim)
-            k = mm(attn_in, w["wk"]).reshape(bmb, cfg.num_kv_heads, cfg.head_dim)
-            v = mm(attn_in, w["wv"]).reshape(bmb, cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:
-                q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q[:, None], pos_mb[:, None], cos, sin)[:, 0]
-            k = apply_rope(k[:, None], pos_mb[:, None], cos, sin)[:, 0]
-            state["kv"] = write_decode_kv(k_layer, v_layer, k, v, slots_mb)
-            attn_out = paged_decode_attention(
-                q, state["kv"][0], state["kv"][1], tables_mb, lens_mb
-            )
-            return mm(attn_out.reshape(bmb, -1), w["wo"])
-
-        x_mb = _block(cfg, w, x_mb, attn, capacity_scale=float(m_count))
-        return x_mb, state["kv"]
-
-    from dynamo_tpu.parallel.pipeline import pipeline_layer_stack
-
-    x, (new_k, new_v) = pipeline_layer_stack(
-        body, x, (positions, slot_ids, block_tables, context_lens),
-        params["layers"], (kv_cache["k"], kv_cache["v"]), pp_mesh,
-        microbatches=microbatches,
-    )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = (
-        x @ params["embed"].T.astype(x.dtype)
-        if cfg.tie_word_embeddings
-        else mm(x, params["lm_head"])
-    )
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
-
-
 def mixtral_forward_verify(
     params, cfg: MixtralConfig, token_ids, kv_cache, block_tables,
     context_lens, slot_ids, cos, sin, *, attention: str = "jax",
 ):
-    """Speculative-verification forward for the MoE family: the [b, w]
-    window runs through the same attention scaffold as decode (multi-query
-    paged window attention) and the MoE FFN sees the window's b*w tokens.
-    Contract matches llama_forward_verify.
+    """Speculative-verification forward, the family's one forward of its own
+    (every other step program is the llama forward of that name, with
+    ``MixtralConfig.ffn`` in its block: models/registry.py).  Contract
+    matches llama_forward_verify; what differs is the ORDER of the window's
+    b*w tokens.
 
     Token order is POSITION-major (all lanes' position-0 tokens first):
     expert-capacity slots assign in dispatch order (ops/moe.py), so the
@@ -488,57 +223,38 @@ def mixtral_forward_verify(
     schedule (a capacity-dropping property, not an acceptance-logic one)."""
     b, w_len = token_ids.shape
     # [b, w] → position-major flat [w*b]
-    x = params["embed"][token_ids.T.reshape(-1)].astype(cfg.dtype)
+    x = _embed(params, cfg, token_ids.T.reshape(-1))
     positions = jnp.maximum(
         context_lens[:, None] - w_len + jnp.arange(w_len)[None, :], 0
     )  # [b, w]
     flat_slots = slot_ids.T.reshape(-1)
 
-    def attend_pages(q, k_layer, v_layer):
-        return window_attention(
-            attention, q, k_layer, v_layer, block_tables, context_lens
-        )
+    def to_bw(t):  # [w*b, heads, d] → [b, w, heads, d]
+        return position_major_to_batch(t, w_len, b, *t.shape[1:])
 
-    def to_bw(t, *tail):
-        return position_major_to_batch(t, w_len, b, *tail)
+    def to_flat(t):  # and back
+        return t.transpose(1, 0, 2, 3).reshape(w_len * b, *t.shape[2:])
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
-        state = {}
-
-        def attn(attn_in):
-            q = to_bw(mm(attn_in, w["wq"]), cfg.num_heads, cfg.head_dim)
-            k = to_bw(mm(attn_in, w["wk"]), cfg.num_kv_heads, cfg.head_dim)
-            v = to_bw(mm(attn_in, w["wv"]), cfg.num_kv_heads, cfg.head_dim)
-            if cfg.qk_norm:
-                q = rms_norm(q, w["q_norm"], cfg.rms_norm_eps)
-                k = rms_norm(k, w["k_norm"], cfg.rms_norm_eps)
-            q = apply_rope(q, positions, cos, sin)
-            k = apply_rope(k, positions, cos, sin)
-            state["kv"] = write_decode_kv(
-                k_layer, v_layer,
-                k.transpose(1, 0, 2, 3).reshape(w_len * b, cfg.num_kv_heads, cfg.head_dim),
-                v.transpose(1, 0, 2, 3).reshape(w_len * b, cfg.num_kv_heads, cfg.head_dim),
-                flat_slots,
+    def attend(q, k, v, k_pages, v_pages, at):
+        # rotate and attend as [batch, window]; K and V go to their slots in
+        # the order they came in
+        q = apply_rope(to_bw(q), positions, cos, sin)
+        k = apply_rope(to_bw(k), positions, cos, sin)
+        with jax.named_scope("kv_write"):
+            pages = write_decode_kv(
+                k_pages, v_pages, to_flat(k), v, at.slots(flat_slots)
             )
-            attn_out = attend_pages(q, state["kv"][0], state["kv"][1])  # [b, w, H, D]
-            flat = attn_out.transpose(1, 0, 2, 3).reshape(w_len * b, -1)
-            return mm(flat, w["wo"])
+        with jax.named_scope("attn"):
+            attn = window_attention(
+                attention, q, *pages, at.blocks(block_tables), context_lens
+            )
+        return to_flat(attn), *pages
 
-        x = _block(cfg, w, x, attn)
-        return x, state["kv"]
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv_cache["k"], kv_cache["v"])
-    )
+    layer = partial(_block, cfg, attend)
+    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = (
-        x @ params["embed"].T.astype(x.dtype)
-        if cfg.tie_word_embeddings
-        else mm(x, params["lm_head"])
-    )
-    logits = logits.reshape(w_len, b, -1).transpose(1, 0, 2)
-    return logits.astype(jnp.float32), {"k": new_k, "v": new_v}
+    logits = _logits(params, cfg, x).reshape(w_len, b, -1).transpose(1, 0, 2)
+    return logits.astype(jnp.float32), kv_cache
 
 
 # ------------------------------------------------------------------ weights

@@ -224,45 +224,41 @@ def _gemma3_family() -> ModelFamily:
     )
 
 
-def _mixtral_family() -> ModelFamily:
-    from dynamo_tpu.models import mixtral
+def _sparse_expert_family(name: str) -> ModelFamily:
+    """Mixtral-style routed experts on the llama geometry: the llama-like
+    families' forwards as they are (``MixtralConfig`` is a ``LlamaConfig``
+    that supplies its own FFN to their block), with the family's own
+    parameters, loader and verify order.  What the shared forwards could do
+    for it but nothing has yet shown they do stays unset: sequence-parallel
+    prefill, a tp-sharded decode kernel, prefill from embeddings."""
+    from dynamo_tpu.models import llama, mixtral
 
     return ModelFamily(
-        name="mixtral",
+        name=name,
         config_from_hf=mixtral.MixtralConfig.from_hf_config,
         init_params=mixtral.init_params,
         param_specs=mixtral.param_specs,
-        forward_prefill=mixtral.mixtral_forward_prefill,
-        forward_decode=mixtral.mixtral_forward_decode,
-        forward_prefill_with_prefix=mixtral.mixtral_forward_prefill_with_prefix,
-        forward_decode_pp=mixtral.mixtral_forward_decode_pp,
+        forward_prefill=llama.llama_forward_prefill,
+        forward_decode=llama.llama_forward_decode,
+        forward_prefill_with_prefix=llama.llama_forward_prefill_with_prefix,
+        forward_decode_pp=llama.llama_forward_decode_pp,
         load_weights=mixtral.load_hf_weights,
         quant_leaves=_PROJ_QUANT_LEAVES,
+        # position-major, so always-emitted tokens get expert capacity first
         forward_verify=mixtral.mixtral_forward_verify,
-        forward_unified=mixtral.mixtral_forward_unified,
+        forward_unified=llama.llama_forward_unified,
     )
+
+
+def _mixtral_family() -> ModelFamily:
+    return _sparse_expert_family("mixtral")
 
 
 def _qwen3_moe_family() -> ModelFamily:
     # Qwen3-MoE = Mixtral-style routed experts + per-head q/k RMSNorm
     # (from_hf_config infers qk_norm from model_type, which the registry
     # key guarantees is present on any config routed here)
-    from dynamo_tpu.models import mixtral
-
-    return ModelFamily(
-        name="qwen3_moe",
-        config_from_hf=mixtral.MixtralConfig.from_hf_config,
-        init_params=mixtral.init_params,
-        param_specs=mixtral.param_specs,
-        forward_prefill=mixtral.mixtral_forward_prefill,
-        forward_decode=mixtral.mixtral_forward_decode,
-        forward_prefill_with_prefix=mixtral.mixtral_forward_prefill_with_prefix,
-        forward_decode_pp=mixtral.mixtral_forward_decode_pp,
-        load_weights=mixtral.load_hf_weights,
-        quant_leaves=_PROJ_QUANT_LEAVES,
-        forward_verify=mixtral.mixtral_forward_verify,
-        forward_unified=mixtral.mixtral_forward_unified,
-    )
+    return _sparse_expert_family("qwen3_moe")
 
 
 def _deepseek_family() -> ModelFamily:

@@ -48,15 +48,18 @@ class ControlPlaneServer:
             await self._server.serve_forever()
 
     async def stop(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+        if self._server is None:
+            return
+        self._server.close()
         # drop established client connections too: stop() must look like a
         # dead server to clients (their reconnect logic depends on seeing
-        # EOF), not like a server that merely stopped accepting
+        # EOF), not like a server that merely stopped accepting.  Before
+        # wait_closed(): since Python 3.12.1 that waits for every connection
+        # to end, so with a client still connected it never returned
         for writer in list(self._client_writers):
             writer.close()
         self._client_writers.clear()
+        await self._server.wait_closed()
 
     async def _handle(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter) -> None:
         self._client_writers.add(writer)

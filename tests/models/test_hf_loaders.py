@@ -9,6 +9,7 @@ import numpy as np
 from safetensors.numpy import save_file
 
 from dynamo_tpu.models import deepseek, mixtral
+from dynamo_tpu.models.registry import get_family
 
 
 def test_mixtral_hf_roundtrip(tmp_path):
@@ -48,11 +49,11 @@ def test_mixtral_hf_roundtrip(tmp_path):
     cos, sin = make_rope_tables(cfg)
     tokens = jnp.arange(3, 11, dtype=jnp.int32)
     blocks = jnp.asarray([0, 1], jnp.int32)
-    ref, _ = mixtral.mixtral_forward_prefill(
+    ref, _ = get_family("mixtral").forward_prefill(
         params, cfg, tokens, init_kv_cache(cfg, 8, 4), blocks,
         jnp.int32(8), jnp.int32(0), cos, sin,
     )
-    out, _ = mixtral.mixtral_forward_prefill(
+    out, _ = get_family("mixtral").forward_prefill(
         loaded, cfg, tokens, init_kv_cache(cfg, 8, 4), blocks,
         jnp.int32(8), jnp.int32(0), cos, sin,
     )

@@ -50,6 +50,7 @@ def _our_llama_logits(model_dir, token_ids: list[int]) -> np.ndarray:
 def _our_mixtral_logits(model_dir, token_ids: list[int]) -> np.ndarray:
     from dynamo_tpu.models import mixtral as mx
     from dynamo_tpu.models.llama import init_kv_cache, make_rope_tables
+    from dynamo_tpu.models.registry import get_family
 
     cfg = mx.MixtralConfig.from_hf_config(f"{model_dir}/config.json")
     cfg = mx.MixtralConfig(**{**cfg.__dict__, "dtype": jnp.float32})
@@ -57,7 +58,7 @@ def _our_mixtral_logits(model_dir, token_ids: list[int]) -> np.ndarray:
     cos, sin = make_rope_tables(cfg)
     cache = init_kv_cache(cfg, 16, 4)
     blocks = jnp.arange(8, dtype=jnp.int32)
-    logits, _ = mx.mixtral_forward_prefill(
+    logits, _ = get_family("mixtral").forward_prefill(
         params, cfg, jnp.asarray(token_ids, jnp.int32), cache, blocks,
         jnp.int32(len(token_ids)), jnp.int32(0), cos, sin,
     )
@@ -372,9 +373,10 @@ def test_llama_decode_path_matches_hf_at_every_position(tmp_path):
 @pytest.mark.slow
 def test_mixtral_decode_path_matches_hf(tmp_path):
     """MoE decode against the oracle: per-token expert routing in the
-    decode path (mixtral_forward_decode) vs HF's full-context forward."""
+    decode path (the family's forward_decode) vs HF's full-context forward."""
     from dynamo_tpu.models import mixtral as mx
     from dynamo_tpu.models.llama import init_kv_cache, make_rope_tables
+    from dynamo_tpu.models.registry import get_family
 
     config = transformers.MixtralConfig(
         vocab_size=320, hidden_size=64, intermediate_size=96,
@@ -401,8 +403,9 @@ def test_mixtral_decode_path_matches_hf(tmp_path):
     cache = init_kv_cache(cfg, 16, block_size)
     blocks = jnp.arange(8, dtype=jnp.int32)
 
+    fam = get_family("mixtral")
     prefill_len = 4
-    logits, cache = mx.mixtral_forward_prefill(
+    logits, cache = fam.forward_prefill(
         params, cfg, jnp.asarray(tokens[:prefill_len], jnp.int32), cache,
         blocks, jnp.int32(prefill_len), jnp.int32(0), cos, sin,
     )
@@ -412,7 +415,7 @@ def test_mixtral_decode_path_matches_hf(tmp_path):
     tables = blocks[None, :]
     for p in range(prefill_len, len(tokens)):
         slot = jnp.asarray([blocks[p // block_size] * block_size + p % block_size])
-        logits, cache = mx.mixtral_forward_decode(
+        logits, cache = fam.forward_decode(
             params, cfg, jnp.asarray([tokens[p]], jnp.int32), cache,
             tables, jnp.asarray([p + 1], jnp.int32), slot, cos, sin,
         )
@@ -590,7 +593,7 @@ def test_qwen3_moe_matches_hf(tmp_path):
     blocks = jnp.arange(8, dtype=jnp.int32)
 
     prefill_len = 4
-    logits, cache = mx.mixtral_forward_prefill(
+    logits, cache = fam.forward_prefill(
         params, cfg, jnp.asarray(tokens[:prefill_len], jnp.int32), cache,
         blocks, jnp.int32(prefill_len), jnp.int32(0), cos, sin,
     )
@@ -600,7 +603,7 @@ def test_qwen3_moe_matches_hf(tmp_path):
     tables = blocks[None, :]
     for p in range(prefill_len, len(tokens)):
         slot = jnp.asarray([blocks[p // block_size] * block_size + p % block_size])
-        logits, cache = mx.mixtral_forward_decode(
+        logits, cache = fam.forward_decode(
             params, cfg, jnp.asarray([tokens[p]], jnp.int32), cache,
             tables, jnp.asarray([p + 1], jnp.int32), slot, cos, sin,
         )

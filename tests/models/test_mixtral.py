@@ -9,17 +9,15 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.llama import init_kv_cache, kv_cache_spec, make_rope_tables
-from dynamo_tpu.models.mixtral import (
-    MixtralConfig,
-    init_params,
-    mixtral_forward_decode,
-    mixtral_forward_prefill,
-    param_specs,
-)
+from dynamo_tpu.models.mixtral import MixtralConfig, init_params, param_specs
+from dynamo_tpu.models.registry import get_family
 from dynamo_tpu.ops.moe import moe_dispatch_combine, moe_ffn, moe_router
 from dynamo_tpu.parallel import MeshConfig, make_mesh, shard_pytree
 
 CFG = MixtralConfig.tiny_moe()
+# the family's step programs, as the engine takes them
+mixtral_forward_prefill = get_family("mixtral").forward_prefill
+mixtral_forward_decode = get_family("mixtral").forward_decode
 BLOCK_SIZE = 4
 NUM_BLOCKS = 32
 

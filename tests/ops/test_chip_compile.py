@@ -16,6 +16,8 @@ from jax.sharding import SingleDeviceSharding
 
 from dynamo_tpu.engine import EngineConfig, JaxLlmEngine
 from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models.mixtral import MixtralConfig
+from dynamo_tpu.models.registry import get_family
 from dynamo_tpu.ops.pallas.block_copy import gather_blocks, scatter_blocks
 from dynamo_tpu.ops.pallas.paged_attention import (
     paged_attention_decode,
@@ -180,33 +182,40 @@ def test_block_scatter_compiles(one_chip, kv_dtype):
 
 
 # the benchmark's two configurations as the cells serve them
-# (benchmark/configs/*.json): layers, blocks of one layer, lanes, the rest
+# (benchmark/configs/*.json): family, layers, blocks of one layer, lanes, the
+# rest; and the sparse-expert family on the same step programs, at
+# Mixtral-8x7B's published widths cut by depth (no cell serves it yet)
 STEP_CONFIGS = {
-    "qwen3-4b": (LlamaConfig(
+    "qwen3-4b": ("qwen3", LlamaConfig(
         vocab_size=151936, hidden_size=2560, intermediate_size=9728,
         num_layers=36, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e6, rms_norm_eps=1e-6,
         tie_word_embeddings=True, qk_norm=True,
     ), 1152, 16, 4),
-    "mistral-7b-l16": (LlamaConfig(
+    "mistral-7b-l16": ("mistral", LlamaConfig(
         vocab_size=32000, hidden_size=4096, intermediate_size=14336,
         num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e4, sliding_window=4096,
     ), 2560, 8, 8),
+    "mixtral-8x7b-l4": ("mixtral", MixtralConfig(
+        vocab_size=32000, hidden_size=4096, intermediate_size=14336,
+        num_layers=4, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_position_embeddings=4096, rope_theta=1e6,
+        num_experts=8, experts_per_token=2,
+    ), 5120, 8, 8),
 }
 
 
 def _compile_forward(one_chip, config, program):
-    """A llama forward at a cell's shapes, cache donated, as a compiled
-    executable for the described chip."""
-    from dynamo_tpu.models import llama
-
-    cfg, num_blocks, lanes, tb = STEP_CONFIGS[config]
+    """A family's step forward at a cell's shapes, cache donated, as a
+    compiled executable for the described chip."""
+    name, cfg, num_blocks, lanes, tb = STEP_CONFIGS[config]
+    family = get_family(name)
     s = _sds(one_chip)
     i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
     params = jax.tree.map(
         lambda a: s(a.shape, a.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+        jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))),
     )
     page = (cfg.num_layers, num_blocks, BLOCK, cfg.num_kv_heads, cfg.head_dim)
     cache = {"k": s(page, jnp.bfloat16), "v": s(page, jnp.bfloat16)}
@@ -214,13 +223,13 @@ def _compile_forward(one_chip, config, program):
     tables = i32(lanes, MAX_BLOCKS)
     if program == "decode":
         def fn(p, c, tok, bt, cl, sl, cos, sin):
-            return llama.llama_forward_decode(
+            return family.forward_decode(
                 p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
         args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
     else:
         t = int(program.removeprefix("unified_t"))
         def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
-            return llama.llama_forward_unified(
+            return family.forward_unified(
                 p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
                 cos, sin, attention="pallas", tb_tokens=tb)
         args = (i32(t), tables, i32(lanes), *(i32(t) for _ in range(6)),
@@ -236,11 +245,21 @@ def test_step_forward_writes_the_donated_cache_in_place(one_chip, config, progra
     """The cache rides the layer loop as a carry: the compiled forward
     aliases the donated K and V to its outputs and holds no copy of them
     among its temporaries (as per-layer scan inputs and stacked outputs it
-    held a whole cache there, 2.72 / 2.68 GB)."""
+    held a whole cache there, 2.72 / 2.68 GB; the sparse-expert family's own
+    forwards still did, 2.01 / 2.05 / 3.29 GB beside this 1.34 GB cache,
+    until it took the shared ones).
+
+    The expert layer's dispatch buffers (``[experts, capacity, hidden]`` and
+    twice ``[experts, capacity, 14336]``) grow with the window: 17 MB of
+    temporaries at 128 tokens, 641 MB at 4,096, where a tenth of the cache
+    (134 MB: four layers of weights and a larger cache do not fit the chip)
+    would measure the experts.  That case is held to less than ONE cache,
+    which a copy of it cannot meet."""
     compiled, cache_bytes = _compile_forward(one_chip, config, program)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= cache_bytes
-    assert memory.temp_size_in_bytes < cache_bytes // 10
+    expert_window = (config, program) == ("mixtral-8x7b-l4", "unified_t4096")
+    assert memory.temp_size_in_bytes < (cache_bytes if expert_window else cache_bytes // 10)
 
 
 @pytest.mark.parametrize("config,operand", [

@@ -218,6 +218,7 @@ async def test_refused_ack_is_not_retried():
         await read_two_part(reader)
         writer.write(encode_frame(TwoPartMessage(header={"ok": False})))
         await writer.drain()
+        writer.close()  # or server.wait_closed() below waits for it for ever
 
     server = await asyncio.start_server(handler, "127.0.0.1", 0)
     address = f"127.0.0.1:{server.sockets[0].getsockname()[1]}"

@@ -129,9 +129,10 @@ def test_pp_ep_mixtral_decode_matches_single_device():
 
     from dynamo_tpu.models import mixtral as mx
     from dynamo_tpu.models.llama import kv_cache_spec
+    from dynamo_tpu.models.registry import get_family
 
     cfg, params, cache, tokens, tables, lens, slots, cos, sin = _mixtral_setup()
-    ref_logits, ref_cache = mx.mixtral_forward_decode(
+    ref_logits, ref_cache = get_family("mixtral").forward_decode(
         params, cfg, tokens, {k: v.copy() for k, v in cache.items()},
         tables, lens, slots, cos, sin,
     )
@@ -145,7 +146,7 @@ def test_pp_ep_mixtral_decode_matches_single_device():
         lambda x: jax.device_put(np.asarray(x), NamedSharding(mesh, kv_cache_spec())),
         cache,
     )
-    pp_logits, pp_cache = mx.mixtral_forward_decode_pp(
+    pp_logits, pp_cache = get_family("mixtral").forward_decode_pp(
         params_m, cfg, tokens, cache_m, tables, lens, slots, cos, sin,
         pp_mesh=mesh, microbatches=2,
     )
