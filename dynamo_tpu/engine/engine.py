@@ -21,6 +21,7 @@ Architecture:
 from __future__ import annotations
 
 import asyncio
+import math
 import os
 import queue as thread_queue
 import threading
@@ -78,10 +79,13 @@ STEP_PHASES = ("schedule", "pack", "upload", "dispatch", "readback", "post")
 
 # Attention-kernel work counters (cumulative, in stats() from engine start).
 KERNEL_WORK_KEYS = (
-    "ragged_live_pages_total",      # pages the ragged kernel walked (pack_spans' page_total)
-    "ragged_page_slots_total",      # page iterations it executed: the same count, no dead step
+    "ragged_live_pages_total",      # pages the ragged kernel copied (pack_spans' span counts)
+    "ragged_page_slots_total",      # page places of the KV steps it executed: steps x pages a step
+    "ragged_kv_steps_total",        # KV steps it executed (pack_spans' kv_steps)
+    "ragged_token_blocks_total",    # token blocks launched with at least one KV step
+    "ragged_live_rows_total",       # live tokens (each all its heads' query rows) in those blocks
     "ragged_attn_flops_total",      # QK^T + attention·V over the attended context, all layers
-    "ragged_kv_read_bytes_total",   # whole pages walked x page bytes, all layers
+    "ragged_kv_read_bytes_total",   # pages copied x page bytes, all layers
     "decode_attn_flops_total",
     "decode_kv_read_bytes_total",
 )
@@ -694,15 +698,23 @@ class JaxLlmEngine:
         self._unified_windows = 0     # mixed windows served by one dispatch
         self._admission_drains = 0    # pipeline drains forced by admission
         # ragged kernel tunable, precedence: explicit knob > tuned
-        # KERNEL_PERF.json row (ops/autotune.py) > heuristic default.
-        # tb: the flat token axis pads to whole kernel blocks of this many
-        # tokens; lanes PACK within a block (per-row routing), so this is
-        # launch-grid granularity only.  The kernel's page loop runs each
-        # block's live page count, so there is no width to configure and
-        # ONE program per token bucket whatever the batch composition.
-        import math as _math
+        # KERNEL_PERF.json row (ops/autotune.py) > the default the kernel
+        # derives from the head geometry.
+        # tb: the LARGEST token block; a bucket's flat token axis is cut
+        # into blocks of gcd(tb, bucket) tokens (_tb_for), so every bucket
+        # packs whole blocks.  Lanes PACK within a block (per-row routing).
+        # The kernel's loop runs each block's live KV steps, so there is no
+        # width to configure and ONE program per token bucket whatever the
+        # batch composition.
+        from dynamo_tpu.ops.pallas.ragged_attention import default_tb_tokens
 
-        tb_default = _math.gcd(config.block_size, 8) or 1
+        heads = int(getattr(cfg, "num_heads", 0) or 1)
+        # query rows of one token in one of the kernel's products: the heads
+        # that share a KV head (every head, over a latent cache)
+        rows_per_token = heads if getattr(cfg, "kv_lora_rank", 0) else max(
+            1, heads // int(getattr(cfg, "num_kv_heads", 0) or heads)
+        )
+        tb_default = default_tb_tokens(rows_per_token, config.block_size)
         tuned = self._resolve_tuned_kernel_config(cfg)
         knob_tb = knobs.get("DYN_AUTOTUNE_TB")
         # a tb that cannot pack every unified bucket would split-fallback
@@ -971,6 +983,13 @@ class JaxLlmEngine:
         if is_quantized(raw_params):
             return raw_params
         return quantize_params(raw_params, self.family.quant_leaves)
+
+    def _tb_for(self, bucket: int) -> int:
+        """Token block of one unified bucket's program and packing: the
+        largest divisor of the bucket in the resolved block (a 64-token
+        block serves buckets 64 ... 4,096 whole, a 32-token bucket as one
+        block, a 528-token chunk + lanes bucket as blocks of 16)."""
+        return math.gcd(self._unified_tb, bucket)
 
     def _resolve_tuned_kernel_config(self, cfg) -> dict | None:
         """Look up the autotuned ragged-kernel row for this engine's
@@ -1369,13 +1388,12 @@ class JaxLlmEngine:
         cfg = self.config.model
         topk_k = self.config.top_logprobs_k
         lanes = self.config.max_batch_size
-        tb = self._unified_tb
         lane_idx = jnp.arange(lanes)
 
         def step(params, cache, gen_counts, prompt_counts, token_ids,
                  feedback, use_fb, block_tables, context_lens, token_pos,
                  token_slot, token_lane, span_lane, span_first, span_count,
-                 page_total, sample_rows, sample_gate, seed_lanes,
+                 kv_steps, sample_rows, sample_gate, seed_lanes,
                  seed_prompt, seed_gen, keys, temp, top_k, top_p, greedy,
                  pres, freq, rep, bias_ids, bias_vals, cos, sin):
             lane_c = jnp.clip(token_lane, 0, lanes - 1)
@@ -1386,8 +1404,9 @@ class JaxLlmEngine:
             logits, cache = self.family.forward_unified(
                 params, cfg, tok, cache, block_tables, context_lens,
                 token_pos, token_slot, token_lane, span_lane, span_first,
-                span_count, page_total, sample_rows, cos, sin,
-                attention=self.attention_impl, tb_tokens=tb,
+                span_count, kv_steps, sample_rows, cos, sin,
+                attention=self.attention_impl,
+                tb_tokens=self._tb_for(token_ids.shape[0]),
             )  # [lanes, vocab]
             prompt_counts = prompt_counts.at[seed_lanes].set(
                 seed_prompt, mode="drop"
@@ -2125,8 +2144,6 @@ class JaxLlmEngine:
             # the first mixed window after a cold start never compiles on
             # the device thread
             nseed = self._unified_seed_slots
-            tb = self._unified_tb
-            pallas = self.attention_impl.startswith("pallas")
             if self.chunk_tokens is not None:
                 ucap = self._bucket_len(
                     min(self.chunk_tokens + lanes, self.max_len)
@@ -2134,9 +2151,7 @@ class JaxLlmEngine:
             else:
                 ucap = self.buckets[-1]
             for b in (x for x in self.buckets if x <= ucap):
-                if pallas and b % tb:
-                    continue  # unpackable bucket: the route check skips it
-                ntb = max(1, b // tb)
+                ntb = b // self._tb_for(b)
                 tok_a = sds((b,), jnp.int32)
                 jobs[("unified", b)] = (
                     self._jit_unified,
@@ -2525,14 +2540,6 @@ class JaxLlmEngine:
             self._unified_skip("bucket_overflow")
             return False
         bucket = self._bucket_len(total)
-        if (
-            self.attention_impl.startswith("pallas")
-            and bucket % self._unified_tb
-        ):
-            # unpackable compile bucket (odd max_len tail): the kernel grid
-            # needs whole token blocks
-            self._unified_skip("unpackable_bucket")
-            return False
         unseeded = sum(
             1 for seq, start, _ in spans if start == seq.cached_tokens
         )
@@ -2568,7 +2575,6 @@ class JaxLlmEngine:
         come from the host while resident decode lanes keep reading the
         previous window's on-device feedback."""
         lanes = self.config.max_batch_size
-        tb = self._unified_tb
         bs = self.config.block_size
         oob = self.config.num_blocks * bs
         vocab = self.config.model.vocab_size
@@ -2636,6 +2642,7 @@ class JaxLlmEngine:
             if not decodes and not spans:
                 return True  # everything preempted: step consumed
 
+        tb = self._tb_for(bucket)
         token_ids = np.zeros((bucket,), np.int32)
         token_pos = np.full((bucket,), -1, np.int32)
         token_slot = np.full((bucket,), oob, np.int32)
@@ -2720,7 +2727,7 @@ class JaxLlmEngine:
         # ONE compiled program regardless of batch composition.
         pallas = self.attention_impl.startswith("pallas")
         if pallas:
-            from dynamo_tpu.ops.pallas import pack_spans
+            from dynamo_tpu.ops.pallas import kv_step_pages, pack_spans
 
             self._phase("pack")
             page_meta = pack_spans(
@@ -2732,7 +2739,7 @@ class JaxLlmEngine:
             # never reads the spans: ship fixed-shape zeros
             flat = np.zeros((bucket,), np.int32)
             page_meta = (
-                flat, flat, flat, np.zeros((max(1, bucket // tb),), np.int32)
+                flat, flat, flat, np.zeros((bucket // tb,), np.int32)
             )
         self._phase("upload")
         sampling_tail = self._device_sampling_tail(emit_seqs, lanes)
@@ -2765,7 +2772,7 @@ class JaxLlmEngine:
         )
         # a unified window always carries a span (decode-only iterations
         # keep the decode program), so it is a prompt window
-        live_pages = int(page_meta[3].sum()) if pallas else 0
+        live_pages = int(page_meta[2].sum()) if pallas else 0
         t_prefill = time.time()
         self._phase("dispatch", kind=KIND_PROMPT, tokens=cursor, live_pages=live_pages)
         tokens, lps, tkvs, tkis, self.cache, self._gen_counts, self._prompt_counts = self._jit_unified(
@@ -2806,12 +2813,19 @@ class JaxLlmEngine:
             self._step_lane_steps += len(decodes)
         if pallas:
             # the kernel's work, from the spans just built: every page of a
-            # span is one whole page copied and one loop iteration computed,
-            # and the loop runs no other iteration
+            # span is copied once, into a KV step of its span (the span's
+            # last step partly empty), and the loop runs no other step
             cost = self.utilization.cost
             work = self._kernel_work
+            launched = page_meta[3] > 0     # token blocks with a KV step
+            kv_steps = int(page_meta[3].sum())
             work["ragged_live_pages_total"] += live_pages
-            work["ragged_page_slots_total"] += live_pages
+            work["ragged_page_slots_total"] += kv_steps * kv_step_pages(bs)
+            work["ragged_kv_steps_total"] += kv_steps
+            work["ragged_token_blocks_total"] += int(launched.sum())
+            work["ragged_live_rows_total"] += int(
+                (np.repeat(launched, tb) & (token_pos >= 0)).sum()
+            )
             work["ragged_attn_flops_total"] += cost.attn_flops(ragged_ctx)
             work["ragged_kv_read_bytes_total"] += (
                 live_pages * bs * cost.kv_bytes_per_token

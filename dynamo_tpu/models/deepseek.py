@@ -532,7 +532,7 @@ def _mla_decode_attn(w, x, cfg: DeepseekConfig, positions, k_layer, v_layer,
 
 def _mla_unified_attn(w, x, cfg: DeepseekConfig, positions, token_pos,
                       token_lane, token_slot, k_layer, v_layer, block_tables,
-                      span_lane, span_first, span_count, page_total, cos, sin,
+                      span_lane, span_first, span_count, kv_steps, cos, sin,
                       attention: str = "jax", tb_tokens: int = 8):
     """Absorbed-form ragged unified-batch MLA attention: the flat token
     axis carries chunked-prefill spans + decode tokens, every token writes
@@ -567,7 +567,7 @@ def _mla_unified_attn(w, x, cfg: DeepseekConfig, positions, token_pos,
 
         ctx = ragged_mla_attention(
             q_lat, q_rope, ck3, kr3, token_lane, token_pos,
-            block_tables, span_lane, span_first, span_count, page_total,
+            block_tables, span_lane, span_first, span_count, kv_steps,
             scale=scale, tb_tokens=tb_tokens,
             interpret=attention == "pallas_interpret",
         )
@@ -798,7 +798,7 @@ def deepseek_forward_unified(
     span_lane,      # [T] int32 (pack_spans): block t's span s at t*tb+s
     span_first,     # [T] int32 first page ordinal of the span
     span_count,     # [T] int32 pages in the span (0 = unused)
-    page_total,     # [T // tb_tokens] int32 live pages per token block
+    kv_steps,       # [T // tb_tokens] int32 KV steps per token block
     sample_rows,    # [lanes] int32 flat index of span's LAST token
     cos,
     sin,
@@ -820,7 +820,7 @@ def deepseek_forward_unified(
         return _mla_unified_attn(
             w, attn_in, cfg, positions, token_pos, token_lane, token_slot,
             k_layer, v_layer, block_tables, span_lane, span_first, span_count,
-            page_total, cos, sin, attention=attention, tb_tokens=tb_tokens,
+            kv_steps, cos, sin, attention=attention, tb_tokens=tb_tokens,
         )
 
     x, new_cache = _forward(params, cfg, x, kv_cache, attn)

@@ -695,7 +695,7 @@ def llama_forward_unified(
     span_lane: jnp.ndarray,     # [T] int32 (pack_spans): block t's span s at t*tb+s
     span_first: jnp.ndarray,     # [T] int32 first page ordinal of the span
     span_count: jnp.ndarray,     # [T] int32 pages in the span (0 = unused)
-    page_total: jnp.ndarray,     # [T // tb_tokens] int32 live pages per token block
+    kv_steps: jnp.ndarray,      # [T // tb_tokens] int32 KV steps per token block
     sample_rows: jnp.ndarray,   # [lanes] int32 flat index of span's LAST token
     cos: jnp.ndarray,
     sin: jnp.ndarray,
@@ -724,7 +724,7 @@ def llama_forward_unified(
 
             return ragged_kernel(
                 q, k_pages, v_pages, token_lane, token_pos,
-                block_tables, span_lane, span_first, span_count, page_total,
+                block_tables, span_lane, span_first, span_count, kv_steps,
                 tb_tokens=tb_tokens,
                 interpret=attention == "pallas_interpret",
                 sliding_window=cfg.sliding_window,

@@ -1,59 +1,62 @@
 """Kernel autotuner for the ragged packed attention family.
 
-The ragged kernels walk exactly the live pages of each token block
+The ragged kernels copy exactly the live pages of each token block, a KV
+step of ``kv_step_pages`` of them at a time
 (ops/pallas/ragged_attention.py), so one tunable is left:
 
-- ``tb_tokens`` — token-block size of the packed ragged kernel (heuristic
-  default ``gcd(block_size, 8)``): a larger block walks a prompt span's
-  pages fewer times, a smaller one wastes fewer masked rows on the pages
-  of packed decode lanes.
+- ``tb_tokens`` — the largest token block of the packed ragged kernel
+  (default ``default_tb_tokens``: what keeps one KV head's score rows at
+  256, at most 64): a larger block copies a prompt span's pages fewer
+  times, a smaller one wastes fewer masked rows on the pages of packed
+  decode lanes.
 
 This module sweeps it per **(model geometry, device_kind, dtype)** key.
 On CPU the sweep is scored by a deterministic cost model over the REAL
 host packer (``pack_spans`` builds the span lists for two synthetic
-windows, so the page counts are exact) with per-block / per-page /
-per-row prices read off a TPU v5e (PERF.md section 6, PR 28); on TPU
+windows, so the step counts are exact) with per-block / per-step /
+per-score-tile prices read off a TPU v5e (PERF.md section 6, PR 37); on TPU
 ``scripts/tpu_validate.py --bench`` passes a wall-clock ``runner`` and the
 winner is measured, not modeled.  Winners persist as provenance-stamped
 rows in ``KERNEL_PERF.json`` (same table the calibration benches write);
 the engine resolves them at init with the precedence **explicit knob >
-tuned row > heuristic default**.
+row measured on this device kind > the default from the head geometry**
+(a cost-model row is a record, it binds nothing).
 
-Row schema (version 2)::
+Row schema (version 3)::
 
     {"bench": "autotune_ragged", "geometry": "h4kv2d64-bs4-l4-mb16",
      "device_kind": "any" | "<jax device_kind>", "dtype": "float32",
-     "source": "cost_model" | "measured", "version": 2,
-     "tb_tokens": 4, "cost": 123.4, "swept": 3}
+     "source": "cost_model" | "measured", "version": 3,
+     "tb_tokens": 16, "cost": 123.4, "swept": 5}
 
-``source="cost_model"`` rows are stamped ``device_kind="any"``;
-``source="measured"`` rows are only trusted for the device kind that
-produced them.
+``source="cost_model"`` rows are stamped ``device_kind="any"`` and are
+never resolved; ``source="measured"`` rows are only trusted for the device
+kind that produced them.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
-import math
 import os
 
 import numpy as np
 
 RAGGED_BENCH = "autotune_ragged"
-SCHEMA_VERSION = 2
+SCHEMA_VERSION = 3
 
-# cost-model prices in nanoseconds, fitted to the live-page kernel alone on
-# a TPU v5e at h32 kv8 d128, block 16, bf16, tb_tokens 2-16 (PERF.md
-# section 6, PR 28): a token block's fixed cost, a page iteration's floor
-# (copy wait + loop), and the page body's cost per score-matrix row at a
-# 128 x 128 page; past 256 rows the softmax state no longer stays in
-# registers and every further row costs several times more.
-_NS_BLOCK = 335.0
-_NS_PAGE = 476.0
-_NS_ROW = 0.855
-_ROWS_IN_REGISTERS = 256
-_NS_SPILLED_ROW = 3.7
+# cost-model prices in nanoseconds, fitted to the kernel alone on a TPU v5e at
+# h32 kv8 d128, block 16, bf16, token blocks of 16-64 and KV steps of 4-16
+# pages over six windows (scripts/ragged_kernel_bench.py; PERF.md section 6,
+# PR 37; 54 rows, the worst a third off): a token block's fixed cost, a KV
+# step's (its copies' wait and the loop), and one KV head's score tile of
+# 8 rows x 128 positions (both products and the softmax over it).  The rows
+# are of the kernel with its head loop unrolled at trace time; as the loop
+# it ships with, a step costs about 1.5 x (ROADMAP S4), which moves no
+# winner: every candidate pays it alike.
+_NS_BLOCK = 1300.0
+_NS_STEP = 1000.0
+_NS_TILE = 12.6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,34 +110,31 @@ def _synthetic_workloads(geom: Geometry, tb: int):
 
 def _pack_stats(geom: Geometry, tb: int):
     """Run the real host packer over the synthetic windows; return
-    per-window (token blocks, live pages) pairs."""
+    per-window (token blocks, KV steps) pairs."""
     from dynamo_tpu.ops.pallas.ragged_attention import pack_spans
 
     stats = []
     for token_lane, token_pos in _synthetic_workloads(geom, tb):
-        page_total = pack_spans(
+        kv_steps = pack_spans(
             token_lane, token_pos, lanes=geom.lanes,
             tb_tokens=tb, block_size=geom.block_size,
         )[3]
-        stats.append((page_total.shape[0], int(page_total.sum())))
+        stats.append((kv_steps.shape[0], int(kv_steps.sum())))
     return stats
 
 
 def cost_model(geom: Geometry, tb: int) -> float:
     """Deterministic score (modeled nanoseconds a layer, lower is better)
     for one token-block size."""
-    tbh = tb * geom.num_heads
-    # the page body's work per row, relative to the 128 x 128 page the
-    # prices were read at
-    page_scale = (
-        geom.block_size * geom.num_kv_heads * geom.head_dim / (128.0 * 128.0)
-    )
-    per_page = _NS_PAGE + page_scale * (
-        _NS_ROW * tbh + _NS_SPILLED_ROW * max(0, tbh - _ROWS_IN_REGISTERS)
-    )
+    from dynamo_tpu.ops.pallas.ragged_attention import kv_step_pages
+
+    rows = tb * geom.num_heads // geom.num_kv_heads
+    positions = kv_step_pages(geom.block_size) * geom.block_size
+    tiles = geom.num_kv_heads * -(-rows // 8) * -(-positions // 128)
+    per_step = _NS_STEP + _NS_TILE * tiles
     return float(sum(
-        _NS_BLOCK * num_tb + per_page * live
-        for num_tb, live in _pack_stats(geom, tb)
+        _NS_BLOCK * num_tb + per_step * steps
+        for num_tb, steps in _pack_stats(geom, tb)
     ))
 
 
@@ -142,10 +142,14 @@ def candidate_grid(geom: Geometry, buckets: tuple[int, ...] = ()) -> list[dict]:
     """The swept tb_tokens candidates.  ``buckets`` (the engine's unified
     token buckets) constrain them: a tb that does not divide every bucket
     would force the split fallback, so it is never a valid winner."""
-    default_tb = math.gcd(geom.block_size, 8) or 1
+    from dynamo_tpu.ops.pallas.ragged_attention import default_tb_tokens
+
+    default_tb = default_tb_tokens(
+        geom.num_heads // geom.num_kv_heads, geom.block_size
+    )
     return [
         {"tb_tokens": t}
-        for t in sorted({1, 2, 4, 8, 16, default_tb})
+        for t in sorted({1, 2, 4, 8, 16, 32, 64, default_tb})
         if t <= max(geom.lanes, default_tb)
         and all(b % t == 0 for b in buckets)
     ]
@@ -269,9 +273,13 @@ def resolve(
     dtype: str,
     bench: str = RAGGED_BENCH,
 ) -> dict | None:
-    """Pick the tuned row for a geometry: a measured row for this exact
-    device kind wins over the hardware-independent cost-model row; rows
-    for other devices, dtypes, or schema versions never match."""
+    """Pick the tuned row for a geometry: a row MEASURED on this exact
+    device kind.  A cost-model row (``device_kind="any"``) is a record of
+    what the model prefers on its synthetic windows and binds nothing: the
+    default the kernel derives from the head geometry was measured on the
+    chip at the cells' windows (PERF.md section 6, PR 37), and a guess does
+    not outrank it.  Rows for other devices, dtypes, or schema versions
+    never match."""
     rows = [
         r for r in table.get("rows", ())
         if r.get("bench") == bench
@@ -286,10 +294,4 @@ def resolve(
         and device_kind is not None
         and r.get("device_kind") == device_kind
     ]
-    if measured:
-        return measured[0]
-    modeled = [
-        r for r in rows
-        if r.get("source") == "cost_model" and r.get("device_kind") == "any"
-    ]
-    return modeled[0] if modeled else None
+    return measured[0] if measured else None

@@ -15,6 +15,7 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_window_attention_decode,
 )
 from dynamo_tpu.ops.pallas.ragged_attention import (
+    kv_step_pages,
     pack_spans,
     ragged_paged_attention,
 )
@@ -27,6 +28,7 @@ __all__ = [
     "ragged_paged_attention",
     "ragged_mla_attention",
     "pack_spans",
+    "kv_step_pages",
     "gather_blocks",
     "scatter_blocks",
 ]

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.pallas.ragged_attention import (
+    kv_step_pages,
     row_routing,
     softmax_finish,
     softmax_init,
@@ -315,14 +316,14 @@ def _ragged_kernel(
     span_lane_ref,      # [T] int32 — pack_spans
     span_first_ref,     # [T] int32
     span_count_ref,     # [T] int32
-    page_total_ref,     # [num_tb] int32 — page iterations per token block
+    kv_steps_ref,       # [num_tb] int32 — KV steps per token block
     q_lat_ref,          # [1, TB*H, R]  (token-major fold: row = tok*H + h)
     q_rope_ref,         # [1, TB*H, P]
     ck_hbm,             # [N, bs, R] whole latent cache, HBM
     kr_hbm,             # [N, bs, P]
     out_ref,            # [1, TB*H, R]
-    ck_buf,             # [2, bs, R] VMEM double buffer
-    kr_buf,             # [2, bs, P]
+    ck_buf,             # [2, pages * bs, R] VMEM double buffer
+    kr_buf,             # [2, pages * bs, P]
     sems,               # DMA semaphores [2, 2]
     m_ref, l_ref, acc_ref,
     *,
@@ -330,6 +331,7 @@ def _ragged_kernel(
     scale: float,
     tb_tokens: int,
     num_heads: int,
+    pages_per_step: int,
 ):
     """Ragged unified-batch MLA: the live-page loop of
     ops/pallas/ragged_attention.py (walk_live_pages) applied to the latent
@@ -341,15 +343,17 @@ def _ragged_kernel(
     softmax_init(m_ref, l_ref, acc_ref)
     q_lat = q_lat_ref[0].astype(jnp.float32)    # [TB*H, R]
     q_rope = q_rope_ref[0].astype(jnp.float32)  # [TB*H, P]
-    pos_in_page = jax.lax.broadcasted_iota(jnp.int32, (1, block_size), 1)
+    pos_in_step = jax.lax.broadcasted_iota(
+        jnp.int32, (1, pages_per_step * block_size), 1
+    )
     row_lane, q_pos = row_routing(
         token_lane_ref, token_pos_ref, base, tb_tokens=tb_tokens,
         heads=num_heads,
     )
 
-    def page_body(slot, page_lane, page_ord):
-        ck = ck_buf[slot].astype(jnp.float32)     # [bs, R]
-        kr = kr_buf[slot].astype(jnp.float32)     # [bs, P]
+    def step_body(slot, step_lane, step_ord):
+        ck = ck_buf[slot].astype(jnp.float32)     # [pages * bs, R]
+        kr = kr_buf[slot].astype(jnp.float32)     # [pages * bs, P]
         s = (
             jax.lax.dot_general(
                 q_lat, ck, dimension_numbers=(((1,), (1,)), ((), ())),
@@ -359,22 +363,24 @@ def _ragged_kernel(
                 q_rope, kr, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        ) * scale                                    # [TB*H, bs]
-        pos = page_ord * block_size + pos_in_page
-        mask = (row_lane == page_lane) & (pos <= q_pos)
+        ) * scale                                    # [TB*H, pages * bs]
+        pos = step_ord * block_size + pos_in_step
+        mask = (row_lane == step_lane) & (pos <= q_pos)
         softmax_update(s, mask, ck, m_ref, l_ref, acc_ref)
 
     walk_live_pages(
-        base, page_total_ref[t], span_lane_ref, span_first_ref,
+        base, kv_steps_ref[t], span_lane_ref, span_first_ref,
         span_count_ref, block_tables_ref,
         ((ck_hbm, ck_buf), (kr_hbm, kr_buf)), sems,
-        tb_tokens=tb_tokens, page_body=page_body,
+        tb_tokens=tb_tokens, pages_per_step=pages_per_step,
+        step_body=step_body,
     )
-    softmax_finish(out_ref, l_ref, acc_ref)
+    softmax_finish(out_ref.at[0], l_ref, acc_ref)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "tb_tokens", "interpret"),
+    jax.jit,
+    static_argnames=("scale", "tb_tokens", "interpret", "pages_per_step"),
 )
 def ragged_mla_attention(
     q_lat: jnp.ndarray,         # [T, H, R] flat ragged token batch
@@ -387,20 +393,23 @@ def ragged_mla_attention(
     span_lane: jnp.ndarray,     # [T] int32 (pack_spans)
     span_first: jnp.ndarray,    # [T] int32
     span_count: jnp.ndarray,    # [T] int32
-    page_total: jnp.ndarray,    # [T // tb_tokens] int32
+    kv_steps: jnp.ndarray,      # [T // tb_tokens] int32
     *,
     scale: float,
     tb_tokens: int = 8,
     interpret: bool = False,
+    pages_per_step: int | None = None,
 ) -> jnp.ndarray:
     """Ragged unified-batch MLA paged attention with packed lanes: one
     launch over mixed chunked-prefill spans + decode tokens against the
-    latent cache, page iterations = live pages.  Returns the latent-space
-    context [T, H, R] (float32); metadata comes from
-    ragged_attention.pack_spans and the latent block tables."""
+    latent cache, only live pages copied, ``pages_per_step`` of them a KV
+    step.  Returns the latent-space context [T, H, R] (float32); metadata
+    comes from ragged_attention.pack_spans (same ``tb_tokens`` and
+    ``pages_per_step``) and the latent block tables."""
     t_pad, h, r = q_lat.shape
     p_dim = q_rope.shape[-1]
     bs = ck_cache.shape[1]
+    pps = pages_per_step or kv_step_pages(bs)
     if t_pad % tb_tokens:
         raise ValueError(
             f"flat token axis ({t_pad}) must pack whole token blocks of "
@@ -427,8 +436,8 @@ def ragged_mla_attention(
         ],
         out_specs=pl.BlockSpec((1, tbh, r), lambda t, *_: (t, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, bs, r), ck_cache.dtype),
-            pltpu.VMEM((2, bs, p_dim), kr_cache.dtype),
+            pltpu.VMEM((2, pps * bs, r), ck_cache.dtype),
+            pltpu.VMEM((2, pps * bs, p_dim), kr_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((tbh, 128), jnp.float32),
             pltpu.VMEM((tbh, 128), jnp.float32),
@@ -441,6 +450,7 @@ def ragged_mla_attention(
         scale=scale,
         tb_tokens=tb_tokens,
         num_heads=h,
+        pages_per_step=pps,
     )
     out = pl.pallas_call(
         kernel,
@@ -449,7 +459,7 @@ def ragged_mla_attention(
         interpret=interpret,
     )(
         token_lane, token_pos, block_tables, span_lane, span_first,
-        span_count, page_total,
+        span_count, kv_steps,
         q_lat.reshape(num_tb, tbh, r),
         q_rope.reshape(num_tb, tbh, p_dim),
         ck_cache, kr_cache,

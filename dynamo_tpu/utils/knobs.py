@@ -241,12 +241,13 @@ K_KERNEL_PERF = register(
         "the repo-root artifact, purely advisory)", section=PERF)
 K_AUTOTUNE = register(
     "DYN_AUTOTUNE", type="bool", default=True,
-    doc="consult KERNEL_PERF.json autotune rows for the ragged kernel's "
-        "token-block size at engine init; `0` keeps the heuristic default",
+    doc="consult KERNEL_PERF.json's measured autotune rows for the ragged "
+        "kernel's token-block size at engine init; `0` keeps the default "
+        "from the head geometry",
     section=PERF)
 K_AUTOTUNE_TB = register(
     "DYN_AUTOTUNE_TB", type="int", default=None,
-    doc="force the ragged kernel's token-block size (overrides tuned rows; "
+    doc="force the ragged kernel's largest token block (overrides tuned rows; "
         "must divide every serving bucket or it falls back with a warning)",
     section=PERF)
 

@@ -1,30 +1,32 @@
 """Time the ragged paged-attention kernel ALONE on the chip, at the
 benchmark cells' shapes (h32 kv8 d128, block 16, bf16 cache).
 
-    python scripts/ragged_kernel_bench.py [--parent DIR] [--iters N]
+    python scripts/ragged_kernel_bench.py [--tree DIR] [--tb N ...] [--pages P ...]
     python scripts/ragged_kernel_bench.py --layers 36 [--num-blocks 1152]
 
-Prints one JSON line per (workload, variant): ms a call (median of
-``--iters`` timed calls, each ended by block_until_ready), token blocks,
-live pages, page iterations.  With ``--parent DIR`` (a checkout of the commit
-before the kernel walked live pages only) it times THAT tree's kernel at its
-full worklist width and at the tightest width that fits, which prices a dead
-grid step and a live page (PERF.md section 6, PR 28).  With ``--layers L`` it
-times both attention kernels as a step program launches them, L times in one
-jitted scan: over ONE layer's pages ``[N, ...]`` with the tables as they are,
-and over the whole cache as flat pages ``[L * N, ...]`` with the tables offset
-by ``layer * N`` (``models/llama.py:_scan_layers``); ms a launch must agree
-(PERF.md section 5, PR 33).  Both operands are a program's arguments, in HBM:
-this prices the operand's SIZE, not where it lives (a layer sliced out inside
-a step program may sit in the chip's fast memory, and the one-query kernel
-then runs twice as fast: ``_LayerPages.on_chip``).  Exits 1 off the TPU: a
-CPU time is not a device time.
+Prints one JSON line per (workload, token block, pages a KV step): ms a call
+(median of ``--iters`` timed calls, each ended by block_until_ready), token
+blocks, pages copied, KV steps, microseconds a KV step.  ``--tree DIR`` times
+the kernel of another checkout on the same workloads (the parent commit's:
+one process a tree, both in one chip call); a tree whose kernel has no KV
+step (one page an iteration, PR 28 - PR 34) is timed at ``--tb`` alone.
+With ``--layers L`` it times both attention kernels as a step program
+launches them, L times in one jitted scan: over ONE layer's pages ``[N, ...]``
+with the tables as they are, and over the whole cache as flat pages
+``[L * N, ...]`` with the tables offset by ``layer * N``
+(``models/llama.py:_scan_layers``); ms a launch must agree (PERF.md section 5,
+PR 33).  Both operands are a program's arguments, in HBM: this prices the
+operand's SIZE, not where it lives (a layer sliced out inside a step program
+may sit in the chip's fast memory, and the one-query kernel then runs twice as
+fast: ``_LayerPages.on_chip``).  Exits 1 off the TPU: a CPU time is not a
+device time.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import statistics
 import sys
@@ -37,17 +39,18 @@ NUM_BLOCKS = 1152
 
 
 def _workloads():
-    """(name, tb_tokens, lanes, bucket, max_blocks, [(lane, start, end)])
+    """(name, lanes, bucket, max_blocks, [(lane, start, end)])
     A decode lane is a one-token span at its context's last position; the
     flat axis carries decodes first, then the prompt span (engine order)."""
     long_decodes = [(i, 1999 + 7 * i, 2000 + 7 * i) for i in range(7)]
     chat_decodes = [(i, 299 + 23 * i, 300 + 23 * i) for i in range(15)]
     return [
-        ("long.span2048+7dec", 8, 8, 4096, 256, long_decodes + [(7, 0, 2048)]),
-        ("long.span2048", 8, 8, 2048, 256, [(7, 0, 2048)]),
-        ("long.7dec+span8", 8, 8, 32, 256, long_decodes + [(7, 0, 8)]),
-        ("chat.span256@512+15dec", 4, 16, 512, 256, chat_decodes + [(15, 256, 512)]),
-        ("chat.span64@320+8dec", 4, 16, 128, 256, chat_decodes[:8] + [(15, 256, 320)]),
+        ("long.span2048+7dec", 8, 4096, 256, long_decodes + [(7, 0, 2048)]),
+        ("long.span2048", 8, 2048, 256, [(7, 0, 2048)]),
+        ("long.span3584", 8, 4096, 256, [(7, 0, 3584)]),
+        ("long.7dec+span8", 8, 32, 256, long_decodes + [(7, 0, 8)]),
+        ("chat.span256@512+15dec", 16, 512, 256, chat_decodes + [(15, 256, 512)]),
+        ("chat.span64@320+8dec", 16, 128, 256, chat_decodes[:8] + [(15, 256, 320)]),
     ]
 
 
@@ -64,23 +67,15 @@ def _flat(spans, bucket):
 
 
 def _tables(lanes, max_blocks, rng, num_blocks=NUM_BLOCKS):
+    """Distinct random pages: 130 a lane, and the last lane (the prompt
+    span's) as many more as are left, up to a full table."""
     per = min(130, max_blocks, (num_blocks - 1) // lanes)
-    perm = rng.permutation(num_blocks - 1)[: lanes * per] + 1
+    perm = rng.permutation(num_blocks - 1) + 1
     bt = np.zeros((lanes, max_blocks), np.int32)
-    bt[:, :per] = perm.reshape(lanes, per)
+    bt[:, :per] = perm[: lanes * per].reshape(lanes, per)
+    more = min(max_blocks - per, num_blocks - 1 - lanes * per)
+    bt[-1, per:per + more] = perm[lanes * per: lanes * per + more]
     return bt
-
-
-def _load_old(checkout):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "parent_ragged_attention",
-        f"{checkout}/dynamo_tpu/ops/pallas/ragged_attention.py",
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _time(fn, args, iters):
@@ -152,9 +147,10 @@ def _bench_layers(a, dev, interpret) -> int:
                 "ms_a_launch": med / layers, "ms_a_launch_min": best / layers,
             }), flush=True)
 
-    for name, tb, lanes, bucket, max_blocks, spans in _workloads():
+    for name, lanes, bucket, max_blocks, spans in _workloads():
+        tb = math.gcd(a.tb[0], bucket)  # as the engine packs a bucket
         if interpret:  # rehearsal: same code path, toy extents
-            bucket, max_blocks = 16, 4
+            bucket, max_blocks, tb = 16, 4, 8
             spans = [(ln, s % 40, s % 40 + min(e - s, 6)) for ln, s, e in spans[-2:]]
         lane, pos = _flat(spans, bucket)
         bt = jnp.asarray(_tables(lanes, max_blocks, rng, n))
@@ -169,7 +165,8 @@ def _bench_layers(a, dev, interpret) -> int:
             ),
             (bucket, H, D), bt,
             {"workload": name, "kernel": "ragged_paged_attention",
-             "live_pages": int(meta[-1].sum())},
+             "tb_tokens": tb, "pages_copied": int(meta[2].sum()),
+             "kv_steps": int(meta[3].sum())},
         )
     for name, lanes, max_blocks, ctx in _decode_workloads():
         if interpret:
@@ -192,14 +189,21 @@ def _bench_layers(a, dev, interpret) -> int:
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--parent", default=None)
+    ap.add_argument("--tree", default=None, metavar="DIR",
+                    help="time the kernel of the checkout in DIR")
     ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--tb", type=int, nargs="*", default=None,
-                    help="time every workload at these token-block sizes "
-                         "instead of its cell's own")
-    ap.add_argument("--check", default=None, metavar="DIR",
-                    help="compare this tree's outputs, to the bit, with the "
-                         "kernel of the checkout in DIR (page worklists)")
+    ap.add_argument("--tb", type=int, nargs="*", default=[64],
+                    help="largest token blocks to time every workload at; a "
+                         "bucket is packed to gcd(this, bucket) tokens a block, "
+                         "as the engine packs it (default: what the engine "
+                         "derives for this head geometry)")
+    ap.add_argument("--pages", type=int, nargs="*", default=[None],
+                    help="pages a KV step (default: the kernel's own, from "
+                         "the page size)")
+    ap.add_argument("--check", action="store_true",
+                    help="also print each row's largest error against the XLA "
+                         "twin (ops/attention.py) over float32 copies of the "
+                         "same operands")
     ap.add_argument("--layers", type=int, default=None,
                     help="time both attention kernels, this many launches in "
                          "one jitted scan, over one layer's pages and over "
@@ -209,7 +213,7 @@ def main() -> int:
     ap.add_argument("--allow-cpu", action="store_true", help="rehearsal only")
     a = ap.parse_args()
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, a.parent or root)
+    sys.path.insert(0, a.tree or root)
     import jax
     import jax.numpy as jnp
 
@@ -222,87 +226,57 @@ def main() -> int:
         return _bench_layers(a, dev, interpret)
     from dynamo_tpu.ops.pallas import ragged_attention as ra
 
+    kv_step = hasattr(ra, "kv_step_pages")  # else: one page an iteration
     rng = np.random.default_rng(0)
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
     k_cache = jax.random.normal(kk, (NUM_BLOCKS, BS, KVH, D), jnp.bfloat16)
     v_cache = jax.random.normal(kv, (NUM_BLOCKS, BS, KVH, D), jnp.bfloat16)
-    work = _workloads()
-    if a.tb:
-        work = [(n, tb, *rest) for n, _, *rest in work for tb in a.tb]
-    for name, tb, lanes, bucket, max_blocks, spans in work:
+    for name, lanes, bucket, max_blocks, spans in _workloads():
+        tbs = a.tb
         if interpret:  # rehearsal: same code path, toy extents
-            bucket, max_blocks = 16, 4
+            bucket, max_blocks, tbs = 16, 4, [8]
             spans = [(ln, s % 40, s % 40 + min(e - s, 6)) for ln, s, e in spans[-2:]]
         lane, pos = _flat(spans, bucket)
         bt = _tables(lanes, max_blocks, rng)
         q = jax.random.normal(kq, (bucket, H, D), jnp.bfloat16)
-        base = {
-            "workload": name, "device": dev.device_kind, "tb_tokens": tb,
-            "token_blocks": bucket // tb, "parent": bool(a.parent),
-        }
-        if a.parent:
-            full = tb * max_blocks
-            tight = ra.pack_page_meta(lane, pos, bt, tb_tokens=tb, block_size=BS)
-            need = tight[0].shape[1]
-            widths = {"full": (full, 1), "tight": (-(-need // 8) * 8, 1)}
-            if tb == 4:
-                widths["tuned520x8"] = (520, 8)
-            outs = {}
-            for variant, (ps, pps) in widths.items():
-                if ps < need:
-                    continue
-                meta = ra.pack_page_meta(
-                    lane, pos, bt, tb_tokens=tb, block_size=BS, page_slots=ps
+        for tb in sorted({math.gcd(tb, bucket) for tb in tbs}):
+            for pages in a.pages if kv_step else [None]:
+                step = {"pages_per_step": pages} if kv_step else {}
+                meta = ra.pack_spans(
+                    lane, pos, lanes=lanes, tb_tokens=tb, block_size=BS, **step
                 )
                 args = (
                     q, k_cache, v_cache, jnp.asarray(lane), jnp.asarray(pos),
-                    *(jnp.asarray(m) for m in meta),
+                    jnp.asarray(bt), *(jnp.asarray(m) for m in meta),
                 )
-                fn = lambda *xs, pps=pps: ra.ragged_paged_attention(  # noqa: E731
-                    *xs, tb_tokens=tb, pages_per_step=pps, interpret=interpret
+                fn = lambda *xs: ra.ragged_paged_attention(  # noqa: E731
+                    *xs, tb_tokens=tb, interpret=interpret, **step
                 )
                 med, best = _time(fn, args, a.iters)
-                outs[variant] = np.asarray(fn(*args).astype(jnp.float32))
+                steps = int(meta[3].sum())
+                err = {}
+                if a.check:
+                    from dynamo_tpu.ops.attention import ragged_paged_attention as twin
+
+                    ctx = np.zeros((lanes,), np.int32)
+                    np.maximum.at(ctx, lane[pos >= 0], pos[pos >= 0] + 1)
+                    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+                    want = twin(
+                        f32(q), f32(k_cache), f32(v_cache), jnp.asarray(bt),
+                        jnp.asarray(ctx), jnp.asarray(lane), jnp.asarray(pos),
+                    )
+                    live = jnp.asarray(pos >= 0)[:, None, None]
+                    err["max_abs_err"] = float(jnp.max(jnp.where(
+                        live, jnp.abs(f32(fn(*args)) - want), 0.0
+                    )))
                 print(json.dumps({
-                    **base, "variant": variant, "page_slots": ps,
-                    "pages_per_step": pps, "ms": med, "ms_min": best,
-                    "live_pages": int(meta[3].sum()),
-                    "page_iterations": int(meta[0].size),
+                    "workload": name, "device": dev.device_kind,
+                    "tree": a.tree or ".", "tb_tokens": tb,
+                    "pages_per_step": (pages or ra.kv_step_pages(BS)) if kv_step else 1,
+                    "ms": med, "ms_min": best, "token_blocks": bucket // tb,
+                    "pages_copied": int(meta[2].sum()), "kv_steps": steps,
+                    "us_a_step": 1e3 * med / max(steps, 1), **err,
                 }), flush=True)
-            ref = outs.pop("full")
-            for variant, o in outs.items():
-                assert np.array_equal(ref, o), f"{name}: {variant} != full"
-        else:
-            spans_meta = ra.pack_spans(
-                lane, pos, lanes=lanes, tb_tokens=tb, block_size=BS
-            )
-            args = (
-                q, k_cache, v_cache, jnp.asarray(lane), jnp.asarray(pos),
-                jnp.asarray(bt), *(jnp.asarray(m) for m in spans_meta),
-            )
-            fn = lambda *xs: ra.ragged_paged_attention(  # noqa: E731
-                *xs, tb_tokens=tb, interpret=interpret
-            )
-            med, best = _time(fn, args, a.iters)
-            live = int(spans_meta[-1].sum())
-            row = {
-                **base, "variant": "live_pages", "ms": med, "ms_min": best,
-                "live_pages": live, "page_iterations": live,
-            }
-            if a.check:
-                old = _load_old(a.check)
-                meta = old.pack_page_meta(
-                    lane, pos, bt, tb_tokens=tb, block_size=BS
-                )
-                want = old.ragged_paged_attention(
-                    q, k_cache, v_cache, jnp.asarray(lane), jnp.asarray(pos),
-                    *(jnp.asarray(m) for m in meta),
-                    tb_tokens=tb, interpret=interpret,
-                )
-                row["equal_to_parent_bitwise"] = bool(
-                    jnp.array_equal(fn(*args), want)
-                )
-            print(json.dumps(row), flush=True)
     return 0
 
 

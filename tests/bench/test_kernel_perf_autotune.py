@@ -1,6 +1,7 @@
 """Kernel autotuner (ops/autotune.py): deterministic CPU sweeps, row
 provenance, persistence cache hits, and the engine's resolution chain
-(explicit knob > tuned KERNEL_PERF.json row > heuristic default).
+(explicit knob > KERNEL_PERF.json row measured on this device kind >
+the default from the head geometry; a cost-model row binds nothing).
 
 Everything here is tier-1: the cost model runs the REAL host packer over
 synthetic workloads — no wall clock, no RNG — so the same geometry always
@@ -34,7 +35,7 @@ def test_sweep_winner_is_deterministic_and_feasible():
     assert a["dtype"] == "float32"
     assert a["version"] == autotune.SCHEMA_VERSION
     assert a["geometry"] == TINY.key == "h4kv2d16-bs4-l4-mb32"
-    assert a["swept"] == len(grid_a) == 3           # tb_tokens 1, 2, 4
+    assert a["swept"] == len(grid_a) == 5           # tb_tokens 1 ... 16
     # the row carries the one tunable left and nothing of the worklists
     assert "page_slots" not in a and "pages_per_step" not in a
     # every bucket stays packable at the tuned tb
@@ -43,22 +44,32 @@ def test_sweep_winner_is_deterministic_and_feasible():
     assert [c["tb_tokens"] for c in autotune.candidate_grid(TINY, (6, 18))] == [1, 2]
 
 
-def test_cost_model_counts_live_pages_and_the_price_of_rows():
+def test_cost_model_counts_kv_steps_and_the_price_of_score_tiles():
     """The model's terms, from the packer's own counts: a larger token
-    block walks a prompt span's pages fewer times (fewer live pages, fewer
-    blocks); at the serving geometry a block past 256 score rows pays the
-    spill price, so 16 tokens of 32 heads lose to 8."""
+    block copies a prompt span's pages fewer times (fewer KV steps, fewer
+    blocks) and pays more score tiles a step.  At the serving geometry its
+    synthetic windows (fifteen lanes decoding at half the context beside a
+    prompt of a quarter of it, and beside a two-page chunk) are decode-heavy,
+    where every step of a 64-token block carries 256 mostly masked rows: the
+    model prefers 16 there, the chip prefers 64 on the cells' windows (a
+    2,048-token span beside 7 decodes: 2.60 / 2.35 / 2.02 ms a layer at 16 /
+    32 / 64, PERF.md section 6, PR 37), which is why a cost-model row binds
+    nothing."""
     stats = {tb: autotune._pack_stats(TINY, tb) for tb in (1, 2, 4)}
     for small, large in ((1, 2), (2, 4)):
-        for (nb_s, live_s), (nb_l, live_l) in zip(stats[small], stats[large]):
-            assert nb_l < nb_s and live_l < live_s
+        for (nb_s, steps_s), (nb_l, steps_l) in zip(stats[small], stats[large]):
+            assert nb_l < nb_s and steps_l < steps_s
     assert autotune.cost_model(TINY, 4) < autotune.cost_model(TINY, 2)
     serving = autotune.Geometry(
         num_heads=32, num_kv_heads=8, head_dim=128,
         block_size=16, lanes=16, max_blocks_per_seq=256,
     )
-    assert autotune.cost_model(serving, 8) < autotune.cost_model(serving, 4)
-    assert autotune.cost_model(serving, 8) < autotune.cost_model(serving, 16)
+    assert autotune.cost_model(serving, 16) < autotune.cost_model(serving, 4)
+    assert autotune.cost_model(serving, 16) < autotune.cost_model(serving, 64)
+    assert autotune.resolve(
+        {"rows": [autotune.sweep(serving)]}, geometry_key=serving.key,
+        device_kind="TPU v5 lite", dtype="float32",
+    ) is None
 
 
 def test_tune_persists_and_rerun_is_cache_hit(tmp_path):
@@ -108,11 +119,11 @@ def test_measured_rows_outrank_cost_model_rows():
         dtype="float32",
     )
     assert got is measured
-    # a different chip falls back to the hardware-independent row
+    # on a different chip nothing binds: the modeled row is a record
     got = autotune.resolve(
         table, geometry_key=TINY.key, device_kind="TPU v6e", dtype="float32",
     )
-    assert got is modeled
+    assert got is None
     # dtype and geometry are part of the key
     assert autotune.resolve(
         table, geometry_key=TINY.key, device_kind=None, dtype="bfloat16",
@@ -122,9 +133,9 @@ def test_measured_rows_outrank_cost_model_rows():
         dtype="float32",
     ) is None
     # rows of the schema that carried worklist widths no longer bind
-    old = dict(modeled, version=1, page_slots=8, pages_per_step=1)
+    old = dict(measured, version=1, page_slots=8, pages_per_step=1)
     assert autotune.resolve(
-        {"rows": [old]}, geometry_key=TINY.key, device_kind=None,
+        {"rows": [old]}, geometry_key=TINY.key, device_kind="TPU v5 lite",
         dtype="float32",
     ) is None
 
@@ -160,9 +171,11 @@ def _engine(tmp_path, monkeypatch, table_rows=None, **overrides):
 
 
 def _tuned_row(**kw):
+    """A row measured on the device these tests run on."""
     row = {
         "bench": autotune.RAGGED_BENCH, "geometry": TINY.key,
-        "device_kind": "any", "dtype": "float32", "source": "cost_model",
+        "device_kind": jax.devices()[0].device_kind, "dtype": "float32",
+        "source": "measured",
         "version": autotune.SCHEMA_VERSION, "tb_tokens": 2,
     }
     row.update(kw)
@@ -194,7 +207,10 @@ def test_engine_default_without_rows(tmp_path, monkeypatch):
     try:
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "default"
-        assert kc["tb_tokens"] == 4          # gcd(block_size=4, 8)
+        # two query heads share a KV head: 64 tokens keep a product's score
+        # rows under 256; the buckets (16, 32, 64) pack to blocks of 16 / 32 / 64
+        assert kc["tb_tokens"] == 64
+        assert [engine._tb_for(b) for b in (16, 32, 64)] == [16, 32, 64]
         # the worklist width, its overflow counter and the pages folded
         # into a grid step went with the static worklists
         assert set(kc) == {"tb_tokens", "source", "geometry"}
@@ -231,8 +247,8 @@ def test_engine_autotune_opt_out(tmp_path, monkeypatch):
 
 def test_engine_rejects_tuned_tb_that_breaks_buckets(tmp_path, monkeypatch):
     """A tuned tb that cannot pack every unified bucket must fall back to
-    the heuristic default (warn, not wedge every window into the split
-    path)."""
+    the default (warn, not wedge every window into the split path), whose
+    blocks are cut to what each bucket holds."""
     engine = _engine(
         tmp_path, monkeypatch,
         table_rows=[_tuned_row(tb_tokens=16)],
@@ -242,6 +258,7 @@ def test_engine_rejects_tuned_tb_that_breaks_buckets(tmp_path, monkeypatch):
     try:
         kc = engine.stats()["kernel_config"]
         assert kc["source"] == "default"
-        assert kc["tb_tokens"] == 4
+        assert kc["tb_tokens"] == 64
+        assert [engine._tb_for(b) for b in (24, 48)] == [8, 16]
     finally:
         engine.stop()

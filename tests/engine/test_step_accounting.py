@@ -15,7 +15,7 @@ from dynamo_tpu.engine.engine import (
     _InflightWindow,
 )
 from dynamo_tpu.observability.step_metrics import KIND_DECODE, KIND_PROMPT
-from dynamo_tpu.ops.pallas import pack_spans
+from dynamo_tpu.ops.pallas import kv_step_pages, pack_spans
 
 from tests.engine.test_jax_engine import CFG, PARAMS, collect, make_engine, request
 
@@ -266,12 +266,13 @@ async def test_a_chunked_prompt_on_a_real_engine_closes_one_prefill_span_a_chunk
 
 # -- the ragged kernel's page spans -----------------------------------------
 
-def hand_counted(spans, tb, bs, window=None):
-    """Live pages of a flat batch laid out span after span: per token block
-    and lane, the pages from the first one the window still shows the
-    block's first token to the one holding its last token."""
+def hand_counted(spans, tb, bs, window=None, pages=1):
+    """(pages copied, KV steps) of a flat batch laid out span after span: per
+    token block and lane, the pages from the first one the window still
+    shows the block's first token to the one holding its last token, and
+    the steps of ``pages`` pages that run of pages takes."""
     flat = [(lane, pos) for lane, start, end in spans for pos in range(start, end)]
-    live = 0
+    live = steps = 0
     for t in range(0, len(flat), tb):
         by_lane = {}
         for lane, pos in flat[t:t + tb]:
@@ -280,11 +281,13 @@ def hand_counted(spans, tb, bs, window=None):
         for lo, hi in by_lane.values():
             first = 0 if window is None else max(0, lo - (window - 1)) // bs
             live += hi // bs + 1 - first
-    return live
+            steps += -(-(hi // bs + 1 - first) // pages)
+    return live, steps
 
 
+@pytest.mark.parametrize("pages", [1, 3, None])
 @pytest.mark.parametrize("window", [None, 8])
-def test_pack_spans_page_iterations_against_a_hand_count(window):
+def test_pack_spans_page_iterations_against_a_hand_count(window, pages):
     tb, bs, lanes = 8, 4, 3
     spans = [(0, 0, 13), (1, 20, 21), (2, 5, 27)]   # a prompt, a decode token, a later chunk
     tokens = sum(end - start for _, start, end in spans)
@@ -296,24 +299,78 @@ def test_pack_spans_page_iterations_against_a_hand_count(window):
         token_lane[i:i + end - start] = lane
         token_pos[i:i + end - start] = np.arange(start, end)
         i += end - start
-    span_lane, _, span_count, page_count = pack_spans(
+    span_lane, _, span_count, kv_steps = pack_spans(
         token_lane, token_pos, lanes=lanes, tb_tokens=tb, block_size=bs,
-        sliding_window=window)
-    # the kernel's loop runs page_count[t] iterations for block t: the sum
-    # of its spans' counts, and nothing for a span that is not there
-    assert int(page_count.sum()) == hand_counted(spans, tb, bs, window)
-    assert page_count.tolist() == span_count.reshape(-1, tb).sum(axis=1).tolist()
+        sliding_window=window, pages_per_step=pages)
+    # the kernel copies a span's pages once each and its loop runs
+    # kv_steps[t] steps for block t: each span's pages in steps of `pages`
+    # (the kernels' own width where none is given), and nothing for a span
+    # that is not there
+    per_step = pages or kv_step_pages(bs)
+    live, steps = hand_counted(spans, tb, bs, window, per_step)
+    assert (int(span_count.sum()), int(kv_steps.sum())) == (live, steps)
+    assert kv_steps.tolist() == (
+        -(-span_count.reshape(-1, tb) // per_step)).sum(axis=1).tolist()
     assert (span_count[span_lane < 0] == 0).all()
     if window is None:
         # block by block: [13 of lane 0 -> 2 then 4 pages incl. the decode token's 6], ...
-        assert int(page_count.sum()) == 2 + (4 + 6 + 2) + 4 + 6 + 7
+        assert live == 2 + (4 + 6 + 2) + 4 + 6 + 7
+        if pages == 3:
+            assert steps == 1 + (2 + 2 + 1) + 2 + 2 + 3
     else:
-        assert int(page_count.sum()) < hand_counted(spans, tb, bs, None)
+        assert live < hand_counted(spans, tb, bs, None)[0]
+
+
+def test_pack_spans_on_a_2048_token_prompt_equals_the_closed_forms():
+    """A 2,048-token prompt alone, pages of 16: a block of TB tokens is
+    block i of 2,048 / TB and sees (i + 1) TB / 16 pages, so the kernel
+    copies (2,048 / TB) (2,048 / TB + 1) / 2 x TB / 16 pages: 16,512 at 8
+    tokens a block (PR 28's kernel), 4,160 at 32, 2,112 at 64; and at 16
+    pages a KV step, 64 tokens a block, it runs sum(ceil((i + 1) / 4)) =
+    144 steps."""
+    pos = np.arange(2048, dtype=np.int32)
+    lane = np.zeros(2048, np.int32)
+
+    def packed(tb, pages):
+        _, _, count, steps = pack_spans(
+            lane, pos, lanes=1, tb_tokens=tb, block_size=16, pages_per_step=pages)
+        return int(count.sum()), int(steps.sum())
+
+    assert packed(8, 1) == (16512, 16512)
+    assert packed(32, 8) == (4160, 544)
+    assert packed(64, 8) == (2112, 272)
+    assert packed(64, 16) == (2112, 144)
+    assert kv_step_pages(16) == 16 and packed(64, None) == (2112, 144)
+
+
+def recount(engine, spans, bucket):
+    """The kernel-work totals of one unified window, from pack_spans over
+    the window's flat batch as the engine lays it out (span after span,
+    padded to the bucket) at the token block the engine packs that bucket to."""
+    tb, bs = engine._tb_for(bucket), engine.config.block_size
+    lanes = engine.config.max_batch_size
+    token_lane = np.full((bucket,), lanes, np.int32)
+    token_pos = np.full((bucket,), -1, np.int32)
+    i = 0
+    for lane, start, end in spans:
+        token_lane[i:i + end - start] = lane
+        token_pos[i:i + end - start] = np.arange(start, end)
+        i += end - start
+    _, _, count, steps = pack_spans(
+        token_lane, token_pos, lanes=lanes, tb_tokens=tb, block_size=bs)
+    launched = np.repeat(steps > 0, tb)
+    return {
+        "ragged_live_pages_total": int(count.sum()),
+        "ragged_kv_steps_total": int(steps.sum()),
+        "ragged_page_slots_total": int(steps.sum()) * kv_step_pages(bs),
+        "ragged_token_blocks_total": int((steps > 0).sum()),
+        "ragged_live_rows_total": int((launched & (token_pos >= 0)).sum()),
+    }
 
 
 async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
     """Pallas (interpreted) unified engine, one request of 21 prompt tokens:
-    the window's live pages recounted from the request's length."""
+    the window's kernel work recounted from the request's length."""
     engine = make_engine(attention_impl="pallas_interpret", block_size=8, num_blocks=32,
                          decode_overlap=False)
     try:
@@ -321,17 +378,22 @@ async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
         n = 21
         await collect(engine, request(list(range(3, 3 + n)), max_tokens=3, ignore_eos=True))
         stats = await settled_stats(engine)
-        tb, bs = engine._unified_tb, engine.config.block_size
+        bs = engine.config.block_size
         assert stats["decode_windows_unified_total"] == 1
-        assert stats["ragged_live_pages_total"] == hand_counted([(0, 0, n)], tb, bs)
-        # the iterations executed: 3 token blocks of 8 walk 1, 2 and 3
-        # pages of 8 positions; the bucket's fourth block walks none
-        assert engine._bucket_len(n) // tb == 4
-        assert stats["ragged_page_slots_total"] == 1 + 2 + 3
+        # the 32-token bucket is ONE token block (two query heads share a KV
+        # head: the block could hold 64 tokens): it copies the prompt's 3
+        # pages of 8 positions once, in one KV step of 16 page places
+        assert (engine._bucket_len(n), engine._tb_for(32)) == (32, 32)
+        want = recount(engine, [(0, 0, n)], 32)
+        assert want == {
+            "ragged_live_pages_total": 3, "ragged_kv_steps_total": 1,
+            "ragged_page_slots_total": 16, "ragged_token_blocks_total": 1,
+            "ragged_live_rows_total": n,
+        }
+        assert {k: stats[k] for k in want} == want
         cost = engine.utilization.cost
         assert stats["ragged_attn_flops_total"] == cost.attn_flops(n * (n + 1) // 2)
-        assert stats["ragged_kv_read_bytes_total"] == (
-            stats["ragged_live_pages_total"] * bs * cost.kv_bytes_per_token)
+        assert stats["ragged_kv_read_bytes_total"] == 3 * bs * cost.kv_bytes_per_token
         # two decode windows followed, each over whole pages of the context
         ctxs = [n + 1, n + 2]
         assert stats["decode_attn_flops_total"] == cost.attn_flops(sum(ctxs))
@@ -345,16 +407,18 @@ async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
         engine.stop()
 
 
-async def test_a_mixed_windows_page_iterations_are_its_live_pages():
+async def test_a_mixed_windows_kv_steps_and_their_fill():
     """A prompt admitted beside a running decode (one unified window with a
-    span and a packed decode lane): the iterations the kernel executed, by
-    hand, and the share of them that walked a live page."""
+    span and a packed decode lane), at a small token block so that several
+    blocks and partly filled steps occur: every kernel-work total equals
+    pack_spans' recount of the two windows, and the live-page share reads
+    the fill of the KV steps executed."""
     import asyncio
 
     engine = make_engine(attention_impl="pallas_interpret", block_size=8, num_blocks=64,
-                         decode_overlap=False)
+                         decode_overlap=False, prefill_buckets=(24, 40))
     try:
-        tb, bs = engine._unified_tb, engine.config.block_size
+        assert (engine._tb_for(24), engine._tb_for(40)) == (8, 8)
         first = asyncio.ensure_future(collect(
             engine, request(list(range(3, 3 + 12)), max_tokens=24, ignore_eos=True)))
         while engine.stats()["decode_steps_total"] < 2:
@@ -366,13 +430,19 @@ async def test_a_mixed_windows_page_iterations_are_its_live_pages():
         # window 1: the 12-token prompt alone.  window 2: the decode lane's
         # token at context c (its pages: ceil(c / 8)) packed in front of
         # the 19-token prompt, so the blocks of 8 hold [decode + 7], [8], [4]
+        alone = recount(engine, [(0, 0, 12)], 24)
+        mixed = [recount(engine, [(0, c - 1, c), (1, 0, 19)], 24) for c in range(13, 13 + 24)]
+        got = {k: stats[k] - alone[k] for k in alone}
+        assert got in mixed, (got, mixed)
+        # [decode + 7] copies the decode lane's 2-5 pages and the prompt's
+        # first, [8] two, [4] three: four KV steps of 16 places each
+        assert got["ragged_kv_steps_total"] == 4
+        assert got["ragged_token_blocks_total"] == 3 and got["ragged_live_rows_total"] == 20
         live, slots = stats["ragged_live_pages_total"], stats["ragged_page_slots_total"]
-        first_window = hand_counted([(0, 0, 12)], tb, bs)
-        mixed = {hand_counted([(0, c - 1, c), (1, 0, 19)], tb, bs) for c in range(13, 13 + 24)}
-        assert live - first_window in mixed, (live, first_window, sorted(mixed))
-        assert slots == live and live / slots >= 0.8
+        assert slots == 16 * stats["ragged_kv_steps_total"]
+        assert 0 < live / slots < 1
         assert stats["ragged_kv_read_bytes_total"] == (
-            live * bs * engine.utilization.cost.kv_bytes_per_token)
+            live * 8 * engine.utilization.cost.kv_bytes_per_token)
     finally:
         engine.stop()
 

@@ -9,6 +9,8 @@ process may load the TPU library, and every xdist worker imports this file.
 Everything lives in this one file so one worker owns the library.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -23,7 +25,10 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_attention_decode,
     paged_window_attention_decode,
 )
-from dynamo_tpu.ops.pallas.ragged_attention import ragged_paged_attention
+from dynamo_tpu.ops.pallas.ragged_attention import (
+    default_tb_tokens,
+    ragged_paged_attention,
+)
 
 # head geometries: what chip_smoke.py serves on one chip (Llama-3.2-3B) and
 # the repo's headline (Llama-3-8B; also one tp=4 shard's KV width times 4)
@@ -34,13 +39,20 @@ NUM_BLOCKS = 1024
 MAX_LEN = 4096                      # chip_smoke.py --context-length
 MAX_BLOCKS = MAX_LEN // BLOCK
 LANES = 8                           # chip_smoke.py --max-batch-size
-# the engine's unified token block for that EngineConfig (tb = gcd(16, 8))
-# and every unified bucket it builds: the default prefill buckets up to max_len
-TB = 8
+# every unified bucket that EngineConfig builds: the default prefill buckets
+# up to max_len; the engine packs a bucket to blocks of gcd(largest token
+# block for the head geometry, bucket) tokens (engine._tb_for)
 SMOKE_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
+# the token block of the MLA kernel's cases (16 heads a token: 256 // 16)
+TB = 16
 # the benchmark's two serving shapes (BENCHMARK.json: both models are h32 kv8
-# d128): 8 lanes at the default token block, 16 lanes at the tuned one
-CELL_SHAPES = {"lanes8_tb8": (8, 8), "lanes16_tb4": (16, 4)}
+# d128: four query heads a KV head, blocks of up to 64 tokens): lanes
+CELL_SHAPES = {"lanes8": 8, "lanes16": 16}
+
+
+def _tb(model, bucket):
+    h, kvh, _ = GEOMETRY[model]
+    return math.gcd(default_tb_tokens(h // kvh, BLOCK), bucket)
 
 
 @pytest.fixture(scope="module")
@@ -92,9 +104,10 @@ def test_window_verify_compiles(one_chip, kv_dtype):
     ).compile()
 
 
-def _ragged_case(s, bucket, model, kv_dtype, lanes=LANES, tb=TB,
+def _ragged_case(s, bucket, model, kv_dtype, lanes=LANES, tb=None,
                  max_blocks=MAX_BLOCKS, sliding_window=None):
     h, kvh, d = GEOMETRY[model]
+    tb = tb or _tb(model, bucket)
     cache = _cache(s, kv_dtype, kvh, d)
     tok = s((bucket,), jnp.int32)
     return ragged_paged_attention.lower(
@@ -107,15 +120,18 @@ def _ragged_case(s, bucket, model, kv_dtype, lanes=LANES, tb=TB,
 
 @pytest.mark.parametrize("bucket", SMOKE_BUCKETS)
 def test_ragged_compiles_at_every_smoke_bucket(one_chip, bucket):
-    """Every (bucket, tb_tokens) the smoke's engine can build.  The scalar
-    memory the kernel needs is seven words a token and the block tables."""
+    """Every (bucket, tb_tokens) the smoke's engine can build (24 query
+    heads over 8: three a KV head, 192 score rows a product at 64 tokens).
+    The scalar memory the kernel needs is seven words a token and the block
+    tables."""
     _ragged_case(_sds(one_chip), bucket, "llama32_3b", "bf16").compile()
 
 
 @pytest.mark.parametrize("bucket", [SMOKE_BUCKETS[0], 528, SMOKE_BUCKETS[-1]])
 def test_ragged_fp8_compiles(one_chip, bucket):
-    """fp8 KV at the headline geometry: smallest, a chunk+lanes mixed bucket
-    (512 + 16 lanes), largest."""
+    """fp8 KV at the headline geometry (a step's pages go through a float32
+    copy, whose rows the per-head read can stride): smallest, a chunk+lanes
+    mixed bucket (512 + 16 lanes, packed to blocks of 16), largest."""
     _ragged_case(_sds(one_chip), bucket, "llama3_8b", "fp8").compile()
 
 
@@ -124,13 +140,14 @@ def test_ragged_fp8_compiles(one_chip, bucket):
 def test_ragged_compiles_at_every_bucket_of_the_benchmark_cells(
     one_chip, shape, kv_dtype
 ):
-    """The live-page kernel at every unified bucket of both benchmark
-    serving shapes (h32 kv8 d128, context 4,096), bf16 and fp8 KV, with
+    """The per-head KV step at every unified bucket of both benchmark
+    serving shapes (h32 kv8 d128, context 4,096: one block of 32 tokens for
+    the smallest bucket, blocks of 64 from there), bf16 and fp8 KV, with
     Mistral's sliding window on the 8-lane shape."""
-    lanes, tb = CELL_SHAPES[shape]
+    lanes = CELL_SHAPES[shape]
     for bucket in SMOKE_BUCKETS:
         _ragged_case(
-            _sds(one_chip), bucket, "llama3_8b", kv_dtype, lanes=lanes, tb=tb,
+            _sds(one_chip), bucket, "llama3_8b", kv_dtype, lanes=lanes,
             sliding_window=4096 if lanes == 8 else None,
         ).compile()
 
@@ -147,9 +164,10 @@ def test_ragged_compiles_at_a_context_of_32768(one_chip):
 @pytest.mark.parametrize("bucket,max_blocks", [(4096, 256), (32768, 2048)])
 def test_ragged_mla_compiles_at_deepseek_v2_lite_widths(one_chip, bucket, max_blocks):
     """The ragged MLA kernel at DeepSeek-V2-Lite's published widths (16
-    heads, latent 512, rope 64), a whole window of the bucket: the static
-    worklists ran out of scalar memory from bucket 512 up, and the 64-wide
-    rope page needs its lanes padded to be copied whole."""
+    heads, latent 512, rope 64), a whole window of the bucket, on the shared
+    walker's KV steps of 16 pages: the static worklists ran out of scalar
+    memory from bucket 512 up, and the 64-wide rope page needs its lanes
+    padded to be copied whole."""
     from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention
 
     s = _sds(one_chip)
@@ -191,25 +209,25 @@ STEP_CONFIGS = {
         num_layers=36, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e6, rms_norm_eps=1e-6,
         tie_word_embeddings=True, qk_norm=True,
-    ), 1152, 16, 4),
+    ), 1152, 16),
     "mistral-7b-l16": ("mistral", LlamaConfig(
         vocab_size=32000, hidden_size=4096, intermediate_size=14336,
         num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e4, sliding_window=4096,
-    ), 2560, 8, 8),
+    ), 2560, 8),
     "mixtral-8x7b-l4": ("mixtral", MixtralConfig(
         vocab_size=32000, hidden_size=4096, intermediate_size=14336,
         num_layers=4, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e6,
         num_experts=8, experts_per_token=2,
-    ), 5120, 8, 8),
+    ), 5120, 8),
 }
 
 
 def _compile_forward(one_chip, config, program):
     """A family's step forward at a cell's shapes, cache donated, as a
     compiled executable for the described chip."""
-    name, cfg, num_blocks, lanes, tb = STEP_CONFIGS[config]
+    name, cfg, num_blocks, lanes = STEP_CONFIGS[config]
     family = get_family(name)
     s = _sds(one_chip)
     i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
@@ -228,6 +246,8 @@ def _compile_forward(one_chip, config, program):
         args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
     else:
         t = int(program.removeprefix("unified_t"))
+        tb = math.gcd(
+            default_tb_tokens(cfg.num_heads // cfg.num_kv_heads, BLOCK), t)
         def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
             return family.forward_unified(
                 p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
@@ -330,6 +350,6 @@ def test_engine_constructs_at_a_context_the_worklists_refused():
     ))
     assert engine.unified_batch
     assert engine.stats()["kernel_config"] == {
-        "tb_tokens": 8, "source": "default",
+        "tb_tokens": 64, "source": "default",
         "geometry": engine.stats()["kernel_config"]["geometry"],
     }

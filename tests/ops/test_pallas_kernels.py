@@ -426,6 +426,7 @@ def test_pack_spans_lists_each_lane_once_per_block():
     token_pos = np.asarray([9, 0, 17, -1, -1, -1, -1, -1], np.int32)
     lane, first, count, total = pack_spans(
         token_lane, token_pos, lanes=3, tb_tokens=4, block_size=8,
+        pages_per_step=1,
     )
     # block 0: lane 0 sees pages 0..2 (pos 9 and 17), lane 1 page 0; the
     # out-of-range lane 3 is padding
@@ -433,11 +434,18 @@ def test_pack_spans_lists_each_lane_once_per_block():
     assert first.tolist() == [0, 0, 0, 0, 0, 0, 0, 0]
     assert count.tolist() == [3, 1, 0, 0, 0, 0, 0, 0]
     assert total.tolist() == [4, 0]
+    # KV steps of two pages: lane 0's three pages take two, lane 1's one;
+    # at the kernels' own width (16 pages of 8 positions) one step a span
+    for pages, steps in ((2, [3, 0]), (None, [2, 0])):
+        assert pack_spans(
+            token_lane, token_pos, lanes=3, tb_tokens=4, block_size=8,
+            pages_per_step=pages,
+        )[3].tolist() == steps
     # a window of 8 positions: lane 0's lowest token (pos 9) sees from
     # position 2, so its span still starts at page 0; a window of 2 drops it
     _, first, count, total = pack_spans(
         token_lane, token_pos, lanes=3, tb_tokens=4, block_size=8,
-        sliding_window=2,
+        sliding_window=2, pages_per_step=1,
     )
     assert (first[:2].tolist(), count[:2].tolist()) == ([1, 0], [2, 1])
     assert total.tolist() == [3, 0]
@@ -446,7 +454,7 @@ def test_pack_spans_lists_each_lane_once_per_block():
                    block_size=8)
 
 
-def _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window):
+def _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window, pages=1):
     """pack_spans as the plain loop it replaced (per block a dict by lane,
     insertion-ordered): the reference for the vectorised packer."""
     t_pad = len(token_lane)
@@ -467,15 +475,17 @@ def _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window):
             lane_o[t * tb + s] = lane
             first_o[t * tb + s] = first
             count_o[t * tb + s] = hi // bs + 1 - first
-            total[t] += hi // bs + 1 - first
+            total[t] += -(-(hi // bs + 1 - first) // pages)
     return lane_o, first_o, count_o, total
 
 
+@pytest.mark.parametrize("pages", [1, 3])
 @pytest.mark.parametrize("window", [None, 5, 40])
-@pytest.mark.parametrize("tb", [1, 4, 8])
-def test_pack_spans_equals_the_plain_loop(tb, window):
+@pytest.mark.parametrize("tb", [1, 4, 8, 32])
+def test_pack_spans_equals_the_plain_loop(tb, window, pages):
     """Random windows — interleaved lanes, holes, pad rows, out-of-range
-    lanes — pack to exactly what the loop packs."""
+    lanes — pack to exactly what the loop packs, in KV steps of one page
+    and of three."""
     from dynamo_tpu.ops.pallas import pack_spans
 
     rng = np.random.default_rng(tb * 100 + (window or 0))
@@ -484,8 +494,10 @@ def test_pack_spans_equals_the_plain_loop(tb, window):
         token_lane = rng.integers(-1, lanes + 2, t_pad).astype(np.int32)
         token_pos = rng.integers(-1, 70, t_pad).astype(np.int32)
         got = pack_spans(token_lane, token_pos, lanes=lanes, tb_tokens=tb,
-                         block_size=bs, sliding_window=window)
-        want = _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window)
+                         block_size=bs, sliding_window=window,
+                         pages_per_step=pages)
+        want = _pack_spans_loop(token_lane, token_pos, lanes, tb, bs, window,
+                                pages)
         for g, w in zip(got, want):
             assert g.dtype == np.int32 and g.tolist() == w.tolist()
 
@@ -703,14 +715,20 @@ def test_ragged_mla_attention_fp8_cache():
 
 
 # The benchmark cells' attention geometry (Qwen3-4B, Mistral-7B: 32 query
-# heads over 8 KV heads of 128, pages of 16) at both token-block sizes the
-# engine resolves there (8 lanes: gcd(16, 8); 16 lanes: the tuned 4).
+# heads over 8 KV heads of 128, pages of 16) at the token blocks the engine
+# packs there (64 tokens; 32 for the 32-token bucket) and at the small ones
+# PR 28's kernel ran (8, 4).
 CELL_WINDOWS = {
     # name: (spans [(lane, start, len)], t_pad, sliding_window, cache dtype)
     "decode_lanes_only": ([(i, 11 + 29 * i, 1) for i in range(8)], None, None, None),
     "one_long_span": ([(2, 0, 150)], None, None, None),
     "span_beside_8_packed_decodes": (
         [(i, 20 + 27 * i, 1) for i in range(8)] + [(8, 64, 40)], None, None, None,
+    ),
+    # 23 pages under the span's last token: two KV steps of 16 pages, the
+    # second partly filled, and decode lanes of 18 and 2 pages beside it
+    "span_across_kv_steps_beside_decodes": (
+        [(0, 280, 1), (1, 20, 1), (8, 300, 60)], None, None, None,
     ),
     "lane_holes_and_padding_rows": ([(0, 36, 1), (3, 90, 1), (6, 17, 21)], 64, None, None),
     "sliding_window_that_cuts": (
@@ -720,46 +738,92 @@ CELL_WINDOWS = {
 }
 
 
-@pytest.mark.parametrize("tb", [4, 8])
-@pytest.mark.parametrize("window", sorted(CELL_WINDOWS))
-def test_ragged_attention_cell_geometry_matches_twin(window, tb):
-    """The live-page kernel against the XLA twin at h32 kv8 d128, block 16:
-    every live row agrees, pad rows are finite, and the kernel's page
-    iterations are exactly the pages the window's lanes can see."""
+def _cell_window(window, tb, q_dtype, h=32, kvh=8):
+    """Kernel and XLA twin over one of CELL_WINDOWS at d128, block 16;
+    returns (kernel out, twin out over float32 copies of the same operands,
+    live-row mask, the routing arguments)."""
     from dynamo_tpu.ops.attention import ragged_paged_attention as ragged_ref
     from dynamo_tpu.ops.pallas import ragged_paged_attention as ragged_kernel
 
-    h, kvh, d, bs, lanes, maxb, nblocks = 32, 8, 128, 16, 9, 16, 40
+    d, bs, lanes, maxb, nblocks = 128, 16, 9, 24, 40
     spans, t_pad, sw, cache_dtype = CELL_WINDOWS[window]
     rng = np.random.default_rng(17)
-    k_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), jnp.float32)
-    v_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), jnp.float32)
+    k_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), q_dtype)
+    v_cache = jnp.asarray(rng.standard_normal((nblocks, bs, kvh, d)), q_dtype)
     if cache_dtype is not None:
         k_cache, v_cache = k_cache.astype(cache_dtype), v_cache.astype(cache_dtype)
     tables = jnp.asarray(rng.integers(0, nblocks, (lanes, maxb)), jnp.int32)
     token_lane, token_pos, ctx = ragged_meta(spans, lanes, tb=tb, t_pad=t_pad)
-    q = jnp.asarray(
-        rng.standard_normal((token_lane.shape[0], h, d)), jnp.float32
-    )
+    q = jnp.asarray(rng.standard_normal((token_lane.shape[0], h, d)), q_dtype)
     args = span_args(token_lane, token_pos, tables, tb, bs, sw)
-    out = np.asarray(ragged_kernel(
+    out = ragged_kernel(
         q, k_cache, v_cache, *args, tb_tokens=tb, interpret=True,
         sliding_window=sw,
-    ))
-    ref = np.asarray(ragged_ref(
-        q, k_cache, v_cache, tables, ctx, token_lane, token_pos,
+    )
+    assert out.dtype == q_dtype
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    ref = ragged_ref(
+        f32(q), f32(k_cache), f32(v_cache), tables, ctx, token_lane, token_pos,
         sliding_window=sw,
-    ))
-    valid = np.asarray(token_pos) >= 0
+    )
+    return np.asarray(f32(out)), np.asarray(ref), np.asarray(token_pos) >= 0, args
+
+
+@pytest.mark.parametrize("tb", [4, 8, 32, 64])
+@pytest.mark.parametrize("window", sorted(CELL_WINDOWS))
+def test_ragged_attention_cell_geometry_matches_twin(window, tb):
+    """The kernel against the XLA twin at h32 kv8 d128, block 16, float32
+    operands (fp8 pages widen exactly): every live row agrees to float32's
+    rounding, pad rows are finite, the pages it copies are exactly the pages
+    the window's lanes can see, and each span's pages take ceil(pages / 16)
+    KV steps."""
+    out, ref, valid, args = _cell_window(window, tb, jnp.float32)
     np.testing.assert_allclose(out[valid], ref[valid], rtol=2e-5, atol=2e-5)
     assert np.isfinite(out).all()
     # hand count: per token block, per lane in it, the pages from the
     # window's floor under its lowest token to its highest token's page
-    tl, tp = np.asarray(token_lane), np.asarray(token_pos)
-    want = 0
+    _, _, sw, _ = CELL_WINDOWS[window]
+    tl, tp = np.asarray(args[0]), np.asarray(args[1])
+    want = steps = 0
     for t in range(0, len(tl), tb):
         for lane in {int(x) for x, p in zip(tl[t:t + tb], tp[t:t + tb]) if p >= 0}:
             pos = [p for x, p in zip(tl[t:t + tb], tp[t:t + tb]) if x == lane and p >= 0]
-            lo = 0 if sw is None else max(0, min(pos) - sw + 1) // bs
-            want += max(pos) // bs + 1 - lo
-    assert int(np.asarray(args[-1]).sum()) == want
+            lo = 0 if sw is None else max(0, min(pos) - sw + 1) // 16
+            want += max(pos) // 16 + 1 - lo
+            steps += -(-(max(pos) // 16 + 1 - lo) // 16)
+    assert int(np.asarray(args[-2]).sum()) == want
+    assert int(np.asarray(args[-1]).sum()) == steps
+
+
+# bfloat16 operands, as every step program hands them over: the products are
+# the twin's (a bf16 x bf16 product is exact in float32), so what differs is
+# the one rounding the kernel adds (the probabilities to bf16 before P.V, a
+# relative 2^-9 on each, against sum(p |v|) <= max |v| < 4.6 here) and the
+# output's own rounding to bf16 (half an ulp of 2^-6 under 4): 0.009 + 0.008
+BF16_ATOL = 0.02
+
+
+@pytest.mark.parametrize("tb", [32, 64])
+@pytest.mark.parametrize("window", sorted(CELL_WINDOWS))
+def test_ragged_attention_cell_geometry_in_bfloat16(window, tb):
+    """The same windows with bfloat16 queries (and pages, but the fp8 case):
+    the per-head rows are read out of bf16 pages as uint32 words, and the
+    MXU is fed bf16."""
+    out, ref, valid, _ = _cell_window(window, tb, jnp.bfloat16)
+    np.testing.assert_allclose(out[valid], ref[valid], rtol=0, atol=BF16_ATOL)
+    assert np.isfinite(out).all()
+    # the rounding is there: further than float32's 2e-5 from the twin
+    assert np.abs(out[valid] - ref[valid]).max() > 2e-4
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("h,kvh", [(8, 8), (16, 2), (3, 1), (9, 3)])
+def test_ragged_attention_group_sizes(h, kvh, q_dtype):
+    """No grouping (G = 1), eight query heads a KV head (G = 8), one KV
+    head (no strided read at all) and an odd count of them (bf16 pages that
+    cannot be read as uint32 pairs go through a float32 copy)."""
+    out, ref, valid, _ = _cell_window(
+        "span_beside_8_packed_decodes", 32, q_dtype, h=h, kvh=kvh)
+    atol = 2e-5 if q_dtype == jnp.float32 else BF16_ATOL
+    np.testing.assert_allclose(out[valid], ref[valid], rtol=0, atol=atol)
+    assert np.isfinite(out).all()
