@@ -39,6 +39,7 @@ import numpy as np
 from dynamo_tpu.engine.kv_manager import (
     BlockAllocator,
     KvEvent,
+    WindowPool,
     compute_block_hashes,
 )
 from dynamo_tpu.engine.scheduler import Scheduler
@@ -50,7 +51,7 @@ from dynamo_tpu.llm.protocols.common import (
     PreprocessedRequest,
     StopConditions,
 )
-from dynamo_tpu.models.llama import LlamaConfig
+from dynamo_tpu.models.llama import KvPools, LlamaConfig
 from dynamo_tpu.models.registry import get_family
 from dynamo_tpu.observability import FlightRecorder, StepTelemetry, get_recorder
 from dynamo_tpu.observability.perf import UtilizationTracker, model_cost
@@ -88,6 +89,22 @@ KERNEL_WORK_KEYS = (
     "ragged_kv_read_bytes_total",   # pages copied x page bytes, all layers
     "decode_attn_flops_total",
     "decode_kv_read_bytes_total",
+    # window layers (a model with a window pool; 0 otherwise): pages their
+    # launches visit, and pages the same launches would visit were the
+    # layers full (ragged spans and decode walks alike, one layer's count)
+    "window_pages_visited_total",
+    "window_pages_full_total",
+)
+
+# The expert layers' counters (a routed model; absent otherwise): what the
+# step programs add up on the device (ops/moe.py MOE_STATS), taken by the
+# device thread between steps and summed here, then priced in stats().
+MOE_STAT_KEYS = (
+    "moe_assignments_routed_total",
+    "moe_assignments_held_total",
+    "moe_experts_touched_total",
+    "moe_expert_rows_max_total",
+    "moe_expert_layers_total",
 )
 
 
@@ -456,6 +473,34 @@ class JaxLlmEngine:
                             "windows ring-shard the sequence evenly"
                         )
 
+        if self.mesh is not None and hasattr(cfg, "grouped_matmul"):
+            # the Pallas grouped matmul is one chip's; under a mesh the
+            # expert layer runs XLA's ragged dot, which GSPMD partitions
+            cfg = dataclasses.replace(cfg, grouped_matmul="xla")
+            config = self.config = dataclasses.replace(config, model=cfg)
+        # a model with window layers: their second pool (models/registry.py)
+        self._window_blocks = 0
+        if self.family.window_pool_blocks is not None:
+            if config.decode_steps > 1:
+                raise ValueError(
+                    "fused multi-step decode derives its slots from ONE block "
+                    f"table; family {config.model_family!r} has a window pool"
+                )
+            self._window_blocks = int(self.family.window_pool_blocks(
+                cfg, config.max_batch_size, self.max_len, config.block_size
+            ))
+            if config.enable_prefix_caching:
+                config = self.config = dataclasses.replace(
+                    config, enable_prefix_caching=False
+                )
+            logger.info(
+                "family %r: prefix caching off (a window layer's prefix is "
+                "gone once a sequence has passed it); window pool %d blocks "
+                "x %d window layers beside %d blocks x %d full layers",
+                config.model_family, self._window_blocks, cfg.window_layers,
+                config.num_blocks, cfg.full_layers,
+            )
+
         if config.attention_impl == "auto":
             mesh_ok = self.mesh is None or (
                 self.family.decode_accepts_tp_mesh
@@ -495,6 +540,7 @@ class JaxLlmEngine:
             raw_cache = self.family.cache_init(
                 cfg, config.num_blocks, config.block_size,
                 resolve_kv_cache_dtype(config.kv_cache_dtype),
+                **({"window_blocks": self._window_blocks} if self._window_blocks else {}),
             )
             cos, sin = self.family.rope_tables(cfg)
             # families build tables out to max_position_embeddings (131k for
@@ -566,6 +612,12 @@ class JaxLlmEngine:
             name: [0.0, 0] for name in STEP_PHASES
         }
         self._sliding_window = getattr(cfg, "sliding_window", None)
+        # expert-layer counters: device leaves taken out of the cache, not
+        # yet read (device thread only), and their sum (stats() reads it)
+        self._moe_pending: list = []
+        self._moe_totals = [0] * len(MOE_STAT_KEYS)
+        self._moe_zero = None
+        self._moe_taken_at = 0.0
         self._phase_name: str | None = None   # the open phase
         self._phase_t0 = 0.0
         self._phase_ann = None
@@ -767,6 +819,11 @@ class JaxLlmEngine:
         self._bt_host = np.zeros((lanes_n, self.max_blocks_per_seq), np.int32)
         self._bt_lane_key: list = [None] * lanes_n
         self._bt_dev = None
+        # the window pool's tables, kept the same way (a model without the
+        # pool never touches them)
+        self._wbt_host = np.zeros_like(self._bt_host)
+        self._wbt_lane_key: list = [None] * lanes_n
+        self._wbt_dev = None
         # overlap windows carry no guided lanes (they fall back to sync):
         # one resident all-unguided mode row, uploaded once
         self._gmodes_unguided = None
@@ -843,6 +900,9 @@ class JaxLlmEngine:
             config.num_blocks, config.block_size, event_sink=self._sink_event,
             enable_prefix_caching=self.prefix_caching,
             offload_sink=offload_sink, host_tier=self.host_tier,
+            window_pool=WindowPool(
+                self._window_blocks, config.block_size, cfg.window
+            ) if self._window_blocks else None,
         )
         # predictive prefetch: pager + HBM headroom reservation (only with
         # an offload tier mounted — with nothing below HBM there is nothing
@@ -950,6 +1010,19 @@ class JaxLlmEngine:
             lambda counts, lane, row: counts.at[lane].set(row),
             donate_argnums=(0,), **set_row_kwargs,
         )
+
+    @property
+    def _attn_layers(self) -> tuple[int, int, int | None]:
+        """Layers by attention kind, for the kernels' work counters: (full
+        layers, window layers, the window layers' window).  A llama-like
+        model's layers are all of its one kind."""
+        cfg = self.config.model
+        if self._window_blocks:
+            return cfg.full_layers, cfg.window_layers, cfg.window
+        layers = int(getattr(cfg, "num_layers", 0) or 1)
+        if self._sliding_window is None:
+            return layers, 0, None
+        return 0, layers, self._sliding_window
 
     @staticmethod
     def _to_default_device(x):
@@ -1726,6 +1799,7 @@ class JaxLlmEngine:
         TAIL blocks past the streamed watermark (``n_blocks`` stays the
         sequence total).  Requires chunked prefill to fire; without it the
         call degenerates to the single-shot contract."""
+        self.allocator.single_pool_only("KV extraction for a decode worker")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         seq = Sequence(
@@ -2072,7 +2146,9 @@ class JaxLlmEngine:
                     sds((n, kb), jnp.float32))
 
         jobs: dict[tuple, tuple] = {}  # dedup key -> (jit_fn, avals)
-        blocks_fixed = sds((self.max_blocks_per_seq,), jnp.int32)
+        # a value a pool where the model has a window pool (``KvPools``)
+        pools = (lambda a: KvPools(a, a)) if self._window_blocks else (lambda a: a)
+        blocks_fixed = pools(sds((self.max_blocks_per_seq,), jnp.int32))
         for n in prompt_lens:
             n = min(int(n), self.max_len - 1)
             if self.chunk_tokens is not None:
@@ -2112,7 +2188,7 @@ class JaxLlmEngine:
                      sds((b,), jnp.int32), blocks_fixed, i32, i32, row_a,
                      key_a, *tail(1), grow_a, cos_a, sin_a),
                 )
-        tables_a = sds((lanes, self.max_blocks_per_seq), jnp.int32)
+        tables_a = pools(sds((lanes, self.max_blocks_per_seq), jnp.int32))
         lanes_i = sds((lanes,), jnp.int32)
         if cfg.decode_steps > 1:
             jobs[("decode",)] = (
@@ -2157,8 +2233,8 @@ class JaxLlmEngine:
                     self._jit_unified,
                     (params_a, cache_a, counts_a, counts_a, tok_a, lanes_i,
                      sds((b,), jnp.bool_), tables_a, lanes_i, tok_a, tok_a,
-                     tok_a, tok_a, tok_a, tok_a,
-                     sds((ntb,), jnp.int32), lanes_i, lanes_i,
+                     tok_a, pools(tok_a), pools(tok_a), pools(tok_a),
+                     pools(sds((ntb,), jnp.int32)), lanes_i, lanes_i,
                      sds((nseed,), jnp.int32), sds((nseed, vocab), jnp.int32),
                      sds((nseed, vocab), jnp.int32), keys_a, *tail(lanes),
                      cos_a, sin_a),
@@ -2269,6 +2345,13 @@ class JaxLlmEngine:
         if self.prefetch_pager is not None:
             out.update(self.prefetch_pager.stats())
         out.update(self._kernel_work)
+        wp = self.allocator.window_pool
+        if wp is not None:
+            out["window_pool_blocks_in_use"] = wp.used_blocks
+            out["window_pool_blocks_total"] = wp.num_blocks
+            out["window_blocks_released_total"] = wp.released_behind_total
+        if "moe_stats" in self.cache:
+            out.update(self._moe_stats())
         # snapshot: the device thread inserts keys (prefetch.page) and
         # updates the pairs concurrently
         phases = {k: tuple(v) for k, v in list(self.phase_stats.items())}
@@ -2281,6 +2364,24 @@ class JaxLlmEngine:
         # max / duration histogram per component and name): recorder.py
         out["spans"] = get_recorder().aggregate()
         return out
+
+    def _moe_stats(self) -> dict:
+        """The expert layers' counters as taken so far (a step or two
+        behind the device) and the grouped products' work they imply: 2 x 3
+        x hidden x expert width operations a row held; bytes = the banks of
+        the experts TOUCHED (each read once a layer and step) and every row
+        in and out of the three products."""
+        cfg = self.config.model
+        totals = dict(zip(MOE_STAT_KEYS, self._moe_totals))
+        h, mi = cfg.hidden_size, cfg.moe_intermediate_size
+        rows = totals["moe_assignments_held_total"]
+        item = jnp.dtype(cfg.dtype).itemsize
+        totals["moe_gmm_flops_total"] = 2 * 3 * h * mi * rows
+        totals["moe_gmm_bytes_total"] = item * (
+            totals["moe_experts_touched_total"] * 3 * h * mi
+            + rows * 3 * (h + mi)
+        )
+        return totals
 
     # -- device thread -----------------------------------------------------
     def _device_loop(self) -> None:
@@ -2734,11 +2835,18 @@ class JaxLlmEngine:
                 token_lane, token_pos, lanes=lanes, tb_tokens=tb,
                 block_size=bs, sliding_window=self._sliding_window,
             )
+            if self._window_blocks:
+                # the window layers walk their own spans: the same lanes,
+                # from the page their window starts in
+                window_meta = pack_spans(
+                    token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                    block_size=bs, sliding_window=self._attn_layers[2],
+                )
         else:
             # the XLA twin routes per token off token_lane/token_pos and
             # never reads the spans: ship fixed-shape zeros
             flat = np.zeros((bucket,), np.int32)
-            page_meta = (
+            page_meta = window_meta = (
                 flat, flat, flat, np.zeros((bucket // tb,), np.int32)
             )
         self._phase("upload")
@@ -2762,11 +2870,16 @@ class JaxLlmEngine:
                     jnp.zeros((nseed, vocab), jnp.int32),
                 )
             seed_args = self._seed_none
+        page_args = [jnp.asarray(a) for a in page_meta]
+        if self._window_blocks:
+            page_args = [
+                KvPools(a, jnp.asarray(b)) for a, b in zip(page_args, window_meta)
+            ]
         args = (
             jnp.asarray(token_ids), feedback_in, jnp.asarray(use_fb),
             tables, jnp.asarray(context_lens), jnp.asarray(token_pos),
             jnp.asarray(token_slot), jnp.asarray(token_lane),
-            *(jnp.asarray(a) for a in page_meta),
+            *page_args,
             jnp.asarray(sample_rows), jnp.asarray(sample_gate),
             *seed_args,
         )
@@ -2781,6 +2894,10 @@ class JaxLlmEngine:
         )
         self._phase("post")
         self._step_dispatched_kind = KIND_PROMPT
+        self._after_dispatch(
+            [(s, int(context_lens[s.lane])) for s in decodes]
+            + [(s, end) for s, _, end in spans]
+        )
 
         # host bookkeeping (device-ordered: any later program — including
         # another engine's extract over published blocks — sees the writes)
@@ -2801,10 +2918,12 @@ class JaxLlmEngine:
                 self.allocator.publish_stored(seq.seq_id, all_tokens[:end])
             opened.append(self._open_prefill_span(seq, t_prefill))
         self._step_decode_tokens += len(decodes)
-        w = self._sliding_window
+        n_full, n_window, w = self._attn_layers
+        full_ctx = sum(self._attended_ctx(a, b)[0] for _, a, b in spans)
         for s in decodes:
             ctx = int(context_lens[s.lane])
             self._step_attn_ctx += ctx
+            full_ctx += ctx
             ragged_ctx += ctx if w is None else min(ctx, w)
         self._step_weight_streams += 1
         self._unified_windows += 1
@@ -2826,10 +2945,21 @@ class JaxLlmEngine:
             work["ragged_live_rows_total"] += int(
                 (np.repeat(launched, tb) & (token_pos >= 0)).sum()
             )
-            work["ragged_attn_flops_total"] += cost.attn_flops(ragged_ctx)
+            # a layer's products over the context IT attends (a window
+            # layer's in-window work only) and the pages IT copies
+            layers_n = n_full + n_window
+            window_pages = int(window_meta[2].sum()) if self._window_blocks else live_pages
+            work["ragged_attn_flops_total"] += (
+                cost.attn_flops(full_ctx) * n_full
+                + cost.attn_flops(ragged_ctx) * n_window
+            ) // layers_n
             work["ragged_kv_read_bytes_total"] += (
-                live_pages * bs * cost.kv_bytes_per_token
+                (live_pages * n_full + window_pages * n_window)
+                * bs * cost.kv_bytes_per_token // layers_n
             )
+            if self._window_blocks:
+                work["window_pages_visited_total"] += window_pages
+                work["window_pages_full_total"] += live_pages
 
         if not overlap:
             _, opened = self._take_unwaited(KIND_PROMPT, opened)
@@ -3503,15 +3633,22 @@ class JaxLlmEngine:
             padded[:end] = tokens[:end]
             block_ids = np.zeros((self.max_blocks_per_seq,), np.int32)
             block_ids[: len(blocks)] = blocks
+            block_ids = jnp.asarray(block_ids)
+            if self._window_blocks:
+                window_ids = np.zeros((self.max_blocks_per_seq,), np.int32)
+                wblocks = self.allocator.window_block_ids(seq.seq_id)
+                window_ids[: len(wblocks)] = wblocks
+                block_ids = KvPools(block_ids, jnp.asarray(window_ids))
             self._phase("dispatch", kind=KIND_PROMPT, tokens=end)
             token, lp, tkv, tki, self.cache, self._gen_counts, self._prompt_counts = self._jit_prefill(
                 self.params, self.cache, self._gen_counts, self._prompt_counts,
-                jnp.int32(lane), jnp.asarray(padded), jnp.asarray(block_ids),
+                jnp.int32(lane), jnp.asarray(padded), block_ids,
                 jnp.int32(end), jnp.int32(0), jnp.asarray(gen_row), jnp.asarray(key),
                 *sampling_tail, self._guided_row(seq), self.cos, self.sin,
             )
         self._phase("post")
         self._step_dispatched_kind = KIND_PROMPT
+        self._after_dispatch([(seq, end)])
         seq.prefilled_tokens = end
         # utilization accounting: this window computed [start, end) — each
         # position p attends p+1 context positions (causal)
@@ -3839,6 +3976,7 @@ class JaxLlmEngine:
                 *args, self.cos, self.sin,
             )
         self._phase("post")
+        self._after_dispatch([(s, int(context_lens[s.lane])) for s in active])
         kind, prefills = self._take_unwaited(KIND_DECODE)
         self._step_dispatched_kind = kind
         # start the device→host copies now; by the time this window is
@@ -3882,27 +4020,80 @@ class JaxLlmEngine:
         reads nothing."""
         dirty = self._bt_dev is None
         for seq in active:
-            lane = seq.lane
-            blocks = self.allocator.block_ids(seq.seq_id)
-            key = self._bt_lane_key[lane]
-            if key is not None and key[0] == seq.seq_id and key[1] == blocks:
-                continue
-            row = self._bt_host[lane]
-            n = len(blocks)
-            row[:n] = blocks
-            row[n:] = 0
-            self._bt_lane_key[lane] = (seq.seq_id, blocks)
-            dirty = True
+            dirty |= self._table_row(
+                self._bt_host, self._bt_lane_key, seq,
+                self.allocator.block_ids(seq.seq_id),
+            )
         if dirty:
             self._bt_dev = jnp.asarray(self._bt_host)
-        return self._bt_dev
+        if not self._window_blocks:
+            return self._bt_dev
+        # the window pool's tables beside them: ordinals as in the full
+        # pool's, entries behind a lane's window stale (nobody reads them)
+        dirty = self._wbt_dev is None
+        for seq in active:
+            dirty |= self._table_row(
+                self._wbt_host, self._wbt_lane_key, seq,
+                self.allocator.window_block_ids(seq.seq_id),
+            )
+        if dirty:
+            # a copy: the host rows are rewritten (entries behind a window
+            # zeroed) while a step that reads this upload may be in flight,
+            # and an upload may alias or still be reading its source
+            self._wbt_dev = jnp.asarray(self._wbt_host.copy())
+        return KvPools(self._bt_dev, self._wbt_dev)
+
+    @staticmethod
+    def _table_row(host, lane_keys, seq: Sequence, blocks: list[int]) -> bool:
+        """Write ``seq``'s block list into its lane's host row if it
+        changed; whether it did."""
+        lane = seq.lane
+        key = lane_keys[lane]
+        if key is not None and key[0] == seq.seq_id and key[1] == blocks:
+            return False
+        row = host[lane]
+        n = len(blocks)
+        row[:n] = blocks
+        row[n:] = 0
+        lane_keys[lane] = (seq.seq_id, blocks)
+        return True
+
+    def _after_dispatch(self, seqs_at: list[tuple[Sequence, int]]) -> None:
+        """Bookkeeping a model with a window pool or expert layers needs
+        once a step program is on its way: each sequence's window-pool
+        blocks behind the window of its NEXT query go back to the pool, and
+        the expert layers' counters leave the cache (the step's result, not
+        yet donated to the next) for a fresh zero leaf."""
+        if self._window_blocks:
+            for seq, next_pos in seqs_at:
+                self.allocator.release_behind_window(seq.seq_id, next_pos)
+        # ... every 50 ms, not every step: the leaf goes on counting on the
+        # device meanwhile (int32: hours at this model's rates, not 50 ms)
+        now = time.monotonic()
+        if "moe_stats" in self.cache and now - self._moe_taken_at >= 0.05:
+            self._moe_taken_at = now
+            taken = self.cache["moe_stats"]
+            taken.copy_to_host_async()
+            if self._moe_zero is None:
+                self._moe_zero = np.zeros(taken.shape, taken.dtype)
+            self.cache = {**self.cache, "moe_stats": jax.device_put(self._moe_zero)}
+            # earlier steps' leaves are read once their step has run: never
+            # waited for (the host is a step ahead of the device)
+            waiting = []
+            for leaf in self._moe_pending:
+                if not leaf.is_ready():
+                    waiting.append(leaf)
+                    continue
+                for i, n in enumerate(np.asarray(leaf).tolist()):
+                    self._moe_totals[i] += n
+            self._moe_pending = [*waiting, taken]
 
     def _attended_ctx(self, start: int, end: int) -> tuple[int, int]:
         """Context positions the tokens at ``[start, end)`` attend, causally
         (position p sees p + 1: what the cost model has always booked) and
         as the kernel sees them (the sliding window's width once past it)."""
         full = (end * (end + 1) - start * (start + 1)) // 2
-        w = self._sliding_window
+        w = self._attn_layers[2]
         if w is None or end <= w:
             return full, full
         a = max(start, w)   # first position whose view the window clips
@@ -3922,20 +4113,28 @@ class JaxLlmEngine:
         if not self.attention_impl.startswith("pallas"):
             return
         bs = self.config.block_size
-        w = self._sliding_window
+        n_full, n_window, w = self._attn_layers
+        ctx = context_lens.astype(np.int64)
+        full_pages = int(((ctx + (bs - 1)) // bs).sum())
         if w is None:
-            attended = ctx_sum
-            pages = int(((context_lens + (bs - 1)) // bs).sum())
+            attended, pages = ctx_sum, full_pages
         else:
-            ctx = context_lens.astype(np.int64)
             attended = int(np.minimum(ctx, w).sum())
             pages = int(((ctx + (bs - 1)) // bs - np.maximum(ctx - w, 0) // bs).sum())
         cost = self.utilization.cost
         work = self._kernel_work
-        work["decode_attn_flops_total"] += cost.attn_flops(attended * steps)
+        layers_n = n_full + n_window
+        work["decode_attn_flops_total"] += (
+            cost.attn_flops(ctx_sum * steps) * n_full
+            + cost.attn_flops(attended * steps) * n_window
+        ) // layers_n
         work["decode_kv_read_bytes_total"] += (
-            pages * steps * bs * cost.kv_bytes_per_token
+            (full_pages * n_full + pages * n_window)
+            * steps * bs * cost.kv_bytes_per_token // layers_n
         )
+        if self._window_blocks:
+            work["window_pages_visited_total"] += pages * steps
+            work["window_pages_full_total"] += full_pages * steps
 
     def _phase(self, name: str | None, **attrs) -> None:
         """Close the open host phase (book its wall time into
@@ -4041,6 +4240,7 @@ class JaxLlmEngine:
             tkv_host = np.asarray(tkvs) if want_top else None
             tki_host = np.asarray(tkis) if want_top else None
         self._phase("post")
+        self._after_dispatch([(s, int(context_lens[s.lane])) for s in active])
         self._step_dispatched_kind = KIND_DECODE
         self._note_wait(kind, prefills)
         self._sync_windows += 1

@@ -51,6 +51,80 @@ class SequenceBlocks:
     restore_plan: list[tuple[int, int]] = field(default_factory=list)
 
 
+class WindowPool:
+    """The second pool of a model with sliding-window layers: blocks of those
+    layers' keys and values, held only while a sequence's window still
+    touches them.
+
+    A sequence's table is indexed by the block's ORDINAL in the sequence
+    (block ``o`` holds positions ``o * block_size ...``), like the full
+    pool's, so that kernels and packers address both pools alike; entries
+    behind ``first_live`` are stale (0) and nobody reads them: a window
+    layer's page walk starts at the window.  No hashes, no reuse: a window
+    layer's prefix is gone once the sequence has passed it.
+
+    Not thread-safe on its own: ``BlockAllocator`` calls it under its lock.
+    """
+
+    def __init__(self, num_blocks: int, block_size: int, window: int):
+        self.num_blocks = num_blocks
+        self.block_size = block_size
+        self.window = window
+        self._free: deque[int] = deque(range(num_blocks))
+        self._tables: dict[str, list[int]] = {}
+        self._first_live: dict[str, int] = {}
+        self.released_behind_total = 0   # blocks given back behind a window
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free)
+
+    @property
+    def used_blocks(self) -> int:
+        return self.num_blocks - len(self._free)
+
+    def needed(self, seq_id: str, last_pos: int) -> int:
+        """Blocks still to take so that the table covers ``last_pos``."""
+        return last_pos // self.block_size + 1 - len(self._tables.get(seq_id, ()))
+
+    def cover(self, seq_id: str, last_pos: int) -> None:
+        """Grow the table through ``last_pos`` (the caller has checked
+        ``needed`` against ``free_blocks``)."""
+        table = self._tables.setdefault(seq_id, [])
+        self._first_live.setdefault(seq_id, 0)
+        for _ in range(self.needed(seq_id, last_pos)):
+            table.append(self._free.popleft())
+
+    def release_behind(self, seq_id: str, next_pos: int) -> None:
+        """Give back the blocks wholly behind the window of a query at
+        ``next_pos`` (it sees keys ``> next_pos - window``): no later query
+        of the sequence reads them."""
+        table = self._tables.get(seq_id)
+        if table is None:
+            return
+        first = self._first_live[seq_id]
+        keep_from = min(max(next_pos - self.window + 1, 0) // self.block_size, len(table))
+        for ordinal in range(first, keep_from):
+            self._free.append(table[ordinal])
+            table[ordinal] = 0
+        if keep_from > first:
+            self._first_live[seq_id] = keep_from
+            self.released_behind_total += keep_from - first
+
+    def free(self, seq_id: str) -> None:
+        table = self._tables.pop(seq_id, None)
+        if table is not None:
+            self._free.extend(table[self._first_live.pop(seq_id):])
+
+    def table(self, seq_id: str) -> list[int]:
+        return list(self._tables.get(seq_id, ()))
+
+    def held(self, seq_id: str) -> int:
+        """Blocks the sequence holds now."""
+        table = self._tables.get(seq_id)
+        return 0 if table is None else len(table) - self._first_live[seq_id]
+
+
 class BlockAllocator:
     """Free-list allocator over ``num_blocks`` fixed-size blocks with an
     LRU prefix-cache reuse tier.
@@ -74,6 +148,11 @@ class BlockAllocator:
         # into the host tier with pin-until-restore semantics
         offload_sink: Callable[[int, int], None] | None = None,
         host_tier=None,
+        # a model with sliding-window layers: their blocks come from this
+        # second pool, taken and given back with the sequence's own
+        # (allocate_sequence / append_slots / free_sequence take from both
+        # pools or from neither; ``release_behind_window`` trims it)
+        window_pool: WindowPool | None = None,
     ):
         # predictive prefetch (prefetch/pager.py): the pager is told when a
         # prefetched block is consumed by a real sequence (hit) or leaves
@@ -91,6 +170,12 @@ class BlockAllocator:
         self.enable_prefix_caching = enable_prefix_caching
         self.offload_sink = offload_sink
         self.host_tier = host_tier
+        self.window_pool = window_pool
+        if window_pool is not None and enable_prefix_caching:
+            raise ValueError(
+                "a window pool serves no prefix cache: a window layer's "
+                "prefix is gone once its sequence has passed it"
+            )
         # evictions collected per public call, offloaded in ONE batched
         # device read (the new owners don't write until the engine runs its
         # step functions, strictly after the mutator returns)
@@ -128,6 +213,9 @@ class BlockAllocator:
         return (num_tokens + self.block_size - 1) // self.block_size
 
     def can_allocate(self, num_tokens: int) -> bool:
+        wp = self.window_pool
+        if wp is not None and self.blocks_needed(num_tokens) > wp.free_blocks:
+            return False    # admitted by both pools or not at all
         return self.free_blocks - self.blocks_needed(num_tokens) >= self.watermark_blocks
 
     # -- block lifecycle helpers ------------------------------------------
@@ -247,7 +335,10 @@ class BlockAllocator:
             # matched block in the cached LRU must not be counted as allocatable
             for _, bid in device_hits:
                 self._incref(bid)
-            if needed > self.free_blocks:
+            wp = self.window_pool
+            if needed > self.free_blocks or (
+                wp is not None and wp.needed(seq_id, num_tokens - 1) > wp.free_blocks
+            ):
                 for _, bid in device_hits:  # roll back: nothing claimed on OOM
                     self._decref(bid)
                 for h in host_hits:
@@ -259,6 +350,10 @@ class BlockAllocator:
                 assert bid is not None  # guaranteed by the capacity check
                 self._ref[bid] = 1
                 fresh.append(bid)
+            if wp is not None:
+                # a prompt is served whole: every window block of it, until
+                # the step that computes it has been dispatched
+                wp.cover(seq_id, num_tokens - 1)
             self.flush_offloads()
             # matched blocks keep prompt order (device and host hits can
             # interleave); host hits take fresh blocks as restore landing zones.
@@ -312,6 +407,12 @@ class BlockAllocator:
             if max_pos is not None:
                 last_pos = min(last_pos, max_pos)
             needed = last_pos // self.block_size + 1 - len(seq.block_ids)
+            wp = self.window_pool
+            if wp is not None:
+                # the blocks the window has left behind pay for the new one
+                wp.release_behind(seq_id, pos)
+                if wp.needed(seq_id, last_pos) > wp.free_blocks:
+                    return None
             if needed > self.free_blocks:
                 return None
             for _ in range(needed):
@@ -319,12 +420,35 @@ class BlockAllocator:
                 assert bid is not None
                 self._ref[bid] = 1
                 seq.block_ids.append(bid)
+            if wp is not None:
+                wp.cover(seq_id, last_pos)
             self.flush_offloads()
             return seq.block_ids[pos // self.block_size] * self.block_size + pos % self.block_size
+
+    def release_behind_window(self, seq_id: str, next_pos: int) -> None:
+        """After a step that computed ``seq_id`` up to ``next_pos - 1`` has
+        been DISPATCHED: give back the window-pool blocks no later query of
+        it can see (device order keeps them intact for the step in flight:
+        whoever takes them writes in a later program)."""
+        if self.window_pool is not None:
+            with self._lock:
+                self.window_pool.release_behind(seq_id, next_pos)
+
+    def window_block_ids(self, seq_id: str) -> list[int]:
+        with self._lock:
+            return self.window_pool.table(seq_id)
+
+    def single_pool_only(self, what: str) -> None:
+        if self.window_pool is not None:
+            raise NotImplementedError(
+                f"{what} is not served for a model with a window pool: the "
+                "window layers' blocks are not transferable state"
+            )
 
     def adopt_sequence(self, seq_id: str, block_ids: list[int]) -> None:
         """Register blocks reserved earlier (disagg: reserved before remote
         prefill, adopted when the sequence starts decoding)."""
+        self.single_pool_only("adopting remotely prefilled blocks")
         with self._lock:
             self._sequences[seq_id] = SequenceBlocks(block_ids=list(block_ids))
 
@@ -335,6 +459,7 @@ class BlockAllocator:
         Called from the asyncio thread — evictions are NOT flushed here
         (the offload copy reads the device cache, which only the device
         thread may touch); the engine loop flushes them before any write."""
+        self.single_pool_only("reserving blocks for remotely prefilled KV")
         with self._lock:
             needed = self.blocks_needed(num_tokens)
             if needed > self.free_blocks:
@@ -486,6 +611,8 @@ class BlockAllocator:
                 if self.host_tier is not None:
                     self.host_tier.unpin(h)
             seq.restore_plan = []
+            if self.window_pool is not None:
+                self.window_pool.free(seq_id)
             if not self.enable_prefix_caching and seq.published_hashes:
                 # without the reuse registry the content is gone the moment
                 # the blocks free — routers must forget the stored hashes

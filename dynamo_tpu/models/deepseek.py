@@ -74,7 +74,6 @@ class DeepseekConfig:
     experts_per_token: int = 6
     n_shared_experts: int = 2
     routed_scaling_factor: float = 1.0
-    capacity_factor: float = 2.0
     # V3/R1 aux-free routing: sigmoid scores + e_score_correction_bias +
     # group-limited top-k; V2 uses plain renormalized softmax
     scoring_func: str = "softmax"     # "softmax" | "sigmoid"
@@ -165,7 +164,7 @@ class DeepseekConfig:
             q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
             qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
             first_k_dense=1, moe_intermediate_size=48, num_experts=4,
-            experts_per_token=2, n_shared_experts=1, capacity_factor=4.0,
+            experts_per_token=2, n_shared_experts=1,
             max_position_embeddings=2048, tie_word_embeddings=True,
             dtype=jnp.float32,
         )
@@ -659,7 +658,7 @@ def _dense_mlp(w, x):
 def _moe_mlp(w, x, cfg: DeepseekConfig):
     routed = moe_ffn(
         x, w["w_router"], w["w_gate"], w["w_up"], w["w_down"],
-        top_k=cfg.experts_per_token, capacity_factor=cfg.capacity_factor,
+        top_k=cfg.experts_per_token,
         router_bias=w.get("router_bias"),
         scoring="sigmoid_noaux" if cfg.scoring_func == "sigmoid" else "softmax",
         n_group=cfg.n_group, topk_group=cfg.topk_group,
@@ -834,8 +833,8 @@ def deepseek_forward_verify(
     context_lens, slot_ids, cos, sin, *, attention: str = "jax",
 ):
     """Speculative-verification forward for the MLA family (contract:
-    llama_forward_verify).  Window tokens run position-major (expert
-    capacity priority, see mixtral_forward_verify)."""
+    llama_forward_verify).  Window tokens run position-major (see
+    mixtral_forward_verify)."""
     b, w_len = token_ids.shape
     x = params["embed"][token_ids.T.reshape(-1)].astype(cfg.dtype)
     positions = jnp.maximum(

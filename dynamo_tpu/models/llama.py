@@ -2,8 +2,12 @@
 geometries via config).
 
 TPU-first design decisions:
-- layer weights stacked on a leading axis and iterated with ``lax.scan`` —
-  one compiled layer body regardless of depth (fast compile, small HLO);
+- layer weights stacked on a leading axis and iterated with ``lax.scan``: one
+  compiled layer body for each RUN of alike layers.  A llama-like model is
+  one run (one body regardless of depth); a model whose layers differ in
+  kind (``LayerKind``: window or full attention, rotated or not, which
+  cache pool, dense MLP or experts: models/exaone_moe.py) is a few runs,
+  one scan each, inside the same step program (``_scan_layer_runs``);
 - tensor parallelism by sharding annotation only: params carry
   ``PartitionSpec``s over mesh axis ``tp``; XLA/GSPMD inserts the
   all-reduces (no hand-written collectives in the model);
@@ -26,7 +30,8 @@ import json
 from dataclasses import dataclass
 from functools import partial
 from pathlib import Path
-from typing import Any
+from collections.abc import Mapping
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +51,42 @@ from dynamo_tpu.ops.attention import (
 from dynamo_tpu.ops.norms import rms_norm
 from dynamo_tpu.ops.quant import mm
 from dynamo_tpu.ops.rope import apply_rope, rope_table
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """What one layer of the shared block is, where a model's layers differ
+    (static: a run of alike layers compiles to one scan body)."""
+
+    window: int | None      # its attention's sliding window (None = full causal)
+    rope: bool              # whether q and k are rotated
+    pool: str               # "kv": the full-length cache pool | "window": the window pool
+    group: str              # the stacked weights its layers lie in (``params[group]``)
+
+
+@dataclass(frozen=True)
+class LayerRun:
+    """``count`` consecutive layers of one kind: rows ``start ...`` of
+    ``params[kind.group]``, layers ``pool_start ...`` of their cache pool."""
+
+    kind: LayerKind
+    start: int
+    count: int
+    pool_start: int
+
+
+class KvPools(NamedTuple):
+    """One value for each of the two cache pools of a model with window
+    layers (block tables, prefill block ids, the ragged kernel's span
+    lists): a layer takes its pool's (``_LayerPages.pick``).  A model with
+    one pool hands plain arrays."""
+
+    full: Any
+    window: Any
+
+
+# the leaves of a cache with a window pool: pool name -> (keys, values)
+POOL_LEAVES = {"kv": ("k", "v"), "window": ("wk", "wv")}
 
 
 @dataclass(frozen=True)
@@ -109,19 +150,20 @@ class LlamaConfig:
 
     @staticmethod
     def _resolve_sliding_window(config: dict) -> int | None:
-        """Match HF transformers' per-layer window semantics, uniformly.
+        """The ONE window of a llama-like config, by HF transformers' rules.
 
         qwen2-family configs pair ``sliding_window`` with
         ``use_sliding_window`` and ``max_window_layers``: layers with index
         >= max_window_layers use the window, layers below it use full
-        attention.  This model applies ONE attention pattern to every layer
-        (one ``lax.scan`` body over the stacked weights, ``_scan_layers``), so:
+        attention.  A ``LlamaConfig`` is one run of alike layers, so:
         - use_sliding_window false, or max_window_layers >= num layers
           (no layer windowed): full attention everywhere;
         - max_window_layers <= 0 (every layer windowed), or the key absent
           (mistral-style configs window every layer): uniform window;
-        - a genuine mixed split: refuse loudly rather than compute wrong
-          logits on the full-attention layers.
+        - a genuine mixed split is refused: the shared block serves a
+          per-layer split through layer kinds (``LayerKind``, as
+          models/exaone_moe.py builds them from ``layer_types``), and no
+          family maps ``max_window_layers`` onto them yet.
         """
         window = config.get("sliding_window") or None
         if window is None or not config.get("use_sliding_window", True):
@@ -134,22 +176,27 @@ class LlamaConfig:
         raise NotImplementedError(
             f"per-layer sliding-window split (max_window_layers={mwl} < "
             f"num_hidden_layers={config['num_hidden_layers']}) is not "
-            "supported: every layer shares one attention pattern"
+            "mapped onto layer kinds for this family (models/llama.py "
+            "LayerKind; models/exaone_moe.py serves a per-layer split)"
         )
 
     # --- what a family supplies to the shared forwards -----------------------
-    def ffn(self, w: dict, x: jnp.ndarray) -> jnp.ndarray:
+    def ffn(self, w: dict, x: jnp.ndarray, valid=None) -> jnp.ndarray:
         """This family's feed-forward over one layer's weights ``w``: the
         gated MLP.  The block every forward below runs (``_block``) asks the
         config for it, so a family whose FFN differs overrides this method
-        (models/mixtral.py) and shares the forwards as they are."""
+        (models/mixtral.py, models/exaone_moe.py) and shares the forwards as
+        they are.  ``valid`` [tokens] marks the rows that are real tokens
+        (an expert layer skips the others; a per-token MLP has no use for
+        it).  An FFN may return ``(out, stats)``: the layer loop adds
+        ``stats`` up into the cache's ``moe_stats`` leaf."""
         return _mlp(x, w["w_gate"], w["w_up"], w["w_down"], self.mlp_activation)
 
-    def for_microbatches(self, count: int) -> "LlamaConfig":
-        """The config whose ``ffn`` sees one of ``count`` equal slices of the
-        batch at a time (the pipelined decode streams microbatches through
-        its stages).  A per-token FFN gives the same answer either way."""
-        return self
+    def layer_runs(self) -> tuple[LayerRun, ...] | None:
+        """The runs of alike layers, for a model whose layers differ in
+        kind; None for one run of ``num_layers`` alike layers over one cache
+        pool (``_scan_layers``, every llama-like family)."""
+        return None
 
     # --- presets (geometries for serving + bench; weights are loaded or
     # random-initialized — no checkpoints ship with the framework) ---------
@@ -312,7 +359,14 @@ def _qkv(attn_in, w, cfg: LlamaConfig):
     return q, k, v
 
 
-def _block(cfg: LlamaConfig, attend, x: jnp.ndarray, w: dict, *cache):
+def _valid_rows(cfg: LlamaConfig, rows: jnp.ndarray):
+    """``rows`` (which of a step's rows are real tokens) for a config whose
+    FFN wants them, None for a per-token MLP: a llama-like step program
+    then holds nothing of it."""
+    return rows if getattr(cfg, "ffn_wants_valid_rows", False) else None
+
+
+def _block(cfg: LlamaConfig, attend, x: jnp.ndarray, w: dict, *cache, valid=None):
     """THE transformer block of the llama-geometry families, dense and
     sparse-expert alike: every forward below runs this one body per layer.
 
@@ -324,15 +378,18 @@ def _block(cfg: LlamaConfig, attend, x: jnp.ndarray, w: dict, *cache):
     lie; nothing for a forward without a cache) and calls its attention.
     ``q/k/v`` come in and ``attn`` goes out token-major ``[tokens, heads,
     head_dim]``; the written pages are handed back behind the new ``x``.
-    The FFN is the family's (``cfg.ffn``)."""
+    The FFN is the family's (``cfg.ffn``); where it also returns counters
+    (an expert layer's routing), they follow the pages."""
     attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
     q, k, v = _qkv(attn_in, w, cfg)
     attn, *pages = attend(q, k, v, *cache)
     x = x + mm(attn.reshape(x.shape[0], -1), w["wo"])
     mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
     with jax.named_scope("mlp"):
-        x = x + cfg.ffn(w, mlp_in)
-    return x, *pages
+        out = cfg.ffn(w, mlp_in, valid)
+    if isinstance(out, tuple):
+        return x + out[0], *pages, out[1]
+    return x + out, *pages
 
 
 def llama_forward_trunk(
@@ -387,6 +444,37 @@ class _LayerPages:
     num_blocks: int     # pages of ONE layer
     block_size: int
     num_layers: int
+    # the layer's kind where a model's layers differ (None: a llama-like
+    # model, every layer as its config says)
+    kind: LayerKind | None = None
+
+    def window(self, cfg: LlamaConfig) -> int | None:
+        """This layer's sliding window."""
+        return cfg.sliding_window if self.kind is None else self.kind.window
+
+    @property
+    def rope(self) -> bool:
+        return self.kind is None or self.kind.rope
+
+    def pick(self, value):
+        """This layer's pool's part of a per-pool value (``KvPools``)."""
+        if not isinstance(value, KvPools):
+            return value
+        return value.window if self.kind.pool == "window" else value.full
+
+    def pool_slots(self, slots, tables, lane, pos, live):
+        """The flat slots this layer writes: ``slots`` (the host's, of the
+        full pool) or, for a layer of the window pool, the slots of the
+        tokens at ``pos`` of ``lane`` in THAT pool (where ``live``;
+        elsewhere out of its range), derived here from its block tables so
+        that a step ships one set of slots."""
+        if not isinstance(tables, KvPools) or self.kind.pool != "window":
+            return slots
+        block = tables.window[lane, pos // self.block_size]
+        return jnp.where(
+            live, block * self.block_size + pos % self.block_size,
+            self.num_blocks * self.block_size,
+        )
 
     def blocks(self, ids: jnp.ndarray) -> jnp.ndarray:
         """A layer's block ids (any shape: a prefill's ids, the lanes'
@@ -457,6 +545,101 @@ def _scan_layers(layer, x, layers: dict, kv_cache: dict):
     return x, {"k": k_pages.reshape(k.shape), "v": v_pages.reshape(v.shape)}
 
 
+class _LayerOf(Mapping):
+    """One layer's weights out of a group's stacked leaves, each taken when
+    it is asked for (``w["wq"]``: a dynamic slice XLA reads in place, as a
+    scan's own).  ``w.stacked(name)`` hands the whole stack and the layer's
+    index instead, for a kernel that reads its layer where it lies
+    (ops/moe.py ``grouped_matmul``)."""
+
+    def __init__(self, leaves: dict, index):
+        self._leaves, self._index = leaves, index
+
+    def __getitem__(self, name):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self._index, keepdims=False),
+            self._leaves[name],
+        )
+
+    def __iter__(self):
+        return iter(self._leaves)
+
+    def __len__(self):
+        return len(self._leaves)
+
+    def stacked(self, name):
+        return self._leaves[name], self._index
+
+
+def layer_bank(w, name: str):
+    """A layer's expert bank for the grouped product: the stack and the
+    layer's index where the layer loop hands a view of the stack
+    (``_LayerOf``), the layer's own array otherwise."""
+    return w.stacked(name) if isinstance(w, _LayerOf) else w[name]
+
+
+def _scan_layer_runs(layer, x, params: dict, kv_cache: dict, runs):
+    """The layer loop of a model whose layers differ in kind: one scan a run
+    (``LayerRun``), each over the layers' indices, its body taking layer
+    ``i``'s weights out of ``params[kind.group]`` (``_LayerOf``) and writing and reading
+    the pages of the run's pool (``POOL_LEAVES``) as ``_scan_layers`` does:
+    a pool's flat pages are the carry of each of its runs' scans, one buffer
+    from the donated argument to the result.  Counters an FFN returns
+    (``_block``) are added into ``kv_cache["moe_stats"]``."""
+    pools = {
+        name: kv_cache[leaves[0]].shape
+        for name, leaves in POOL_LEAVES.items() if leaves[0] in kv_cache
+    }
+    flat = {
+        name: tuple(
+            kv_cache[leaf].reshape(shape[0] * shape[1], *shape[2:])
+            for leaf in POOL_LEAVES[name]
+        )
+        for name, shape in pools.items()
+    }
+    stats = kv_cache.get("moe_stats")
+    for run in runs:
+        kind = run.kind
+        num_layers, num_blocks, block_size = pools[kind.pool][:3]
+        weights = params[kind.group]
+
+        def body(carry, index, kind=kind, weights=weights,
+                 num_layers=num_layers, num_blocks=num_blocks, block_size=block_size):
+            x, k_pages, v_pages, stats = carry
+            w = _LayerOf(weights, index[0])
+            at = _LayerPages(
+                index[1] * num_blocks, num_blocks, block_size, num_layers, kind
+            )
+            x, k_pages, v_pages, *counted = layer(x, w, k_pages, v_pages, at)
+            if counted and stats is not None:
+                stats = stats + counted[0]
+            return (x, k_pages, v_pages, stats), None
+
+        steps = jnp.arange(run.count, dtype=jnp.int32)[:, None] + jnp.asarray(
+            [run.start, run.pool_start], jnp.int32
+        )
+        (x, *pages, stats), _ = jax.lax.scan(
+            body, (x, *flat[kind.pool], stats), steps
+        )
+        flat[kind.pool] = tuple(pages)
+    out = dict(kv_cache)
+    for name, shape in pools.items():
+        for leaf, pages in zip(POOL_LEAVES[name], flat[name]):
+            out[leaf] = pages.reshape(shape)
+    if stats is not None:
+        out["moe_stats"] = stats
+    return x, out
+
+
+def _layers(cfg: LlamaConfig, layer, x, params: dict, kv_cache: dict):
+    """Every cache-carrying forward's layer loop: one run of alike layers
+    (``_scan_layers``) or the config's runs (``_scan_layer_runs``)."""
+    runs = cfg.layer_runs()
+    if runs is None:
+        return _scan_layers(layer, x, params["layers"], kv_cache)
+    return _scan_layer_runs(layer, x, params, kv_cache, runs)
+
+
 def llama_forward_prefill(
     params: dict,
     cfg: LlamaConfig,
@@ -515,11 +698,12 @@ def llama_forward_prefill_embeds(
         from dynamo_tpu.ops.ring_attention import ring_attention
 
     def attend(q, k, v, k_pages, v_pages, at):
-        q = apply_rope(q, positions, cos, sin)
-        k = apply_rope(k, positions, cos, sin)
+        if at.rope:
+            q = apply_rope(q, positions, cos, sin)
+            k = apply_rope(k, positions, cos, sin)
         with jax.named_scope("kv_write"):
             pages = write_prefill_kv(
-                k_pages, v_pages, k, v, at.blocks(block_ids), seq_len
+                k_pages, v_pages, k, v, at.blocks(at.pick(block_ids)), seq_len
             )
         with jax.named_scope("attn"):
             if sp_mesh is not None:
@@ -527,12 +711,12 @@ def llama_forward_prefill_embeds(
             else:
                 attn = dense_causal_attention(
                     q[None], k[None], v[None], seq_len[None],
-                    sliding_window=cfg.sliding_window,
+                    sliding_window=at.window(cfg),
                 )[0]
         return attn, *pages
 
-    layer = partial(_block, cfg, attend)
-    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
+    layer = partial(_block, cfg, attend, valid=_valid_rows(cfg, jnp.arange(s) < seq_len))
+    x, kv_cache = _layers(cfg, layer, x, params, kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(seq_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
@@ -599,8 +783,8 @@ def llama_forward_prefill_with_prefix(
             )
         return attn, *pages
 
-    layer = partial(_block, cfg, attend)
-    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
+    layer = partial(_block, cfg, attend, valid=_valid_rows(cfg, jnp.arange(s) < tail_len))
+    x, kv_cache = _layers(cfg, layer, x, params, kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     last = x[jnp.maximum(tail_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
@@ -633,17 +817,21 @@ def llama_forward_decode(
     x = _embed(params, cfg, token_ids)  # [b, h]
     positions = jnp.maximum(context_lens - 1, 0)      # this token's position
 
+    live = context_lens > 0
+
     def paged(q, k_pages, v_pages, at):
+        lane_tables = at.pick(block_tables)
+        window = at.window(cfg)
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import paged_attention_decode
 
             interpret = attention == "pallas_interpret"
-            k_read, v_read, tables = at.on_chip(k_pages, v_pages, block_tables)
+            k_read, v_read, tables = at.on_chip(k_pages, v_pages, lane_tables)
             if tp_mesh is not None and tp_mesh.shape.get("tp", 1) > 1:
                 kernel = jax.shard_map(
                     lambda q_, k_, v_, bt, cl: paged_attention_decode(
                         q_, k_, v_, bt, cl, interpret=interpret,
-                        sliding_window=cfg.sliding_window,
+                        sliding_window=window,
                     ),
                     mesh=tp_mesh,
                     in_specs=(
@@ -659,24 +847,28 @@ def llama_forward_decode(
                 return kernel(q, k_read, v_read, tables, context_lens)
             return paged_attention_decode(
                 q, k_read, v_read, tables, context_lens,
-                interpret=interpret, sliding_window=cfg.sliding_window,
+                interpret=interpret, sliding_window=window,
             )
         return paged_decode_attention(
-            q, k_pages, v_pages, at.blocks(block_tables), context_lens,
-            sliding_window=cfg.sliding_window,
+            q, k_pages, v_pages, at.blocks(lane_tables), context_lens,
+            sliding_window=window,
         )
 
     def attend(q, k, v, k_pages, v_pages, at):
-        # apply_rope expects a seq axis: insert and drop it
-        q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
-        k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
+        if at.rope:
+            # apply_rope expects a seq axis: insert and drop it
+            q = apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0]
+            k = apply_rope(k[:, None], positions[:, None], cos, sin)[:, 0]
+        slots = at.pool_slots(
+            slot_ids, block_tables, jnp.arange(token_ids.shape[0]), positions, live
+        )
         with jax.named_scope("kv_write"):
-            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(slot_ids))
+            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(slots))
         with jax.named_scope("attn"):
             return paged(q, *pages, at), *pages
 
-    layer = partial(_block, cfg, attend)
-    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
+    layer = partial(_block, cfg, attend, valid=_valid_rows(cfg, live))
+    x, kv_cache = _layers(cfg, layer, x, params, kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x)
     return logits.astype(jnp.float32), kv_cache
@@ -716,36 +908,46 @@ def llama_forward_unified(
     x = _embed(params, cfg, token_ids)  # [t, h]
     positions = jnp.maximum(token_pos, 0)
 
-    def ragged(q, k_pages, v_pages, block_tables):
+    lanes = context_lens.shape[0]
+    live = (token_pos >= 0) & (token_lane >= 0) & (token_lane < lanes)
+
+    def ragged(q, k_pages, v_pages, at):
+        tables = at.blocks(at.pick(block_tables))
         if attention.startswith("pallas"):
             from dynamo_tpu.ops.pallas import (
                 ragged_paged_attention as ragged_kernel,
             )
 
             return ragged_kernel(
-                q, k_pages, v_pages, token_lane, token_pos,
-                block_tables, span_lane, span_first, span_count, kv_steps,
+                q, k_pages, v_pages, token_lane, token_pos, tables,
+                at.pick(span_lane), at.pick(span_first), at.pick(span_count),
+                at.pick(kv_steps),
                 tb_tokens=tb_tokens,
                 interpret=attention == "pallas_interpret",
-                sliding_window=cfg.sliding_window,
+                sliding_window=at.window(cfg),
             )
         return ragged_paged_attention(
-            q, k_pages, v_pages, block_tables, context_lens, token_lane,
-            token_pos, sliding_window=cfg.sliding_window,
+            q, k_pages, v_pages, tables, context_lens, token_lane,
+            token_pos, sliding_window=at.window(cfg),
         )
 
     def attend(q, k, v, k_pages, v_pages, at):
-        q = apply_rope(q, positions, cos, sin)
-        k = apply_rope(k, positions, cos, sin)
+        if at.rope:
+            q = apply_rope(q, positions, cos, sin)
+            k = apply_rope(k, positions, cos, sin)
+        slots = at.pool_slots(
+            token_slot, block_tables, jnp.clip(token_lane, 0, lanes - 1),
+            positions, live,
+        )
         # every token writes before anyone reads: span tokens see their own
         # in-window predecessors through the cache (pads scatter-drop)
         with jax.named_scope("kv_write"):
-            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(token_slot))
+            pages = write_decode_kv(k_pages, v_pages, k, v, at.slots(slots))
         with jax.named_scope("attn"):
-            return ragged(q, *pages, at.blocks(block_tables)), *pages
+            return ragged(q, *pages, at), *pages
 
-    layer = partial(_block, cfg, attend)
-    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
+    layer = partial(_block, cfg, attend, valid=_valid_rows(cfg, live))
+    x, kv_cache = _layers(cfg, layer, x, params, kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated
     logits = _logits(params, cfg, rows)
@@ -799,7 +1001,7 @@ def llama_forward_verify(
         return attn, *pages
 
     layer = partial(_block, cfg, attend)
-    x, kv_cache = _scan_layers(layer, x, params["layers"], kv_cache)
+    x, kv_cache = _layers(cfg, layer, x, params, kv_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = _logits(params, cfg, x).reshape(b, w_len, -1)
     return logits.astype(jnp.float32), kv_cache
@@ -826,8 +1028,6 @@ def llama_forward_decode_pp(
     Matches llama_forward_decode exactly (same block)."""
     x = _embed(params, cfg, token_ids)
     positions = jnp.maximum(context_lens - 1, 0)
-    # the FFN runs once per MICROBATCH here, not once per batch
-    cfg = cfg.for_microbatches(microbatches or pp_mesh.shape["pp"])
 
     def attend(q, k, v, k_layer, v_layer, aux_mb):
         pos_mb, slots_mb, tables_mb, lens_mb = aux_mb

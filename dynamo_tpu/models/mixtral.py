@@ -16,7 +16,7 @@ examples/sglang/README.md:105; here the MoE engine is native.)
 from __future__ import annotations
 
 import json
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from functools import partial
 from pathlib import Path
 
@@ -25,11 +25,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.llama import (
+    LayerKind,
+    LayerRun,
     LlamaConfig,
     _block,
     _embed,
     _logits,
     _scan_layers,
+    layer_bank,
 )
 from dynamo_tpu.ops.attention import (
     position_major_to_batch,
@@ -45,7 +48,6 @@ from dynamo_tpu.ops.rope import apply_rope
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
-    capacity_factor: float = 2.0
     # expert FFN width; 0 = same as intermediate_size (Mixtral proper).
     # Qwen3-MoE configs carry a distinct moe_intermediate_size.
     moe_intermediate_size: int = 0
@@ -53,42 +55,36 @@ class MixtralConfig(LlamaConfig):
     # variants disable it)
     norm_topk_prob: bool = True
 
-    def __post_init__(self):
-        # inherited from LlamaConfig, and the shared forwards would honor it;
-        # mixtral_forward_verify does not, and no windowed MoE has been
-        # checked against its reference — refuse rather than serve it half
-        # windowed; from_hf_config parses the HF window fields specifically
-        # so this fires on checkpoints
-        if self.sliding_window is not None:
-            raise NotImplementedError(
-                "mixtral-family attention has no sliding-window mask"
-            )
+    # grouped product of the expert layer (ops/moe.py ``grouped_matmul``):
+    # "auto" = the Pallas kernel on a TPU; the engine sets "xla" under a mesh
+    grouped_matmul: str = "auto"
+
+    # the expert layer skips rows that are no token (llama._valid_rows)
+    ffn_wants_valid_rows = True
 
     @property
     def expert_intermediate_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
-    def ffn(self, w: dict, x: jnp.ndarray) -> jnp.ndarray:
-        """The family's FFN for the shared block: route each token to its
-        top-k experts, each with room for ``capacity_factor`` times its even
-        share of the tokens in ``x``."""
-        return moe_ffn(
-            x, w["w_router"], w["w_gate"], w["w_up"], w["w_down"],
-            top_k=self.experts_per_token,
-            capacity_factor=self.capacity_factor,
-            norm_topk_prob=self.norm_topk_prob,
-        )
+    def layer_runs(self) -> tuple[LayerRun, ...]:
+        """One run of alike layers, through the loop that hands a layer's
+        weights as a view of the stack (``llama._scan_layer_runs``): the
+        grouped product then reads a layer's expert banks where they lie
+        (sliced out for the kernel they would be copied every step)."""
+        kind = LayerKind(self.sliding_window, True, "kv", "layers")
+        return (LayerRun(kind, 0, self.num_layers, 0),)
 
-    def for_microbatches(self, count: int) -> "MixtralConfig":
-        """Routing per MICROBATCH (the pipelined decode): capacity counts
-        from the tokens ``ffn`` sees, so the factor is scaled back up by the
-        microbatch count and each expert's per-call capacity equals what
-        full-batch routing would allocate.  Tokens then only compete for
-        slots within their own microbatch — outputs match the plain decode
-        exactly whenever no drops occur (the served regime capacity_factor
-        is sized for), and under extreme routing skew the pipelined path
-        drops no earlier than full-batch routing would."""
-        return replace(self, capacity_factor=self.capacity_factor * float(count))
+    def ffn(self, w: dict, x: jnp.ndarray, valid=None) -> jnp.ndarray:
+        """The family's FFN for the shared block: route each token to its
+        top-k experts; every assignment is computed (ops/moe.py), rows that
+        are not ``valid`` tokens are not."""
+        return moe_ffn(
+            x, w["w_router"],
+            *(layer_bank(w, name) for name in ("w_gate", "w_up", "w_down")),
+            top_k=self.experts_per_token,
+            norm_topk_prob=self.norm_topk_prob,
+            valid=valid, impl=self.grouped_matmul,
+        )
 
     @classmethod
     def mixtral_8x7b(cls) -> "MixtralConfig":
@@ -106,7 +102,7 @@ class MixtralConfig(LlamaConfig):
             num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
             max_position_embeddings=2048, rope_theta=10000.0,
             tie_word_embeddings=True, dtype=jnp.float32,
-            num_experts=4, experts_per_token=2, capacity_factor=4.0,
+            num_experts=4, experts_per_token=2,
         )
 
     @classmethod
@@ -135,10 +131,8 @@ class MixtralConfig(LlamaConfig):
             qk_norm=config.get(
                 "qk_norm", config.get("model_type") == "qwen3_moe"
             ),
-            # parsed with HF's use_sliding_window/max_window_layers
-            # semantics; a genuinely-windowed MoE checkpoint then hits the
-            # __post_init__ refusal instead of silently running full
-            # attention
+            # HF's use_sliding_window/max_window_layers semantics; the shared
+            # block honours the window in every forward
             sliding_window=cls._resolve_sliding_window(config),
         )
 
@@ -214,13 +208,10 @@ def mixtral_forward_verify(
     matches llama_forward_verify; what differs is the ORDER of the window's
     b*w tokens.
 
-    Token order is POSITION-major (all lanes' position-0 tokens first):
-    expert-capacity slots assign in dispatch order (ops/moe.py), so the
-    always-emitted position-0 tokens never lose a slot to a later draft
-    position.  MoE parity with plain decode is therefore near-exact but
-    not guaranteed under extreme routing skew — capacity grows w-fold with
-    the window, yet which tokens drop can differ from the non-speculative
-    schedule (a capacity-dropping property, not an acceptance-logic one)."""
+    Token order is POSITION-major (all lanes' position-0 tokens first), as
+    it was when the expert layer gave out capacity slots in dispatch order;
+    the expert layer drops nothing now, so the order no longer decides any
+    token's result."""
     b, w_len = token_ids.shape
     # [b, w] → position-major flat [w*b]
     x = _embed(params, cfg, token_ids.T.reshape(-1))
@@ -246,7 +237,8 @@ def mixtral_forward_verify(
             )
         with jax.named_scope("attn"):
             attn = window_attention(
-                attention, q, *pages, at.blocks(block_tables), context_lens
+                attention, q, *pages, at.blocks(block_tables), context_lens,
+                sliding_window=cfg.sliding_window,
             )
         return to_flat(attn), *pages
 

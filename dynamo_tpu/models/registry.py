@@ -60,10 +60,16 @@ class ModelFamily:
     # param-tree leaf names eligible for weight-only int8 (ops/quant.py);
     # empty = the family's forwards don't route matmuls through quant.mm
     quant_leaves: tuple[str, ...] = ()
+    # a family with window layers keeps their keys and values in a second
+    # pool whose blocks are released behind the window: (cfg, lanes,
+    # max_len, block_size) -> blocks of that pool (None = one pool; the
+    # engine then hands ``init_kv_cache`` a ``window_blocks`` keyword,
+    # serves no prefix cache and no KV transfer for the family)
+    window_pool_blocks: Callable | None = None
 
-    def cache_init(self, cfg, num_blocks: int, block_size: int, dtype=None):
+    def cache_init(self, cfg, num_blocks: int, block_size: int, dtype=None, **pools):
         if self.init_kv_cache is not None:
-            return self.init_kv_cache(cfg, num_blocks, block_size, dtype)
+            return self.init_kv_cache(cfg, num_blocks, block_size, dtype, **pools)
         from dynamo_tpu.models import llama
 
         return llama.init_kv_cache(cfg, num_blocks, block_size, dtype)
@@ -244,7 +250,6 @@ def _sparse_expert_family(name: str) -> ModelFamily:
         forward_decode_pp=llama.llama_forward_decode_pp,
         load_weights=mixtral.load_hf_weights,
         quant_leaves=_PROJ_QUANT_LEAVES,
-        # position-major, so always-emitted tokens get expert capacity first
         forward_verify=mixtral.mixtral_forward_verify,
         forward_unified=llama.llama_forward_unified,
     )
@@ -259,6 +264,30 @@ def _qwen3_moe_family() -> ModelFamily:
     # (from_hf_config infers qk_norm from model_type, which the registry
     # key guarantees is present on any config routed here)
     return _sparse_expert_family("qwen3_moe")
+
+
+def _exaone_moe_family() -> ModelFamily:
+    # EXAONE-MoE = the llama block with a KIND per layer (window or full
+    # attention, rotated or not, dense MLP or routed experts beside a shared
+    # one) and a second cache pool for the window layers
+    # (models/exaone_moe.py).  Its window layers' prefix is gone once a
+    # sequence has passed it, so: no continued prefill (prefix cache,
+    # chunked prefill), no verify, no pipelined decode.
+    from dynamo_tpu.models import exaone_moe, llama
+
+    return ModelFamily(
+        name="exaone_moe",
+        config_from_hf=exaone_moe.ExaoneMoeConfig.from_hf_config,
+        init_params=exaone_moe.init_params,
+        param_specs=exaone_moe.param_specs,
+        forward_prefill=llama.llama_forward_prefill,
+        forward_decode=llama.llama_forward_decode,
+        init_kv_cache=exaone_moe.init_kv_cache,
+        kv_cache_specs=exaone_moe.kv_cache_specs,
+        quant_leaves=_PROJ_QUANT_LEAVES + ("ws_gate", "ws_up", "ws_down"),
+        forward_unified=llama.llama_forward_unified,
+        window_pool_blocks=exaone_moe.window_pool_blocks,
+    )
 
 
 def _deepseek_family() -> ModelFamily:
@@ -305,6 +334,7 @@ _FAMILIES: dict[str, Callable[[], ModelFamily]] = {
     # HF model_type keys for the MLA architectures only — classic
     # DeepSeek-MoE ("deepseek") uses conventional attention and would need
     # its own family
+    "exaone_moe": _exaone_moe_family,
     "deepseek_v2": _deepseek_family,
     "deepseek_v3": _deepseek_family,
 }

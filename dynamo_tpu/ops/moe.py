@@ -1,17 +1,26 @@
 """Mixture-of-Experts layer ops.
 
-Capacity-based top-k routing with static shapes (XLA-friendly: no ragged
-dispatch):
+One expert layer for every routed family (Mixtral/Qwen3-MoE, DeepSeek,
+EXAONE-MoE), told which experts it holds:
 
-    dispatch  [T, H] → [E, C, H]   (one-hot scatter by expert slot)
-    experts   batched einsum over the expert axis (MXU)
-    combine   [E, C, H] → [T, H]   weighted by router probabilities
+    route     over ALL the model's experts (the router keeps its width)
+    keep      the (token, choice) assignments whose expert is held here
+    sort      them by expert, so each held expert's rows are contiguous
+    experts   a grouped matrix product over the held experts' banks
+    combine   each row weighted by its router probability (normalised over
+              all the token's choices), summed back per token
 
-Expert parallelism = sharding the expert axis over mesh axis ``ep``; GSPMD
-lowers dispatch/combine into all-to-alls over ICI (SURVEY.md §2.5 expert
-parallel — the reference delegates this to DeepEP inside SGLang; here it is
-native).  Tokens over capacity are dropped (standard capacity-factor
-behavior); capacity is sized to make drops negligible at serving batch sizes.
+No token is dropped at any skew: the sorted buffer has room for every
+assignment (``tokens x k`` rows) and the grouped product runs over the rows
+that are live, so ITS time follows the assignments held; the gathers and
+elementwise passes around it run over the whole buffer (ROADMAP: a walk of
+the live rows in chunks).  There is no capacity and no capacity factor.
+
+Expert parallelism: a chip that holds experts ``[first, first + E_held)`` of
+``E`` computes its own experts' part of each token's sum; the parts of all
+chips add up to the whole layer (the exchange that would add them is not
+here: one chip runs its share alone).  Under a GSPMD ``ep`` mesh the banks
+carry ``P(None, "ep", ...)`` and every expert is "held" by the program.
 """
 
 from __future__ import annotations
@@ -19,7 +28,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.ops.quant import qeinsum
+from dynamo_tpu.ops.quant import QuantizedMatrix
+
+# counters one expert layer adds to (``MOE_STATS`` order), int32: what the
+# routing did, which only the device knows.  The engine sums them over
+# layers and steps (engine.stats() "moe_*").
+MOE_STATS = (
+    "assignments_routed",   # valid tokens x k
+    "assignments_held",     # ... whose expert is held here
+    "experts_touched",      # held experts with at least one row
+    "expert_rows_max",      # rows of the busiest held expert (summed over layers)
+    "expert_layers",        # expert layers run (the divisor of the two above)
+)
 
 
 def moe_router(
@@ -80,79 +100,149 @@ def moe_router_sigmoid_noaux(
     return top_ids.astype(jnp.int32), top_scores
 
 
-def moe_dispatch_combine(
-    x: jnp.ndarray,          # [T, H]
-    expert_ids: jnp.ndarray,  # [T, k]
-    probs: jnp.ndarray,       # [T, k] f32
-    w_gate: jnp.ndarray,      # [E, H, I]
-    w_up: jnp.ndarray,        # [E, H, I]
-    w_down: jnp.ndarray,      # [E, I, H]
+def grouped_matmul(
+    lhs: jnp.ndarray,          # [M, K] rows sorted by group
+    rhs,                       # [G, K, N] one matrix a group (may be quantized),
+                               # or (stacked [L, G, K, N], layer): layer's G matrices
+    group_sizes: jnp.ndarray,  # [G] int32 rows of each group, in order
     *,
-    capacity: int,
+    impl: str = "auto",        # "auto" | "pallas" | "pallas_interpret" | "xla"
 ) -> jnp.ndarray:
+    """``lhs[rows of group g] @ rhs[g]`` for every group; rows past
+    ``sum(group_sizes)`` come back as zeros.  On a TPU the product is the
+    Pallas grouped matmul that ships with JAX (megablox ``gmm``: its grid
+    covers the live row tiles only, so dead rows cost nothing); elsewhere
+    ``jax.lax.ragged_dot``.
+
+    A layer's matrices may come as the whole stack and the layer's index: a
+    kernel's operand is a buffer of its own, so a layer sliced out of the
+    stack inside a layer loop would be COPIED for it each step (1.2 GB a
+    layer at 16 experts of 6144 x 2048 x 3).  The kernel takes the stack
+    flat, ``[L x G, K, N]``, and group sizes that are zero but for the
+    layer's own groups, and reads the matrices where they lie."""
+    stacked, layer = rhs if isinstance(rhs, tuple) else (None, None)
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if stacked is not None and (impl == "xla" or isinstance(stacked, QuantizedMatrix)):
+        rhs, stacked = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False), stacked
+        ), None
+    if isinstance(rhs, QuantizedMatrix):
+        rhs = rhs.q.astype(lhs.dtype) * rhs.s.astype(lhs.dtype)
+    m = lhs.shape[0]
+    live = jnp.arange(m)[:, None] < jnp.sum(group_sizes)
+    if impl == "xla":
+        out = jax.lax.ragged_dot(
+            lhs, rhs.astype(lhs.dtype), group_sizes,
+            preferred_element_type=jnp.float32,
+        ).astype(lhs.dtype)
+        return jnp.where(live, out, 0)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    group_sizes = group_sizes.astype(jnp.int32)
+    if stacked is not None:
+        groups = group_sizes.shape[0]
+        rhs = stacked.reshape(-1, *stacked.shape[2:])
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((rhs.shape[0],), jnp.int32), group_sizes, (layer * groups,)
+        )
+    k, n = rhs.shape[1:]
+    tm = 128 if m <= 1024 else 512
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(
+        lhs, rhs.astype(lhs.dtype), group_sizes,
+        preferred_element_type=lhs.dtype,
+        tiling=(tm, min(k, 1024), min(n, 1024)),
+        interpret=impl == "pallas_interpret",
+    )[:m]
+    # the kernel leaves the tiles it never visits unwritten
+    return jnp.where(live, out, 0)
+
+
+def moe_experts(
+    x: jnp.ndarray,           # [T, H]
+    expert_ids: jnp.ndarray,  # [T, k] ids among ALL experts
+    probs: jnp.ndarray,       # [T, k] f32 combine weights
+    w_gate,                   # [E_held, H, I], or (stacked [L, E_held, H, I], layer)
+    w_up,                     # the same
+    w_down,                   # [E_held, I, H], or its stack and the layer
+    *,
+    first_expert: int = 0,
+    valid: jnp.ndarray | None = None,   # [T] bool: rows that are real tokens
+    impl: str = "auto",
+):
+    """The held experts' part of every token's weighted sum, and the layer's
+    ``MOE_STATS``.  Assignments to experts outside ``[first_expert,
+    first_expert + E_held)`` and those of rows that are not ``valid`` (a
+    bucket's padding, an empty lane) sort behind the live rows, where the
+    grouped product does not go."""
     t, h = x.shape
-    e = w_gate.shape[0]
+    e = w_gate[0].shape[1] if isinstance(w_gate, tuple) else w_gate.shape[0]
     k = expert_ids.shape[1]
-
-    flat_ids = expert_ids.reshape(-1)                      # [T*k]
-    onehot = jax.nn.one_hot(flat_ids, e, dtype=jnp.int32)  # [T*k, E]
-    # slot of each (token, k) within its expert's buffer
-    slot = jnp.cumsum(onehot, axis=0) * onehot             # [T*k, E]
-    slots = jnp.max(slot, axis=-1) - 1                     # [T*k] position, -1 invalid
-    within_capacity = (slots >= 0) & (slots < capacity)
-
-    # scatter tokens into [E, C, H]
-    buffers = jnp.zeros((e, capacity, h), x.dtype)
-    token_idx = jnp.repeat(jnp.arange(t), k)
-    safe_expert = jnp.where(within_capacity, flat_ids, 0)
-    safe_slot = jnp.where(within_capacity, slots, capacity)  # OOB → dropped
-    buffers = buffers.at[safe_expert, safe_slot].set(
-        x[token_idx], mode="drop"
-    )
-
-    # expert FFN batched over E (rides the MXU per expert shard; qeinsum
-    # streams int8-quantized expert banks from HBM — the dominant bytes of
-    # an MoE decode step)
-    hidden = jax.nn.silu(qeinsum("ech,ehi->eci", buffers, w_gate)) * qeinsum(
-        "ech,ehi->eci", buffers, w_up
-    )
-    out_buffers = qeinsum("eci,eih->ech", hidden, w_down)  # [E, C, H]
-
-    # combine: gather each (token, k)'s expert output, weight by prob
-    gathered = out_buffers[safe_expert, safe_slot]            # [T*k, H]
-    weights = jnp.where(within_capacity, probs.reshape(-1), 0.0)
-    weighted = gathered.astype(jnp.float32) * weights[:, None]
-    combined = jnp.zeros((t, h), jnp.float32).at[token_idx].add(weighted)
-    return combined.astype(x.dtype)
+    local = expert_ids.astype(jnp.int32) - first_expert
+    real = jnp.ones((t, 1), bool) if valid is None else valid[:, None]
+    held = (local >= 0) & (local < e) & real
+    group = jnp.where(held, local, e).reshape(-1)           # [T*k], e = not here
+    order = jnp.argsort(group, stable=True)
+    place = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))   # where each landed
+    group_sizes = jnp.zeros((e,), jnp.int32).at[group].add(1, mode="drop")
+    # the sorted buffer has a row for every assignment: the live rows come
+    # first and the grouped product visits their tiles only
+    rows = x[order // k]                                            # [T*k, H]
+    hidden = jax.nn.silu(
+        grouped_matmul(rows, w_gate, group_sizes, impl=impl)
+    ) * grouped_matmul(rows, w_up, group_sizes, impl=impl)
+    out = grouped_matmul(hidden, w_down, group_sizes, impl=impl)    # zeros past the live rows
+    # back in token order: each token's k rows, weighted and summed
+    combined = jnp.einsum(
+        "tkh,tk->th", out[place].reshape(t, k, h), jnp.where(held, probs, 0.0),
+        preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
+    stats = jnp.stack([
+        jnp.sum(real) * k,
+        jnp.sum(group_sizes),
+        jnp.sum(group_sizes > 0),
+        jnp.max(group_sizes),
+        jnp.int32(1),
+    ]).astype(jnp.int32)
+    return combined, stats
 
 
 def moe_ffn(
     x: jnp.ndarray,
-    w_router: jnp.ndarray,
-    w_gate: jnp.ndarray,
-    w_up: jnp.ndarray,
-    w_down: jnp.ndarray,
+    w_router: jnp.ndarray,    # [H, E] over ALL experts
+    w_gate,
+    w_up,
+    w_down,
     *,
     top_k: int,
-    capacity_factor: float = 2.0,
     router_bias: jnp.ndarray | None = None,
     scoring: str = "softmax",     # "softmax" | "sigmoid_noaux"
     n_group: int = 1,
     topk_group: int = 1,
     norm_topk_prob: bool = True,
-) -> jnp.ndarray:
-    t = x.shape[0]
-    e = w_gate.shape[0]
-    capacity = max(1, int(t * top_k / e * capacity_factor))
+    first_expert: int = 0,
+    valid: jnp.ndarray | None = None,
+    impl: str = "auto",
+    with_stats: bool = False,
+):
+    """Route over the router's width, compute the experts held
+    (``w_gate.shape[0]`` of them, from ``first_expert``).  ``with_stats``
+    also returns the layer's ``MOE_STATS`` vector."""
+    e_all = w_router.shape[-1]
     if scoring == "sigmoid_noaux":
         ids, probs = moe_router_sigmoid_noaux(
             x, w_router,
-            router_bias if router_bias is not None else jnp.zeros((e,), jnp.float32),
+            router_bias if router_bias is not None else jnp.zeros((e_all,), jnp.float32),
             top_k, n_group=n_group, topk_group=topk_group,
             norm_topk_prob=norm_topk_prob,
         )
     else:
         ids, probs = moe_router(x, w_router, top_k, norm_topk_prob=norm_topk_prob)
-    return moe_dispatch_combine(
-        x, ids, probs, w_gate, w_up, w_down, capacity=capacity
+    out, stats = moe_experts(
+        x, ids, probs, w_gate, w_up, w_down,
+        first_expert=first_expert, valid=valid, impl=impl,
     )
+    return (out, stats) if with_stats else out

@@ -162,6 +162,25 @@ def paged_window_attention_decode(
     pps = pages_per_step
     if pps < 1:
         raise ValueError(f"pages_per_step must be >= 1, got {pps}")
+    if sliding_window is not None:
+        # the pages a lane's queries can see: the window behind its first
+        # query through its last, wherever the two fall in their pages
+        seen = -(-(sliding_window + w - 1) // bs) + 1
+        if seen < maxb:
+            # a window far shorter than the tables (a window LAYER of a
+            # long-context model): walk only those pages.  The tables are
+            # cut to them and the lengths counted from the first, here in
+            # XLA; the kernel's masks compare positions with positions, so
+            # a common offset changes nothing, and its grid is lanes x
+            # ``seen`` steps whatever the context.
+            first = jnp.maximum(context_lens - w - (sliding_window - 1), 0) // bs
+            block_tables = jnp.take_along_axis(
+                block_tables,
+                jnp.minimum(first[:, None] + jnp.arange(seen), maxb - 1),
+                axis=1,
+            )
+            context_lens = context_lens - first * bs
+            maxb = seen
     pps = min(pps, maxb)
 
     if sliding_window is None:

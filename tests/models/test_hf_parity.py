@@ -195,9 +195,8 @@ def test_qwen3_matches_hf(tmp_path):
 
 @pytest.mark.slow
 def test_mixtral_matches_hf(tmp_path):
-    """MoE family vs HF Mixtral.  HF routes exact top-k with no capacity
-    limit; ours is capacity-based — the tiny prompt keeps every token
-    within capacity, so logits must still agree."""
+    """MoE family vs HF Mixtral: exact top-k, every assignment computed, on
+    both sides."""
     config = transformers.MixtralConfig(
         vocab_size=320, hidden_size=64, intermediate_size=96,
         num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,

@@ -11,7 +11,7 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.models.llama import init_kv_cache, kv_cache_spec, make_rope_tables
 from dynamo_tpu.models.mixtral import MixtralConfig, init_params, param_specs
 from dynamo_tpu.models.registry import get_family
-from dynamo_tpu.ops.moe import moe_dispatch_combine, moe_ffn, moe_router
+from dynamo_tpu.ops.moe import moe_experts, moe_ffn, moe_router
 from dynamo_tpu.parallel import MeshConfig, make_mesh, shard_pytree
 
 CFG = MixtralConfig.tiny_moe()
@@ -23,8 +23,8 @@ NUM_BLOCKS = 32
 
 
 def test_moe_matches_per_token_dense():
-    """Capacity dispatch (ample capacity) must equal computing each token
-    through its own top-k experts directly."""
+    """The sorted grouped product must equal computing each token through
+    its own top-k experts directly."""
     rng = jax.random.PRNGKey(0)
     t, h, i, e, k = 6, 16, 24, 4, 2
     keys = jax.random.split(rng, 5)
@@ -34,7 +34,7 @@ def test_moe_matches_per_token_dense():
     w_up = jax.random.normal(keys[3], (e, h, i), jnp.float32) / 4
     w_down = jax.random.normal(keys[4], (e, i, h), jnp.float32) / 4
 
-    out = moe_ffn(x, w_router, w_gate, w_up, w_down, top_k=k, capacity_factor=float(e))
+    out = moe_ffn(x, w_router, w_gate, w_up, w_down, top_k=k)
 
     ids, probs = moe_router(x, w_router, k)
     expected = np.zeros((t, h), np.float32)
@@ -46,20 +46,21 @@ def test_moe_matches_per_token_dense():
     np.testing.assert_allclose(np.asarray(out), expected, rtol=2e-4, atol=2e-4)
 
 
-def test_capacity_drops_overflow_tokens():
+def test_no_token_dropped_when_all_route_to_one_expert():
+    """Every token routed to ONE expert still gets that expert's result
+    (the capacity path zeroed all but the first ``capacity`` of them)."""
     rng = jax.random.PRNGKey(1)
-    t, h, i, e = 8, 8, 8, 2
+    t, h, i = 8, 8, 8
     x = jax.random.normal(rng, (t, h), jnp.float32)
-    # all tokens routed to expert 0 with prob 1
     ids = jnp.zeros((t, 1), jnp.int32)
     probs = jnp.ones((t, 1), jnp.float32)
-    w = jnp.stack([jnp.eye(h, i), jnp.eye(h, i)])
-    out = moe_dispatch_combine(
-        x, ids, probs, w, w, jnp.stack([jnp.eye(i, h)] * 2), capacity=3
+    w = jnp.stack([jnp.eye(h, i), 2 * jnp.eye(h, i)])
+    out, stats = moe_experts(x, ids, probs, w, w, jnp.stack([jnp.eye(i, h)] * 2))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(jax.nn.silu(x) * x), rtol=1e-5, atol=1e-5
     )
-    # tokens beyond capacity 3 contribute nothing
-    assert np.allclose(np.asarray(out[3:]), 0.0)
-    assert not np.allclose(np.asarray(out[:3]), 0.0)
+    # routed, held, experts touched, busiest expert's rows, layers
+    assert stats.tolist() == [t, t, 1, t, 1]
 
 
 def test_mixtral_prefill_decode_consistency():

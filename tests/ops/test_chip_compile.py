@@ -32,7 +32,10 @@ from dynamo_tpu.ops.pallas.ragged_attention import (
 
 # head geometries: what chip_smoke.py serves on one chip (Llama-3.2-3B) and
 # the repo's headline (Llama-3-8B; also one tp=4 shard's KV width times 4)
-GEOMETRY = {"llama32_3b": (24, 8, 128), "llama3_8b": (32, 8, 128)}
+GEOMETRY = {"llama32_3b": (24, 8, 128), "llama3_8b": (32, 8, 128),
+            # one chip's share of K-EXAONE-236B's attention: 8 of 64 query
+            # heads over 1 of 8 KV heads (benchmark/configs/k-exaone-236b-l8.json)
+            "k_exaone_share": (8, 1, 128)}
 KV_DTYPES = {"bf16": jnp.bfloat16, "fp8": jnp.float8_e4m3fn}
 BLOCK = 16
 NUM_BLOCKS = 1024
@@ -152,6 +155,38 @@ def test_ragged_compiles_at_every_bucket_of_the_benchmark_cells(
         ).compile()
 
 
+@pytest.mark.parametrize("sliding_window", [128, None], ids=["window128", "full"])
+@pytest.mark.parametrize("bucket", [32, 4096, 8192])
+def test_ragged_compiles_for_window_and_full_layers_of_one_kv_head(
+    one_chip, bucket, sliding_window
+):
+    """``k-exaone-236b-l8``'s two kinds of layer in one step program: eight
+    query heads over ONE KV head (blocks of 32 tokens), 16 lanes of 512-page
+    tables at context 8,192, a 128 window or none."""
+    _ragged_case(
+        _sds(one_chip), bucket, "k_exaone_share", "bf16", lanes=16,
+        max_blocks=512, sliding_window=sliding_window,
+    ).compile()
+
+
+def test_decode_kernel_walks_a_window_not_the_context(one_chip):
+    """A 128 window under 512-page tables: the wrapper cuts each lane's
+    table to the nine pages its query can see, so the kernels grid is lanes x
+    9 steps whatever the context (it was lanes x 512, the pages behind the
+    window fetched once more and skipped)."""
+    h, kvh, d = GEOMETRY["k_exaone_share"]
+    s = _sds(one_chip)
+    cache = s((678, BLOCK, kvh, d), jnp.bfloat16)
+    compiled = paged_attention_decode.lower(
+        s((16, h, d), jnp.bfloat16), cache, cache,
+        s((16, 512), jnp.int32), s((16,), jnp.int32), sliding_window=128,
+    ).compile()
+    hlo = compiled.as_text()
+    assert "paged_window_attention_decode" in hlo
+    # the table the kernel is handed (the argument's 512 columns are cut)
+    assert "s32[16,9]" in hlo
+
+
 def test_ragged_compiles_at_a_context_of_32768(one_chip):
     """One whole 32,768-token window against 16 lanes of 2,048-page block
     tables: five words a token and 128 KiB of tables fit scalar memory.
@@ -220,8 +255,51 @@ STEP_CONFIGS = {
         num_layers=4, num_heads=32, num_kv_heads=8, head_dim=128,
         max_position_embeddings=4096, rope_theta=1e6,
         num_experts=8, experts_per_token=2,
+        grouped_matmul="pallas",    # what "auto" is on the chip
     ), 5120, 8),
 }
+
+
+def test_a_layers_expert_banks_are_read_where_they_lie(one_chip):
+    """``k-exaone-236b-l8``'s decode step at the cell's shapes (10.4 GB of
+    arguments): both pools are written in place, and the grouped product
+    reads a layer's banks out of the stack (``ops/moe.py``): sliced out for
+    the kernel they were 1.2 GB of temporaries a layer; the step holds 0.12."""
+    from dynamo_tpu.models.exaone_moe import ExaoneMoeConfig
+    from dynamo_tpu.models.llama import KvPools
+
+    cfg = ExaoneMoeConfig(
+        vocab_size=19200, hidden_size=6144, intermediate_size=18432,
+        num_layers=8, num_heads=8, num_kv_heads=1, head_dim=128,
+        max_position_embeddings=8192, rope_theta=1e6,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
+        mlp_layer_types=("dense",) + ("sparse",) * 7, window=128,
+        num_experts=16, expert_parallel_size=8, experts_per_token=8,
+        moe_intermediate_size=2048, grouped_matmul="pallas",
+    )
+    family = get_family("exaone_moe")
+    s = _sds(one_chip)
+    lanes, context = 16, 8192
+    shaped = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)  # noqa: E731
+    params = shaped(jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shaped(jax.eval_shape(lambda: family.cache_init(
+        cfg, 8320, BLOCK, None,
+        window_blocks=family.window_pool_blocks(cfg, lanes, context, BLOCK),
+    )))
+    tables = s((lanes, context // BLOCK), jnp.int32)
+    rope = s((context, cfg.head_dim // 2), jnp.float32)
+    lane = s((lanes,), jnp.int32)
+
+    def fn(p, c, tok, bt, cl, sl, cos, sin):
+        return family.forward_decode(p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
+
+    memory = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, lane, KvPools(tables, tables), lane, lane, rope, rope
+    ).compile().memory_analysis()
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= pools - 64
+    assert memory.temp_size_in_bytes < 400e6
+    assert 10.3e9 < memory.argument_size_in_bytes < 10.7e9
 
 
 def _compile_forward(one_chip, config, program):
@@ -269,9 +347,9 @@ def test_step_forward_writes_the_donated_cache_in_place(one_chip, config, progra
     forwards still did, 2.01 / 2.05 / 3.29 GB beside this 1.34 GB cache,
     until it took the shared ones).
 
-    The expert layer's dispatch buffers (``[experts, capacity, hidden]`` and
-    twice ``[experts, capacity, 14336]``) grow with the window: 17 MB of
-    temporaries at 128 tokens, 641 MB at 4,096, where a tenth of the cache
+    The expert layer's sorted buffers (``[tokens x 2, hidden]`` in and out
+    and twice ``[tokens x 2, 14336]``) grow with the window: a tenth of the
+    cache holds them at 128 tokens, not at 4,096, where a tenth of the cache
     (134 MB: four layers of weights and a larger cache do not fit the chip)
     would measure the experts.  That case is held to less than ONE cache,
     which a copy of it cannot meet."""

@@ -92,14 +92,13 @@ def test_engine_rejects_indivisible_pp_config():
 def _mixtral_setup(batch=8, num_blocks=16, block_size=4):
     from dynamo_tpu.models import mixtral as mx
 
-    # default capacity_factor on purpose: per-microbatch routing must scale
-    # capacity back up (capacity_scale), or pp would drop tokens the plain
-    # decode keeps and this parity check would catch it
+    # routing per microbatch equals routing the whole batch: the expert
+    # layer computes every assignment, whatever slice of the batch it sees
     cfg = mx.MixtralConfig(
         vocab_size=512, hidden_size=64, intermediate_size=96, num_layers=4,
         num_heads=4, num_kv_heads=2, head_dim=16, max_position_embeddings=2048,
         rope_theta=10000.0, tie_word_embeddings=True, dtype=jnp.float32,
-        num_experts=4, experts_per_token=2, capacity_factor=2.0,
+        num_experts=4, experts_per_token=2,
     )
     params = mx.init_params(cfg, jax.random.PRNGKey(2))
     cos, sin = make_rope_tables(cfg)
