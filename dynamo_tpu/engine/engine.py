@@ -105,6 +105,7 @@ MOE_STAT_KEYS = (
     "moe_experts_touched_total",
     "moe_expert_rows_max_total",
     "moe_expert_layers_total",
+    "moe_rows_walked_total",
 )
 
 
@@ -2369,8 +2370,8 @@ class JaxLlmEngine:
         """The expert layers' counters as taken so far (a step or two
         behind the device) and the grouped products' work they imply: 2 x 3
         x hidden x expert width operations a row held; bytes = the banks of
-        the experts TOUCHED (each read once a layer and step) and every row
-        in and out of the three products."""
+        the experts TOUCHED (each read once a chunk of the layer's walk that
+        visits it) and every row in and out of the three products."""
         cfg = self.config.model
         totals = dict(zip(MOE_STAT_KEYS, self._moe_totals))
         h, mi = cfg.hidden_size, cfg.moe_intermediate_size

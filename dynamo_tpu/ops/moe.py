@@ -10,11 +10,14 @@ EXAONE-MoE), told which experts it holds:
     combine   each row weighted by its router probability (normalised over
               all the token's choices), summed back per token
 
-No token is dropped at any skew: the sorted buffer has room for every
-assignment (``tokens x k`` rows) and the grouped product runs over the rows
-that are live, so ITS time follows the assignments held; the gathers and
-elementwise passes around it run over the whole buffer (ROADMAP: a walk of
-the live rows in chunks).  There is no capacity and no capacity factor.
+No token is dropped at any skew, and there is no capacity and no capacity
+factor: only the ORDER (``tokens x k`` int32 indices) has a place for every
+assignment.  The rows themselves are walked ``CHUNK_ROWS`` at a time by a
+loop whose trip count is the rows HELD, read on the device: a trip gathers
+its rows of ``x``, runs the three grouped products over them, gates, and adds
+each row, weighted, into the tokens' float32 sum.  So the time of everything
+here but route and sort follows the assignments held, and the program holds
+no array of ``tokens x k`` rows of the hidden or the expert width.
 
 Expert parallelism: a chip that holds experts ``[first, first + E_held)`` of
 ``E`` computes its own experts' part of each token's sum; the parts of all
@@ -36,10 +39,15 @@ from dynamo_tpu.ops.quant import QuantizedMatrix
 MOE_STATS = (
     "assignments_routed",   # valid tokens x k
     "assignments_held",     # ... whose expert is held here
-    "experts_touched",      # held experts with at least one row
+    "experts_touched",      # held experts with a row, once a chunk that visits them
     "expert_rows_max",      # rows of the busiest held expert (summed over layers)
     "expert_layers",        # expert layers run (the divisor of the two above)
+    "rows_walked",          # chunks walked x their rows: what the products were given
 )
+
+# rows a trip of ``moe_experts``' walk holds.  A step of fewer assignments
+# (decode: 16 lanes x 8 = 128) is one chunk of its own size.
+CHUNK_ROWS = 2048
 
 
 def moe_router(
@@ -111,8 +119,9 @@ def grouped_matmul(
     """``lhs[rows of group g] @ rhs[g]`` for every group; rows past
     ``sum(group_sizes)`` come back as zeros.  On a TPU the product is the
     Pallas grouped matmul that ships with JAX (megablox ``gmm``: its grid
-    covers the live row tiles only, so dead rows cost nothing); elsewhere
-    ``jax.lax.ragged_dot``.
+    covers the live row tiles only); elsewhere ``jax.lax.ragged_dot``.
+    ``moe_experts`` calls it a chunk of its walk at a time, so ``M`` is a
+    chunk's rows and the groups are the experts' rows inside the chunk.
 
     A layer's matrices may come as the whole stack and the layer's index: a
     kernel's operand is a buffer of its own, so a layer sliced out of the
@@ -147,7 +156,7 @@ def grouped_matmul(
             jnp.zeros((rhs.shape[0],), jnp.int32), group_sizes, (layer * groups,)
         )
     k, n = rhs.shape[1:]
-    tm = 128 if m <= 1024 else 512
+    tm = 128 if m <= 1024 else 256
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
@@ -177,7 +186,7 @@ def moe_experts(
     ``MOE_STATS``.  Assignments to experts outside ``[first_expert,
     first_expert + E_held)`` and those of rows that are not ``valid`` (a
     bucket's padding, an empty lane) sort behind the live rows, where the
-    grouped product does not go."""
+    walk does not go."""
     t, h = x.shape
     e = w_gate[0].shape[1] if isinstance(w_gate, tuple) else w_gate.shape[0]
     k = expert_ids.shape[1]
@@ -185,27 +194,50 @@ def moe_experts(
     real = jnp.ones((t, 1), bool) if valid is None else valid[:, None]
     held = (local >= 0) & (local < e) & real
     group = jnp.where(held, local, e).reshape(-1)           # [T*k], e = not here
-    order = jnp.argsort(group, stable=True)
-    place = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))   # where each landed
-    group_sizes = jnp.zeros((e,), jnp.int32).at[group].add(1, mode="drop")
-    # the sorted buffer has a row for every assignment: the live rows come
-    # first and the grouped product visits their tiles only
-    rows = x[order // k]                                            # [T*k, H]
-    hidden = jax.nn.silu(
-        grouped_matmul(rows, w_gate, group_sizes, impl=impl)
-    ) * grouped_matmul(rows, w_up, group_sizes, impl=impl)
-    out = grouped_matmul(hidden, w_down, group_sizes, impl=impl)    # zeros past the live rows
-    # back in token order: each token's k rows, weighted and summed
-    combined = jnp.einsum(
-        "tkh,tk->th", out[place].reshape(t, k, h), jnp.where(held, probs, 0.0),
-        preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
+    order = jnp.argsort(group, stable=True)                 # live rows first, by expert
+    # (a count by comparison: as a scatter-add of ones it was 0.57 ms at 65,536)
+    group_sizes = jnp.sum(group[:, None] == jnp.arange(e), axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(group_sizes)
+    starts, live_rows = ends - group_sizes, ends[-1]
+    c = min(t * k, CHUNK_ROWS)
+    chunks = (live_rows + c - 1) // c
+    order = jnp.pad(order, (0, -(t * k) % c))               # the last chunk's slice is whole
+    weight = jnp.where(held, probs, 0.0).reshape(-1)
+    # a token's sum is kept as whole (8, 128) float32 tiles, so that adding a
+    # row into it rewrites 6 tiles and not one sublane of 48 (on the chip a
+    # chunk of 2,048 rows of 6,144: 0.46 ms where [T, H] took 2.8)
+    lanes = 128 if h % 128 == 0 else h
+
+    def chunk(i, acc):
+        """Rows ``[lo, lo + c)`` of the sorted order: each expert's rows
+        clipped to them are the chunk's groups."""
+        lo = i * c
+        at = jax.lax.dynamic_slice(order, (lo,), (c,))
+        token = at // k
+        sizes = jnp.clip(ends, lo, lo + c) - jnp.clip(starts, lo, lo + c)
+        rows = x[token]                                             # [c, H]
+        hidden = jax.nn.silu(
+            grouped_matmul(rows, w_gate, sizes, impl=impl)
+        ) * grouped_matmul(rows, w_up, sizes, impl=impl)
+        out = grouped_matmul(hidden, w_down, sizes, impl=impl)      # zeros past the live rows
+        scale = jnp.where(lo + jnp.arange(c) < live_rows, weight[at], 0.0)
+        tiles = out.reshape(c, -1, lanes).astype(jnp.float32)
+        return acc.at[token].add(tiles * scale[:, None, None])
+
+    # (a chunk is a function of its own in the lowered program: JAX stamps what
+    # it lowers inline in a loop's body with the loop's own location, and the
+    # trace finds the grouped products by the name theirs gives them)
+    combined = jax.lax.fori_loop(
+        0, chunks, jax.jit(chunk), jnp.zeros((t, h // lanes, lanes), jnp.float32)
+    ).reshape(t, h).astype(x.dtype)
     stats = jnp.stack([
         jnp.sum(real) * k,
-        jnp.sum(group_sizes),
-        jnp.sum(group_sizes > 0),
+        live_rows,
+        # an expert whose rows straddle chunks has its banks read in each
+        jnp.sum(jnp.where(group_sizes > 0, (ends - 1) // c - starts // c + 1, 0)),
         jnp.max(group_sizes),
         jnp.int32(1),
+        chunks * c,
     ]).astype(jnp.int32)
     return combined, stats
 

@@ -25,11 +25,12 @@ def _banks(key, e, h, i):
             jax.random.normal(kd, (e, i, h)) / 4)
 
 
-def _per_token(x, ids, probs, banks, first=0):
-    """Each token through each of its chosen experts that is held."""
+def _per_token(x, ids, probs, banks, first=0, valid=None):
+    """Each token (of the ``valid`` rows) through each of its chosen experts
+    that is held."""
     gate, up, down = (np.asarray(b) for b in banks)
     out = np.zeros(x.shape, np.float32)
-    for t in range(x.shape[0]):
+    for t in range(x.shape[0]) if valid is None else np.flatnonzero(valid):
         for e, p in zip(np.asarray(ids[t]) - first, np.asarray(probs[t])):
             if 0 <= e < gate.shape[0]:
                 hid = np.asarray(jax.nn.silu(x[t] @ gate[e])) * np.asarray(x[t] @ up[e])

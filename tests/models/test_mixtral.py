@@ -59,8 +59,8 @@ def test_no_token_dropped_when_all_route_to_one_expert():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(jax.nn.silu(x) * x), rtol=1e-5, atol=1e-5
     )
-    # routed, held, experts touched, busiest expert's rows, layers
-    assert stats.tolist() == [t, t, 1, t, 1]
+    # routed, held, experts touched, busiest expert's rows, layers, rows walked
+    assert stats.tolist() == [t, t, 1, t, 1, t]
 
 
 def test_mixtral_prefill_decode_consistency():

@@ -302,6 +302,52 @@ def test_a_layers_expert_banks_are_read_where_they_lie(one_chip):
     assert 10.3e9 < memory.argument_size_in_bytes < 10.7e9
 
 
+@pytest.mark.parametrize("tokens", [16, 8192])
+def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, tokens):
+    """``moe_experts`` at ``k-exaone-236b-l8``'s widths (16 of 128 experts
+    held, 8 choices a token, banks stacked over 7 layers), a decode step's
+    16 lanes and the 8,192 bucket: the three grouped products are the Pallas
+    kernel inside the walk's loop, over ONE chunk's rows, and nothing of
+    ``tokens x 8`` rows but indices is left (the sorted buffers were 805 MB
+    each at 8,192; the program holds the tokens' float32 sum, 201 MB, and
+    a chunk).  The products keep the instruction name ``gmm*`` that
+    ``benchmark/metrics/moe_*.json`` look for, with locations as short as
+    the serving process makes them (``utils/compile_cache.py``): lowered
+    inline in the loop's body they were ``tpu_custom_call.<n>`` on the chip
+    and both metrics fell silent."""
+    import re
+
+    from dynamo_tpu.ops import moe
+
+    s = _sds(one_chip)
+    k, h, i, e, layers = 8, 6144, 2048, 16, 7
+    bank = lambda a, b: (s((layers, e, a, b), jnp.bfloat16), s((), jnp.int32))  # noqa: E731
+
+    def fn(x, ids, probs, gate, up, down, valid):
+        return moe.moe_experts(
+            x, ids, probs, gate, up, down, first_expert=32, valid=valid, impl="pallas")
+
+    full = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        compiled = jax.jit(fn).lower(
+            s((tokens, h), jnp.bfloat16), s((tokens, k), jnp.int32), s((tokens, k), jnp.float32),
+            bank(h, i), bank(h, i), bank(i, h), s((tokens,), jnp.bool_),
+        ).compile()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    hlo = compiled.as_text()
+    rows = min(tokens * k, moe.CHUNK_ROWS)
+    products = re.findall(
+        r"%([\w.\-]+) = bf16\[(\d+),\d+\][^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(products) == 3, products
+    assert all(re.match(r"gmm(\.\d+)?$", name) and int(m) == rows for name, m in products), products
+    # (the count by expert compares [tokens x 8, 16] inside a fusion)
+    wide = {m for m in re.findall(rf"\w+\[{tokens * k},(\d+)\]", hlo) if int(m) >= i}
+    assert tokens * k == rows or not wide, wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+
+
 def _compile_forward(one_chip, config, program):
     """A family's step forward at a cell's shapes, cache donated, as a
     compiled executable for the described chip."""
@@ -347,12 +393,14 @@ def test_step_forward_writes_the_donated_cache_in_place(one_chip, config, progra
     forwards still did, 2.01 / 2.05 / 3.29 GB beside this 1.34 GB cache,
     until it took the shared ones).
 
-    The expert layer's sorted buffers (``[tokens x 2, hidden]`` in and out
-    and twice ``[tokens x 2, 14336]``) grow with the window: a tenth of the
-    cache holds them at 128 tokens, not at 4,096, where a tenth of the cache
-    (134 MB: four layers of weights and a larger cache do not fit the chip)
-    would measure the experts.  That case is held to less than ONE cache,
-    which a copy of it cannot meet."""
+    The expert layer walks its live rows a chunk at a time (``ops/moe.py``):
+    a chunk's rows (``[2048, hidden]`` in and out, twice ``[2048, 14336]``)
+    and the tokens' float32 sum (``[tokens, hidden]``) are 265 MB at 4,096
+    tokens (the sorted buffers of ``tokens x 2`` rows were 641 MB), 16 MB at
+    128.  A tenth of the cache (134 MB: four layers of weights and a larger
+    cache do not fit the chip) would still measure the experts at 4,096, so
+    that case is held to less than ONE cache, which a copy of it cannot
+    meet."""
     compiled, cache_bytes = _compile_forward(one_chip, config, program)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= cache_bytes
