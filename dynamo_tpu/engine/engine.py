@@ -96,6 +96,13 @@ KERNEL_WORK_KEYS = (
     "window_pages_full_total",
 )
 
+def _pages(cache: dict) -> dict:
+    """The cache's page leaves (``[layers, blocks, ...]``: what block ids
+    index, what extract / inject / offload move), without the expert layers'
+    counters that ride the same pytree."""
+    return {name: leaf for name, leaf in cache.items() if name != "moe_stats"}
+
+
 # The expert layers' counters (a routed model; absent otherwise): what the
 # step programs add up on the device (ops/moe.py MOE_STATS), taken by the
 # device thread between steps and summed here, then priced in stats().
@@ -868,7 +875,7 @@ class JaxLlmEngine:
         if config.host_offload_blocks and self.prefix_caching:
             from dynamo_tpu.engine.offload import HostOffloadTier
 
-            leaves = dict(self.cache)
+            leaves = _pages(self.cache)
             self.host_tier = HostOffloadTier(
                 config.host_offload_blocks,
                 {k: (v.shape[0], *v.shape[2:]) for k, v in leaves.items()},
@@ -1584,7 +1591,7 @@ class JaxLlmEngine:
         leaves with different widths)."""
 
         def fn(cache, block_ids):
-            return jax.tree.map(lambda c: c[:, block_ids], cache)
+            return jax.tree.map(lambda c: c[:, block_ids], _pages(cache))
 
         return jax.jit(_named(fn, "dyn_kv_extract"))
 
@@ -1596,9 +1603,11 @@ class JaxLlmEngine:
         def fn(cache, new, block_ids, n):
             maxb = block_ids.shape[0]
             ids = jnp.where(jnp.arange(maxb) < n, block_ids, num_blocks)
-            return jax.tree.map(
-                lambda c, x: c.at[:, ids].set(x.astype(c.dtype), mode="drop"), cache, new
+            written = jax.tree.map(
+                lambda c, x: c.at[:, ids].set(x.astype(c.dtype), mode="drop"),
+                _pages(cache), new,
             )
+            return {**cache, **written}
 
         kwargs = {}
         if self.mesh is not None:
@@ -3219,7 +3228,7 @@ class JaxLlmEngine:
                     return jnp.asarray(out)
 
                 try:
-                    padded = jax.tree.map(pad, self.cache, blocks)
+                    padded = jax.tree.map(pad, _pages(self.cache), blocks)
                     self.cache = self._jit_inject(
                         self.cache, padded, jnp.asarray(ids), jnp.int32(n)
                     )
@@ -3401,7 +3410,7 @@ class JaxLlmEngine:
         ids = np.full((nb,), self.config.num_blocks, np.int32)
         staged = {
             k: np.zeros((v.shape[0], nb, *v.shape[2:]), np.dtype(v.dtype))
-            for k, v in dict(self.cache).items()
+            for k, v in _pages(self.cache).items()
         }
         # one batched read per tier (a G4-resident prefix costs one DCN
         # round trip for the whole plan, not one per block)

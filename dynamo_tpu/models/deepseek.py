@@ -22,10 +22,14 @@ MLA, TPU-first:
 - Prefill decompresses K/V for the current chunk only (dense causal
   attention on the MXU) while writing latents to the paged cache.
 
-Cache layout reuses the engine's {"k", "v"} pytree so paged bookkeeping,
-extract/inject and disagg KV shipping work unchanged:
-    k: [layers, num_blocks, block_size, 1, kv_lora_rank]   (latent)
-    v: [layers, num_blocks, block_size, 1, qk_rope_head_dim] (rope key)
+Cache layout keeps the engine's {"k", "v"} leaves (layers first, blocks
+second), so paged bookkeeping, extract/inject, offload and disagg KV
+shipping work unchanged, in the one layout the MLA kernels copy in place
+(``init_kv_cache``, ``rope_page_width``):
+    k: [layers, num_blocks, block_size, kv_lora_rank]      (latent)
+    v: [layers, num_blocks, block_size, 128-lane tiles]    (rope key, zeros behind)
+The layer loop is the shared one (llama._scan_layer_runs): the dense layers
+then the sparse ones, each a scan whose carry is the cache's flat pages.
 
 Routing: V2-style renormalized softmax top-k, or V3/R1 aux-free sigmoid
 routing (e_score_correction_bias steers selection only, group-limited
@@ -38,6 +42,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 from typing import Any
 
@@ -46,10 +51,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.ops.attention import NEG_INF, write_decode_kv, write_prefill_kv
-from dynamo_tpu.ops.moe import moe_ffn
+from dynamo_tpu.models.llama import LayerKind, LayerRun, _scan_layer_runs, layer_bank
+from dynamo_tpu.ops.attention import NEG_INF, position_major_to_batch
+from dynamo_tpu.ops.moe import MOE_STATS, moe_ffn
 from dynamo_tpu.ops.norms import rms_norm
-from dynamo_tpu.ops.attention import position_major_to_batch
 from dynamo_tpu.ops.quant import mm
 from dynamo_tpu.ops.rope import apply_rope, rope_table
 
@@ -89,6 +94,10 @@ class DeepseekConfig:
     rope_scaling: Any = None
     tie_word_embeddings: bool = False
     dtype: Any = jnp.bfloat16
+    # the grouped product's implementation (as ``MixtralConfig``'s): "auto"
+    # is the platform's in ``ops/moe.py``; the engine, which alone knows of a
+    # mesh, writes "xla" here under one
+    grouped_matmul: str = "auto"
 
     @property
     def qk_head_dim(self) -> int:
@@ -104,6 +113,17 @@ class DeepseekConfig:
     @property
     def num_moe_layers(self) -> int:
         return self.num_layers - self.first_k_dense
+
+    def layer_runs(self) -> tuple[LayerRun, ...]:
+        """The leading dense layers (``params["dense_layers"]``), then the
+        sparse ones (``params["moe_layers"]``), over the one cache pool."""
+        kind = lambda group: LayerKind(window=None, rope=True, pool="kv", group=group)  # noqa: E731
+        kd = self.first_k_dense
+        runs = (
+            LayerRun(kind("dense_layers"), 0, kd, 0),
+            LayerRun(kind("moe_layers"), 0, self.num_moe_layers, kd),
+        )
+        return tuple(run for run in runs if run.count)
 
     @classmethod
     def from_hf_config(cls, config: dict | str | Path) -> "DeepseekConfig":
@@ -175,83 +195,93 @@ class DeepseekConfig:
 # ---------------------------------------------------------------------------
 
 
-def _attn_params(cfg: DeepseekConfig, keys, n: int) -> dict:
-    h = cfg.hidden_size
-    hd_q = cfg.num_heads * cfg.qk_head_dim
-
-    def norm_init(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(cfg.dtype)
-
-    params = {
-        "attn_norm": jnp.ones((n, h), cfg.dtype),
-        "w_dkv": norm_init(keys[0], (n, h, cfg.kv_lora_rank + cfg.qk_rope_head_dim), h),
-        "kv_norm": jnp.ones((n, cfg.kv_lora_rank), cfg.dtype),
-        "w_uk": norm_init(
-            keys[1], (n, cfg.kv_lora_rank, cfg.num_heads * cfg.qk_nope_head_dim),
-            cfg.kv_lora_rank,
-        ),
-        "w_uv": norm_init(
-            keys[2], (n, cfg.kv_lora_rank, cfg.num_heads * cfg.v_head_dim),
-            cfg.kv_lora_rank,
-        ),
-        "wo": norm_init(keys[3], (n, cfg.num_heads * cfg.v_head_dim, h),
-                        cfg.num_heads * cfg.v_head_dim),
-    }
-    if cfg.q_lora_rank:
-        params["w_dq"] = norm_init(keys[4], (n, h, cfg.q_lora_rank), h)
-        params["q_norm"] = jnp.ones((n, cfg.q_lora_rank), cfg.dtype)
-        params["w_uq"] = norm_init(keys[5], (n, cfg.q_lora_rank, hd_q), cfg.q_lora_rank)
-    else:
-        params["wq"] = norm_init(keys[4], (n, h, hd_q), h)
-    return params
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _draw(key, shape, fan_in, dtype):
+    """One drawn array, as ONE program (the division by a constant and the
+    rounding fused: the reference compiles the same expression, and the two
+    agree to the bit; a layer's experts never exist in float32)."""
+    return (jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
 
 
 def init_params(cfg: DeepseekConfig, rng: jax.Array) -> dict:
-    h = cfg.hidden_size
-    kd, km = cfg.first_k_dense, cfg.num_moe_layers
-    keys = jax.random.split(rng, 24)
+    """Random weights from the seed, the recipe a model served without a
+    checkpoint gets (and benchmark/reference/deepseek_mla.py repeats key for
+    key): every leaf that is drawn has its own key of ``split(rng, 32)``, in
+    the order drawn here (embedding, head, then the dense group's attention
+    leaves and MLP, then the sparse group's attention leaves, router, bias,
+    expert banks and shared expert); a leaf stacked over layers draws layer
+    ``l`` of its stack from ``fold_in(key, l)``, so that nobody holds a whole
+    stack in float32 (a layer's 64 experts are 0.74 GB there); each matrix
+    ``normal / sqrt(fan_in)`` in ``cfg.dtype`` (the embedding: fan-in 1);
+    the selection bias ``0.01 x normal`` in float32 (small: a trained bias is
+    what balances the experts' load, models/exaone_moe.py); norms all ones."""
+    keys = iter(jax.random.split(rng, 32))
+    h, v = cfg.hidden_size, cfg.vocab_size
+    hd_q = cfg.num_heads * cfg.qk_head_dim
+    r = cfg.kv_lora_rank
 
-    def norm_init(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(cfg.dtype)
+    def one(key, shape, fan_in, dtype):
+        return _draw(key, tuple(shape), float(fan_in), dtype)
+
+    def draw(shape, fan_in, dtype=cfg.dtype):
+        """One leaf stacked over ``shape[0]`` layers."""
+        key = next(keys)
+        return jnp.stack([
+            one(jax.random.fold_in(key, layer), shape[1:], fan_in, dtype)
+            for layer in range(shape[0])
+        ])
+
+    def attention(n):
+        leaves = {
+            "attn_norm": jnp.ones((n, h), cfg.dtype),
+            "mlp_norm": jnp.ones((n, h), cfg.dtype),
+            "kv_norm": jnp.ones((n, r), cfg.dtype),
+            "w_dkv": draw((n, h, r + cfg.qk_rope_head_dim), h),
+            "w_uk": draw((n, r, cfg.num_heads * cfg.qk_nope_head_dim), r),
+            "w_uv": draw((n, r, cfg.num_heads * cfg.v_head_dim), r),
+            "wo": draw((n, cfg.num_heads * cfg.v_head_dim, h), cfg.num_heads * cfg.v_head_dim),
+        }
+        if cfg.q_lora_rank:
+            leaves["w_dq"] = draw((n, h, cfg.q_lora_rank), h)
+            leaves["q_norm"] = jnp.ones((n, cfg.q_lora_rank), cfg.dtype)
+            leaves["w_uq"] = draw((n, cfg.q_lora_rank, hd_q), cfg.q_lora_rank)
+        else:
+            leaves["wq"] = draw((n, h, hd_q), h)
+        return leaves
 
     params: dict = {
-        "embed": norm_init(keys[0], (cfg.vocab_size, h), 1.0),
+        "embed": one(next(keys), (v, h), 1.0, cfg.dtype),
         "final_norm": jnp.ones((h,), cfg.dtype),
     }
+    if not cfg.tie_word_embeddings:
+        params["lm_head"] = one(next(keys), (h, v), h, cfg.dtype)
+    kd, km = cfg.first_k_dense, cfg.num_moe_layers
     if kd:
         i = cfg.intermediate_size
-        dense = _attn_params(cfg, keys[1:7], kd)
-        dense.update(
-            mlp_norm=jnp.ones((kd, h), cfg.dtype),
-            w_gate=norm_init(keys[7], (kd, h, i), h),
-            w_up=norm_init(keys[8], (kd, h, i), h),
-            w_down=norm_init(keys[9], (kd, i, h), i),
-        )
-        params["dense_layers"] = dense
+        params["dense_layers"] = {
+            **attention(kd),
+            "w_gate": draw((kd, h, i), h),
+            "w_up": draw((kd, h, i), h),
+            "w_down": draw((kd, i, h), i),
+        }
     if km:
         mi, e = cfg.moe_intermediate_size, cfg.num_experts
         si = cfg.n_shared_experts * mi
-        moe = _attn_params(cfg, keys[10:16], km)
+        moe = {**attention(km), "w_router": draw((km, h, e), h)}
+        if cfg.scoring_func == "sigmoid":
+            moe["router_bias"] = 0.01 * draw((km, e), 1.0, jnp.float32)
         moe.update(
-            mlp_norm=jnp.ones((km, h), cfg.dtype),
-            w_router=norm_init(keys[16], (km, h, e), h),
-            **(
-                {"router_bias": jnp.zeros((km, e), jnp.float32)}
-                if cfg.scoring_func == "sigmoid" else {}
-            ),
-            w_gate=norm_init(keys[17], (km, e, h, mi), h),
-            w_up=norm_init(keys[18], (km, e, h, mi), h),
-            w_down=norm_init(keys[19], (km, e, mi, h), mi),
+            w_gate=draw((km, e, h, mi), h),
+            w_up=draw((km, e, h, mi), h),
+            w_down=draw((km, e, mi, h), mi),
         )
         if si:
             moe.update(
-                ws_gate=norm_init(keys[20], (km, h, si), h),
-                ws_up=norm_init(keys[21], (km, h, si), h),
-                ws_down=norm_init(keys[22], (km, si, h), si),
+                ws_gate=draw((km, h, si), h),
+                ws_up=draw((km, h, si), h),
+                ws_down=draw((km, si, h), si),
             )
         params["moe_layers"] = moe
-    if not cfg.tie_word_embeddings:
-        params["lm_head"] = norm_init(keys[23], (h, cfg.vocab_size), h)
     return params
 
 
@@ -314,22 +344,51 @@ def param_specs(cfg: DeepseekConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# KV cache: latent + rope-key, tiny per token
+# KV cache: flat latent pages the kernels copy in place
 # ---------------------------------------------------------------------------
+
+_LANES = 128    # a TPU tile's minor axis
+
+
+def rope_page_width(cfg: DeepseekConfig) -> int:
+    """Width the rotated key is STORED at: ``qk_rope_head_dim`` rounded up to
+    whole 128-lane tiles (64 -> 128, zeros behind the key).  A page is copied
+    HBM -> VMEM whole, by DMA, and a DMA moves whole tiles: stored 64 wide the
+    rope cache was zero-padded to 128, all of it, in every call of every
+    layer (25-50 MB a layer-call at 11,008 blocks).  The zeros add exact
+    zeros to the scores and cost 128 B a token-layer: 1,280 B at 512 + 64,
+    where the published 1,152 B would not be read in place."""
+    return -(-cfg.qk_rope_head_dim // _LANES) * _LANES
 
 
 def init_kv_cache(cfg: DeepseekConfig, num_blocks: int, block_size: int, dtype=None):
+    """``k``: the normalised latents ``[layers, blocks, block_size,
+    kv_lora_rank]`` (keys AND values of every head); ``v``: the one rotated
+    key all heads share, ``[layers, blocks, block_size, rope_page_width]``.
+    No head axis: a unit axis second from last would be the tiled one.  The
+    layer loop views both as flat pages ``[layers x blocks, block_size,
+    width]`` (llama._scan_layer_runs), which is what the kernels read.
+    ``moe_stats`` collects the expert layers' counters (ops/moe.py
+    ``MOE_STATS``) until the engine takes them."""
     dtype = dtype or cfg.dtype
-    return {
-        "k": jnp.zeros((cfg.num_layers, num_blocks, block_size, 1, cfg.kv_lora_rank), dtype),
-        "v": jnp.zeros((cfg.num_layers, num_blocks, block_size, 1, cfg.qk_rope_head_dim), dtype),
+    page = (cfg.num_layers, num_blocks, block_size)
+    cache = {
+        "k": jnp.zeros((*page, cfg.kv_lora_rank), dtype),
+        "v": jnp.zeros((*page, rope_page_width(cfg)), dtype),
     }
+    if cfg.num_moe_layers:
+        cache["moe_stats"] = jnp.zeros((len(MOE_STATS),), jnp.int32)
+    return cache
 
 
 def kv_cache_specs(cfg: DeepseekConfig) -> dict:
     # the latent is shared across heads — replicate across tp (it is ~4x
     # smaller than a GQA cache even unsharded)
-    return {"k": P(None, None, None, None, None), "v": P(None, None, None, None, None)}
+    pages = P(None, None, None, None)
+    specs = {"k": pages, "v": pages}
+    if cfg.num_moe_layers:
+        specs["moe_stats"] = P(None)
+    return specs
 
 
 def make_rope_tables(cfg: DeepseekConfig):
@@ -344,6 +403,15 @@ def make_rope_tables(cfg: DeepseekConfig):
 # ---------------------------------------------------------------------------
 # Forward passes
 # ---------------------------------------------------------------------------
+#
+# One block (``_block``) and one layer loop (llama._scan_layer_runs: the dense
+# run, then the sparse run, the cache's flat pages the scans' carry, written
+# in place) for all five forwards.  A forward supplies ``attend(w, attn_in,
+# ck_pages, kr_pages, at) -> (attn_out [rows, hidden], ck_pages, kr_pages)``:
+# it rotates at its positions, writes its tokens' latents into the layer's
+# pages (``at``: llama._LayerPages) and attends.  The prompt-only forwards
+# decompress K and V of their own chunk; everything that reads the cache
+# attends absorbed, in latent space.
 
 
 def _project_q(w, x, cfg: DeepseekConfig):
@@ -357,6 +425,13 @@ def _project_q(w, x, cfg: DeepseekConfig):
     return q.reshape(t, cfg.num_heads, cfg.qk_head_dim)
 
 
+def _queries(w, x, cfg: DeepseekConfig, rotate):
+    """x [t, h] -> (q_nope [t, H, nope], q_rope [t, H, rope] rotated by
+    ``rotate``)."""
+    q = _project_q(w, x, cfg)
+    return q[..., : cfg.qk_nope_head_dim], rotate(q[..., cfg.qk_nope_head_dim:])
+
+
 def _latent_kv(w, x, cfg: DeepseekConfig):
     """x [t, h] → (c_kv [t, r] normalized, k_rope [t, rope_dim] un-roped)."""
     dkv = mm(x, w["w_dkv"])
@@ -365,375 +440,192 @@ def _latent_kv(w, x, cfg: DeepseekConfig):
     return c_kv, k_rope
 
 
-def _mla_prefill_attn(w, x, cfg: DeepseekConfig, positions, seq_len, k_layer, v_layer,
-                      block_ids, cos, sin):
-    """Dense causal MLA attention for one prefill chunk; writes latents to
-    the paged cache.  Returns (attn_out [s, h], (k_layer, v_layer))."""
-    s = x.shape[0]
-    H = cfg.num_heads
-    q = _project_q(w, x, cfg)
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, positions, cos, sin)
+def _up_projections(w, cfg: DeepseekConfig):
+    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim)
+    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim)
+    return w_uk, w_uv
 
-    c_kv, k_rope = _latent_kv(w, x, cfg)
-    k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
 
-    k_layer, v_layer = write_prefill_kv(
-        k_layer, v_layer, c_kv[:, None, :], k_rope[:, None, :], block_ids, seq_len
-    )
+def _absorb(w_uk, q_nope, q_rope, width: int, dtype):
+    """The absorbed queries the kernels take, in the model's dtype:
+    ``q_lat [..., H, R]`` (q_nope through the K up-projection, accumulated in
+    float32) and ``q_rope`` widened with zeros to the rope page's width (an
+    activation's pad, a few KB a token; the cache is never padded)."""
+    q_lat = jnp.einsum(
+        "...hn,rhn->...hr", q_nope, w_uk, preferred_element_type=jnp.float32
+    ).astype(dtype)
+    pad = [(0, 0)] * (q_rope.ndim - 1) + [(0, width - q_rope.shape[-1])]
+    return q_lat, jnp.pad(q_rope.astype(dtype), pad)
 
-    # decompress K/V for the in-chunk dense attention (prefill is
-    # compute-bound; this keeps the big matmuls on the MXU)
-    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
-    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
-    k_nope = jnp.einsum("tr,rhn->thn", c_kv, w_uk)
-    v = jnp.einsum("tr,rhv->thv", c_kv, w_uv)
 
-    scale = jnp.float32(cfg.attn_scale)
+def _decompress(w, w_uv, ctx, cfg: DeepseekConfig):
+    """Latent context [..., H, R] -> the attention sublayer's output rows
+    [rows, hidden], through the V up-projection and ``wo``."""
+    out = jnp.einsum(
+        "...hr,rhv->...hv", ctx, w_uv.astype(ctx.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(cfg.dtype)
+    return mm(out.reshape(-1, cfg.num_heads * cfg.v_head_dim), w["wo"])
+
+
+def _write_latents(ck_pages, kr_pages, c_kv, k_rope, slots):
+    """Rows ``c_kv [t, R]`` / ``k_rope [t, rope]`` into flat slots of the
+    whole cache (``at.slots``; out of range = dropped), in place: the pages
+    are the layer loop's carry."""
+    n, bs, r = ck_pages.shape
+    width = kr_pages.shape[-1]
+    k_rope = jnp.pad(k_rope, ((0, 0), (0, width - k_rope.shape[-1])))
+    with jax.named_scope("kv_write"):
+        ck = ck_pages.reshape(n * bs, r).at[slots].set(
+            c_kv.astype(ck_pages.dtype), mode="drop")
+        kr = kr_pages.reshape(n * bs, width).at[slots].set(
+            k_rope.astype(kr_pages.dtype), mode="drop")
+    return ck.reshape(ck_pages.shape), kr.reshape(kr_pages.shape)
+
+
+def _prompt_slots(at, block_ids, count, s: int):
+    """Flat slots of a prompt chunk's ``s`` rows (``count`` of them real) in
+    the blocks ``block_ids`` of layer ``at``."""
+    idx = jnp.arange(s, dtype=jnp.int32)
+    slots = block_ids[idx // at.block_size] * at.block_size + idx % at.block_size
+    return at.slots(jnp.where(idx < count, slots, at.num_blocks * at.block_size))
+
+
+def _by_head(x):
+    """[t, H, d] -> [H, t, d] float32."""
+    return x.astype(jnp.float32).transpose(1, 0, 2)
+
+
+def _prompt_attention(q_nope, q_rope, k_nope, k_rope, v, count, scale, prefix=None):
+    """A prompt chunk's causal attention against itself, DECOMPRESSED (every
+    head's own keys ``k_nope`` and values ``v``, the one rotated key), float32,
+    a head at a time: 16 heads' scores of an 8,192-token chunk at once are
+    4.3 GB beside the weights.  Rows past ``count`` are masked as keys.
+
+    ``prefix`` = ``(q_lat [s, H, R], q_wide [s, H, P], ck [t, R], kr [t, P],
+    visible [t], w_uv [R, H, v])``: resident latents the queries attend too,
+    absorbed, under the SAME softmax; their context is summed in latent space
+    and decompressed once.  Returns [s, H, v] float32."""
+    s = q_nope.shape[0]
+    pos = jnp.arange(s)
+    mask = (pos[None, :] <= pos[:, None]) & (pos[None, :] < count)
+    k_rope = k_rope.astype(jnp.float32)
+
+    def head(of):
+        qn, qr, kn, vh, *more = of
+        sc = jnp.where(mask, (qn @ kn.T + qr @ k_rope.T) * scale, NEG_INF)
+        if prefix is None:
+            return jax.nn.softmax(sc, axis=-1) @ vh
+        ql, qw, uv = more
+        sp = jnp.where(visible[None, :], (ql @ ck.T + qw @ kr.T) * scale, NEG_INF)
+        weights = jax.nn.softmax(jnp.concatenate([sp, sc], axis=-1), axis=-1)
+        t = ck.shape[0]
+        return (weights[:, :t] @ ck) @ uv + weights[:, t:] @ vh
+
+    heads = (_by_head(q_nope), _by_head(q_rope), _by_head(k_nope), _by_head(v))
+    if prefix is not None:
+        q_lat, q_wide, ck, kr, visible, w_uv = prefix
+        ck, kr = ck.astype(jnp.float32), kr.astype(jnp.float32)
+        heads += (_by_head(q_lat), _by_head(q_wide), w_uv.astype(jnp.float32).transpose(1, 0, 2))
+    return jax.lax.map(head, heads).transpose(1, 0, 2)
+
+
+def _gathered_scores(q_lat, q_rope, ck, kr, visible, scale):
+    """The XLA fallback's absorbed scores against gathered pages: ``q_*``
+    [b, ..., H, *], ``ck`` / ``kr`` [b, t, *], ``visible`` broadcastable to
+    the result [b, H, ..., t]."""
     logits = (
-        jnp.einsum("qhn,khn->hqk", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-        + jnp.einsum("qhp,kp->hqk", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
+        jnp.einsum("b...hr,btr->bh...t", q_lat.astype(jnp.float32), ck.astype(jnp.float32))
+        + jnp.einsum("b...hp,btp->bh...t", q_rope.astype(jnp.float32), kr.astype(jnp.float32))
     ) * scale
-    pos = jnp.arange(s)
-    mask = (pos[None, :] <= pos[:, None]) & (pos[None, :] < seq_len)  # [q, k]
-    logits = jnp.where(mask[None], logits, NEG_INF)
-    weights = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("hqk,khv->qhv", weights, v.astype(jnp.float32)).astype(cfg.dtype)
-    return mm(out.reshape(s, -1), w["wo"]), (k_layer, v_layer)
-
-
-def _mla_prefill_attn_with_prefix(
-    w, x, cfg: DeepseekConfig, positions, tail_len, start_pos, k_layer, v_layer,
-    full_block_ids, tail_block_ids, cos, sin,
-):
-    """Continued MLA prefill: the tail's queries attend to the resident
-    prefix LATENTS (absorbed form — scores in latent space, context
-    decompressed once) jointly with the in-chunk dense attention under one
-    softmax; only the tail's latents are written.  Enables prefix-cache
-    reuse and chunked prefill for the MLA family."""
-    s = x.shape[0]
-    H = cfg.num_heads
-    q = _project_q(w, x, cfg)
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, positions, cos, sin)
-
-    c_kv, k_rope = _latent_kv(w, x, cfg)
-    k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
-
-    # gather the resident prefix BEFORE writing the tail
-    block_size = k_layer.shape[1]
-    t_pref = full_block_ids.shape[0] * block_size
-    ck_pref = k_layer[full_block_ids].reshape(t_pref, cfg.kv_lora_rank)
-    kr_pref = v_layer[full_block_ids].reshape(t_pref, cfg.qk_rope_head_dim)
-
-    k_layer, v_layer = write_prefill_kv(
-        k_layer, v_layer, c_kv[:, None, :], k_rope[:, None, :], tail_block_ids, tail_len
-    )
-
-    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
-    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
-    scale = jnp.float32(cfg.attn_scale)
-
-    # prefix scores, absorbed: q_lat·ck + q_rope·kr (identical math to
-    # decompressing the prefix keys, without materializing them per head)
-    q_lat = jnp.einsum(
-        "qhn,rhn->qhr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32)
-    )
-    sp = (
-        jnp.einsum("qhr,tr->hqt", q_lat, ck_pref.astype(jnp.float32))
-        + jnp.einsum("qhp,tp->hqt", q_rope.astype(jnp.float32), kr_pref.astype(jnp.float32))
-    ) * scale
-    pref_valid = jnp.arange(t_pref)[None, :] < start_pos  # [1, Tp]
-    sp = jnp.where(pref_valid[None], sp, NEG_INF)
-
-    # in-chunk dense scores (decompressed, as in _mla_prefill_attn)
-    k_nope = jnp.einsum("tr,rhn->thn", c_kv, w_uk)
-    sc = (
-        jnp.einsum("qhn,khn->hqk", q_nope.astype(jnp.float32), k_nope.astype(jnp.float32))
-        + jnp.einsum("qhp,kp->hqk", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
-    ) * scale
-    pos = jnp.arange(s)
-    chunk_mask = (pos[None, :] <= pos[:, None]) & (pos[None, :] < tail_len)
-    sc = jnp.where(chunk_mask[None], sc, NEG_INF)
-
-    # one softmax across prefix + chunk keys
-    logits = jnp.concatenate([sp, sc], axis=-1)  # [H, s, Tp + s]
-    weights = jax.nn.softmax(logits, axis=-1)
-    wp, wc = weights[..., :t_pref], weights[..., t_pref:]
-
-    # prefix context in latent space, decompressed once; chunk context dense
-    ctx_lat = jnp.einsum("hqt,tr->qhr", wp, ck_pref.astype(jnp.float32))
-    out_pref = jnp.einsum("qhr,rhv->qhv", ctx_lat, w_uv.astype(jnp.float32))
-    v_chunk = jnp.einsum("tr,rhv->thv", c_kv, w_uv)
-    out_chunk = jnp.einsum("hqk,khv->qhv", wc, v_chunk.astype(jnp.float32))
-    out = (out_pref + out_chunk).astype(cfg.dtype)
-    return mm(out.reshape(s, -1), w["wo"]), (k_layer, v_layer)
-
-
-def _mla_decode_attn(w, x, cfg: DeepseekConfig, positions, k_layer, v_layer,
-                     block_tables, context_lens, slot_ids, cos, sin,
-                     attention: str = "jax"):
-    """Absorbed-form batched decode attention against the latent cache.
-
-    ``attention="pallas"`` runs the MLA paged-attention kernel
-    (ops/pallas/mla_attention.py): page latents stream VMEM-ward via the
-    block table with online softmax — no [B, maxb*bs, R] gather
-    materialized in HBM.  The XLA gather path is the portable fallback.
-    """
-    b = x.shape[0]
-    H = cfg.num_heads
-    q = _project_q(w, x, cfg)
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope[:, None], positions[:, None], cos, sin)[:, 0]
-
-    c_kv_new, k_rope_new = _latent_kv(w, x, cfg)
-    k_rope_new = apply_rope(k_rope_new[:, None, None, :], positions[:, None], cos, sin)[:, 0]
-    k_layer, v_layer = write_decode_kv(
-        k_layer, v_layer, c_kv_new[:, None, :], k_rope_new, slot_ids
-    )
-
-    # absorb q through the k up-projection: scores live in latent space
-    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
-    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32))
-
-    num_blocks, block_size = k_layer.shape[0], k_layer.shape[1]
-    scale = float(cfg.attn_scale)
-
-    if attention in ("pallas", "pallas_interpret"):
-        from dynamo_tpu.ops.pallas.mla_attention import mla_paged_attention_decode
-
-        ctx = mla_paged_attention_decode(
-            q_lat, q_rope,
-            k_layer.reshape(num_blocks, block_size, cfg.kv_lora_rank),
-            v_layer.reshape(num_blocks, block_size, cfg.qk_rope_head_dim),
-            block_tables, context_lens,
-            scale=scale, interpret=attention == "pallas_interpret",
-        )
-    else:
-        max_blocks = block_tables.shape[1]
-        length = max_blocks * block_size
-        ck = k_layer[block_tables].reshape(b, length, cfg.kv_lora_rank)
-        kr = v_layer[block_tables].reshape(b, length, cfg.qk_rope_head_dim)
-        logits = (
-            jnp.einsum("bhr,btr->bht", q_lat, ck.astype(jnp.float32))
-            + jnp.einsum("bhp,btp->bht", q_rope.astype(jnp.float32), kr.astype(jnp.float32))
-        ) * scale
-        valid = jnp.arange(length)[None, :] < context_lens[:, None]
-        logits = jnp.where(valid[:, None, :], logits, NEG_INF)
-        weights = jax.nn.softmax(logits, axis=-1)
-        # context in latent space
-        ctx = jnp.einsum("bht,btr->bhr", weights, ck.astype(jnp.float32))
-    # decompress through the v up-projection
-    out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv.astype(jnp.float32)).astype(cfg.dtype)
-    return mm(out.reshape(b, -1), w["wo"]), (k_layer, v_layer)
-
-
-def _mla_unified_attn(w, x, cfg: DeepseekConfig, positions, token_pos,
-                      token_lane, token_slot, k_layer, v_layer, block_tables,
-                      span_lane, span_first, span_count, kv_steps, cos, sin,
-                      attention: str = "jax", tb_tokens: int = 8):
-    """Absorbed-form ragged unified-batch MLA attention: the flat token
-    axis carries chunked-prefill spans + decode tokens, every token writes
-    its latent before anyone reads, scores stay in latent space per token.
-    ``attention="pallas"`` runs the packed-lane ragged MLA kernel; the XLA
-    twin (ops/attention.ragged_mla_paged_attention) is the fallback."""
-    t = x.shape[0]
-    H = cfg.num_heads
-    q = _project_q(w, x, cfg)
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, positions, cos, sin)
-
-    c_kv, k_rope = _latent_kv(w, x, cfg)
-    k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)
-    k_layer, v_layer = write_decode_kv(
-        k_layer, v_layer, c_kv[:, None, :], k_rope, token_slot
-    )
-
-    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
-    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
-    q_lat = jnp.einsum(
-        "thn,rhn->thr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32)
-    )
-
-    num_blocks, block_size = k_layer.shape[0], k_layer.shape[1]
-    scale = float(cfg.attn_scale)
-    ck3 = k_layer.reshape(num_blocks, block_size, cfg.kv_lora_rank)
-    kr3 = v_layer.reshape(num_blocks, block_size, cfg.qk_rope_head_dim)
-
-    if attention in ("pallas", "pallas_interpret"):
-        from dynamo_tpu.ops.pallas import ragged_mla_attention
-
-        ctx = ragged_mla_attention(
-            q_lat, q_rope, ck3, kr3, token_lane, token_pos,
-            block_tables, span_lane, span_first, span_count, kv_steps,
-            scale=scale, tb_tokens=tb_tokens,
-            interpret=attention == "pallas_interpret",
-        )
-    else:
-        from dynamo_tpu.ops.attention import ragged_mla_paged_attention
-
-        ctx = ragged_mla_paged_attention(
-            q_lat, q_rope, ck3, kr3, block_tables, token_lane, token_pos,
-            scale=scale,
-        )
-    out = jnp.einsum("thr,rhv->thv", ctx, w_uv.astype(jnp.float32)).astype(cfg.dtype)
-    return mm(out.reshape(t, -1), w["wo"]), (k_layer, v_layer)
-
-
-def _mla_window_attn(w, x, cfg: DeepseekConfig, positions, k_layer, v_layer,
-                     block_tables, context_lens, flat_slots, cos, sin,
-                     b: int, w_len: int, attention: str = "jax"):
-    """Multi-query absorbed-form attention for speculative verification:
-    w window queries per lane against the latent cache.
-    ``attention="pallas"`` runs the MLA window kernel (W queries folded
-    into the head axis, latent pages streamed once for all W positions);
-    the XLA gather path is the portable fallback.
-    ``x`` is position-major flat [w*b, h] (see mixtral_forward_verify on
-    why dispatch order matters for the MoE layers)."""
-    H = cfg.num_heads
-
-    def to_bw(t, *tail):
-        return position_major_to_batch(t, w_len, b, *tail)
-
-    q = _project_q(w, x, cfg)                    # [w*b, H, qk_head_dim]
-    q = to_bw(q, H, cfg.qk_head_dim)             # [b, w, H, d]
-    q_nope, q_rope = q[..., : cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim :]
-    q_rope = apply_rope(q_rope, positions, cos, sin)  # [b, w, H, p]
-
-    c_kv_new, k_rope_new = _latent_kv(w, x, cfg)  # [w*b, r], [w*b, p]
-    k_rope_bw = to_bw(k_rope_new, cfg.qk_rope_head_dim)[:, :, None, :]  # [b, w, 1, p]
-    k_rope_bw = apply_rope(k_rope_bw, positions, cos, sin)
-    k_layer, v_layer = write_decode_kv(
-        k_layer, v_layer,
-        c_kv_new[:, None, :],
-        k_rope_bw.transpose(1, 0, 2, 3).reshape(w_len * b, 1, -1),
-        flat_slots,
-    )
-
-    w_uk = w["w_uk"].reshape(cfg.kv_lora_rank, H, cfg.qk_nope_head_dim)
-    w_uv = w["w_uv"].reshape(cfg.kv_lora_rank, H, cfg.v_head_dim)
-    q_lat = jnp.einsum(
-        "bwhn,rhn->bwhr", q_nope.astype(jnp.float32), w_uk.astype(jnp.float32)
-    )
-
-    num_blocks, block_size = k_layer.shape[0], k_layer.shape[1]
-    if attention in ("pallas", "pallas_interpret"):
-        from dynamo_tpu.ops.pallas.mla_attention import (
-            mla_paged_window_attention_decode,
-        )
-
-        ctx = mla_paged_window_attention_decode(
-            q_lat, q_rope,
-            k_layer.reshape(num_blocks, block_size, cfg.kv_lora_rank),
-            v_layer.reshape(num_blocks, block_size, cfg.qk_rope_head_dim),
-            block_tables, context_lens,
-            scale=float(cfg.attn_scale),
-            interpret=attention == "pallas_interpret",
-        )
-    else:
-        max_blocks = block_tables.shape[1]
-        length = max_blocks * block_size
-        ck = k_layer[block_tables].reshape(b, length, cfg.kv_lora_rank)
-        kr = v_layer[block_tables].reshape(b, length, cfg.qk_rope_head_dim)
-        logits = (
-            jnp.einsum("bwhr,btr->bhwt", q_lat, ck.astype(jnp.float32))
-            + jnp.einsum("bwhp,btp->bhwt", q_rope.astype(jnp.float32), kr.astype(jnp.float32))
-        ) * float(cfg.attn_scale)
-        q_pos = context_lens[:, None] - w_len + jnp.arange(w_len)[None, :]   # [b, w]
-        kv_pos = jnp.arange(length)[None, None, :]                            # [1, 1, t]
-        mask = kv_pos <= q_pos[:, :, None]                                    # [b, w, t]
-        logits = jnp.where(mask[:, None, :, :], logits, NEG_INF)
-        weights = jax.nn.softmax(logits, axis=-1)
-        ctx = jnp.einsum("bhwt,btr->bwhr", weights, ck.astype(jnp.float32))
-    out = jnp.einsum("bwhr,rhv->bwhv", ctx, w_uv.astype(jnp.float32)).astype(cfg.dtype)
-    flat = out.transpose(1, 0, 2, 3).reshape(w_len * b, -1)
-    return mm(flat, w["wo"]), (k_layer, v_layer)
+    return jax.nn.softmax(jnp.where(visible, logits, NEG_INF), axis=-1)
 
 
 def _dense_mlp(w, x):
     return mm(jax.nn.silu(mm(x, w["w_gate"])) * mm(x, w["w_up"]), w["w_down"])
 
 
-def _moe_mlp(w, x, cfg: DeepseekConfig):
-    routed = moe_ffn(
-        x, w["w_router"], w["w_gate"], w["w_up"], w["w_down"],
+def _moe_mlp(w, x, cfg: DeepseekConfig, valid=None):
+    """The routed experts (all held here, every row of ``valid`` walked) and
+    the shared ones; returns the layer's ``MOE_STATS`` beside the output."""
+    routed, stats = moe_ffn(
+        x, w["w_router"],
+        *(layer_bank(w, name) for name in ("w_gate", "w_up", "w_down")),
         top_k=cfg.experts_per_token,
         router_bias=w.get("router_bias"),
         scoring="sigmoid_noaux" if cfg.scoring_func == "sigmoid" else "softmax",
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         norm_topk_prob=cfg.norm_topk_prob,
+        valid=valid, impl=cfg.grouped_matmul, with_stats=True,
     )
     out = routed * jnp.asarray(cfg.routed_scaling_factor, routed.dtype)
     if cfg.n_shared_experts:
         out = out + mm(jax.nn.silu(mm(x, w["ws_gate"])) * mm(x, w["ws_up"]), w["ws_down"])
-    return out
+    return out, stats
 
 
-def _run_stack(params_key, mlp_fn, x, cache_k, cache_v, attn_fn, cfg):
-    """Scan one homogeneous layer stack, threading its cache slice."""
+def _block(cfg: DeepseekConfig, attend, valid, x, w, ck_pages, kr_pages, at):
+    """THE transformer block of the family: latent attention (the forward's
+    ``attend``), then the dense MLP or the expert layer, each behind its
+    RMSNorm.  ``valid`` [rows]: the rows that are real tokens (the expert
+    layer walks no others)."""
+    attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
+    attn_out, ck_pages, kr_pages = attend(w, attn_in, ck_pages, kr_pages, at)
+    x = x + attn_out
+    mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
+    if "w_router" not in w:
+        with jax.named_scope("mlp"):
+            return x + _dense_mlp(w, mlp_in), ck_pages, kr_pages
+    with jax.named_scope("moe"):
+        out, stats = _moe_mlp(w, mlp_in, cfg, valid)
+    return x + out, ck_pages, kr_pages, stats
 
-    def layer(x, layer_in):
-        w, k_layer, v_layer = layer_in
-        attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
-        attn_out, (k_layer, v_layer) = attn_fn(w, attn_in, k_layer, v_layer)
-        x = x + attn_out
-        mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
-        x = x + mlp_fn(w, mlp_in)
-        return x, (k_layer, v_layer)
 
-    return jax.lax.scan(layer, x, (params_key, cache_k, cache_v))
-
-
-def _forward(params, cfg: DeepseekConfig, x, kv_cache, attn_fn):
-    """Shared trunk: dense stack then MoE stack, cache split on the layer
-    axis and re-concatenated."""
-    kd = cfg.first_k_dense
-    k_cache, v_cache = kv_cache["k"], kv_cache["v"]
-    new_k_parts, new_v_parts = [], []
-    if kd:
-        x, (nk, nv) = _run_stack(
-            params["dense_layers"], lambda w, t: _dense_mlp(w, t),
-            x, k_cache[:kd], v_cache[:kd], attn_fn, cfg,
-        )
-        new_k_parts.append(nk)
-        new_v_parts.append(nv)
-    if cfg.num_moe_layers:
-        x, (nk, nv) = _run_stack(
-            params["moe_layers"], lambda w, t: _moe_mlp(w, t, cfg),
-            x, k_cache[kd:], v_cache[kd:], attn_fn, cfg,
-        )
-        new_k_parts.append(nk)
-        new_v_parts.append(nv)
-    new_cache = {
-        "k": jnp.concatenate(new_k_parts) if len(new_k_parts) > 1 else new_k_parts[0],
-        "v": jnp.concatenate(new_v_parts) if len(new_v_parts) > 1 else new_v_parts[0],
-    }
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    return x, new_cache
+def _forward(params, cfg: DeepseekConfig, x, kv_cache, attend, valid=None):
+    """Shared trunk: the dense run then the sparse run over the cache's flat
+    pages, carried and written in place; final norm."""
+    x, kv_cache = _scan_layer_runs(
+        partial(_block, cfg, attend, valid), x, params, kv_cache, cfg.layer_runs()
+    )
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps), kv_cache
 
 
 def _logits(params, cfg, x):
-    if cfg.tie_word_embeddings:
-        return x @ params["embed"].T.astype(x.dtype)
-    return mm(x, params["lm_head"])
+    with jax.named_scope("logits"):
+        if cfg.tie_word_embeddings:
+            return x @ params["embed"].T.astype(x.dtype)
+        return mm(x, params["lm_head"])
 
 
 def deepseek_forward_prefill(
     params, cfg: DeepseekConfig, token_ids, kv_cache, block_ids, seq_len, start_pos,
     cos, sin,
 ):
-    """Single-sequence prefill → (last-token logits [vocab], new cache)."""
+    """Single-sequence prefill → (last-token logits [vocab], new cache).
+    Dense causal attention over the chunk with K and V decompressed (the
+    chunk only; nothing is read from the cache), latents written."""
     s = token_ids.shape[0]
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = start_pos + jnp.arange(s, dtype=jnp.int32)
+    scale = jnp.float32(cfg.attn_scale)
 
-    def attn(w, attn_in, k_layer, v_layer):
-        return _mla_prefill_attn(
-            w, attn_in, cfg, positions, seq_len, k_layer, v_layer, block_ids, cos, sin
-        )
+    def attend(w, attn_in, ck_pages, kr_pages, at):
+        q_nope, q_rope = _queries(
+            w, attn_in, cfg, lambda q: apply_rope(q, positions, cos, sin))
+        c_kv, k_rope = _latent_kv(w, attn_in, cfg)
+        k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
+        pages = _write_latents(
+            ck_pages, kr_pages, c_kv, k_rope, _prompt_slots(at, block_ids, seq_len, s))
+        w_uk, w_uv = _up_projections(w, cfg)
+        with jax.named_scope("attn"):
+            k_nope = jnp.einsum("tr,rhn->thn", c_kv, w_uk)
+            v = jnp.einsum("tr,rhv->thv", c_kv, w_uv)
+            out = _prompt_attention(
+                q_nope, q_rope, k_nope, k_rope, v, seq_len, scale).astype(cfg.dtype)
+        return mm(out.reshape(s, -1), w["wo"]), *pages
 
-    x, new_cache = _forward(params, cfg, x, kv_cache, attn)
+    x, new_cache = _forward(
+        params, cfg, x, kv_cache, attend, valid=jnp.arange(s) < seq_len)
     last = x[jnp.maximum(seq_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
     return logits.astype(jnp.float32), new_cache
@@ -743,19 +635,41 @@ def deepseek_forward_prefill_with_prefix(
     params, cfg: DeepseekConfig, token_ids, kv_cache, full_block_ids,
     tail_block_ids, tail_len, start_pos, cos, sin,
 ):
-    """Continued prefill over a reused prefix for the MLA family (same
-    contract as llama_forward_prefill_with_prefix)."""
+    """Continued prefill over a reused prefix (same contract as
+    llama_forward_prefill_with_prefix): the tail's queries attend the
+    resident prefix LATENTS absorbed (scores in latent space, context
+    decompressed once) jointly with the in-chunk dense attention under one
+    softmax; only the tail's latents are written."""
     s = token_ids.shape[0]
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = start_pos + jnp.arange(s, dtype=jnp.int32)
+    scale = jnp.float32(cfg.attn_scale)
 
-    def attn(w, attn_in, k_layer, v_layer):
-        return _mla_prefill_attn_with_prefix(
-            w, attn_in, cfg, positions, tail_len, start_pos, k_layer, v_layer,
-            full_block_ids, tail_block_ids, cos, sin,
-        )
+    def attend(w, attn_in, ck_pages, kr_pages, at):
+        q_nope, q_rope = _queries(
+            w, attn_in, cfg, lambda q: apply_rope(q, positions, cos, sin))
+        c_kv, k_rope = _latent_kv(w, attn_in, cfg)
+        k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
+        # gather the resident prefix BEFORE writing the tail
+        pref = at.blocks(full_block_ids)
+        t_pref = pref.shape[0] * at.block_size
+        ck_pref = ck_pages[pref].reshape(t_pref, -1)
+        kr_pref = kr_pages[pref].reshape(t_pref, -1)
+        pages = _write_latents(
+            ck_pages, kr_pages, c_kv, k_rope, _prompt_slots(at, tail_block_ids, tail_len, s))
+        w_uk, w_uv = _up_projections(w, cfg)
+        with jax.named_scope("attn"):
+            q_lat, q_wide = _absorb(w_uk, q_nope, q_rope, kr_pref.shape[-1], jnp.float32)
+            k_nope = jnp.einsum("tr,rhn->thn", c_kv, w_uk)
+            v_chunk = jnp.einsum("tr,rhv->thv", c_kv, w_uv)
+            out = _prompt_attention(
+                q_nope, q_rope, k_nope, k_rope, v_chunk, tail_len, scale,
+                prefix=(q_lat, q_wide, ck_pref, kr_pref, jnp.arange(t_pref) < start_pos, w_uv),
+            ).astype(cfg.dtype)
+        return mm(out.reshape(s, -1), w["wo"]), *pages
 
-    x, new_cache = _forward(params, cfg, x, kv_cache, attn)
+    x, new_cache = _forward(
+        params, cfg, x, kv_cache, attend, valid=jnp.arange(s) < tail_len)
     last = x[jnp.maximum(tail_len - 1, 0)]
     logits = _logits(params, cfg, last[None])[0]
     return logits.astype(jnp.float32), new_cache
@@ -765,21 +679,45 @@ def deepseek_forward_decode(
     params, cfg: DeepseekConfig, token_ids, kv_cache, block_tables, context_lens,
     slot_ids, cos, sin, *, attention: str = "jax",
 ):
-    """Batched single-token decode → (logits [batch, vocab], new cache).
-    MLA decode runs the absorbed latent path; ``attention="pallas"``
-    dispatches the MLA paged-attention kernel, anything else the XLA
-    gather fallback."""
+    """Batched single-token decode → (logits [batch, vocab], new cache),
+    absorbed: ``attention="pallas"`` runs the MLA decode kernel on the flat
+    pages (ops/pallas/mla_attention.py), anything else the XLA gather
+    fallback."""
+    b = token_ids.shape[0]
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = jnp.maximum(context_lens - 1, 0)
+    scale = float(cfg.attn_scale)
 
-    def attn(w, attn_in, k_layer, v_layer):
-        return _mla_decode_attn(
-            w, attn_in, cfg, positions, k_layer, v_layer,
-            block_tables, context_lens, slot_ids, cos, sin,
-            attention=attention,
-        )
+    def attend(w, attn_in, ck_pages, kr_pages, at):
+        # apply_rope expects a seq axis: insert and drop it
+        q_nope, q_rope = _queries(
+            w, attn_in, cfg,
+            lambda q: apply_rope(q[:, None], positions[:, None], cos, sin)[:, 0])
+        c_kv, k_rope = _latent_kv(w, attn_in, cfg)
+        k_rope = apply_rope(k_rope[:, None, None, :], positions[:, None], cos, sin)[:, 0, 0]
+        ck_pages, kr_pages = _write_latents(
+            ck_pages, kr_pages, c_kv, k_rope, at.slots(slot_ids))
+        w_uk, w_uv = _up_projections(w, cfg)
+        q_lat, q_wide = _absorb(w_uk, q_nope, q_rope, kr_pages.shape[-1], cfg.dtype)
+        tables = at.blocks(block_tables)
+        with jax.named_scope("attn"):
+            if attention.startswith("pallas"):
+                from dynamo_tpu.ops.pallas.mla_attention import mla_paged_attention_decode
 
-    x, new_cache = _forward(params, cfg, x, kv_cache, attn)
+                ctx = mla_paged_attention_decode(
+                    q_lat, q_wide, ck_pages, kr_pages, tables, context_lens,
+                    scale=scale, interpret=attention == "pallas_interpret",
+                )
+            else:
+                length = tables.shape[1] * at.block_size
+                ck = ck_pages[tables].reshape(b, length, -1)
+                kr = kr_pages[tables].reshape(b, length, -1)
+                visible = jnp.arange(length)[None, :] < context_lens[:, None]
+                weights = _gathered_scores(q_lat, q_wide, ck, kr, visible[:, None, :], scale)
+                ctx = jnp.einsum("bht,btr->bhr", weights, ck.astype(jnp.float32))
+        return _decompress(w, w_uv, ctx, cfg), ck_pages, kr_pages
+
+    x, new_cache = _forward(params, cfg, x, kv_cache, attend, valid=context_lens > 0)
     logits = _logits(params, cfg, x)
     return logits.astype(jnp.float32), new_cache
 
@@ -807,22 +745,46 @@ def deepseek_forward_unified(
 ):
     """Ragged unified-batch forward for the MLA family: mixed spans +
     decode tokens in one launch against the latent cache (the llama
-    unified contract).  Every token writes its compressed latent + rope
-    key at its cache slot before attention reads, so span tokens see
-    their own in-window predecessors through the cache; the MoE stack
-    routes per token exactly as the sparse-expert families' FFN does in
-    the llama one."""
+    unified contract), absorbed for every row.  Every token writes its
+    latent and rotated key at its cache slot before attention reads, so span
+    tokens see their own in-window predecessors through the cache; the
+    expert layers route per token and walk the live rows only."""
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = jnp.maximum(token_pos, 0)
+    lanes = context_lens.shape[0]
+    live = (token_pos >= 0) & (token_lane >= 0) & (token_lane < lanes)
+    scale = float(cfg.attn_scale)
 
-    def attn(w, attn_in, k_layer, v_layer):
-        return _mla_unified_attn(
-            w, attn_in, cfg, positions, token_pos, token_lane, token_slot,
-            k_layer, v_layer, block_tables, span_lane, span_first, span_count,
-            kv_steps, cos, sin, attention=attention, tb_tokens=tb_tokens,
-        )
+    def attend(w, attn_in, ck_pages, kr_pages, at):
+        q_nope, q_rope = _queries(
+            w, attn_in, cfg, lambda q: apply_rope(q, positions, cos, sin))
+        c_kv, k_rope = _latent_kv(w, attn_in, cfg)
+        k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
+        ck_pages, kr_pages = _write_latents(
+            ck_pages, kr_pages, c_kv, k_rope, at.slots(token_slot))
+        w_uk, w_uv = _up_projections(w, cfg)
+        q_lat, q_wide = _absorb(w_uk, q_nope, q_rope, kr_pages.shape[-1], cfg.dtype)
+        tables = at.blocks(block_tables)
+        with jax.named_scope("attn"):
+            if attention.startswith("pallas"):
+                from dynamo_tpu.ops.pallas import ragged_mla_attention
 
-    x, new_cache = _forward(params, cfg, x, kv_cache, attn)
+                ctx = ragged_mla_attention(
+                    q_lat, q_wide, ck_pages, kr_pages, token_lane, token_pos,
+                    tables, span_lane, span_first, span_count, kv_steps,
+                    scale=scale, tb_tokens=tb_tokens,
+                    interpret=attention == "pallas_interpret",
+                )
+            else:
+                from dynamo_tpu.ops.attention import ragged_mla_paged_attention
+
+                ctx = ragged_mla_paged_attention(
+                    q_lat, q_wide, ck_pages, kr_pages, tables, token_lane,
+                    token_pos, scale=scale,
+                )
+        return _decompress(w, w_uv, ctx, cfg), ck_pages, kr_pages
+
+    x, new_cache = _forward(params, cfg, x, kv_cache, attend, valid=live)
     rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated
     logits = _logits(params, cfg, rows)
     return logits.astype(jnp.float32), new_cache
@@ -833,22 +795,56 @@ def deepseek_forward_verify(
     context_lens, slot_ids, cos, sin, *, attention: str = "jax",
 ):
     """Speculative-verification forward for the MLA family (contract:
-    llama_forward_verify).  Window tokens run position-major (see
-    mixtral_forward_verify)."""
+    llama_forward_verify): w window queries a lane against the latent cache,
+    absorbed.  Window tokens run position-major (see mixtral_forward_verify
+    on why dispatch order matters for the expert layers)."""
     b, w_len = token_ids.shape
     x = params["embed"][token_ids.T.reshape(-1)].astype(cfg.dtype)
     positions = jnp.maximum(
         context_lens[:, None] - w_len + jnp.arange(w_len)[None, :], 0
     )
     flat_slots = slot_ids.T.reshape(-1)
+    scale = float(cfg.attn_scale)
+    H = cfg.num_heads
 
-    def attn(w, attn_in, k_layer, v_layer):
-        return _mla_window_attn(
-            w, attn_in, cfg, positions, k_layer, v_layer, block_tables,
-            context_lens, flat_slots, cos, sin, b, w_len, attention=attention,
-        )
+    def to_bw(t, *tail):
+        return position_major_to_batch(t, w_len, b, *tail)
 
-    x, new_cache = _forward(params, cfg, x, kv_cache, attn)
+    def attend(w, attn_in, ck_pages, kr_pages, at):
+        q = to_bw(_project_q(w, attn_in, cfg), H, cfg.qk_head_dim)     # [b, w, H, d]
+        q_nope = q[..., : cfg.qk_nope_head_dim]
+        q_rope = apply_rope(q[..., cfg.qk_nope_head_dim:], positions, cos, sin)
+        c_kv, k_rope = _latent_kv(w, attn_in, cfg)                     # [w*b, *]
+        k_rope = apply_rope(
+            to_bw(k_rope, cfg.qk_rope_head_dim)[:, :, None, :], positions, cos, sin
+        )[:, :, 0].transpose(1, 0, 2).reshape(w_len * b, -1)
+        ck_pages, kr_pages = _write_latents(
+            ck_pages, kr_pages, c_kv, k_rope, at.slots(flat_slots))
+        w_uk, w_uv = _up_projections(w, cfg)
+        q_lat, q_wide = _absorb(w_uk, q_nope, q_rope, kr_pages.shape[-1], cfg.dtype)
+        tables = at.blocks(block_tables)
+        with jax.named_scope("attn"):
+            if attention.startswith("pallas"):
+                from dynamo_tpu.ops.pallas.mla_attention import (
+                    mla_paged_window_attention_decode,
+                )
+
+                ctx = mla_paged_window_attention_decode(
+                    q_lat, q_wide, ck_pages, kr_pages, tables, context_lens,
+                    scale=scale, interpret=attention == "pallas_interpret",
+                )
+            else:
+                length = tables.shape[1] * at.block_size
+                ck = ck_pages[tables].reshape(b, length, -1)
+                kr = kr_pages[tables].reshape(b, length, -1)
+                q_pos = context_lens[:, None] - w_len + jnp.arange(w_len)[None, :]
+                visible = jnp.arange(length)[None, None, :] <= q_pos[:, :, None]
+                weights = _gathered_scores(q_lat, q_wide, ck, kr, visible[:, None], scale)
+                ctx = jnp.einsum("bhwt,btr->bwhr", weights, ck.astype(jnp.float32))
+        out = _decompress(w, w_uv, ctx, cfg).reshape(b, w_len, -1)
+        return out.transpose(1, 0, 2).reshape(w_len * b, -1), ck_pages, kr_pages
+
+    x, new_cache = _forward(params, cfg, x, kv_cache, attend)
     logits = _logits(params, cfg, x)
     logits = logits.reshape(w_len, b, -1).transpose(1, 0, 2)
     return logits.astype(jnp.float32), new_cache

@@ -590,9 +590,10 @@ def _scan_layer_runs(layer, x, params: dict, kv_cache: dict, runs):
         name: kv_cache[leaves[0]].shape
         for name, leaves in POOL_LEAVES.items() if leaves[0] in kv_cache
     }
+    # (each leaf by its own shape: a latent cache's two leaves differ in width)
     flat = {
         name: tuple(
-            kv_cache[leaf].reshape(shape[0] * shape[1], *shape[2:])
+            kv_cache[leaf].reshape(shape[0] * shape[1], *kv_cache[leaf].shape[2:])
             for leaf in POOL_LEAVES[name]
         )
         for name, shape in pools.items()
@@ -623,9 +624,9 @@ def _scan_layer_runs(layer, x, params: dict, kv_cache: dict, runs):
         )
         flat[kind.pool] = tuple(pages)
     out = dict(kv_cache)
-    for name, shape in pools.items():
+    for name in pools:
         for leaf, pages in zip(POOL_LEAVES[name], flat[name]):
-            out[leaf] = pages.reshape(shape)
+            out[leaf] = pages.reshape(kv_cache[leaf].shape)
     if stats is not None:
         out["moe_stats"] = stats
     return x, out

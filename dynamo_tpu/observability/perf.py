@@ -10,7 +10,9 @@ profiling run required):
   family's config by duck-typing the common geometry fields.  MoE families
   count ACTIVE expert FLOPs but TOTAL expert bytes (decode streams only the
   routed experts, but capacity planning cares about resident weights);
-  exotic attention geometries (MLA) degrade to the GQA approximation.
+  a latent-attention family (MLA: a config with ``kv_lora_rank``) is priced
+  as its kernels work: absorbed products against the latent page, the
+  page's stored bytes (``_latent_cost``).
 - :class:`UtilizationTracker` turns the engine device loop's per-step facts
   (prefill/decode token counts, attended context tokens, weight streams,
   emitted tokens, step wall time) into rolling-window **MFU**
@@ -156,6 +158,15 @@ def model_cost(
     else:
         mlp_params_total = mlp_params_active = 3 * h * inter
 
+    attn_flops_per_ctx_token = 4 * layers * heads * head_dim
+    kv_values_per_token = 2 * layers * kv_heads * head_dim
+    if getattr(model, "kv_lora_rank", 0):
+        latent = _latent_cost(model)
+        attn_params = latent["attn_params"]
+        attn_flops_per_ctx_token = layers * latent["attn_flops_per_ctx_token"]
+        kv_values_per_token = layers * latent["page_row"]
+        mlp_params_total, mlp_params_active = latent["mlp_params"]
+
     embed = vocab * h
     head_params = 0 if tied else vocab * h
     param_count = embed + head_params + layers * (attn_params + mlp_params_total)
@@ -175,9 +186,45 @@ def model_cost(
         linear_flops_per_token=2 * active_params,
         # per attended context position per layer: 2·heads·head_dim for
         # QK^T plus the same for attention·V
-        attn_flops_per_ctx_token=4 * layers * heads * head_dim,
-        kv_bytes_per_token=2 * layers * kv_heads * head_dim * kv_dtype_bytes,
+        attn_flops_per_ctx_token=attn_flops_per_ctx_token,
+        kv_bytes_per_token=kv_values_per_token * kv_dtype_bytes,
     )
+
+
+def _latent_cost(model) -> dict:
+    """A latent-attention (MLA) family's own numbers, one layer's, from the
+    fields of ``models.deepseek.DeepseekConfig``: what the kernels of
+    ops/pallas/mla_attention.py do for a (query, key) pair, every head
+    against the ONE latent row (absorbed: ``2 x heads x (latent + rope)`` for
+    the scores, ``2 x heads x latent`` for the context; the zeros the rope
+    part is stored with are not counted as work), the values a cached token
+    takes as STORED (``rope_page_width``: what a page copy moves), the
+    attention's matrices, and the MLP's parameters averaged over the leading
+    dense layers and the sparse ones (held, and met by one token: its routed
+    experts, the shared ones, the router)."""
+    from dynamo_tpu.models.deepseek import rope_page_width
+
+    h, heads, layers = model.hidden_size, model.num_heads, model.num_layers
+    r, rope = model.kv_lora_rank, model.qk_rope_head_dim
+    q_out = heads * (model.qk_nope_head_dim + rope)
+    q_params = (
+        h * model.q_lora_rank + model.q_lora_rank * q_out if model.q_lora_rank else h * q_out
+    )
+    expert = 3 * h * model.moe_intermediate_size
+    dense = 3 * h * model.intermediate_size
+    always = h * model.num_experts + model.n_shared_experts * expert
+    sparse_layers = layers - model.first_k_dense
+    mean = lambda routed: (  # noqa: E731
+        model.first_k_dense * dense + sparse_layers * (always + routed * expert)
+    ) // layers
+    return {
+        "attn_params": q_params + h * (r + rope)
+        + r * heads * (model.qk_nope_head_dim + model.v_head_dim)
+        + heads * model.v_head_dim * h,
+        "attn_flops_per_ctx_token": 2 * heads * (r + rope) + 2 * heads * r,
+        "page_row": r + rope_page_width(model),
+        "mlp_params": (mean(model.num_experts), mean(model.experts_per_token)),
+    }
 
 
 def detect_peaks() -> tuple[float, float] | None:

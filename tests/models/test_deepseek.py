@@ -26,13 +26,19 @@ NUM_BLOCKS = 32
 
 
 def test_latent_cache_is_compact():
-    """The MLA cache stores kv_lora_rank + rope_dim floats per token — far
-    smaller than a GQA cache of the same model class."""
+    """The MLA cache stores the latent and the one rotated key a token, the
+    key in whole 128-lane tiles (the layout the kernels copy in place): 640
+    values at the published 512 + 64, a sixth of the 4,096 + 2,048 that 16
+    heads' decompressed keys and values would take."""
+    from dynamo_tpu.models.deepseek import rope_page_width
+
     cache = init_kv_cache(CFG, NUM_BLOCKS, BLOCK_SIZE)
-    per_token = cache["k"].shape[-1] + cache["v"].shape[-1]
-    assert per_token == CFG.kv_lora_rank + CFG.qk_rope_head_dim
-    # GQA equivalent for this head count would be 2 * heads * qk dims
-    assert per_token < 2 * CFG.num_heads * CFG.qk_head_dim
+    assert cache["k"].shape == (CFG.num_layers, NUM_BLOCKS, BLOCK_SIZE, CFG.kv_lora_rank)
+    assert cache["v"].shape[-1] == rope_page_width(CFG) == 128
+    lite = DeepseekConfig.deepseek_v2_lite()
+    per_token = lite.kv_lora_rank + rope_page_width(lite)
+    assert per_token == 512 + 128
+    assert per_token * 6 < lite.num_heads * (lite.qk_head_dim + lite.v_head_dim)
 
 
 def test_prefill_decode_consistency():
@@ -150,8 +156,11 @@ def test_decode_pallas_kernel_matches_gather_path():
 
     # write some prefix content so attention sees a real context
     key = jax.random.PRNGKey(1)
+    # (the pages; the expert layers' counters stay as they are.  The lanes
+    # behind the rotated key get noise too: the queries are zero there)
     cache = {
-        k: jax.random.normal(jax.random.fold_in(key, i), v.shape, v.dtype)
+        k: v if k == "moe_stats"
+        else jax.random.normal(jax.random.fold_in(key, i), v.shape, v.dtype)
         for i, (k, v) in enumerate(cache.items())
     }
 
@@ -211,7 +220,7 @@ def test_prefix_prefill_matches_plain_prefill():
     np.testing.assert_allclose(
         np.asarray(logits2), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
     )
-    for key in ref_cache:
+    for key in ("k", "v"):  # the pages (the counters count two steps here, one there)
         np.testing.assert_allclose(
             np.asarray(cache2[key]), np.asarray(ref_cache[key]), rtol=1e-5, atol=1e-5
         )

@@ -196,25 +196,128 @@ def test_ragged_compiles_at_a_context_of_32768(one_chip):
     ).compile()
 
 
-@pytest.mark.parametrize("bucket,max_blocks", [(4096, 256), (32768, 2048)])
-def test_ragged_mla_compiles_at_deepseek_v2_lite_widths(one_chip, bucket, max_blocks):
-    """The ragged MLA kernel at DeepSeek-V2-Lite's published widths (16
-    heads, latent 512, rope 64), a whole window of the bucket, on the shared
-    walker's KV steps of 16 pages: the static worklists ran out of scalar
-    memory from bucket 512 up, and the 64-wide rope page needs its lanes
-    padded to be copied whole."""
+# the latent cache as ``moonlight-16b-l9`` serves it (benchmark/configs/
+# moonlight-16b-l9.json; DeepSeek-V2-Lite's attention widths too): 16 heads
+# over one latent of 512 and a rotated key stored 128 wide, 9 layers of
+# 11,008 blocks as flat pages, 24 lanes of 8,192 tokens
+MLA = {"heads": 16, "latent": 512, "rope_page": 128, "pages": 9 * 11008, "lanes": 24,
+       "max_blocks": 512}
+
+
+def _mla_pages(s):
+    return (s((MLA["pages"], BLOCK, MLA["latent"]), jnp.bfloat16),
+            s((MLA["pages"], BLOCK, MLA["rope_page"]), jnp.bfloat16))
+
+
+def _kernel_names(compiled):
+    import re
+
+    return set(re.findall(
+        r"%([A-Za-z_]+)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text()))
+
+
+@pytest.mark.parametrize("bucket,max_blocks", [
+    (128, 512), (1024, 512), (8192, 512), (32768, 2048)])
+def test_ragged_mla_compiles_at_every_bucket_of_the_cell(one_chip, bucket, max_blocks):
+    """The ragged MLA kernel at the published widths, bf16 operands, the
+    pages read where they lie (flat, both leaves a whole number of 128-lane
+    tiles: no pad of the rope cache), a whole window of the bucket on the
+    shared walker's KV steps of 16 pages, 512-page tables (and a context of
+    32,768: the static worklists ran out of scalar memory from bucket 512
+    up).  The kernel keeps the name the trace shows."""
     from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention
 
     s = _sds(one_chip)
-    h, r, p = 16, 512, 64
     tok = s((bucket,), jnp.int32)
-    ragged_mla_attention.lower(
-        s((bucket, h, r), jnp.float32), s((bucket, h, p), jnp.bfloat16),
-        s((NUM_BLOCKS, BLOCK, r), jnp.bfloat16),
-        s((NUM_BLOCKS, BLOCK, p), jnp.bfloat16),
-        tok, tok, s((LANES, max_blocks), jnp.int32), tok, tok, tok,
-        s((bucket // TB,), jnp.int32), scale=0.1, tb_tokens=TB,
+    compiled = ragged_mla_attention.lower(
+        s((bucket, MLA["heads"], MLA["latent"]), jnp.bfloat16),
+        s((bucket, MLA["heads"], MLA["rope_page"]), jnp.bfloat16),
+        *_mla_pages(s), tok, tok, s((MLA["lanes"], max_blocks), jnp.int32), tok, tok, tok,
+        s((bucket // TB,), jnp.int32), scale=0.07, tb_tokens=TB,
     ).compile()
+    assert _kernel_names(compiled) == {"ragged_mla_attention"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+@pytest.mark.parametrize("launch", ["decode", "verify_w5"])
+def test_mla_lane_kernels_compile_at_the_cells_shapes(one_chip, launch):
+    """One query a lane (decode) and a verify window of 5: the same body, a
+    token block a lane, its spans derived on the device; 24 lanes, 512-page
+    tables."""
+    from dynamo_tpu.ops.pallas.mla_attention import (
+        mla_paged_attention_decode,
+        mla_paged_window_attention_decode,
+    )
+
+    s = _sds(one_chip)
+    lanes = MLA["lanes"]
+    fn, lead = ((mla_paged_attention_decode, (lanes,)) if launch == "decode"
+                else (mla_paged_window_attention_decode, (lanes, 5)))
+    compiled = fn.lower(
+        s((*lead, MLA["heads"], MLA["latent"]), jnp.bfloat16),
+        s((*lead, MLA["heads"], MLA["rope_page"]), jnp.bfloat16),
+        *_mla_pages(s), s((lanes, MLA["max_blocks"]), jnp.int32), s((lanes,), jnp.int32),
+        scale=0.07,
+    ).compile()
+    assert _kernel_names(compiled) == {fn.__name__}
+
+
+@pytest.mark.parametrize("program", ["decode", "unified_t8192"])
+def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one_chip, program):
+    """``moonlight-16b-l9``'s whole decode and 8,192-token step programs at
+    the cell's shapes (10.87 GB of weights, 2.03 GB of latent pages): both
+    leaves aliased to the outputs, the kernels named as the benchmark's
+    metrics look for them (the MLA launch of the program, the grouped
+    products of the walk), less than 1 GB of temporaries (a copy, a pad or a
+    relayout of the rope leaf alone would be 0.41 GB, of the latent 1.62)."""
+    import dataclasses
+
+    from dynamo_tpu.models.deepseek import DeepseekConfig
+
+    cfg = dataclasses.replace(DeepseekConfig.from_hf_config({
+        "vocab_size": 163840, "hidden_size": 2048, "num_hidden_layers": 9,
+        "num_attention_heads": 16, "q_lora_rank": None, "kv_lora_rank": 512,
+        "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "intermediate_size": 11264, "first_k_dense_replace": 1, "moe_intermediate_size": 1408,
+        "n_routed_experts": 64, "num_experts_per_tok": 6, "n_shared_experts": 2,
+        "routed_scaling_factor": 2.446, "scoring_func": "sigmoid", "rope_theta": 50000,
+        "max_position_embeddings": 8192, "rms_norm_eps": 1e-5,
+    }), grouped_matmul="pallas")      # what "auto" is on the chip
+    family = get_family("deepseek_v3")
+    s = _sds(one_chip)
+    lanes, context, blocks = MLA["lanes"], 8192, 11008
+    shaped = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)  # noqa: E731
+    params = shaped(jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shaped(jax.eval_shape(lambda: family.cache_init(cfg, blocks, BLOCK, None)))
+    i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
+    rope = s((context, cfg.qk_rope_head_dim // 2), jnp.float32)
+    tables = i32(lanes, context // BLOCK)
+    if program == "decode":
+        def fn(p, c, tok, bt, cl, sl, cos, sin):
+            return family.forward_decode(p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
+        args, kernel = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope), "mla_paged_attention_decode"
+    else:
+        t = int(program.removeprefix("unified_t"))
+        def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
+            return family.forward_unified(
+                p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
+                cos, sin, attention="pallas", tb_tokens=TB)
+        args = (i32(t), tables, i32(lanes), *(i32(t) for _ in range(6)),
+                i32(t // TB), i32(lanes), rope, rope)
+        kernel = "ragged_mla_attention"
+    full = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    assert _kernel_names(compiled) == {kernel, "gmm"}
+    memory = compiled.memory_analysis()
+    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= pools - 64
+    assert memory.temp_size_in_bytes < 1e9
+    assert 12.8e9 < memory.argument_size_in_bytes < 13.0e9
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
