@@ -331,6 +331,9 @@ class _InflightWindow:
     # "prompt" when the window carried a prompt token, else "decode": the
     # iteration that waits for this window books its time under this kind
     kind: str = KIND_DECODE
+    # a lane of it samples, so its program sorts the vocabulary
+    # (``ops/sampling.py``); booked like ``kind``
+    samples: bool = False
     # open ``engine.prefill`` spans (``_open_prefill_span``) of the prefill
     # windows this window's readback covers: closed when it retires
     prefills: list = field(default_factory=list)
@@ -658,6 +661,11 @@ class JaxLlmEngine:
         # iteration's time is booked to the first that is set)
         self._step_waited_kind: str | None = None
         self._step_dispatched_kind: str | None = None
+        # the same of whether a lane of that window samples; `_packed_samples`
+        # is of the lanes packed since a window last took it (_take_unwaited)
+        self._step_waited_samples = False
+        self._step_dispatched_samples = False
+        self._packed_samples = False
         # open engine.prefill spans of prompt work dispatched that nothing
         # waits for; the next window dispatched inherits them (_take_unwaited)
         self._unwaited_prefills: list = []
@@ -2457,6 +2465,7 @@ class JaxLlmEngine:
         self._step_lane_steps = 0
         self._step_readback_s = 0.0
         self._step_waited_kind = self._step_dispatched_kind = None
+        self._step_waited_samples = self._step_dispatched_samples = False
         self._phase("schedule")
         return time.perf_counter(), self._tokens_emitted
 
@@ -2494,6 +2503,10 @@ class JaxLlmEngine:
             attn_ctx_tokens=self._step_attn_ctx,
             weight_streams=self._step_weight_streams,
             emitted_tokens=self._tokens_emitted - emitted_before,
+            sample_sort_skipped=not (
+                self._step_waited_samples if self._step_waited_kind
+                else self._step_dispatched_samples
+            ),
         )
         self.step_telemetry.observe(rec)
         self.utilization.observe(rec)
@@ -2972,7 +2985,7 @@ class JaxLlmEngine:
                 work["window_pages_full_total"] += live_pages
 
         if not overlap:
-            _, opened = self._take_unwaited(KIND_PROMPT, opened)
+            _, opened, samples = self._take_unwaited(KIND_PROMPT, opened)
             self._phase("readback", kind=KIND_PROMPT)
             tokens_h = np.asarray(tokens)
             lps_h = np.asarray(lps)
@@ -2982,7 +2995,7 @@ class JaxLlmEngine:
             tkv_h = np.asarray(tkvs) if want_top else None
             tki_h = np.asarray(tkis) if want_top else None
             self._phase("post")
-            self._note_wait(KIND_PROMPT, opened)
+            self._note_wait(KIND_PROMPT, opened, samples)
             self._sync_windows += 1
             for seq in emit_seqs:
                 if seq.status != SeqStatus.RUNNING:
@@ -3002,11 +3015,11 @@ class JaxLlmEngine:
         for seq in emit_seqs:
             seq.inflight_tokens += 1
         if emit_seqs:
+            _, opened, samples = self._take_unwaited(KIND_PROMPT, opened)
             self._inflight = _InflightWindow(
                 tokens=tokens, lps=lps, feedback=tokens,
                 active=emit_seqs, lane_ids=[s.lane for s in emit_seqs],
-                steps=1, kind=KIND_PROMPT,
-                prefills=self._take_unwaited(KIND_PROMPT, opened)[1],
+                steps=1, kind=KIND_PROMPT, samples=samples, prefills=opened,
             )
         else:
             # a chunk-only window samples nothing worth retiring: nothing
@@ -3051,24 +3064,30 @@ class JaxLlmEngine:
                 component="engine", status=status, attrs=attrs,
             )
 
-    def _take_unwaited(self, own: str, prefills: list | None = None) -> tuple[str, list]:
-        """Kind and open prefill spans of the window being dispatched,
-        together with the prompt work dispatched before it that nothing
-        waits for (a chunk-only unified window, a split prefill's
-        intermediate chunk).  On the device that work runs first, so the
-        wait for THIS window covers its time: the window inherits the kind
-        ``prompt`` and the spans."""
+    def _take_unwaited(
+        self, own: str, prefills: list | None = None
+    ) -> tuple[str, list, bool]:
+        """Kind and open prefill spans of the window being dispatched, and
+        whether a lane of it samples, together with the prompt work
+        dispatched before it that nothing waits for (a chunk-only unified
+        window, a split prefill's intermediate chunk).  On the device that
+        work runs first, so the wait for THIS window covers its time: the
+        window inherits the kind ``prompt``, the spans and a lane that
+        samples."""
         pending, self._unwaited_prefills = self._unwaited_prefills, []
+        samples, self._packed_samples = self._packed_samples, False
         if pending:
-            return KIND_PROMPT, pending + (prefills or [])
-        return own, prefills or []
+            return KIND_PROMPT, pending + (prefills or []), samples
+        return own, prefills or [], samples
 
-    def _note_wait(self, kind: str, prefills: list) -> None:
+    def _note_wait(self, kind: str, prefills: list, samples: bool) -> None:
         """The host has blocked on a window of ``kind``: the heaviest
-        window waited for names the iteration, and the prefill windows the
+        window waited for names the iteration (which sorted a vocabulary if
+        any window it waited for ``samples``), and the prefill windows the
         wait covered close their spans."""
         if self._step_waited_kind != KIND_PROMPT:
             self._step_waited_kind = kind
+        self._step_waited_samples |= samples
         self._close_prefill_spans(prefills)
 
     def _on_preempt(self, seq: Sequence) -> None:
@@ -3468,6 +3487,9 @@ class JaxLlmEngine:
                 for j, (tok, val) in enumerate(entries):
                     bias_ids[lane, j] = tok
                     bias_vals[lane, j] = val
+        # by the program's own rule (``sample_tokens``: ``force_greedy``)
+        if not (greedy | (temp <= np.float32(1e-5))).all():
+            self._packed_samples = self._step_dispatched_samples = True
         return temp, top_k, top_p, greedy, pres, freq, rep, bias_ids, bias_vals
 
     def _next_rng(self) -> np.ndarray:
@@ -3709,11 +3731,11 @@ class JaxLlmEngine:
     def _read_prefill_sample(self, token, lp) -> tuple[int, float]:
         """Read a split prefill's sampled token back (the `readback` phase:
         the host blocks until the prefill program is done)."""
-        _, prefills = self._take_unwaited(KIND_PROMPT)  # earlier chunks
+        _, prefills, samples = self._take_unwaited(KIND_PROMPT)  # earlier chunks
         self._phase("readback", kind=KIND_PROMPT)
         out = int(token), float(lp)
         self._phase("post")
-        self._note_wait(KIND_PROMPT, prefills)
+        self._note_wait(KIND_PROMPT, prefills, samples)
         return out
 
     def _ngram_draft(self, tokens: list[int]) -> list[int]:
@@ -3846,7 +3868,7 @@ class JaxLlmEngine:
                 tokens_host = tokens_host[None, :]
                 lps_host = lps_host[None, :]
             self._phase("post")
-            self._note_wait(w.kind, w.prefills)
+            self._note_wait(w.kind, w.prefills, w.samples)
             for seq in w.active:
                 seq.inflight_tokens = max(0, seq.inflight_tokens - w.steps)
             for s in range(tokens_host.shape[0]):
@@ -3987,7 +4009,7 @@ class JaxLlmEngine:
             )
         self._phase("post")
         self._after_dispatch([(s, int(context_lens[s.lane])) for s in active])
-        kind, prefills = self._take_unwaited(KIND_DECODE)
+        kind, prefills, samples = self._take_unwaited(KIND_DECODE)
         self._step_dispatched_kind = kind
         # start the device→host copies now; by the time this window is
         # retired (one iteration from now) the transfer may already be done
@@ -3998,7 +4020,7 @@ class JaxLlmEngine:
         self._inflight = _InflightWindow(
             tokens=w_tokens, lps=w_lps, feedback=feedback,
             active=list(active), lane_ids=[s.lane for s in active],
-            steps=steps, kind=kind, prefills=prefills,
+            steps=steps, kind=kind, samples=samples, prefills=prefills,
         )
         self._overlap_windows += 1
         self._count_decode_window(context_lens, len(active), steps)
@@ -4213,7 +4235,7 @@ class JaxLlmEngine:
         )
         self._phase("upload")
         sampling_tail = self._device_sampling_tail(active, lanes)
-        kind, prefills = self._take_unwaited(KIND_DECODE)
+        kind, prefills, samples = self._take_unwaited(KIND_DECODE)
         if steps <= 1:
             gmodes = np.full((lanes,), -1, np.int32)
             for seq in active:
@@ -4252,7 +4274,7 @@ class JaxLlmEngine:
         self._phase("post")
         self._after_dispatch([(s, int(context_lens[s.lane])) for s in active])
         self._step_dispatched_kind = KIND_DECODE
-        self._note_wait(kind, prefills)
+        self._note_wait(kind, prefills, samples)
         self._sync_windows += 1
         self._count_decode_window(context_lens, len(active), int(tokens_host.shape[0]))
 
@@ -4348,7 +4370,7 @@ class JaxLlmEngine:
             jnp.asarray(spec_ok), *sampling_tail,
             self.cos, self.sin,
         )
-        kind, prefills = self._take_unwaited(KIND_DECODE)
+        kind, prefills, samples = self._take_unwaited(KIND_DECODE)
         self._phase("readback", kind=kind)
         tokens_h = np.asarray(tokens)
         n_h = np.asarray(n_accept)
@@ -4357,7 +4379,7 @@ class JaxLlmEngine:
         tki_h = np.asarray(tkis) if want_top else None
         self._phase("post")
         self._step_dispatched_kind = KIND_DECODE
-        self._note_wait(kind, prefills)
+        self._note_wait(kind, prefills, samples)
         # count attempts only after the jit succeeded (an attention-fallback
         # retry re-enters this method for the same step); attempted = the
         # whole window (pads can accept too), so accepted/drafted <= 1

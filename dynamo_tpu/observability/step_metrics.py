@@ -44,6 +44,9 @@ class StepRecord:
     attn_ctx_tokens: int = 0            # attended context positions
     weight_streams: float = 0.0         # full weight passes dispatched
     emitted_tokens: int = 0
+    # no lane of the window this time is booked to samples, so its program
+    # took ``sample_tokens``' empty branch: no sort over the vocabulary
+    sample_sort_skipped: bool = False
     # filled by UtilizationTracker.observe (the cost model lives there)
     flops: float = 0.0
 
@@ -72,6 +75,7 @@ class StepTelemetry:
         self.snapshot = StepSnapshot()
         self.steps_total = 0
         self.busy_steps_total = 0        # steps with at least one running lane
+        self.sample_sort_skipped_steps_total = 0  # those of them that sorted no vocabulary
         self.step_time_total_s = 0.0
         # by kind of the window the time is booked to
         self.kind_steps_total = {KIND_DECODE: 0, KIND_PROMPT: 0}
@@ -98,6 +102,7 @@ class StepTelemetry:
         self.steps_total += 1
         if rec.num_running:
             self.busy_steps_total += 1
+            self.sample_sort_skipped_steps_total += rec.sample_sort_skipped
         self.step_time_total_s += rec.duration_s
         self.kind_steps_total[rec.kind] += 1
         self.kind_time_total_s[rec.kind] += rec.duration_s
@@ -119,6 +124,7 @@ class StepTelemetry:
             "step_kv_active_blocks": s.kv_active_blocks,
             "engine_steps_total": self.steps_total,
             "engine_busy_steps_total": self.busy_steps_total,
+            "sample_sort_skipped_steps_total": self.sample_sort_skipped_steps_total,
             "engine_step_time_total_s": self.step_time_total_s,
             "last_step_duration_s": s.step_duration_s,
             "engine_decode_steps_total": self.kind_steps_total[KIND_DECODE],

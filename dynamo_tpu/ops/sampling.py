@@ -25,36 +25,44 @@ def sample_tokens(
 
     Per-lane keys make sampling reproducible per request (OpenAI ``seed``):
     lane i draws only from its own key stream regardless of batch
-    composition."""
+    composition.
+
+    Everything that exists only to draw a sample runs under one
+    ``lax.cond``, taken when some lane samples: a step whose lanes all
+    decode greedily sorts no vocabulary."""
     b, v = logits.shape
     logits = logits.astype(jnp.float32)
     greedy_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     force_greedy = greedy | (temperature <= 1e-5)
-    safe_temp = jnp.where(force_greedy, 1.0, temperature)
-    scaled = logits / safe_temp[:, None]
 
-    # sorted-space filtering: one descending sort serves both top-k and top-p
-    sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]
-    sort_idx = jnp.argsort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_logits, axis=-1)
-    cum_excl = jnp.cumsum(probs, axis=-1) - probs
-    ranks = jnp.arange(v)[None, :]
+    def draw():
+        safe_temp = jnp.where(force_greedy, 1.0, temperature)
+        scaled = logits / safe_temp[:, None]
 
-    k_eff = jnp.where(top_k <= 0, v, top_k)[:, None]
-    p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
-    keep = (ranks < k_eff) & (cum_excl < p_eff)
-    keep = keep.at[:, 0].set(True)  # always keep the best token
+        # sorted-space filtering: one descending sort serves both top-k and top-p
+        sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]
+        sort_idx = jnp.argsort(scaled, axis=-1)[:, ::-1]
+        probs = jax.nn.softmax(sorted_logits, axis=-1)
+        cum_excl = jnp.cumsum(probs, axis=-1) - probs
+        ranks = jnp.arange(v)[None, :]
 
-    filtered_sorted = jnp.where(keep, sorted_logits, NEG_INF)
-    # sample in sorted space, map back through sort_idx
-    if rng.ndim == 1:
-        keys = jax.random.split(rng, b)
-    else:
-        keys = rng
-    choice = jax.vmap(lambda k, lg: jax.random.categorical(k, lg))(keys, filtered_sorted)
-    sampled_ids = jnp.take_along_axis(sort_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+        k_eff = jnp.where(top_k <= 0, v, top_k)[:, None]
+        p_eff = jnp.where(top_p >= 1.0, 2.0, top_p)[:, None]
+        keep = (ranks < k_eff) & (cum_excl < p_eff)
+        keep = keep.at[:, 0].set(True)  # always keep the best token
 
+        filtered_sorted = jnp.where(keep, sorted_logits, NEG_INF)
+        # sample in sorted space, map back through sort_idx
+        if rng.ndim == 1:
+            keys = jax.random.split(rng, b)
+        else:
+            keys = rng
+        choice = jax.vmap(lambda k, lg: jax.random.categorical(k, lg))(keys, filtered_sorted)
+        return jnp.take_along_axis(sort_idx, choice[:, None], axis=-1)[:, 0].astype(jnp.int32)
+
+    # the predicate is read on the device, from the lanes' own parameters
+    sampled_ids = jax.lax.cond(jnp.any(~force_greedy), draw, lambda: greedy_ids)
     return jnp.where(force_greedy, greedy_ids, sampled_ids)
 
 

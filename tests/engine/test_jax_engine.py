@@ -834,3 +834,39 @@ async def test_phase_timing_stats():
             assert 0 < stats["engine_host_time_total_s"] < stats["engine_step_time_total_s"]
         finally:
             engine.stop()
+
+
+@pytest.mark.parametrize("mode", [
+    {},                                                   # unified windows, overlapped
+    {"unified_batch": False, "decode_overlap": False},    # split steps, read back at once
+    {"decode_steps": 4},                                  # the fused multi-step scan
+], ids=["unified_overlap", "split_sync", "multistep"])
+async def test_a_step_of_greedy_lanes_is_booked_as_sorting_no_vocabulary(mode):
+    """``sample_sort_skipped_steps_total`` rises by one for each busy step
+    of an all-greedy batch and stays put while a request with a temperature
+    is on a lane (its window's program sorts for every lane); the greedy
+    requests' tokens are the same with and without that neighbour."""
+    prompts = [list(range(3 + i, 10 + i)) for i in range(2)]
+    greedy = [request(p, max_tokens=6, ignore_eos=True) for p in prompts]
+    # the neighbour arrives first and answers longest: on a lane in every
+    # busy step
+    neighbour = sampled_request(prompts[0], max_tokens=24, temperature=0.8, seed=5)
+
+    async def serve(*requests):
+        engine = make_engine(**mode)
+        try:
+            answers = await asyncio.gather(*[collect(engine, r) for r in requests])
+        finally:
+            engine.stop()
+        stats = engine.stats()
+        return (
+            [tokens for tokens, _ in answers],
+            stats["engine_busy_steps_total"],
+            stats["sample_sort_skipped_steps_total"],
+        )
+
+    alone, busy, skipped = await serve(*greedy)
+    assert busy > 0 and skipped == busy
+    beside, busy, skipped = await serve(neighbour, *greedy)
+    assert busy > 0 and skipped == 0
+    assert beside[1:] == alone

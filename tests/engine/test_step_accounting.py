@@ -134,7 +134,8 @@ def test_a_chunk_nothing_waits_for_hands_its_kind_and_its_span_to_the_next_windo
     goes out and nothing is put in flight for it.  On the device it runs
     before the decode window dispatched next, so the wait for THAT window
     covers the chunk: it is a prompt step, and the chunk's engine.prefill
-    span closes there, not at its dispatch."""
+    span closes there, not at its dispatch.  A lane of the chunk that samples
+    (its program sorts the vocabulary) rides the same way."""
     from dynamo_tpu.observability import get_recorder
 
     engine = idle_engine()
@@ -142,6 +143,7 @@ def test_a_chunk_nothing_waits_for_hands_its_kind_and_its_span_to_the_next_windo
     # iteration 1: D0 is in flight; the chunk goes out, the host waits for D0
     t_step, emitted = engine._begin_step()
     engine._step_dispatched_kind = KIND_PROMPT
+    engine._packed_samples = True
     engine._unwaited_prefills.append(engine._open_prefill_span(seq, time.time()))
     engine._retire_window(window(KIND_DECODE, 0.01))
     engine._end_step(t_step, emitted)
@@ -149,17 +151,18 @@ def test_a_chunk_nothing_waits_for_hands_its_kind_and_its_span_to_the_next_windo
     assert not get_recorder().spans_for(seq.trace.trace_id)     # the chunk is still out
     # iteration 2: D1 goes out behind the chunk and inherits it; nothing to wait for
     t_step, emitted = engine._begin_step()
-    kind, prefills = engine._take_unwaited(KIND_DECODE)
+    kind, prefills, samples = engine._take_unwaited(KIND_DECODE)
     engine._step_dispatched_kind = kind
     d1 = window(kind, 0.05)
-    d1.prefills = prefills
+    d1.prefills, d1.samples = prefills, samples
     engine._end_step(t_step, emitted)
-    assert kind == KIND_PROMPT and engine._unwaited_prefills == []
+    assert kind == KIND_PROMPT and samples and engine._unwaited_prefills == []
     # iteration 3: D2 goes out (a plain decode window again), the host waits for chunk + D1
     t_step, emitted = engine._begin_step()
-    assert engine._take_unwaited(KIND_DECODE) == (KIND_DECODE, [])
+    assert engine._take_unwaited(KIND_DECODE) == (KIND_DECODE, [], False)
     engine._step_dispatched_kind = KIND_DECODE
     engine._retire_window(d1)
+    assert engine._step_waited_samples
     engine._end_step(t_step, emitted)
     stats = engine.stats()
     assert stats["engine_prompt_steps_total"] == 2 and stats["engine_decode_steps_total"] == 1

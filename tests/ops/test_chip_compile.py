@@ -540,6 +540,38 @@ def test_decode_kernel_reads_its_pages_from_fast_memory_where_a_layer_fits(
         assert re.search(operand, definition.split(" = ")[1][:120]), definition[:200]
 
 
+@pytest.mark.parametrize("program", ["decode", "unified_t128"])
+def test_a_step_programs_only_sort_sits_in_a_branch(one_chip, program):
+    """The engine's own `dyn_decode_w1` and a `dyn_unified_t*` program at
+    `qwen3-4b`'s vocabulary and 16 lanes (one narrow layer: the `sample`
+    scope is the whole model's), compiled for the chip: ONE sort over
+    `[lanes, vocab]`, in a computation that a `conditional` names as a
+    branch, so a step whose lanes are all greedy never runs it; the top-20
+    log-probabilities stay the chip's own `TopK` call, not a sort."""
+    import dataclasses
+    import re
+
+    from tests.ops.test_sampling import sorts_by_reach
+
+    _, cell, _, lanes = STEP_CONFIGS["qwen3-4b"]
+    cfg = dataclasses.replace(
+        cell, hidden_size=256, intermediate_size=512, num_layers=1, num_heads=2, num_kv_heads=1)
+    engine = JaxLlmEngine(EngineConfig(
+        model=cfg, model_family="qwen3", block_size=BLOCK, max_batch_size=lanes,
+        num_blocks=64, max_model_len=512, attention_impl="pallas",
+    ))
+    name = ("decode",) if program == "decode" else ("unified", int(program.removeprefix("unified_t")))
+    jit_fn, avals = engine._aot_jobs([100])[name]
+    s = _sds(one_chip)
+    hlo = jit_fn.lower(*jax.tree.map(lambda a: s(a.shape, a.dtype), avals)).compile().as_text()
+
+    assert " conditional(" in hlo and sorts_by_reach(hlo) == (1, 0)
+    assert re.search(rf"f32\[{lanes},{cfg.vocab_size}\][^\n]* sort\(", hlo)
+    topk = re.findall(
+        r"= \((f32\[[\d,]+\])[^\n]*?(s32\[[\d,]+\])[^\n]*? custom-call\([^\n]*custom_call_target=\"TopK\"", hlo)
+    assert topk == [(f"f32[{lanes},20]", f"s32[{lanes},20]")]
+
+
 def test_kernel_bytes_do_not_depend_on_the_call_stack(one_chip):
     """A program's persistent-cache key covers the Pallas kernel's bytecode,
     locations included.  Once the cache resolver ran, lowering the same
