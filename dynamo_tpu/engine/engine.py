@@ -55,7 +55,12 @@ from dynamo_tpu.models.llama import KvPools, LlamaConfig
 from dynamo_tpu.models.registry import get_family
 from dynamo_tpu.observability import FlightRecorder, StepTelemetry, get_recorder
 from dynamo_tpu.observability.perf import UtilizationTracker, model_cost
-from dynamo_tpu.observability.step_metrics import KIND_DECODE, KIND_PROMPT, StepRecord
+from dynamo_tpu.observability.step_metrics import (
+    KIND_DECODE,
+    KIND_PROMPT,
+    LoopAccount,
+    StepRecord,
+)
 from dynamo_tpu.robustness.faults import ENGINE_STEP, FAULTS
 from dynamo_tpu.ops.sampling import (
     apply_logit_bias,
@@ -77,6 +82,23 @@ logger = get_logger("engine")
 # books its time under these names (stats()["phase_ms"], "dyn.<phase>" in a
 # profiler trace).
 STEP_PHASES = ("schedule", "pack", "upload", "dispatch", "readback", "post")
+# The named parts of the phases that are large (stats()["phase_ms"][<phase>]
+# ["parts"], "dyn.<phase>.<part>" in a profiler trace).  What a phase spends
+# outside its parts is its own bookkeeping.  `emit` runs once a token and is
+# two clock reads around the call, never an annotation.
+PHASE_PARTS = {
+    # the scheduler's decision and the route to a step program; the lanes'
+    # slot growth; the window's host arrays; the block-table rows
+    "schedule": ("admit", "slots", "build", "tables"),
+    "pack": ("spans", "window_spans"),
+    # the lanes' sampling parameters (compared, uploaded when they changed);
+    # the window's arrays handed to the device
+    "upload": ("sampling", "arrays"),
+    # blocks behind a window, the expert counters, deferred finishes; the
+    # stop, guided and length rules a token; the output built and handed to
+    # the event loop a token; completed blocks published
+    "post": ("release", "tokens", "emit", "publish"),
+}
 
 # Attention-kernel work counters (cumulative, in stats() from engine start).
 KERNEL_WORK_KEYS = (
@@ -613,15 +635,33 @@ class JaxLlmEngine:
         self._host_rng = np.random.Generator(np.random.PCG64(config.seed))
         self._lane_keys = np.zeros((lanes, 2), np.uint32)
 
-        # Host-phase accounting, always on: wall seconds + counts per phase
-        # of the step loop, surfaced via stats()["phase_ms"] and mirrored as
-        # jax.profiler TraceAnnotations ("dyn.<phase>") so a device trace
-        # shows them on the profiler's clock.  One set of names for every
-        # branch that serves a window; `readback` is the host blocked on
-        # the device.  Two perf_counter reads and one TraceMe a phase.
-        self.phase_stats: dict[str, list[float]] = {
-            name: [0.0, 0] for name in STEP_PHASES
-        }
+        # Host-phase accounting, always on (observability/step_metrics.py
+        # LoopAccount): wall and thread-CPU seconds a phase of the step
+        # loop and the parts of the large ones, how long the chip stood
+        # empty while the loop had work, and the loop's time with none;
+        # surfaced via stats()["phase_ms"] and mirrored as jax.profiler
+        # TraceAnnotations ("dyn.<phase>", "dyn.<phase>.<part>") so a device
+        # trace shows them on the profiler's clock.  One set of names for
+        # every branch that serves a window; `readback` is the host blocked
+        # on the device.  Two clock reads, at most one is_ready() and one
+        # TraceMe a boundary.  The newest result dispatched, which the
+        # account asks is_ready(), is `_gen_counts`: every step program
+        # returns it.
+        self.loop_account = LoopAccount(
+            STEP_PHASES, PHASE_PARTS,
+            observe=lambda s: get_recorder().observe(
+                "engine.starved", s, component="engine"),
+            newest=lambda: self._gen_counts,
+            annotate=jax.profiler.TraceAnnotation,
+        )
+        # `_phase(name, **attrs)` closes the open host phase and opens
+        # `name` (None: just close), `_part(name)` the same for a part of
+        # the open phase that runs once a step: the account's own methods,
+        # called at every boundary of the loop
+        self._phase = self.loop_account.phase
+        self._part = self.loop_account.part
+        self._emit_row = self.loop_account.part_row("post", "emit")
+        self._publish_row = self.loop_account.part_row("post", "publish")
         self._sliding_window = getattr(cfg, "sliding_window", None)
         # expert-layer counters: device leaves taken out of the cache, not
         # yet read (device thread only), and their sum (stats() reads it)
@@ -629,9 +669,6 @@ class JaxLlmEngine:
         self._moe_totals = [0] * len(MOE_STAT_KEYS)
         self._moe_zero = None
         self._moe_taken_at = 0.0
-        self._phase_name: str | None = None   # the open phase
-        self._phase_t0 = 0.0
-        self._phase_ann = None
         # Step telemetry: batch occupancy / queue depth / KV pool usage per
         # scheduler iteration, merged into stats() → load-metrics publisher
         # → dyn_worker_* Prometheus gauges (observability.step_metrics).
@@ -656,7 +693,6 @@ class JaxLlmEngine:
         self._step_attn_ctx = 0         # sum of attended context positions
         self._step_weight_streams = 0.0 # full weight passes dispatched
         self._step_lane_steps = 0       # decode lanes x device steps dispatched
-        self._step_readback_s = 0.0     # host blocked on the device
         # kind of the window this iteration waited for / dispatched (the
         # iteration's time is booked to the first that is set)
         self._step_waited_kind: str | None = None
@@ -2370,14 +2406,11 @@ class JaxLlmEngine:
             out["window_blocks_released_total"] = wp.released_behind_total
         if "moe_stats" in self.cache:
             out.update(self._moe_stats())
-        # snapshot: the device thread inserts keys (prefetch.page) and
-        # updates the pairs concurrently
-        phases = {k: tuple(v) for k, v in list(self.phase_stats.items())}
-        out["phase_ms"] = {
-            name: {"total_ms": round(tot * 1e3, 2), "n": n,
-                   "mean_ms": round(tot / n * 1e3, 3) if n else 0.0}
-            for name, (tot, n) in phases.items()
-        }
+        out["phase_ms"] = self.loop_account.snapshot()
+        # flat, for a reader of two counters: the `post` phase and the part
+        # of it that hands tokens to the event loop
+        out["engine_post_time_total_s"] = self.loop_account.rows["post"][0]
+        out["engine_post_emit_time_total_s"] = self._emit_row[0]
         # request-path spans of this process, aggregated (count / total /
         # max / duration histogram per component and name): recorder.py
         out["spans"] = get_recorder().aggregate()
@@ -2407,6 +2440,7 @@ class JaxLlmEngine:
             "engine loop started (max_len=%d blocks=%d bs=%d buckets=%s)",
             self.max_len, self.config.num_blocks, self.config.max_batch_size, self.buckets,
         )
+        self.loop_account.loop_started()
         while not self._stop:
             try:
                 # chaos seam: an injected step failure exercises the loop's
@@ -2426,8 +2460,10 @@ class JaxLlmEngine:
                         idle=not self.scheduler.has_work()
                     )
                     if progress and not self.scheduler.has_work():
+                        self.loop_account.idle()
                         continue
                 if not self.scheduler.has_work():
+                    self.loop_account.idle()
                     self._wake.wait(timeout=0.05)
                     self._wake.clear()
                     continue
@@ -2440,6 +2476,7 @@ class JaxLlmEngine:
                 # keep the thread alive (callers would hang forever), don't
                 # hot-spin
                 self._phase(None)
+                self.loop_account.abandon_step()
                 logger.exception("engine step failed")
                 if self.flight.enabled:
                     self.flight.record_event(
@@ -2457,17 +2494,19 @@ class JaxLlmEngine:
 
     def _begin_step(self) -> tuple[float, int]:
         """Open one iteration of the step loop: reset the per-step scratch
-        and start the `schedule` phase."""
+        and start the `schedule` phase (its part `admit`: the scheduler's
+        decision and the route to a step program)."""
+        t_step = self.loop_account.begin_step()
         self._step_prefill_tokens = 0
         self._step_decode_tokens = 0
         self._step_attn_ctx = 0
         self._step_weight_streams = 0.0
         self._step_lane_steps = 0
-        self._step_readback_s = 0.0
         self._step_waited_kind = self._step_dispatched_kind = None
         self._step_waited_samples = self._step_dispatched_samples = False
         self._phase("schedule")
-        return time.perf_counter(), self._tokens_emitted
+        self._part("admit")
+        return t_step, self._tokens_emitted
 
     def _end_step(self, t_step: float, emitted_before: int) -> None:
         """Close the iteration: ONE record of it, handed to all three
@@ -2477,7 +2516,8 @@ class JaxLlmEngine:
         that served no window carried no prompt token."""
         self._phase(None)
         self._iterations += 1
-        step_duration_s = time.perf_counter() - t_step
+        acct = self.loop_account
+        step_duration_s = acct.end_step() - t_step
         if step_duration_s > 1.0:
             # seconds-long iterations are compiles or giant windows;
             # either way the operator wants to know which
@@ -2492,7 +2532,7 @@ class JaxLlmEngine:
             kind=(self._step_waited_kind or self._step_dispatched_kind
                   or KIND_DECODE),
             duration_s=step_duration_s,
-            readback_wait_s=self._step_readback_s,
+            readback_wait_s=acct.step_readback_s,
             num_running=self.scheduler.num_running,
             num_waiting=self.scheduler.num_waiting,
             kv_active_blocks=self.allocator.used_blocks,
@@ -2507,8 +2547,18 @@ class JaxLlmEngine:
                 self._step_waited_samples if self._step_waited_kind
                 else self._step_dispatched_samples
             ),
+            starved_s=acct.step_starved_s,
+            starved_slack_s=acct.step_slack_s,
+            starved_dispatches=acct.step_starved_dispatches,
+            offcpu_s=acct.step_offcpu_s,
+            no_work_s=acct.step_no_work_s,
         )
         self.step_telemetry.observe(rec)
+        if rec.num_running:
+            # a busy step's length, so a window's tail of steps is the
+            # difference of two histograms (no per-step series kept)
+            get_recorder().observe(
+                "engine.step." + rec.kind, step_duration_s, component="engine")
         self.utilization.observe(rec)
         if not self.flight.enabled:
             return
@@ -2533,6 +2583,9 @@ class JaxLlmEngine:
             emitted_tokens=rec.emitted_tokens,
             step_duration_s=rec.duration_s,
             readback_wait_s=rec.readback_wait_s,
+            starved_s=rec.starved_s,
+            offcpu_s=rec.offcpu_s,
+            no_work_s=rec.no_work_s,
             mfu=self.utilization.step_mfu(rec),
             goodput_tok_s=(
                 rec.emitted_tokens / rec.duration_s
@@ -2732,6 +2785,7 @@ class JaxLlmEngine:
         # path serves this iteration; sync mode drains first and preempts
         # like the plain decode path.
         slots: dict[str, int] = {}
+        self._part("slots")
         if overlap:
             for seq in decodes:
                 dev_ctx = min(
@@ -2766,6 +2820,7 @@ class JaxLlmEngine:
             if not decodes and not spans:
                 return True  # everything preempted: step consumed
 
+        self._part("build")
         tb = self._tb_for(bucket)
         token_ids = np.zeros((bucket,), np.int32)
         token_pos = np.full((bucket,), -1, np.int32)
@@ -2844,6 +2899,7 @@ class JaxLlmEngine:
                 emit_seqs.append(seq)
             cursor += span
 
+        self._part("tables")
         tables = self._decode_tables(decodes + [s for s, _, _ in spans])
         # per token block, the spans of pages its lanes can see: the ragged
         # kernel loops over exactly those (it resolves physical pages from
@@ -2854,6 +2910,7 @@ class JaxLlmEngine:
             from dynamo_tpu.ops.pallas import kv_step_pages, pack_spans
 
             self._phase("pack")
+            self._part("spans")
             page_meta = pack_spans(
                 token_lane, token_pos, lanes=lanes, tb_tokens=tb,
                 block_size=bs, sliding_window=self._sliding_window,
@@ -2861,6 +2918,7 @@ class JaxLlmEngine:
             if self._window_blocks:
                 # the window layers walk their own spans: the same lanes,
                 # from the page their window starts in
+                self._part("window_spans")
                 window_meta = pack_spans(
                     token_lane, token_pos, lanes=lanes, tb_tokens=tb,
                     block_size=bs, sliding_window=self._attn_layers[2],
@@ -2873,7 +2931,9 @@ class JaxLlmEngine:
                 flat, flat, flat, np.zeros((bucket // tb,), np.int32)
             )
         self._phase("upload")
+        self._part("sampling")
         sampling_tail = self._device_sampling_tail(emit_seqs, lanes)
+        self._part("arrays")
         if overlap and prev is not None:
             feedback_in = prev.feedback
         else:
@@ -2936,9 +2996,9 @@ class JaxLlmEngine:
             if end >= len(all_tokens):
                 if seq.status == SeqStatus.PREFILLING:
                     seq.status = SeqStatus.RUNNING
-                self.allocator.publish_stored(seq.seq_id, all_tokens)
+                self._publish_stored(seq.seq_id, all_tokens)
             else:
-                self.allocator.publish_stored(seq.seq_id, all_tokens[:end])
+                self._publish_stored(seq.seq_id, all_tokens[:end])
             opened.append(self._open_prefill_span(seq, t_prefill))
         self._step_decode_tokens += len(decodes)
         n_full, n_window, w = self._attn_layers
@@ -2997,6 +3057,7 @@ class JaxLlmEngine:
             self._phase("post")
             self._note_wait(KIND_PROMPT, opened, samples)
             self._sync_windows += 1
+            self._part("tokens")
             for seq in emit_seqs:
                 if seq.status != SeqStatus.RUNNING:
                     continue
@@ -3690,7 +3751,7 @@ class JaxLlmEngine:
         if not final:
             # intermediate chunk: KV written, no token sampled; publish the
             # completed blocks so routers (and future prompts) can hit them
-            self.allocator.publish_stored(seq.seq_id, tokens[:end])
+            self._publish_stored(seq.seq_id, tokens[:end])
             if seq.prefill_only and seq.on_chunk_done is not None:
                 self._stream_prefill_chunk(seq, blocks, end)
             return
@@ -3722,11 +3783,13 @@ class JaxLlmEngine:
                 seq.on_prefill_done(result)
             return
         if seq.mm_embeds is None:
-            self.allocator.publish_stored(seq.seq_id, tokens)
+            self._publish_stored(seq.seq_id, tokens)
         want_top = seq.request.sampling.top_logprobs > 0
+        self._part("tokens")
         self._process_token(
             seq, token_h, lp_h, top=(tkv, tki) if want_top else None
         )
+        self._part(None)
 
     def _read_prefill_sample(self, token, lp) -> tuple[int, float]:
         """Read a split prefill's sampled token back (the `readback` phase:
@@ -3871,6 +3934,7 @@ class JaxLlmEngine:
             self._note_wait(w.kind, w.prefills, w.samples)
             for seq in w.active:
                 seq.inflight_tokens = max(0, seq.inflight_tokens - w.steps)
+            self._part("tokens")
             for s in range(tokens_host.shape[0]):
                 for seq in w.active:
                     if seq.status != SeqStatus.RUNNING:
@@ -3886,8 +3950,10 @@ class JaxLlmEngine:
             # readback/emission above raised (this window is no longer
             # reachable from self._inflight, so a skipped release here
             # would leak the lane and blocks forever)
-            for seq in w.deferred:
-                self.scheduler.finish(seq)
+            if w.deferred:
+                self._part("release")
+                for seq in w.deferred:
+                    self.scheduler.finish(seq)
 
     def _finish_decoded(self, seq: Sequence) -> None:
         """Finish a sequence from the decode path.  While an in-flight
@@ -3950,6 +4016,7 @@ class JaxLlmEngine:
         # writes; on OOM the pipeline drains and the preempting synchronous
         # path serves this iteration instead.
         slots: dict[str, int] = {}
+        self._part("slots")
         for seq in active:
             # clamp at max_len: a lane the host is about to LENGTH-finish can
             # have in-flight windows past the end — those steps are pure
@@ -3964,6 +4031,7 @@ class JaxLlmEngine:
                 return self._run_plain_decode(seqs)
             slots[seq.seq_id] = slot
 
+        self._part("build")
         context_lens = np.zeros((lanes,), np.int32)
         slot_ids = np.full((lanes,), oob, np.int32)
         token_ids = np.zeros((lanes,), np.int32) if prev is None else None
@@ -3977,9 +4045,12 @@ class JaxLlmEngine:
                 slot_ids[lane] = slots[seq.seq_id]
             if token_ids is not None:
                 token_ids[lane] = seq.all_token_ids[-1]
+        self._part("tables")
         tables = self._decode_tables(active)
         self._phase("upload")
+        self._part("sampling")
         sampling_tail = self._device_sampling_tail(active, lanes)
+        self._part("arrays")
         # token feedback: step N+1's input IS step N's on-device output —
         # the host never sees (or waits for) the tokens it dispatches
         tok_in = prev.feedback if prev is not None else jnp.asarray(token_ids)
@@ -4096,13 +4167,18 @@ class JaxLlmEngine:
         blocks behind the window of its NEXT query go back to the pool, and
         the expert layers' counters leave the cache (the step's result, not
         yet donated to the next) for a fresh zero leaf."""
-        if self._window_blocks:
+        released = bool(self._window_blocks)
+        if released:
+            self._part("release")
             for seq, next_pos in seqs_at:
                 self.allocator.release_behind_window(seq.seq_id, next_pos)
         # ... every 50 ms, not every step: the leaf goes on counting on the
         # device meanwhile (int32: hours at this model's rates, not 50 ms)
         now = time.monotonic()
         if "moe_stats" in self.cache and now - self._moe_taken_at >= 0.05:
+            if not released:
+                released = True
+                self._part("release")
             self._moe_taken_at = now
             taken = self.cache["moe_stats"]
             taken.copy_to_host_async()
@@ -4119,6 +4195,8 @@ class JaxLlmEngine:
                 for i, n in enumerate(np.asarray(leaf).tolist()):
                     self._moe_totals[i] += n
             self._moe_pending = [*waiting, taken]
+        if released:
+            self._part(None)
 
     def _attended_ctx(self, start: int, end: int) -> tuple[int, int]:
         """Context positions the tokens at ``[start, end)`` attend, causally
@@ -4168,29 +4246,9 @@ class JaxLlmEngine:
             work["window_pages_visited_total"] += pages * steps
             work["window_pages_full_total"] += full_pages * steps
 
-    def _phase(self, name: str | None, **attrs) -> None:
-        """Close the open host phase (book its wall time into
-        ``phase_stats``, end its ``dyn.<phase>`` TraceAnnotation) and open
-        ``name`` (None: just close).  ``attrs`` ride on the annotation —
-        with no profiler session active a TraceMe costs about a
-        microsecond and records nothing."""
-        now = time.perf_counter()
-        cur = self._phase_name
-        if cur is not None:
-            dt = now - self._phase_t0
-            row = self.phase_stats.get(cur)
-            if row is None:
-                row = self.phase_stats[cur] = [0.0, 0]
-            row[0] += dt
-            row[1] += 1
-            if cur == "readback":
-                self._step_readback_s += dt
-            self._phase_ann.__exit__(None, None, None)
-        self._phase_name = name
-        if name is not None:
-            self._phase_t0 = now
-            self._phase_ann = jax.profiler.TraceAnnotation("dyn." + name, **attrs)
-            self._phase_ann.__enter__()
+    @property
+    def _phase_name(self) -> str | None:
+        return self.loop_account.name
 
     def _run_plain_decode(self, seqs: list[Sequence]) -> None:
         lanes = self.config.max_batch_size
@@ -4202,6 +4260,7 @@ class JaxLlmEngine:
 
         slots: dict[str, int] = {}
         candidates: list[Sequence] = []
+        self._part("slots")
         for seq in list(seqs):
             if seq.status != SeqStatus.RUNNING:
                 continue  # preempted as a victim earlier in this loop
@@ -4219,6 +4278,7 @@ class JaxLlmEngine:
         # preempted as a victim must not keep a live lane pointing at freed
         # (possibly re-allocated) blocks
         active = [s for s in candidates if s.status == SeqStatus.RUNNING]
+        self._part("build")
         for seq in active:
             self._prep_decode_seq(seq)
             lane = seq.lane
@@ -4228,13 +4288,16 @@ class JaxLlmEngine:
                 slot_ids[lane] = slots[seq.seq_id]
         if not active:
             return
+        self._part("tables")
         tables = self._decode_tables(active)
 
         want_top = any(
             seq.request.sampling.top_logprobs > 0 for seq in active
         )
         self._phase("upload")
+        self._part("sampling")
         sampling_tail = self._device_sampling_tail(active, lanes)
+        self._part("arrays")
         kind, prefills, samples = self._take_unwaited(KIND_DECODE)
         if steps <= 1:
             gmodes = np.full((lanes,), -1, np.int32)
@@ -4278,6 +4341,7 @@ class JaxLlmEngine:
         self._sync_windows += 1
         self._count_decode_window(context_lens, len(active), int(tokens_host.shape[0]))
 
+        self._part("tokens")
         for s in range(tokens_host.shape[0]):
             for seq in active:
                 if seq.status != SeqStatus.RUNNING:
@@ -4361,7 +4425,9 @@ class JaxLlmEngine:
 
         want_top = any(s.request.sampling.top_logprobs > 0 for s in active)
         self._phase("upload")
+        self._part("sampling")
         sampling_tail = self._device_sampling_tail(active, lanes)
+        self._part("arrays")
         self._phase("dispatch", kind=KIND_DECODE, tokens=len(active) * w)
         tokens, n_accept, lps, tkvs, tkis, self.cache, self._gen_counts = self._jit_verify(
             self.params, self.cache, self._gen_counts, self._prompt_counts,
@@ -4389,6 +4455,7 @@ class JaxLlmEngine:
         self._step_decode_tokens += len(active) * w
         self._step_attn_ctx += int(context_lens.sum()) * w
         self._step_weight_streams += 1
+        self._part("tokens")
         for seq in active:
             lane = seq.lane
             n = int(n_h[lane])
@@ -4431,15 +4498,30 @@ class JaxLlmEngine:
                 vals, ids = top
                 k = min(want, len(ids))
                 top_rows = [[[int(ids[i]), float(vals[i])] for i in range(k)]]
+            # the part `emit` of `post`, once a token: two clock reads into
+            # its row, taken out of the open part `tokens` (no annotation)
+            t0 = time.perf_counter()
             seq.emit(
                 [token], finish,
                 logprobs=None if logprob is None else [logprob],
                 top_logprobs=top_rows,
             )
+            row = self._emit_row
+            row[0] += time.perf_counter() - t0
+            row[1] += 1
         if finish is not None:
             self._record_decode_span(seq)
             self._finish_decoded(seq)
         elif seq.context_len % self.config.block_size == 0 and seq.mm_embeds is None:
             # (multimodal blocks never publish: text-token hashes cannot
             # describe patch-embedding content)
-            self.allocator.publish_stored(seq.seq_id, seq.all_token_ids)
+            self._publish_stored(seq.seq_id, seq.all_token_ids)
+
+    def _publish_stored(self, seq_id: str, tokens: list[int]) -> None:
+        """``allocator.publish_stored``, booked as the part `publish` of
+        `post` (a block's worth of tokens a lane: measured where it runs)."""
+        t0 = time.perf_counter()
+        self.allocator.publish_stored(seq_id, tokens)
+        row = self._publish_row
+        row[0] += time.perf_counter() - t0
+        row[1] += 1

@@ -299,7 +299,6 @@ class UtilizationTracker:
         self.decode_tokens_total = 0
         self.emitted_tokens_total = 0
         self.flops_total = 0.0
-        self.bytes_total = 0.0
 
     def observe(self, rec: StepRecord, now: float | None = None) -> None:
         """Book one engine step; the step's own FLOPs are written back onto
@@ -319,7 +318,6 @@ class UtilizationTracker:
             self.decode_tokens_total += rec.decode_tokens
             self.emitted_tokens_total += rec.emitted_tokens
             self.flops_total += flops
-            self.bytes_total += moved
             self._samples.append(
                 _Sample(
                     t=t, duration_s=rec.duration_s, flops=flops, bytes_moved=moved,
@@ -391,6 +389,5 @@ class UtilizationTracker:
             decode_tokens_total=self.decode_tokens_total,
             tokens_emitted_total=self.emitted_tokens_total,
             model_flops_total=self.flops_total,
-            model_bytes_total=self.bytes_total,
         )
         return out

@@ -9,8 +9,9 @@ The reference relies on external genai-perf plus ``tracing`` spans
 - env ``DYN_PROFILER_TRACE_DIR``: capture a device trace of the whole engine
   serve window (``maybe_start_trace_from_env`` at engine start,
   ``maybe_stop_trace`` at engine stop) — open the result in TensorBoard /
-  xprof, where the engine's ``dyn.<phase>`` annotations (always emitted)
-  sit beside the device planes.
+  xprof, where the engine's ``dyn.<phase>`` and ``dyn.<phase>.<part>``
+  annotations (always emitted) sit beside the device planes.  The Python
+  tracer is off in this trace.
 """
 
 from __future__ import annotations
@@ -53,8 +54,13 @@ def maybe_start_trace_from_env() -> str | None:
         return None
     import jax
 
+    # no Python tracer: it records every call of the host code (740 k events
+    # and 30 MB for a four-token request), slows the loop it is to show by
+    # an order of magnitude and is not what this trace is for
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(log_dir)
+        jax.profiler.start_trace(log_dir, profiler_options=options)
     except Exception as exc:  # noqa: BLE001 — profiling must never stop serving
         logger.warning("profiler trace start failed: %r", exc)
         return None

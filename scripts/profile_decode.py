@@ -81,11 +81,6 @@ def _decode_phase_shares(phase_ms: dict) -> dict:
     return {k: round(v / total, 4) for k, v in step.items()}
 
 
-def _reset_phase_stats(engine) -> None:
-    for row in engine.phase_stats.values():
-        row[:] = [0.0, 0]
-
-
 async def run(args: argparse.Namespace, *, overlap: bool | None = None) -> dict:
     import jax
     import numpy as np
@@ -198,7 +193,7 @@ async def run(args: argparse.Namespace, *, overlap: bool | None = None) -> dict:
     # queued prefill programs and bills them to decode).
     async def clear_at_steady():
         await all_started.wait()
-        _reset_phase_stats(engine)
+        engine.loop_account.reset()
 
     t0 = time.monotonic()
     results = await asyncio.gather(
@@ -318,7 +313,7 @@ async def run_mixed(args: argparse.Namespace, *, unified: bool) -> dict:
     warm.append(asyncio.ensure_future(drive(make_request())))
     await asyncio.gather(*warm)
     before = engine.stats()
-    _reset_phase_stats(engine)
+    engine.loop_account.reset()
     t0 = time.monotonic()
     tasks = []
     if getattr(args, "decode_heavy", False):

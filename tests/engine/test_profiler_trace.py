@@ -53,6 +53,13 @@ async def test_profiler_trace_dir_env_captures_serve_window(tmp_path, monkeypatc
     planes = host_spans.load_planes(trace.find_xplane(str(trace_dir)))
     names = {e[0] for lines in planes.values() for events in lines.values() for e in events}
     assert {"dyn.schedule", "dyn.upload", "dyn.dispatch", "dyn.readback", "dyn.post"} <= names
+    # ... and the parts of the large ones that run once a step; `emit` and
+    # `publish` run once a token and are never an annotation
+    assert {"dyn.post.tokens", "dyn.schedule.admit", "dyn.schedule.slots", "dyn.schedule.build",
+            "dyn.schedule.tables", "dyn.upload.sampling", "dyn.upload.arrays"} <= names
+    assert not {"dyn.post.emit", "dyn.post.publish"} & names
+    # the env-started trace runs without the Python tracer
+    assert not any(n.startswith("$") for n in names)
     assert any(n.startswith("PjitFunction(dyn_unified_t16") for n in names), sorted(
         n for n in names if "Pjit" in n)
     assert any(n.startswith("PjitFunction(dyn_decode_w1") for n in names)
