@@ -183,6 +183,21 @@ def _round_chunk_tokens(chunk_tokens: int, block_size: int) -> int:
     return max(1, (chunk_tokens + block_size - 1) // block_size) * block_size
 
 
+def _token_buckets(configured: tuple[int, ...], max_len: int) -> list[int]:
+    """The step programs' token buckets: the configured ones clipped to the
+    context, then, above the last of them (B), half-octave steps 1.5 B, 2 B,
+    3 B, 4 B, 6 B, ... while below the context, and the context itself.  So a
+    window above B pads by at most a third of its rows whatever the context
+    (with the context alone above B, a 5,000-token prompt runs 8,192 rows at
+    a context of 8,192 and 262,144 at 262,144), for two programs a bucket."""
+    buckets = {min(b, max_len) for b in configured}
+    octave = max(buckets)
+    while 0 < octave < max_len:
+        buckets |= {octave * 3 // 2, octave * 2}
+        octave *= 2
+    return sorted(b for b in buckets if b < max_len) + [max_len]
+
+
 def _kernel_perf_path() -> str:
     """DYN_KERNEL_PERF override or the repo-root KERNEL_PERF.json."""
     import os
@@ -392,9 +407,7 @@ class JaxLlmEngine:
         self.family = get_family(config.model_family)
         self.max_len = config.resolved_max_len()
         self.max_blocks_per_seq = (self.max_len + config.block_size - 1) // config.block_size
-        self.buckets = sorted({min(b, self.max_len) for b in config.prefill_buckets})
-        if self.buckets[-1] < self.max_len:
-            self.buckets.append(self.max_len)
+        self.buckets = _token_buckets(config.prefill_buckets, self.max_len)
 
         self.mesh = None
         if config.mesh is not None and (
@@ -800,6 +813,11 @@ class JaxLlmEngine:
                 unified = False
         self.unified_batch = unified
         self._unified_windows = 0     # mixed windows served by one dispatch
+        # how well the token buckets fit the prompt windows dispatched: the
+        # tokens a window carried (its spans + the decode lanes packed beside
+        # them) and the rows of the bucket it ran
+        self._prompt_window_live_tokens = 0
+        self._prompt_window_bucket_tokens = 0
         self._admission_drains = 0    # pipeline drains forced by admission
         # ragged kernel tunable, precedence: explicit knob > tuned
         # KERNEL_PERF.json row (ops/autotune.py) > the default the kernel
@@ -2348,6 +2366,8 @@ class JaxLlmEngine:
             "decode_windows_overlapped_total": self._overlap_windows,
             "decode_windows_sync_total": self._sync_windows,
             "decode_windows_unified_total": self._unified_windows,
+            "prompt_window_live_tokens_total": self._prompt_window_live_tokens,
+            "prompt_window_bucket_tokens_total": self._prompt_window_bucket_tokens,
             "admission_drains_total": self._admission_drains,
             # reason-slug → count of windows (or the engine init) that fell
             # back from the unified step; each reason also logged once
@@ -3010,6 +3030,9 @@ class JaxLlmEngine:
             ragged_ctx += ctx if w is None else min(ctx, w)
         self._step_weight_streams += 1
         self._unified_windows += 1
+        self._book_prompt_window(
+            len(decodes) + sum(end - start for _, start, end in spans), bucket
+        )
         if decodes:
             self._decode_steps_total += 1
             self._step_lane_steps += len(decodes)
@@ -3324,6 +3347,10 @@ class JaxLlmEngine:
             if b >= n:
                 return b
         return self.buckets[-1]
+
+    def _book_prompt_window(self, live: int, bucket: int) -> None:
+        self._prompt_window_live_tokens += live
+        self._prompt_window_bucket_tokens += bucket
 
     def _table_len(self, nblocks: int) -> int:
         """Smallest block-table compile bucket covering ``nblocks``.
@@ -3680,6 +3707,7 @@ class JaxLlmEngine:
                 self._guided_row(seq), self.cos, self.sin,
             )
             seq.prefilled_tokens = total
+            self._book_prompt_window(total, bucket)
             self._step_prefill_tokens += total
             self._step_attn_ctx += total * (total + 1) // 2
             self._step_weight_streams += 1
@@ -3699,7 +3727,7 @@ class JaxLlmEngine:
             # scales with the actual context, not max_blocks_per_seq
             start_blocks = start // self.config.block_size
             tail = tokens[start:end]
-            t = len(tail)
+            live = t = len(tail)
             padded = np.zeros((self._bucket_len(t),), np.int32)
             padded[:t] = tail
             table_len = self.allocator.blocks_needed(
@@ -3722,6 +3750,7 @@ class JaxLlmEngine:
                 self.cos, self.sin,
             )
         else:
+            live = end
             padded = np.zeros((self._bucket_len(end),), np.int32)
             padded[:end] = tokens[:end]
             block_ids = np.zeros((self.max_blocks_per_seq,), np.int32)
@@ -3743,6 +3772,7 @@ class JaxLlmEngine:
         self._step_dispatched_kind = KIND_PROMPT
         self._after_dispatch([(seq, end)])
         seq.prefilled_tokens = end
+        self._book_prompt_window(live, padded.shape[0])
         # utilization accounting: this window computed [start, end) — each
         # position p attends p+1 context positions (causal)
         self._step_prefill_tokens += end - start

@@ -9,6 +9,7 @@ process may load the TPU library, and every xdist worker imports this file.
 Everything lives in this one file so one worker owns the library.
 """
 
+import functools
 import math
 
 import jax
@@ -263,14 +264,7 @@ def test_mla_lane_kernels_compile_at_the_cells_shapes(one_chip, launch):
     assert _kernel_names(compiled) == {fn.__name__}
 
 
-@pytest.mark.parametrize("program", ["decode", "unified_t8192"])
-def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one_chip, program):
-    """``moonlight-16b-l9``'s whole decode and 8,192-token step programs at
-    the cell's shapes (10.87 GB of weights, 2.03 GB of latent pages): both
-    leaves aliased to the outputs, the kernels named as the benchmark's
-    metrics look for them (the MLA launch of the program, the grouped
-    products of the walk), less than 1 GB of temporaries (a copy, a pad or a
-    relayout of the rope leaf alone would be 0.41 GB, of the latent 1.62)."""
+def _moonlight_16b_l9():
     import dataclasses
 
     from dynamo_tpu.models.deepseek import DeepseekConfig
@@ -284,40 +278,113 @@ def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one
         "routed_scaling_factor": 2.446, "scoring_func": "sigmoid", "rope_theta": 50000,
         "max_position_embeddings": 8192, "rms_norm_eps": 1e-5,
     }), grouped_matmul="pallas")      # what "auto" is on the chip
-    family = get_family("deepseek_v3")
+    # family, config, blocks, lanes, query rows a token in one product, rope width
+    return "deepseek_v3", cfg, 11008, MLA["lanes"], cfg.num_heads, cfg.qk_rope_head_dim
+
+
+def _k_exaone_236b_l8():
+    from dynamo_tpu.models.exaone_moe import ExaoneMoeConfig
+
+    cfg = ExaoneMoeConfig(
+        vocab_size=19200, hidden_size=6144, intermediate_size=18432,
+        num_layers=8, num_heads=8, num_kv_heads=1, head_dim=128,
+        max_position_embeddings=8192, rope_theta=1e6,
+        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
+        mlp_layer_types=("dense",) + ("sparse",) * 7, window=128,
+        num_experts=16, expert_parallel_size=8, experts_per_token=8,
+        moe_intermediate_size=2048, grouped_matmul="pallas",
+    )
+    return "exaone_moe", cfg, 8320, 16, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
+
+
+EXPERT_CELLS = {"moonlight-16b-l9": _moonlight_16b_l9, "k-exaone-236b-l8": _k_exaone_236b_l8}
+
+
+@functools.cache
+def _expert_cell_program(one_chip, config, program):
+    """A step forward of an 8,192-context expert cell at the cell's shapes
+    (``decode``, ``unified_t<tokens>``, ``prefill_t<tokens>``), the cache
+    donated, compiled for the described chip (once a module), and the bytes
+    of its cache."""
+    from dynamo_tpu.models.llama import KvPools
+
+    name, cfg, blocks, lanes, rows, rope_dim = EXPERT_CELLS[config]()
+    family = get_family(name)
     s = _sds(one_chip)
-    lanes, context, blocks = MLA["lanes"], 8192, 11008
+    context = 8192
+    window = (family.window_pool_blocks(cfg, lanes, context, BLOCK)
+              if family.window_pool_blocks else 0)
+    pools = (lambda a: KvPools(a, a)) if window else (lambda a: a)
     shaped = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)  # noqa: E731
     params = shaped(jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = shaped(jax.eval_shape(lambda: family.cache_init(cfg, blocks, BLOCK, None)))
+    cache = shaped(jax.eval_shape(lambda: family.cache_init(
+        cfg, blocks, BLOCK, None, **({"window_blocks": window} if window else {}))))
     i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
-    rope = s((context, cfg.qk_rope_head_dim // 2), jnp.float32)
-    tables = i32(lanes, context // BLOCK)
-    if program == "decode":
+    rope = s((context, rope_dim // 2), jnp.float32)
+    tables = pools(i32(lanes, context // BLOCK))
+    kind, _, t = program.partition("_t")
+    t = int(t or 0)
+    if kind == "decode":
         def fn(p, c, tok, bt, cl, sl, cos, sin):
             return family.forward_decode(p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
-        args, kernel = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope), "mla_paged_attention_decode"
-    else:
-        t = int(program.removeprefix("unified_t"))
+        args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
+    elif kind == "unified":
+        tb = math.gcd(default_tb_tokens(rows, BLOCK), t)
         def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
             return family.forward_unified(
                 p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
-                cos, sin, attention="pallas", tb_tokens=TB)
-        args = (i32(t), tables, i32(lanes), *(i32(t) for _ in range(6)),
-                i32(t // TB), i32(lanes), rope, rope)
-        kernel = "ragged_mla_attention"
+                cos, sin, attention="pallas", tb_tokens=tb)
+        args = (i32(t), tables, i32(lanes), i32(t), i32(t), i32(t),
+                *(pools(i32(t)) for _ in range(3)), pools(i32(t // tb)), i32(lanes), rope, rope)
+    else:
+        def fn(p, c, tok, ids, n, start, cos, sin):
+            return family.forward_prefill(p, cfg, tok, c, ids, n, start, cos, sin)
+        args = (i32(t), pools(i32(context // BLOCK)), i32(), i32(), rope, rope)
     full = jax.config.jax_include_full_tracebacks_in_locations
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
     try:
         compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
     finally:
         jax.config.update("jax_include_full_tracebacks_in_locations", full)
+    return compiled, sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+
+
+@pytest.mark.parametrize("program", ["decode", "unified_t8192"])
+def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one_chip, program):
+    """``moonlight-16b-l9``'s whole decode and 8,192-token step programs at
+    the cell's shapes (10.87 GB of weights, 2.03 GB of latent pages): both
+    leaves aliased to the outputs, the kernels named as the benchmark's
+    metrics look for them (the MLA launch of the program, the grouped
+    products of the walk), less than 1 GB of temporaries (a copy, a pad or a
+    relayout of the rope leaf alone would be 0.41 GB, of the latent 1.62)."""
+    compiled, pools = _expert_cell_program(one_chip, "moonlight-16b-l9", program)
+    kernel = "mla_paged_attention_decode" if program == "decode" else "ragged_mla_attention"
     assert _kernel_names(compiled) == {kernel, "gmm"}
     memory = compiled.memory_analysis()
-    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert memory.alias_size_in_bytes >= pools - 64
     assert memory.temp_size_in_bytes < 1e9
     assert 12.8e9 < memory.argument_size_in_bytes < 13.0e9
+
+
+@pytest.mark.parametrize("kind", ["unified", "prefill"])
+@pytest.mark.parametrize("config", sorted(EXPERT_CELLS))
+def test_the_6144_bucket_of_the_8192_context_cells_compiles_between_its_neighbours(
+        one_chip, config, kind):
+    """A context of 8,192 has a 6,144-token bucket (``engine._token_buckets``):
+    both expert cells' ``unified_6144`` and ``prefill_6144`` programs compile
+    for the chip, write every leaf of the cache in place as their neighbours
+    do (no pad, slice or relayout of it), and their temporaries lie between
+    the 4,096 and the 8,192 programs', which stay under 1 GB as they are held
+    today (``k-exaone-236b-l8``'s prompt-only program under 2.7: its dense
+    attention holds 8 heads' scores over 8,192 x 8,192 at once, 2.1 GB)."""
+    limit = 2.7e9 if (config, kind) == ("k-exaone-236b-l8", "prefill") else 1e9
+    temps = {}
+    for tokens in (4096, 6144, 8192):
+        compiled, pools = _expert_cell_program(one_chip, config, f"{kind}_t{tokens}")
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= pools - 64, tokens
+        temps[tokens] = memory.temp_size_in_bytes
+    assert temps[4096] <= temps[6144] <= temps[8192] < limit, temps
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])
@@ -368,38 +435,8 @@ def test_a_layers_expert_banks_are_read_where_they_lie(one_chip):
     arguments): both pools are written in place, and the grouped product
     reads a layer's banks out of the stack (``ops/moe.py``): sliced out for
     the kernel they were 1.2 GB of temporaries a layer; the step holds 0.12."""
-    from dynamo_tpu.models.exaone_moe import ExaoneMoeConfig
-    from dynamo_tpu.models.llama import KvPools
-
-    cfg = ExaoneMoeConfig(
-        vocab_size=19200, hidden_size=6144, intermediate_size=18432,
-        num_layers=8, num_heads=8, num_kv_heads=1, head_dim=128,
-        max_position_embeddings=8192, rope_theta=1e6,
-        layer_types=(("sliding_attention",) * 3 + ("full_attention",)) * 2,
-        mlp_layer_types=("dense",) + ("sparse",) * 7, window=128,
-        num_experts=16, expert_parallel_size=8, experts_per_token=8,
-        moe_intermediate_size=2048, grouped_matmul="pallas",
-    )
-    family = get_family("exaone_moe")
-    s = _sds(one_chip)
-    lanes, context = 16, 8192
-    shaped = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)  # noqa: E731
-    params = shaped(jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = shaped(jax.eval_shape(lambda: family.cache_init(
-        cfg, 8320, BLOCK, None,
-        window_blocks=family.window_pool_blocks(cfg, lanes, context, BLOCK),
-    )))
-    tables = s((lanes, context // BLOCK), jnp.int32)
-    rope = s((context, cfg.head_dim // 2), jnp.float32)
-    lane = s((lanes,), jnp.int32)
-
-    def fn(p, c, tok, bt, cl, sl, cos, sin):
-        return family.forward_decode(p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
-
-    memory = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, lane, KvPools(tables, tables), lane, lane, rope, rope
-    ).compile().memory_analysis()
-    pools = sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    compiled, pools = _expert_cell_program(one_chip, "k-exaone-236b-l8", "decode")
+    memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pools - 64
     assert memory.temp_size_in_bytes < 400e6
     assert 10.3e9 < memory.argument_size_in_bytes < 10.7e9
