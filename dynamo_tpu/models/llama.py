@@ -340,6 +340,27 @@ def _mlp(x, gate, up, down, activation: str = "silu"):
     return mm(act * mm(x, up), down)
 
 
+def _split_on_activation(rows: int, hidden: int) -> bool:
+    """Whether a step program of ``rows`` rows splits a projection's columns
+    into heads on the ACTIVATION (``_qkv``).  Left free, XLA folds the
+    reshape into the product: a convolution with the head axis a spatial
+    dimension, whose weight operand must be hidden-minor, so every layer of
+    every step slices ``wq``, ``wk`` and ``wv`` into fast memory and
+    transposes them there (31.5 MB a ``qwen3-4b`` layer, 50.3 MB a
+    ``mistral-7b`` one) before it multiplies.  With few rows the activation
+    is the small thing to turn (16 x 4,096 bf16 = 128 KB) and the weight is
+    read where it lies in the layer stack, as ``wo`` and the MLP's three are.
+    Both relayouts grow with the projection's columns, so rows against hidden
+    decides.  Fitted on a v5e (scripts/qkv_split_bench.py, whole forwards;
+    the table is in PERF.md section 6, PR 48): the split on the activation
+    saved 40-58 us a ``qwen3-4b`` layer from 16 to 1,024 rows and 16 at
+    2,048, and LOST 334 at 4,096 (q's float32 relayout, 67 MB behind the q/k
+    norm, no longer fits fast memory); 69-177 us a ``mistral-7b`` layer at
+    every width up to 4,096.  The line sits where every measured point on
+    its near side is a gain and the cliff is on the far side."""
+    return 2 * rows <= hidden
+
+
 def _qkv(attn_in, w, cfg: LlamaConfig):
     """Project+bias+head-split (+ Qwen3 per-head q/k RMSNorm, pre-rope);
     shared by prefill/decode/trunk.  Projections run through ``mm`` so
@@ -350,6 +371,8 @@ def _qkv(attn_in, w, cfg: LlamaConfig):
     v_proj = mm(attn_in, w["wv"])
     if cfg.attention_bias:
         q_proj, k_proj, v_proj = q_proj + w["bq"], k_proj + w["bk"], v_proj + w["bv"]
+    if _split_on_activation(s, attn_in.shape[1]):
+        q_proj, k_proj, v_proj = jax.lax.optimization_barrier((q_proj, k_proj, v_proj))
     q = q_proj.reshape(s, cfg.num_heads, cfg.head_dim)
     k = k_proj.reshape(s, cfg.num_kv_heads, cfg.head_dim)
     v = v_proj.reshape(s, cfg.num_kv_heads, cfg.head_dim)
@@ -548,7 +571,10 @@ def _scan_layers(layer, x, layers: dict, kv_cache: dict):
 class _LayerOf(Mapping):
     """One layer's weights out of a group's stacked leaves, each taken when
     it is asked for (``w["wq"]``: a dynamic slice XLA reads in place, as a
-    scan's own).  ``w.stacked(name)`` hands the whole stack and the layer's
+    scan's own: tests/ops/test_chip_compile.py holds the cells' compiled
+    decode steps to it for all seven weights of a layer,
+    ``test_a_decode_step_reads_its_projection_weights_where_they_lie``).
+    ``w.stacked(name)`` hands the whole stack and the layer's
     index instead, for a kernel that reads its layer where it lies
     (ops/moe.py ``grouped_matmul``)."""
 

@@ -240,3 +240,80 @@ def test_qwen3_registry_config():
     assert cfg.qk_norm and not cfg.attention_bias
     params = fam.init_params(cfg, jax.random.PRNGKey(0))
     assert params["layers"]["q_norm"].shape == (2, 16)
+
+
+QKV_KINDS = {"plain": {}, "qk_norm": {"qk_norm": True}, "attention_bias": {"attention_bias": True}}
+
+
+@pytest.mark.parametrize("rows", [1, 16, 2048])
+@pytest.mark.parametrize("kind", sorted(QKV_KINDS))
+def test_qkv_is_three_plain_products_on_both_sides_of_the_row_rule(kind, rows):
+    """``_qkv`` in bf16 on random weights equals the three products computed
+    separately in float32 (bias, head split and q/k norm behind them) within
+    bf16 rounding, where the head split is held on the activation
+    (``_split_on_activation``: 1 and 16 rows of hidden 64) and where the
+    compiler is left free (2,048 rows); the traced program holds the barrier
+    on the first side only."""
+    import dataclasses
+
+    from dynamo_tpu.models.llama import _qkv, _split_on_activation
+    from dynamo_tpu.ops.norms import rms_norm
+
+    cfg = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.bfloat16, **QKV_KINDS[kind])
+    keys = jax.random.split(jax.random.PRNGKey(rows), 4)
+    w = jax.tree.map(lambda a: a[1], init_params(cfg, keys[0])["layers"])
+    for i, name in enumerate(n for n in ("bq", "bk", "bv", "q_norm", "k_norm") if n in w):
+        w[name] = (0.5 + jax.random.uniform(jax.random.fold_in(keys[1], i), w[name].shape)
+                   ).astype(cfg.dtype)
+    x = jax.random.normal(keys[2], (rows, cfg.hidden_size), jnp.float32).astype(cfg.dtype)
+
+    split = _split_on_activation(rows, cfg.hidden_size)
+    assert split == (rows < 2048)
+    assert ("optimization_barrier" in str(jax.make_jaxpr(lambda x, w: _qkv(x, w, cfg))(x, w))) == split
+    got = jax.jit(lambda x, w: _qkv(x, w, cfg))(x, w)
+
+    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), w)
+    heads = {"q": cfg.num_heads, "k": cfg.num_kv_heads, "v": cfg.num_kv_heads}
+    for out, (name, n) in zip(got, heads.items()):
+        ref = x.astype(jnp.float32) @ f32[f"w{name}"]
+        if cfg.attention_bias:
+            ref = ref + f32[f"b{name}"]
+        ref = ref.reshape(rows, n, cfg.head_dim)
+        if cfg.qk_norm and name != "v":
+            ref = rms_norm(ref, f32[f"{name}_norm"], cfg.rms_norm_eps)
+        assert out.shape == ref.shape and out.dtype == jnp.bfloat16
+        # one rounding of the product to bf16 (2^-9 of its size), a second and
+        # a third through the norm
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref), rtol=2 ** -6, atol=2 ** -7)
+
+
+def test_greedy_decode_tokens_of_a_two_layer_model_are_what_they_were():
+    """Prefill, then seven greedy ``llama_forward_decode`` steps of the tiny
+    two-layer model (its head untied, so that the ids wander): the ids the
+    tree gave before ``_qkv`` held its head split on the activation (one row
+    is under the row rule: this runs the barrier's side)."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, tie_word_embeddings=False)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    cos, sin = make_rope_tables(cfg)
+    cache = init_kv_cache(cfg, NUM_BLOCKS, BLOCK_SIZE)
+    prompt = list(range(2, 12))
+    block_ids = jnp.arange(6, dtype=jnp.int32)
+    logits, cache = llama_forward_prefill(
+        params, cfg, jnp.asarray(prompt + [0] * 6, jnp.int32), cache, block_ids,
+        jnp.int32(len(prompt)), jnp.int32(0), cos, sin,
+    )
+    ids = [int(jnp.argmax(logits))]
+    decode = jax.jit(partial(llama_forward_decode, cfg=cfg, cos=cos, sin=sin))
+    for _ in range(7):
+        length = len(prompt) + len(ids)
+        slot = block_ids[(length - 1) // BLOCK_SIZE] * BLOCK_SIZE + (length - 1) % BLOCK_SIZE
+        logits, cache = decode(
+            params, token_ids=jnp.asarray(ids[-1:], jnp.int32), kv_cache=cache,
+            block_tables=block_ids[None, :], context_lens=jnp.asarray([length], jnp.int32),
+            slot_ids=slot[None],
+        )
+        ids.append(int(jnp.argmax(logits[0])))
+    assert ids == [478, 11, 206, 26, 430, 412, 464, 383]

@@ -11,6 +11,7 @@ Everything lives in this one file so one worker owns the library.
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -211,8 +212,6 @@ def _mla_pages(s):
 
 
 def _kernel_names(compiled):
-    import re
-
     return set(re.findall(
         r"%([A-Za-z_]+)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
         compiled.as_text()))
@@ -455,8 +454,6 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, tokens):
     the serving process makes them (``utils/compile_cache.py``): lowered
     inline in the loop's body they were ``tpu_custom_call.<n>`` on the chip
     and both metrics fell silent."""
-    import re
-
     from dynamo_tpu.ops import moe
 
     s = _sds(one_chip)
@@ -488,9 +485,10 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
+@functools.cache
 def _compile_forward(one_chip, config, program):
     """A family's step forward at a cell's shapes, cache donated, as a
-    compiled executable for the described chip."""
+    compiled executable for the described chip (once a module)."""
     name, cfg, num_blocks, lanes = STEP_CONFIGS[config]
     family = get_family(name)
     s = _sds(one_chip)
@@ -560,8 +558,6 @@ def test_decode_kernel_reads_its_pages_from_fast_memory_where_a_layer_fits(
     slices in the chip's fast memory (``S(1)`` in the layout), where the
     one-query kernel runs twice as fast; ``mistral-7b-l16``'s (168 MB) do
     not fit, and its kernel reads the flat pages in HBM."""
-    import re
-
     compiled, _ = _compile_forward(one_chip, config, "decode")
     hlo = compiled.as_text()
     (call,) = [
@@ -577,6 +573,130 @@ def test_decode_kernel_reads_its_pages_from_fast_memory_where_a_layer_fits(
         assert re.search(operand, definition.split(" = ")[1][:120]), definition[:200]
 
 
+class _Hlo:
+    """A compiled program's text by computation: ``name -> [(instruction,
+    dtype, dims, op, line)]``, which computations are fusions' bodies, and
+    which are loops' bodies."""
+
+    _INSTR = re.compile(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\w+\[[\d,]*\](?:\{[^ ]*\})?) ([\w\-]+)\(")
+    BYTES = {"bf16": 2, "f32": 4, "s32": 4}
+
+    def __init__(self, text):
+        self.computations, self.fused, self.loop_bodies = {}, set(), set()
+        current = None
+        for line in text.splitlines():
+            head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+            if head:
+                current = self.computations.setdefault(head.group(1), [])
+                continue
+            m = self._INSTR.match(line)
+            if m is None or current is None:
+                continue
+            name, result, op = m.groups()
+            # (a tuple's result has no one shape: no dtype, no dims)
+            dtype, dims = re.match(r"(?:(\w+)\[([\d,]*)\])?", result).groups()
+            current.append((name, dtype, tuple(map(int, filter(None, (dims or "").split(",")))), op, line))
+            if op == "fusion":
+                self.fused.add(re.search(r"calls=%([\w.\-]+)", line).group(1))
+            if op == "while":
+                self.loop_bodies.add(re.search(r"body=%([\w.\-]+)", line).group(1))
+
+    def inside(self, instr):
+        """Every instruction of the computation a fusion calls, nested
+        fusions' too (of another instruction: none)."""
+        if instr[3] != "fusion":
+            return []
+        body = self.computations[re.search(r"calls=%([\w.\-]+)", instr[4]).group(1)]
+        return [inner for i in body for inner in (i, *self.inside(i))]
+
+    def launched(self, loops_only=False):
+        """The instructions the program launches by themselves: those of no
+        fusion's body (``loops_only``: of the loops' bodies alone)."""
+        for name, instrs in self.computations.items():
+            if name not in self.fused and (not loops_only or name in self.loop_bodies):
+                yield from instrs
+
+    def moved_in_loops(self, floor=1 << 20):
+        """Bytes of the copies and transposes of ``floor`` bytes or more that
+        an iteration of the program's loops runs, alone or inside a fusion."""
+        total = 0
+        for instr in self.launched(loops_only=True):
+            for _, dtype, dims, op, _ in (instr, *self.inside(instr)):
+                size = math.prod(dims) * self.BYTES.get(dtype, 4)
+                if op in ("copy", "transpose") and size >= floor:
+                    total += size
+        return total
+
+
+def _decode_program(one_chip, config):
+    """(compiled decode forward, config, layers of each run) of a cell"""
+    if config in STEP_CONFIGS:
+        cfg = STEP_CONFIGS[config][1]
+        return _compile_forward(one_chip, config, "decode")[0], cfg, (cfg.num_layers,)
+    cfg = EXPERT_CELLS[config]()[1]
+    return (_expert_cell_program(one_chip, config, "decode")[0], cfg,
+            tuple(run.count for run in cfg.layer_runs()))
+
+
+# operations that compute nothing: a fusion made of these alone is a move
+_MOVES = {"parameter", "constant", "bitcast", "copy", "transpose", "dynamic-slice",
+          "slice", "reshape", "get-tuple-element"}
+
+
+@pytest.mark.parametrize("config", ["qwen3-4b", "mistral-7b-l16", "k-exaone-236b-l8"])
+def test_a_decode_step_reads_its_projection_weights_where_they_lie(one_chip, config):
+    """``_LayerOf``'s promise, for ``wq``, ``wk`` and ``wv`` too
+    (``llama._qkv`` splits the heads on the activation where the rows are
+    few): in a cell's compiled decode step nothing the program launches by
+    itself (a copy, a transpose, a slice, a fusion that only moves) yields an
+    array of the size of a layer's, or a run of layers', q or k/v projection
+    weight, and every one-layer slice of a weight stack (the four of
+    attention; a dense model's three of the MLP) sits inside the fusion of a
+    plain product (``convolution ... dim_labels=bf_io->bf``).  Left to fold
+    the head split into the product, XLA sliced ``wq``, ``wk`` and ``wv``
+    into fast memory and transposed them there in every layer of every step:
+    31.5 MB a ``qwen3-4b`` layer, 50.3 MB a ``mistral-7b-l16`` one, and
+    ``k-exaone-236b-l8``'s seven sparse layers' ``wq`` whole, 88 MB a
+    step."""
+    compiled, cfg, runs = _decode_program(one_chip, config)
+    hlo = _Hlo(compiled.as_text())
+    hidden, q_cols, kv_cols = (
+        cfg.hidden_size, cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim)
+    sizes = {n * hidden * cols for n in (1, *runs) for cols in (q_cols, kv_cols)}
+    slices = {(1, hidden, q_cols), (1, hidden, kv_cols), (1, q_cols, hidden)}
+    if config in STEP_CONFIGS:
+        slices |= {(1, hidden, cfg.intermediate_size), (1, cfg.intermediate_size, hidden)}
+    moved, in_products = [], 0
+    for instr in hlo.launched():
+        name, dtype, dims, op, line = instr
+        inner = hlo.inside(instr)
+        if dtype == "bf16" and math.prod(dims) in sizes and (
+                op in ("copy", "transpose", "dynamic-slice", "slice")
+                or (inner and all(i[3] in _MOVES for i in inner))):
+            moved.append((name, dims, op))
+        sliced = sum(i[3] == "dynamic-slice" and i[2] in slices for i in inner)
+        if sliced:
+            assert any(i[3] == "convolution" and "dim_labels=bf_io->bf" in i[4]
+                       for i in inner), line[:200]
+            in_products += sliced
+    assert not moved, moved
+    assert in_products >= (7 if config in STEP_CONFIGS else 4), in_products
+
+
+# the parent's (PR 45's tree: XLA's own choice), counted by `_Hlo.moved_in_loops`
+@pytest.mark.parametrize("tokens,parent_bytes", [(2048, 81.8e6), (4096, 132.2e6)])
+def test_a_wide_window_moves_no_more_bytes_than_xla_chose(one_chip, tokens, parent_bytes):
+    """The other side of ``llama._split_on_activation``: at 2,048 rows and up
+    the activation is the larger thing to turn (split there, ``qwen3-4b``'s
+    ``unified_t2048`` loses 31.5 MB of weight copies a layer and gains two
+    float32 relayouts of q of 33.6 MB each), so a wide window keeps the
+    form XLA chooses and its layer moves no more bytes in copies and
+    transposes of 1 MB or more than before the rule."""
+    compiled, _ = _compile_forward(one_chip, "qwen3-4b", f"unified_t{tokens}")
+    assert _Hlo(compiled.as_text()).moved_in_loops() <= parent_bytes + 0.05e6
+
+
 @pytest.mark.parametrize("program", ["decode", "unified_t128"])
 def test_a_step_programs_only_sort_sits_in_a_branch(one_chip, program):
     """The engine's own `dyn_decode_w1` and a `dyn_unified_t*` program at
@@ -586,8 +706,6 @@ def test_a_step_programs_only_sort_sits_in_a_branch(one_chip, program):
     branch, so a step whose lanes are all greedy never runs it; the top-20
     log-probabilities stay the chip's own `TopK` call, not a sort."""
     import dataclasses
-    import re
-
     from tests.ops.test_sampling import sorts_by_reach
 
     _, cell, _, lanes = STEP_CONFIGS["qwen3-4b"]
