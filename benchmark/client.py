@@ -47,6 +47,10 @@ async def _one(session, url, model, request, filled, t0, record, deadline):
                     text = (choice.get("delta") or {}).get("content") or ""
                     for entry in (choice.get("logprobs") or {}).get("content") or []:
                         record["logprobs"].append(entry["logprob"])
+                        if "decided_at" in entry:
+                            # a server that fixes a block's tokens pass by pass says which
+                            # pass fixed this one; the reference is forced along that path
+                            record["passes"].append(int(entry["decided_at"]))
                         if request.get("probe"):
                             record["top"].append([[int(a["token"][1:]), a["logprob"]]
                                                   for a in entry.get("top_logprobs") or []])
@@ -63,7 +67,7 @@ async def _one(session, url, model, request, filled, t0, record, deadline):
 
 def new_record(request: dict, prompt_tokens: int) -> dict:
     return {"index": request["index"], "due": request["due"], "prompt_tokens": prompt_tokens,
-            "output_len": request["output_len"], "sent": None, "chunks": [], "ids": [], "logprobs": [], "top": [], "probe": request.get("probe", 0),
+            "output_len": request["output_len"], "sent": None, "chunks": [], "ids": [], "logprobs": [], "passes": [], "top": [], "probe": request.get("probe", 0),
             "usage": None, "finish": None, "done": None, "error": None}
 
 

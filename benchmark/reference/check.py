@@ -5,13 +5,19 @@ has gone (a chip belongs to one process).
 
 The job holds the configuration's ``config.json`` keys, the file of the
 reference module the configuration names (``benchmark/README.md`` has the
-interface; nothing here knows a reference or a leaf by name), the weights'
-seed and a sample of finished requests: the ids the engine must have been
-handed and the ids it served.  For each, ONE reference trunk over prompt +
-served tokens; its served rows then go through the head ``PAD`` at a time,
-however many there are, and give, at every served position, how far the
-served token's logit lies below the reference's best, in units of that row's
-logit spread (the two configurations' logits differ fifty-fold in scale).
+interface; nothing here knows a reference, a leaf or a way of generating by
+name), the weights' seed and a sample of finished requests: the ids the
+engine must have been handed and the ids it served.  For each, ONE reference
+trunk over prompt + served tokens; its served rows then go through the head
+``PAD`` at a time, however many there are, and give, at every served
+position, how far the served token's logit lies below the reference's best,
+in units of that row's logit spread (the two configurations' logits differ
+fifty-fold in scale).  WHICH row decided served token ``a`` is row
+``n - 1 + a`` of that trunk (the row before it: next-token generation)
+unless the module offers ``decided_by``: then the module hands over the
+deciding rows itself, teacher-forced on the path the server reported
+(``served_passes``: for each served token, the pass over its block that
+fixed it).
 ``control`` (``fp8``) also puts the reference at that precision in the
 program's place and reads the same numbers of it.
 """
@@ -74,10 +80,11 @@ def _topk_err(values, at_top, sigma):
     return np.abs(got - want)[:, 1:] / sigma[:, None]
 
 
-def _slices(s: dict):
+def _slices(s: dict, decided: bool = False):
     """A sample's served rows, ``PAD`` at a time: the rows of the trunk's
-    output, the served ids and the probe's first k, each padded to ``PAD``,
-    and how many of them are real."""
+    output (of the module's own deciding rows where it handed those over:
+    one a served token, in the answer's order), the served ids and the
+    probe's first k, each padded to ``PAD``, and how many of them are real."""
     import numpy as np
 
     n, m = len(s["prompt_ids"]), len(s["served_ids"])
@@ -87,6 +94,8 @@ def _slices(s: dict):
         real = min(PAD, m - a)
         rows = np.zeros(PAD, np.int32)
         rows[:real] = np.arange(n - 1 + a, n - 1 + a + real)
+        if decided:
+            rows[:real] = np.arange(a, a + real)
         served = np.zeros(PAD, np.int32)
         served[:real] = s["served_ids"][a:a + real]
         top_tokens = np.zeros((PAD, max(k, 1)), np.int32)
@@ -95,6 +104,16 @@ def _slices(s: dict):
             top_tokens[:real] = [[t for t, _ in r[:k]] for r in top[a:a + real]]
             top_values[:real] = [[v for _, v in r[:k]] for r in top[a:a + real]]
         yield real, rows, served, top_tokens, top_values
+
+
+def _path(s: dict):
+    """What a module with ``decided_by`` is handed of a sample.  A server
+    that did not say how it decoded cannot be ``correct``: the child fails."""
+    passes = s.get("served_passes")
+    if passes is None or len(passes) != len(s["served_ids"]):
+        raise SystemExit(f"sample {s['index']}: the reference decides a served token by the pass that "
+                         f"fixed it, and the server reported no decided_at for every token it served")
+    return s["prompt_ids"], s["served_ids"], passes
 
 
 def run(job: dict) -> dict:
@@ -124,14 +143,20 @@ def run(job: dict) -> dict:
     # set of programs, whatever the seed drew and however long an answer is
     longest = max(len(s["prompt_ids"]) + len(s["served_ids"]) for s in job["samples"])
     length = longest + (-longest % PAD)
+    # a module that says itself which row decided a served token (a token
+    # fixed by a pass over its block): asked in place of the row before it
+    decided_by = getattr(ref, "decided_by", None)
     out, kept = [], []
     for s in job["samples"]:
         ids = s["prompt_ids"] + s["served_ids"]
         n, m = len(s["prompt_ids"]), len(s["served_ids"])
         padded = ids + [0] * (length - len(ids))
-        x = ref.hidden(weights, hf, padded)
+        if decided_by:
+            x = decided_by(weights, hf, *_path(s), length)
+        else:
+            x = ref.hidden(weights, hf, padded)
         parts, top_values, held = [], [], []
-        for real, rows, served, top_tokens, values in _slices(s):
+        for real, rows, served, top_tokens, values in _slices(s, bool(decided_by)):
             logits = ref.logits(weights, hf, x[jnp.asarray(rows)])
             parts.append({a: np.asarray(b)[:real] for a, b in
                           row_stats(logits, jnp.asarray(served), jnp.asarray(top_tokens)).items()})
@@ -151,7 +176,7 @@ def run(job: dict) -> dict:
             e = _topk_err(top_values, st["at_top"], st["sigma"])
             rec["topk_err_max"], rec["topk_err_mean"] = float(e.max()), float(e.mean())
         if job.get("control"):
-            kept.append((rec, padded, held, st["sigma"]))
+            kept.append((rec, s, padded, held, st["sigma"]))
         out.append(rec)
         print(f"sample {s['index']} ({n}+{m} tokens) after {time.monotonic() - t0:.1f} s", flush=True)
     if job.get("control"):
@@ -160,8 +185,11 @@ def run(job: dict) -> dict:
         control = {}
         for name in list(weights):
             control.update(ref.quantize({name: weights.pop(name)}, job["control"], hf))
-        for rec, padded, held, sigma in kept:
-            x = ref.hidden(control, hf, padded)
+        for rec, s, padded, held, sigma in kept:
+            if decided_by:
+                x = decided_by(control, hf, *_path(s), length)
+            else:
+                x = ref.hidden(control, hf, padded)
             cs = cat([{a: np.asarray(b)[:real] for a, b in control_stats(
                 jnp.asarray(logits), ref.logits(control, hf, x[jnp.asarray(rows)])).items()}
                 for real, rows, logits in held])

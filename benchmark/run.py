@@ -87,14 +87,18 @@ def cache_dir() -> Path:
     return Path(os.environ.get("JAX_COMPILATION_CACHE_DIR") or ROOT / ".jax_cache")
 
 
-def warm_marker(cache: Path, cell: str) -> Path:
-    """Written when a run of ``cell`` from this checkout has reached its end
-    under ``cache``: until then the cell's server and reference compile, and
-    the run gets the long deadline.  (A cache that is warm for another cell,
-    or for another checkout, says nothing: the serving arguments and the
-    reference's shapes are the cell's, and a kernel's key holds the
-    checkout's path.)"""
-    return cache / f"bench-warm.{cell}.{hashlib.sha1(str(ROOT).encode()).hexdigest()[:12]}"
+def warm_markers(cache: Path, cell: str, work: Path = WORK) -> tuple[Path, Path]:
+    """Both written when a run of ``cell`` from this checkout has reached its
+    end: until then the cell's server and reference compile, and the run gets
+    the long deadline.  One lies under ``cache`` (a cache that is warm for
+    another cell, or for another checkout, says nothing: the serving
+    arguments and the reference's shapes are the cell's, and a kernel's key
+    holds the checkout's path).  The other lies in the checkout itself: a
+    cache directory that outlives the machine brings its markers along, and a
+    new machine compiles the cell's programs again all the same (PR 49: three
+    runs killed at the short deadline in their first run on a machine)."""
+    return (cache / f"bench-warm.{cell}.{hashlib.sha1(str(ROOT).encode()).hexdigest()[:12]}",
+            work / f"bench-warm.{cell}")
 
 
 def metrics_of(bench: dict, cell: str, group: str) -> list[dict]:
@@ -160,6 +164,7 @@ def pick_sample(records, plan_by_index, filled, k: int, seed: int) -> list[dict]
     return [{"index": r["index"], "served_ids": r["ids"],
              "top": r["top"] if r["probe"] and len(r["top"]) == len(r["ids"]) else None,
              "served_logprobs": r["logprobs"] if len(r["logprobs"]) == len(r["ids"]) else None,
+             "served_passes": r["passes"] if len(r["passes"]) == len(r["ids"]) else None,
              "prompt_ids": traffic.templated_ids(plan_by_index[r["index"]], filled)}
             for r in chosen]
 
@@ -184,9 +189,10 @@ def run_reference(job: dict, work: Path, deadline: float) -> dict:
     return load_json(out_path)
 
 
-def compare(check: dict, counts: dict, limits: dict) -> tuple[bool, list[str]]:
-    """Each number compared beside its limit; every one has to hold."""
-    rows = [
+def compared(check: dict, counts: dict, limits: dict) -> list[tuple[str, float, float]]:
+    """``(name, value, limit)`` of each number compared: a value holds while
+    it is at most its limit (a least count is held as its negative)."""
+    return [
         ("failed_requests", counts["failed"], 0),
         ("token_count_mismatches", counts["mismatched"], 0),
         ("checked_tokens_min", -check.get("tokens", 0), -limits["min_checked_tokens"]),
@@ -195,8 +201,12 @@ def compare(check: dict, counts: dict, limits: dict) -> tuple[bool, list[str]]:
         ("probed_tokens_min", -check.get("probed_tokens", 0), -limits["min_probed_tokens"]),
         ("topk_err_mean", check.get("topk_err_mean", float("inf")), limits["topk_err_mean"]),
     ]
+
+
+def compare(check: dict, counts: dict, limits: dict) -> tuple[bool, list[str]]:
+    """Each number compared beside its limit; every one has to hold."""
     ok, lines = True, []
-    for name, value, limit in rows:
+    for name, value, limit in compared(check, counts, limits):
         good = value <= limit
         ok &= good
         lines.append(f"compare {name}: value {value!r} limit {limit!r} {'ok' if good else 'FAIL'}")
@@ -217,8 +227,8 @@ def parse(argv):
     return p.parse_args(argv)
 
 
-def run_deadline(started: float, marker: Path) -> float:
-    return started + (RUN_DEADLINE_S if marker.exists() else COLD_DEADLINE_S)
+def run_deadline(started: float, markers) -> float:
+    return started + (RUN_DEADLINE_S if all(m.exists() for m in markers) else COLD_DEADLINE_S)
 
 
 def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path=None,
@@ -251,8 +261,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
         raise SystemExit(f"--seconds {seconds:g} offers fewer requests than the cell's "
                          f"gap_requests {gap_requests}: itl_p95_ms would be another set's")
     serving = [str(a) for a in [*config["serving"]["args"], *loaded["own"].get("serving_args", [])]]
-    marker = warm_marker(cache_dir(), args.workload)
-    deadline = run_deadline(started, marker)
+    markers = warm_markers(cache_dir(), args.workload)
+    deadline = run_deadline(started, markers)
     server = Server(model_dir, serving, weights_seed, work / "server.log", deadline,
                     launcher=launcher, env_overlay=env_overlay)
     holder: dict = {"samples": []}
@@ -331,7 +341,8 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
          "samples": sample, "control": args.control,
          "require_platform": require_platform}, work, deadline) if sample else {}
     ref_s = time.monotonic() - t_ref
-    correct, lines = compare(check, {"failed": len(failed), "mismatched": len(mismatched)}, limits)
+    counts = {"failed": len(failed), "mismatched": len(mismatched)}
+    correct, lines = compare(check, counts, limits)
     if args.control and check:
         lines.append(f"control ({args.control} reference) logit_gap_max {check.get('control_gap_max')!r} "
                      f"logprob_err_mean {check.get('control_logprob_err_mean')!r} "
@@ -354,6 +365,10 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
               "metrics": metrics, "device": device_out}
     if trace:
         result["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["gap_kinds"]}
+    # each number compared beside its limit, last in the line (a number that
+    # could not be read is null: the line stays JSON)
+    result["compared"] = {name: {"value": value if value < float("inf") else None, "limit": limit}
+                          for name, value, limit in compared(check, counts, limits)}
     if args.dump:
         Path(args.dump).parent.mkdir(parents=True, exist_ok=True)
         Path(args.dump).write_text(json.dumps({
@@ -370,8 +385,9 @@ def run(args, *, require_platform: str | None = "tpu", launcher=None, bench_path
     print(json.dumps(result), flush=True)
     for line in lines:
         print(line, file=sys.stderr, flush=True)
-    marker.parent.mkdir(parents=True, exist_ok=True)
-    marker.touch()
+    for marker in markers:
+        marker.parent.mkdir(parents=True, exist_ok=True)
+        marker.touch()
     return 0, result
 
 

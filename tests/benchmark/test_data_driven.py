@@ -58,8 +58,12 @@ def test_the_real_cells_load_and_size_as_their_files_say(tree, cell, roots):
     serving = loaded["config"]["serving"]
     assert own.cache_bytes(hf, serving) == serving["kv_bytes"]
     # weights alone pass a quarter of the chip's 16 GB; weights + two
-    # copies of the cache stay under it
-    assert own.weight_bytes(hf) > 4e9
+    # copies of the cache stay under it.  (The one exception is a toy of the
+    # next tree that the tests instantiate on the CPU: its file says so under
+    # ``notes``, and the repository itself may hold none.)
+    toy = "toy" in loaded["config"].get("notes", {})
+    assert not (toy and tree == "real")
+    assert own.weight_bytes(hf) > 4e9 or toy
     assert 2 * own.total_params(hf) + 2 * serving["kv_bytes"] < 15e9
     assert loaded["reference"].is_file()
     # nothing of the harness's leaks into the served config.json
@@ -103,21 +107,27 @@ def test_a_configuration_that_names_no_module_of_its_own_is_an_error(tmp_path, k
 
 
 def test_a_run_is_cold_until_one_of_its_own_cell_has_reached_its_end(tmp_path):
-    """The long deadline is decided by a marker the harness writes itself,
-    per cell and checkout, under the cache directory: a cache that is warm
-    for another cell says nothing."""
-    cache = tmp_path / "cache"
+    """The long deadline is decided by two markers the harness writes itself,
+    per cell and checkout: one under the cache directory (a cache that is
+    warm for another cell says nothing), one in the checkout (a cache
+    directory that outlives the machine brings its markers along; the new
+    machine compiles all the same)."""
+    cache, work = tmp_path / "cache", tmp_path / "work"
     cache.mkdir()
     (cache / "jit_some_program-of-another-cell").write_text("x")
-    mine, other = runner.warm_marker(cache, "a.chat"), runner.warm_marker(cache, "b.chat")
-    assert mine != other and mine.parent == cache
-    other.touch()
+    mine, other = runner.warm_markers(cache, "a.chat", work), runner.warm_markers(cache, "b.chat", work)
+    assert not set(mine) & set(other) and (mine[0].parent, mine[1].parent) == (cache, work)
+    for marker in other:
+        marker.parent.mkdir(exist_ok=True)
+        marker.touch()
     # a first run of `a` still compiles; one that follows a run that reached its end does not
     assert runner.run_deadline(100.0, mine) == 100.0 + runner.COLD_DEADLINE_S
-    mine.touch()
-    assert runner.run_deadline(100.0, runner.warm_marker(cache, "a.chat")) == 100.0 + runner.RUN_DEADLINE_S
-    moved = runner.warm_marker(tmp_path / "moved", "a.chat")
-    assert runner.run_deadline(100.0, moved) == 100.0 + runner.COLD_DEADLINE_S
+    for marker in mine:
+        marker.touch()
+    assert runner.run_deadline(100.0, runner.warm_markers(cache, "a.chat", work)) == 100.0 + runner.RUN_DEADLINE_S
+    # another cache directory, or the old one under a fresh checkout: cold again
+    assert runner.run_deadline(100.0, runner.warm_markers(tmp_path / "moved", "a.chat", work)) == 100.0 + runner.COLD_DEADLINE_S
+    assert runner.run_deadline(100.0, runner.warm_markers(cache, "a.chat", tmp_path / "fresh")) == 100.0 + runner.COLD_DEADLINE_S
 
 
 @pytest.mark.parametrize("tree", TREES)

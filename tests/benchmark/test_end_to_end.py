@@ -58,7 +58,13 @@ def test_sound_and_control(tmp_path, seed):
           f"gap_max {check['control_gap_max']:.4f} logprob_err_mean "
           f"{check['control_logprob_err_mean']:.5f} topk_err_mean {check['control_topk_err_mean']:.5f}")
     assert rc == 0 and result["correct"] is True and result["failed"] == 0
-    assert set(result) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
+    # each number compared beside its limit, last in the line, as the lines on both streams have them
+    assert list(result["compared"]) == ["failed_requests", "token_count_mismatches", "checked_tokens_min",
+                                        "logit_gap_max", "logprob_err_mean", "probed_tokens_min", "topk_err_mean"]
+    assert result["compared"]["topk_err_mean"] == {"value": check["topk_err_mean"], "limit": LIMITS["topk_err_mean"]}
+    assert result["compared"]["checked_tokens_min"] == {"value": -check["tokens"], "limit": -LIMITS["min_checked_tokens"]}
+    assert all(row["value"] <= row["limit"] for row in result["compared"].values())
     assert set(result["metrics"]) == {"itl_p50_ms", "itl_p95_ms", "tok_per_s", "setup_s"}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert check["tokens"] >= LIMITS["min_checked_tokens"]
@@ -83,6 +89,7 @@ def test_a_broken_timed_path_is_not_correct(tmp_path):
     rc, result, dump = drive(tmp_path, SEEDS[0], launcher=[sys.executable, str(launcher)])
     assert rc == 0 and result["failed"] == 0
     assert result["correct"] is False
+    assert result["compared"]["logit_gap_max"] == {"value": dump["check"]["gap_max"], "limit": LIMITS["gap_max"]}
     assert dump["check"]["gap_max"] > LIMITS["gap_max"]
     assert dump["check"]["topk_err_mean"] > LIMITS["topk_err_mean"]
 

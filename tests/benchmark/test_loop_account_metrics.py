@@ -5,6 +5,7 @@
 reader the benchmark already had, reads a hand-built window, and reads as
 nothing on a program that keeps no such counter (the parent commit)."""
 
+import hashlib
 import importlib
 import json
 
@@ -29,6 +30,18 @@ TABLE = {
     "step_decode_p99_ms": ("span_percentile", "ms", 30.0),
     "no_work_share": ("counter_ratio", "%", 2.0),
 }
+
+
+# the 76 entries the list held when PR 44 appended its eight: their names, in order
+BEFORE_THEM = (76, "a1b9cf007f91571b53715e5dacf5295cd4a627b5")
+
+
+def in_place(names) -> bool:
+    """The eight, in order, once, right behind the entries that were there
+    before them, those unmoved; whatever comes after is a later PR's."""
+    first = names.index("device_starved_share")
+    digest = hashlib.sha1("\n".join(names[:first]).encode()).hexdigest()
+    return names[first:first + len(TABLE)] == list(TABLE) and (first, digest) == BEFORE_THEM
 
 
 def table_of(bench):
@@ -72,11 +85,20 @@ def window() -> dict:
 
 @pytest.mark.parametrize("tree", ["real", "next"])
 def test_the_eight_are_all_there_once_at_the_end_of_the_list(tree):
+    """The eight, in order, once, where PR 44 appended them: nothing of the
+    list before them moved, and whatever a later PR appends comes after."""
     bench = bench_of(tree)
     held = table_of(bench)
     assert [m["name"] for m in held] == list(TABLE)
-    if tree == "real":                    # appended: nothing of the list before them moved
-        assert [m["name"] for m in bench["per_layer"][-len(TABLE):]] == list(TABLE)
+    assert in_place([m["name"] for m in bench["per_layer"]])
+
+
+def test_an_entry_put_among_or_before_the_eight_is_seen():
+    names = [m["name"] for m in bench_of("real")["per_layer"]]
+    first = names.index("device_starved_share")
+    for at in (0, first, first + 3):
+        assert not in_place(names[:at] + ["new.metric"] + names[at:])
+    assert in_place(names + ["new.metric"])         # appended after them: as a later PR does
 
 
 @pytest.mark.parametrize("tree,metric", each(table_of))

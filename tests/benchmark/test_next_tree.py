@@ -1,8 +1,10 @@
-"""The dry run: what the next PR of the queue brings (``next_tree/``: a
+"""The dry run: what the next PRs of the queue bring (``next_tree/``: a
 configuration with a latent cache and a recurrent state a lane, its cell, a
-reasoning mix, a mix of sessions, four per-layer metrics) is added to a copy
-of the real tree as new files and appended entries, and the copy is held to
-everything the real tree is held to.  The tree-wide tests of the other files
+reasoning mix, a mix of sessions, four per-layer metrics; and a configuration
+that generates by passes over a block, whose reference says itself which row
+decided a served token, its cell, its mix, three per-layer metrics) is added
+to a copy of the real tree as new files and appended entries, and the copy is
+held to everything the real tree is held to.  The tree-wide tests of the other files
 run on that copy too; here is what only the addition can show."""
 
 import copy
@@ -11,7 +13,7 @@ import json
 import jax
 import pytest
 
-from benchmark import modules, run as runner
+from benchmark import modules, run as runner, traffic
 from benchmark.readers import kernel_roofline
 from benchmark.server import write_model_dir
 
@@ -19,6 +21,7 @@ from . import trees
 from .trees import ROOT, bench_of, each
 
 CELL = "hybrid-toy.reasoning-toy"
+BLOCKS = "blockdiff-toy.blocks-toy"
 
 
 def test_the_next_tree_is_the_real_one_with_files_added_and_lists_grown(roots):
@@ -26,11 +29,11 @@ def test_the_next_tree_is_the_real_one_with_files_added_and_lists_grown(roots):
     tree differs or BENCHMARK.json changed otherwise than by lists growing;
     this counts what was added."""
     added = trees.added_files()
-    assert len(added) == 10 and all(not (ROOT / rel).exists() for rel in added)
+    assert len(added) == 18 and all(not (ROOT / rel).exists() for rel in added)
     assert all((roots["next"] / rel).is_file() for rel in added)
     real, nxt = bench_of("real"), bench_of("next")
     grew = {key: len(nxt[key]) - len(real[key]) for key in ("configs", "workloads", "end_to_end", "per_layer")}
-    assert grew == {"configs": 1, "workloads": 1, "end_to_end": 0, "per_layer": 4}
+    assert grew == {"configs": 2, "workloads": 2, "end_to_end": 0, "per_layer": 7}
     assert all(nxt[key] == real[key] for key in ("command", "paths", "run_seconds"))
 
 
@@ -59,6 +62,9 @@ def test_every_configuration_brings_the_two_interfaces(tree, entry, roots):
     shapes = modules.load(modules.path_of(config, "shapes", bench_dir, entry["name"]))
     for name in ("init_weights", "hidden", "logits", "quantize"):
         assert callable(getattr(reference, name)), name
+    # the fifth is offered by the one configuration whose tokens are decided
+    # by a pass over their block, and by no configuration of the repository
+    assert hasattr(reference, "decided_by") == (entry["name"] == BLOCKS.split(".")[0])
     hf = runner.hf_config(config)
     for name in ("total_params", "matmul_params", "weight_bytes", "kv_bytes_per_token", "flops_per_token"):
         assert getattr(shapes, name)(hf) > 0, name
@@ -104,6 +110,7 @@ def test_the_new_cell_reports_what_its_entries_say(roots):
     end = [m["name"] for m in runner.metrics_of(bench, CELL, "end_to_end")]
     assert end == ["itl_p50_ms", "itl_p95_ms", "setup_s"]       # appended to the first one's list
     assert [m["name"] for m in runner.metrics_of(bench, "qwen3-4b.chat", "end_to_end")] == end
+    assert [m["name"] for m in runner.metrics_of(bench, BLOCKS, "end_to_end")] == end
     specs = runner.metric_specs(bench, CELL, roots["next"] / "benchmark")
     assert [m["name"] for m, _ in specs] == [
         "step_ms_decode.reasoning-toy", "preemptions.reasoning-toy",
@@ -145,3 +152,36 @@ def test_a_new_kernels_roofline_is_a_metric_file_and_nothing_else(roots):
     # a program that does not keep those counters: the roofline is left out
     ctx["stats1"]["stats"].pop("kda_decode_flops_total")
     assert "kda_decode_roofline.reasoning-toy" not in runner.read_per_layer(bench, CELL, ctx, bench_dir)
+
+
+def test_a_cell_whose_server_answers_a_block_a_chunk_comes_as_files(roots):
+    """``blockdiff-toy.blocks-toy``: one reference module with ``decided_by``,
+    one shapes module, one configuration, one mix without a backlog, one cell
+    file, three metric files over readers the tree has, appended entries."""
+    bench, bench_dir = bench_of("next"), roots["next"] / "benchmark"
+    mine = [str(rel) for rel in trees.added_files() if "block" in rel.name]
+    assert mine == ["benchmark/blockdiff_toy_shapes.py", "benchmark/cells/blockdiff-toy.blocks-toy.json",
+                    "benchmark/configs/blockdiff-toy.json", "benchmark/metrics/preemptions.blocks-toy.json",
+                    "benchmark/metrics/step_ms_decode.blocks-toy.json",
+                    "benchmark/metrics/tokens_per_pass.blocks-toy.json",
+                    "benchmark/reference/blockdiff_toy.py", "benchmark/traffic/blocks-toy.json"]
+    loaded = runner.load_cell(bench, BLOCKS, bench_dir)
+    config, hf = loaded["config"], runner.hf_config(loaded["config"])
+    assert "backlog" not in loaded["mix"] and not {"gap_requests", "replay"} & set(loaded["own"])
+    # the decoding settings reach the served config.json; the harness's keys do not
+    assert (hf["block_length"], hf["tokens_per_pass"]) == (4, 2)
+    assert traffic.RESERVED <= hf["mask_token_id"] < hf["vocab_size"]      # a word a prompt may hold
+    assert not {"notes", "assumed", "limits", "serving"} & set(hf) and "toy" in config["notes"]
+    # a served token costs its block's passes and the committing one
+    assert loaded["shapes"].rows_per_token(hf) == 3
+    specs = runner.metric_specs(bench, BLOCKS, bench_dir)
+    assert [m["name"] for m, _ in specs] == ["step_ms_decode.blocks-toy", "preemptions.blocks-toy",
+                                             "tokens_per_pass.blocks-toy"]
+    assert not [rel for rel in trees.added_files() if rel.parts[:2] == ("benchmark", "readers")]
+    zero = {"block_tokens_fixed_total": 0, "block_lane_passes_total": 0}
+    ctx = {"stats0": {"stats": zero}, "stats1": {"stats": {"block_tokens_fixed_total": 900,
+                                                           "block_lane_passes_total": 450}}}
+    got = runner.read_per_layer(bench, BLOCKS, ctx, bench_dir)
+    assert got == {"tokens_per_pass.blocks-toy": {"value": 2.0, "unit": "count"}}
+    # the cell says how many tokens a chunk holds
+    assert "a chunk is one committed block of 4 tokens" in loaded["cell"]["why"]

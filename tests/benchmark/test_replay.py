@@ -6,7 +6,7 @@ points ``run.py`` itself calls.
 - only the decode steps get shorter: the reading stays where it was, while
   the tail of every gap that closed inside the window (the reading until
   PR 32, kept as ``itl_p95_window_ms.long``) moves by whole windows at some
-  of those speeds, on one of the cells by more than the 3% bound - the
+  of those speeds, on one of the cells by more than the metric's bound - the
   control: the fault this reduction exists to cure is still shown;
 - every prompt window gets 3% longer or shorter: the reading follows;
 - a window of 45 s reads what one of 51 s reads;
@@ -21,7 +21,8 @@ from benchmark import arith, replay
 
 from .trees import ADDED, ROOT, bench_of, each
 
-BOUND = 0.03
+# the bound BENCHMARK.json holds ``itl_p95_ms`` to (3% until PR 49, 5.5% since)
+BOUND = next(m["bound"] for m in bench_of("real")["end_to_end"] if m["name"] == "itl_p95_ms")
 
 
 def own_of(cell: str) -> dict:
@@ -81,8 +82,9 @@ def window_tail_moves(cell) -> list[float]:
 def test_control_the_tail_of_the_window_moves_though_no_step_got_longer(cell):
     """Plateaus and cliffs: which window the percentile names depends on how
     far the server gets, so some speed-ups of the decode step read as nothing
-    and others as a whole window's worth of gain or loss."""
-    assert max(abs(m) for m in window_tail_moves(cell)) > 0.01
+    and others as a whole window's worth of gain or loss.  How far it moves is
+    asked of SOME cell (the next test): where 15 gaps in 100 are prompt
+    windows at any speed, no shorter decode step moves the 95th percentile."""
     # the same gaps, the same percentile: only the set differs
     assert cell.read()["itl_p95_window_ms"] == arith.percentile(arith.gaps_ms(cell.records(), cell.seconds), 95)
 

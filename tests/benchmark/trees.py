@@ -4,7 +4,9 @@
 and ``benchmark/`` to which the files under ``next_tree/`` have been ADDED and
 the entries of ``next_tree/BENCHMARK.append.json`` APPENDED: what the next PR
 of the queue brings (a configuration with a cache of its own kind, its cell,
-two mixes, four per-layer metrics), made the only way a later PR may make it.
+two mixes, four per-layer metrics; a configuration that generates by passes
+over a block, its cell, its mix, three per-layer metrics), made the only way
+a later PR may make it.
 A tree-wide test that fails on ``next`` would refuse that PR.
 
 The lists of cases are worked out while the tests are collected, from the
