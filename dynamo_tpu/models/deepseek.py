@@ -31,6 +31,11 @@ shipping work unchanged, in the one layout the MLA kernels copy in place
 The layer loop is the shared one (llama._scan_layer_runs): the dense layers
 then the sparse ones, each a scan whose carry is the cache's flat pages.
 
+Residual path: one stream a token, or (``hc_mult`` > 1, ``model_type``
+``xing4_0``) ``hc_mult`` streams mixed token by token before and after every
+sublayer (``_residual``, ops/hyper_connections.py); the streams ride the
+layer loop as ``[rows, hc_mult x hidden]`` and take no cache.
+
 Routing: V2-style renormalized softmax top-k, or V3/R1 aux-free sigmoid
 routing (e_score_correction_bias steers selection only, group-limited
 top-k) behind ``scoring_func="sigmoid"``.  Long context: YaRN rope scaling
@@ -52,6 +57,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.llama import LayerKind, LayerRun, _scan_layer_runs, layer_bank
+from dynamo_tpu.ops import hyper_connections as hc
 from dynamo_tpu.ops.attention import NEG_INF, position_major_to_batch
 from dynamo_tpu.ops.moe import MOE_STATS, moe_ffn
 from dynamo_tpu.ops.norms import rms_norm
@@ -93,6 +99,13 @@ class DeepseekConfig:
     # (mscale) — see attn_scale
     rope_scaling: Any = None
     tie_word_embeddings: bool = False
+    # residual streams a token (1 = the plain residual) and how their
+    # stream-to-stream matrix is made doubly stochastic (ops/hyper_connections.py)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     dtype: Any = jnp.bfloat16
     # the grouped product's implementation (as ``MixtralConfig``'s): "auto"
     # is the platform's in ``ops/moe.py``; the engine, which alone knows of a
@@ -156,6 +169,11 @@ class DeepseekConfig:
             rope_theta=config.get("rope_theta", 10000.0),
             rope_scaling=config.get("rope_scaling"),
             tie_word_embeddings=config.get("tie_word_embeddings", False),
+            hc_mult=config.get("hc_mult") or 1,
+            hc_sinkhorn_iters=config.get("hc_sinkhorn_iters", 20),
+            hc_eps=config.get("hc_eps", 1e-6),
+            mhc_h_res_clamp_min=config.get("mhc_h_res_clamp_min", -30.0),
+            mhc_h_res_clamp_max=config.get("mhc_h_res_clamp_max", 30.0),
         )
 
     # --- presets ----------------------------------------------------------
@@ -189,6 +207,25 @@ class DeepseekConfig:
             dtype=jnp.float32,
         )
 
+    @classmethod
+    def tiny_xing(cls, vocab_size: int = 512) -> "DeepseekConfig":
+        """Test geometry of the family with residual streams: four of them,
+        two dense and three sparse layers, a compressed query, YaRN."""
+        return cls(
+            vocab_size=vocab_size, hidden_size=256, num_layers=5, num_heads=4,
+            q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
+            first_k_dense=2, moe_intermediate_size=48, num_experts=8,
+            experts_per_token=2, n_shared_experts=1, routed_scaling_factor=2.0,
+            scoring_func="sigmoid", max_position_embeddings=2048,
+            rope_scaling={
+                "type": "yarn", "factor": 64, "beta_fast": 32, "beta_slow": 1,
+                "mscale": 1, "mscale_all_dim": 1,
+                "original_max_position_embeddings": 64,
+            },
+            hc_mult=4, dtype=jnp.float32,
+        )
+
 
 # ---------------------------------------------------------------------------
 # Parameters
@@ -214,7 +251,13 @@ def init_params(cfg: DeepseekConfig, rng: jax.Array) -> dict:
     stack in float32 (a layer's 64 experts are 0.74 GB there); each matrix
     ``normal / sqrt(fan_in)`` in ``cfg.dtype`` (the embedding: fan-in 1);
     the selection bias ``0.01 x normal`` in float32 (small: a trained bias is
-    what balances the experts' load, models/exaone_moe.py); norms all ones."""
+    what balances the experts' load, models/exaone_moe.py); norms all ones.
+    With residual streams (``hc_mult`` > 1) each group draws two more keys
+    LAST, for the two sublayers' ``hc_phi`` (``normal / sqrt(n x hidden)``)
+    and ``hc_bias`` (standard normal), float32, ``hc_alpha`` all ones: the
+    dynamic and the static part of every coefficient are both of order one,
+    so a program that skipped the mixing would be a different model (a
+    trained checkpoint starts near the identity, ``alpha`` 0.01)."""
     keys = iter(jax.random.split(rng, 32))
     h, v = cfg.hidden_size, cfg.vocab_size
     hd_q = cfg.num_heads * cfg.qk_head_dim
@@ -249,6 +292,18 @@ def init_params(cfg: DeepseekConfig, rng: jax.Array) -> dict:
             leaves["wq"] = draw((n, h, hd_q), h)
         return leaves
 
+    def streams(n):
+        """The two sublayers' mixing leaves of ``n`` layers (attention's at
+        index 0, the FFN's at 1)."""
+        if cfg.hc_mult == 1:
+            return {}
+        wide, outs = cfg.hc_mult * h, hc.coefficient_count(cfg.hc_mult)
+        return {
+            "hc_phi": draw((n, 2, wide, outs), wide, jnp.float32),
+            "hc_alpha": jnp.ones((n, 2, 3), jnp.float32),
+            "hc_bias": draw((n, 2, outs), 1.0, jnp.float32),
+        }
+
     params: dict = {
         "embed": one(next(keys), (v, h), 1.0, cfg.dtype),
         "final_norm": jnp.ones((h,), cfg.dtype),
@@ -263,6 +318,7 @@ def init_params(cfg: DeepseekConfig, rng: jax.Array) -> dict:
             "w_gate": draw((kd, h, i), h),
             "w_up": draw((kd, h, i), h),
             "w_down": draw((kd, i, h), i),
+            **streams(kd),
         }
     if km:
         mi, e = cfg.moe_intermediate_size, cfg.num_experts
@@ -281,6 +337,7 @@ def init_params(cfg: DeepseekConfig, rng: jax.Array) -> dict:
                 ws_up=draw((km, h, si), h),
                 ws_down=draw((km, si, h), si),
             )
+        moe.update(streams(km))
         params["moe_layers"] = moe
     return params
 
@@ -300,6 +357,11 @@ def _attn_specs(cfg: DeepseekConfig) -> dict:
         specs["w_uq"] = P(None, None, "tp")
     else:
         specs["wq"] = P(None, None, "tp")
+    if cfg.hc_mult > 1:
+        # a token's coefficients need its whole row: replicated
+        specs["hc_phi"] = P(None, None, None, None)
+        specs["hc_alpha"] = P(None, None, None)
+        specs["hc_bias"] = P(None, None, None)
     return specs
 
 
@@ -564,29 +626,61 @@ def _moe_mlp(w, x, cfg: DeepseekConfig, valid=None):
     return out, stats
 
 
+def _residual(cfg: DeepseekConfig, w, x, sublayer: int):
+    """``(h, add)`` around one sublayer (0: attention, 1: the FFN) of the
+    layer ``w``: ``h [rows, hidden]`` is what the sublayer's norm takes and
+    ``add(y)`` what the block carries on once the sublayer gave ``y``.  One
+    residual stream: ``x`` and ``x + y``.  ``hc_mult`` streams (``x [rows,
+    hc_mult x hidden]``): the row's own mix of them, and all of them mixed
+    among themselves plus ``y`` spread over them (ops/hyper_connections.py)."""
+    if cfg.hc_mult == 1:
+        return x, lambda y: x + y
+    with jax.named_scope("mhc_pre"):
+        h_pre, h_post, h_res = hc.coefficients(
+            x, w["hc_phi"][sublayer], w["hc_alpha"][sublayer], w["hc_bias"][sublayer],
+            cfg.hc_mult, norm_eps=cfg.rms_norm_eps, iters=cfg.hc_sinkhorn_iters,
+            eps=cfg.hc_eps, clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
+        )
+        h = hc.pre_mix(x, h_pre)
+
+    def add(y):
+        with jax.named_scope("mhc_post"):
+            return hc.post_mix(x, y, h_post, h_res)
+
+    return h, add
+
+
 def _block(cfg: DeepseekConfig, attend, valid, x, w, ck_pages, kr_pages, at):
     """THE transformer block of the family: latent attention (the forward's
     ``attend``), then the dense MLP or the expert layer, each behind its
-    RMSNorm.  ``valid`` [rows]: the rows that are real tokens (the expert
-    layer walks no others)."""
-    attn_in = rms_norm(x, w["attn_norm"], cfg.rms_norm_eps)
+    RMSNorm and inside the residual path (``_residual``).  ``valid`` [rows]:
+    the rows that are real tokens (the expert layer walks no others)."""
+    h, add = _residual(cfg, w, x, 0)
+    attn_in = rms_norm(h, w["attn_norm"], cfg.rms_norm_eps)
     attn_out, ck_pages, kr_pages = attend(w, attn_in, ck_pages, kr_pages, at)
-    x = x + attn_out
-    mlp_in = rms_norm(x, w["mlp_norm"], cfg.rms_norm_eps)
+    x = add(attn_out)
+    h, add = _residual(cfg, w, x, 1)
+    mlp_in = rms_norm(h, w["mlp_norm"], cfg.rms_norm_eps)
     if "w_router" not in w:
         with jax.named_scope("mlp"):
-            return x + _dense_mlp(w, mlp_in), ck_pages, kr_pages
+            return add(_dense_mlp(w, mlp_in)), ck_pages, kr_pages
     with jax.named_scope("moe"):
         out, stats = _moe_mlp(w, mlp_in, cfg, valid)
-    return x + out, ck_pages, kr_pages, stats
+    return add(out), ck_pages, kr_pages, stats
 
 
 def _forward(params, cfg: DeepseekConfig, x, kv_cache, attend, valid=None):
     """Shared trunk: the dense run then the sparse run over the cache's flat
-    pages, carried and written in place; final norm."""
+    pages, carried and written in place; final norm.  With residual streams
+    the embedding goes in as ``hc_mult`` equal streams, the layer loop
+    carries them, and their sum comes out."""
+    if cfg.hc_mult > 1:
+        x = hc.replicate(x, cfg.hc_mult)
     x, kv_cache = _scan_layer_runs(
         partial(_block, cfg, attend, valid), x, params, kv_cache, cfg.layer_runs()
     )
+    if cfg.hc_mult > 1:
+        x = hc.collapse(x, cfg.hc_mult)
     return rms_norm(x, params["final_norm"], cfg.rms_norm_eps), kv_cache
 
 
@@ -863,7 +957,14 @@ def load_hf_weights(cfg: DeepseekConfig, model_dir) -> dict:
 
     from dynamo_tpu.models.hf_io import read_safetensors
 
-    tensors = read_safetensors(model_dir)
+    tensors = read_safetensors(model_dir)   # (none there: FileNotFoundError, seeded weights)
+    if cfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"hc_mult {cfg.hc_mult}: no checkpoint with residual streams has been read "
+            "here, so the names and layouts of its mixing tensors (a sublayer's phi, "
+            "alpha and bias: params['*_layers']['hc_phi' / 'hc_alpha' / 'hc_bias']) are "
+            "not known; such a model is served from seeded weights only (init_params)"
+        )
 
     def get(name: str, transpose: bool = False):
         t = tensors[name]

@@ -337,6 +337,9 @@ _FAMILIES: dict[str, Callable[[], ModelFamily]] = {
     "exaone_moe": _exaone_moe_family,
     "deepseek_v2": _deepseek_family,
     "deepseek_v3": _deepseek_family,
+    # the same latent attention and expert layers inside hc_mult residual
+    # streams a token (config keys hc_* / mhc_*; ops/hyper_connections.py)
+    "xing4_0": _deepseek_family,
 }
 
 

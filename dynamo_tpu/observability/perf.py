@@ -100,6 +100,10 @@ class ModelCost:
     linear_flops_per_token: int     # matmul FLOPs per token (2·active params)
     attn_flops_per_ctx_token: int   # QK^T + AV FLOPs per attended ctx token
     kv_bytes_per_token: int         # KV cache bytes written per new token
+    # residual streams (a config with ``hc_mult`` > 1; else 0): sublayers a
+    # row passes, and the bytes a perfect mixing moves for a row of one
+    mhc_sublayers: int = 0
+    mhc_stream_bytes_per_row: int = 0
 
     def attn_flops(self, attn_ctx_tokens: int) -> float:
         """FLOPs of attention's own products over ``attn_ctx_tokens``
@@ -166,13 +170,16 @@ def model_cost(
         attn_flops_per_ctx_token = layers * latent["attn_flops_per_ctx_token"]
         kv_values_per_token = layers * latent["page_row"]
         mlp_params_total, mlp_params_active = latent["mlp_params"]
+    streams = _stream_cost(model)
 
     embed = vocab * h
     head_params = 0 if tied else vocab * h
-    param_count = embed + head_params + layers * (attn_params + mlp_params_total)
+    param_count = embed + head_params + layers * (
+        attn_params + mlp_params_total + streams["params"])
     # active matmul params per token: embedding lookup is a gather (no
     # matmul), the unembedding projection always runs
-    active_params = vocab * h + layers * (attn_params + mlp_params_active)
+    active_params = vocab * h + layers * (
+        attn_params + mlp_params_active + streams["matmul_params"])
 
     weight_dtype_bytes = _dtype_bytes(getattr(model, "dtype", None))
     if quantize == "int8":
@@ -182,13 +189,42 @@ def model_cost(
 
     return ModelCost(
         param_count=param_count,
-        weight_bytes=param_count * weight_dtype_bytes,
+        # (the mixing leaves are float32 whatever the model's dtype)
+        weight_bytes=(param_count - layers * streams["params"]) * weight_dtype_bytes
+        + layers * streams["params"] * 4,
         linear_flops_per_token=2 * active_params,
         # per attended context position per layer: 2·heads·head_dim for
         # QK^T plus the same for attention·V
         attn_flops_per_ctx_token=attn_flops_per_ctx_token,
         kv_bytes_per_token=kv_values_per_token * kv_dtype_bytes,
+        mhc_sublayers=streams["sublayers"],
+        mhc_stream_bytes_per_row=streams["bytes_per_row"],
     )
+
+
+def _stream_cost(model) -> dict:
+    """The residual streams of a config with ``hc_mult`` > 1
+    (ops/hyper_connections.py), all zeros otherwise: one layer's mixing
+    parameters (two sublayers' ``phi``, ``alpha``, ``bias``) and those a
+    token multiplies against (``phi``), the sublayers a row passes (two a
+    layer), and the bytes a perfect implementation moves for one row of one
+    sublayer: the streams read and written and the sublayer's input and
+    output, in the model's dtype (``(2 n + 2) x hidden``: 71,680 B at four
+    streams of 3,584 in bfloat16)."""
+    n = int(getattr(model, "hc_mult", 1) or 1)
+    if n == 1:
+        return {"params": 0, "matmul_params": 0, "sublayers": 0, "bytes_per_row": 0}
+    from dynamo_tpu.ops.hyper_connections import coefficient_count, stream_bytes_per_row
+
+    outs = coefficient_count(n)
+    phi = n * model.hidden_size * outs
+    return {
+        "params": 2 * (phi + outs + 3),
+        "matmul_params": 2 * phi,
+        "sublayers": 2 * model.num_layers,
+        "bytes_per_row": stream_bytes_per_row(
+            n, model.hidden_size, _dtype_bytes(getattr(model, "dtype", None))),
+    }
 
 
 def _latent_cost(model) -> dict:
@@ -299,6 +335,7 @@ class UtilizationTracker:
         self.decode_tokens_total = 0
         self.emitted_tokens_total = 0
         self.flops_total = 0.0
+        self.mhc_rows_total = 0
 
     def observe(self, rec: StepRecord, now: float | None = None) -> None:
         """Book one engine step; the step's own FLOPs are written back onto
@@ -318,6 +355,7 @@ class UtilizationTracker:
             self.decode_tokens_total += rec.decode_tokens
             self.emitted_tokens_total += rec.emitted_tokens
             self.flops_total += flops
+            self.mhc_rows_total += tokens * self.cost.mhc_sublayers
             self._samples.append(
                 _Sample(
                     t=t, duration_s=rec.duration_s, flops=flops, bytes_moved=moved,
@@ -390,4 +428,11 @@ class UtilizationTracker:
             tokens_emitted_total=self.emitted_tokens_total,
             model_flops_total=self.flops_total,
         )
+        if self.cost.mhc_sublayers:
+            # a model with residual streams: live rows x the sublayers each
+            # passed, and what a perfect mixing would have moved for them
+            out.update(
+                mhc_rows_total=self.mhc_rows_total,
+                mhc_stream_bytes_total=self.mhc_rows_total * self.cost.mhc_stream_bytes_per_row,
+            )
         return out
