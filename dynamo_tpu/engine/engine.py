@@ -109,6 +109,11 @@ KERNEL_WORK_KEYS = (
     "ragged_live_rows_total",       # live tokens (each all its heads' query rows) in those blocks
     "ragged_attn_flops_total",      # QK^T + attention·V over the attended context, all layers
     "ragged_kv_read_bytes_total",   # pages copied x page bytes, all layers
+    # a latent family's unified steps (a decode step books neither): the
+    # positions their rows attended, and those of them that were rows of the
+    # same window, taken decompressed (the rest: absorbed, by the page walk)
+    "mla_attended_ctx_total",
+    "mla_window_ctx_total",
     "decode_attn_flops_total",
     "decode_kv_read_bytes_total",
     # window layers (a model with a window pool; 0 otherwise): pages their
@@ -2931,8 +2936,15 @@ class JaxLlmEngine:
 
             self._phase("pack")
             self._part("spans")
+            # a family that attends its window's own rows itself has only
+            # the pages resident BEFORE the window walked
+            walk_pos = token_pos
+            if self.family.unified_attends_window:
+                from dynamo_tpu.ops.pallas.mla_attention import last_resident_pos
+
+                walk_pos = last_resident_pos(token_lane, token_pos, lanes)
             page_meta = pack_spans(
-                token_lane, token_pos, lanes=lanes, tb_tokens=tb,
+                token_lane, walk_pos, lanes=lanes, tb_tokens=tb,
                 block_size=bs, sliding_window=self._sliding_window,
             )
             if self._window_blocks:
@@ -3055,11 +3067,24 @@ class JaxLlmEngine:
             # layer's in-window work only) and the pages IT copies
             layers_n = n_full + n_window
             window_pages = int(window_meta[2].sum()) if self._window_blocks else live_pages
-            work["ragged_attn_flops_total"] += (
-                cost.attn_flops(full_ctx) * n_full
+            own_flops = own_bytes = 0
+            walked_ctx = full_ctx
+            if self.family.unified_attends_window:
+                # the keys that are rows of this window (a span's triangle;
+                # a decode row itself) went decompressed, at that launch's
+                # rate and traffic; the rest, resident, through the walk
+                own_ctx = len(decodes) + sum(
+                    (b - a) * (b - a + 1) // 2 for _, a, b in spans)
+                work["mla_window_ctx_total"] += own_ctx
+                work["mla_attended_ctx_total"] += full_ctx
+                own_flops = own_ctx * cost.window_attn_flops_per_ctx_token
+                own_bytes = cursor * cost.window_attn_bytes_per_row
+                walked_ctx = full_ctx - own_ctx
+            work["ragged_attn_flops_total"] += own_flops + (
+                cost.attn_flops(walked_ctx) * n_full
                 + cost.attn_flops(ragged_ctx) * n_window
             ) // layers_n
-            work["ragged_kv_read_bytes_total"] += (
+            work["ragged_kv_read_bytes_total"] += own_bytes + (
                 (live_pages * n_full + window_pages * n_window)
                 * bs * cost.kv_bytes_per_token // layers_n
             )

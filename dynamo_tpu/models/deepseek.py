@@ -530,6 +530,30 @@ def _decompress(w, w_uv, ctx, cfg: DeepseekConfig):
     return mm(out.reshape(-1, cfg.num_heads * cfg.v_head_dim), w["wo"])
 
 
+def _merge_parts(ctx, lse_resident, out_window, lse_window, w_uv):
+    """One softmax over a row's two parts, each normalised by its own sum:
+    the resident pages' latent context ``ctx [t, H, R]`` (decompressed here
+    through ``w_uv``) and the window's own output ``out_window [t, H x v]``,
+    weighted by ``exp(lse_part - lse)`` of their log-sum-exp ``[t, H]``.  The
+    two weights sum to one, so the resident part's is the logistic of the
+    difference and the result ``window + a x (resident - window)``: a row
+    with nothing resident (its lse ``NEG_INF``, ``a`` exactly 0) takes the
+    window part unscaled; a pad, both empty, zeros.  All of it on
+    ``[t, H x v]`` rows, the layout the window launch writes and ``wo``
+    takes; a head's weight is spread over its ``v`` columns by a product
+    with a 0/1 matrix.  Returns [t, H x v] float32."""
+    t, h, _ = ctx.shape
+    v = w_uv.shape[-1]
+    a = jax.nn.sigmoid(lse_resident - lse_window)
+    spread = jnp.repeat(jnp.eye(h, dtype=jnp.float32), v, axis=1)      # [H, H x v]
+    a = jnp.dot(a, spread, precision=jax.lax.Precision.HIGHEST)
+    resident = jnp.einsum(
+        "thr,rhv->thv", ctx, w_uv.astype(ctx.dtype), preferred_element_type=jnp.float32
+    ).reshape(t, h * v)
+    window = out_window.astype(jnp.float32)
+    return window + a * (resident - window)
+
+
 def _write_latents(ck_pages, kr_pages, c_kv, k_rope, slots):
     """Rows ``c_kv [t, R]`` / ``k_rope [t, rope]`` into flat slots of the
     whole cache (``at.slots``; out of range = dropped), in place: the pages
@@ -839,44 +863,73 @@ def deepseek_forward_unified(
 ):
     """Ragged unified-batch forward for the MLA family: mixed spans +
     decode tokens in one launch against the latent cache (the llama
-    unified contract), absorbed for every row.  Every token writes its
-    latent and rotated key at its cache slot before attention reads, so span
-    tokens see their own in-window predecessors through the cache; the
-    expert layers route per token and walk the live rows only."""
+    unified contract).  Every token writes its latent and rotated key at its
+    cache slot; the expert layers route per token and walk the live rows only.
+
+    Where a key lies decides its form (``attention="pallas"``).  A key that
+    is a row of THIS window (its lane's rows at flat index <= the query's:
+    the engine packs a span in ascending position) is attended DECOMPRESSED,
+    from the window's own ``c_kv``: one flash launch a layer, 640 products a
+    (query, key, head) where the absorbed form takes 2,304.  A key on a page
+    resident before the window (its lane's positions below the lane's first
+    row here, ``last_resident_pos``) is attended ABSORBED by the page walk,
+    whose spans (``pack_spans``) the caller built from those same resident
+    positions.  The two parts of a row merge under one softmax by their
+    log-sum-exp.  A whole prompt has no resident part, a decode row is a
+    window of one beside its context, a pad has neither.  The XLA route
+    attends absorbed in one piece, through the cache."""
     x = params["embed"][token_ids].astype(cfg.dtype)
     positions = jnp.maximum(token_pos, 0)
     lanes = context_lens.shape[0]
     live = (token_pos >= 0) & (token_lane >= 0) & (token_lane < lanes)
     scale = float(cfg.attn_scale)
+    pallas = attention.startswith("pallas")
+    if pallas:
+        from dynamo_tpu.ops.pallas.mla_attention import (
+            last_resident_pos,
+            ragged_mla_attention,
+            ragged_mla_attention_window,
+        )
+
+        resident_pos = last_resident_pos(token_lane, token_pos, lanes, jnp)
 
     def attend(w, attn_in, ck_pages, kr_pages, at):
         q_nope, q_rope = _queries(
             w, attn_in, cfg, lambda q: apply_rope(q, positions, cos, sin))
         c_kv, k_rope = _latent_kv(w, attn_in, cfg)
         k_rope = apply_rope(k_rope[:, None, :], positions, cos, sin)[:, 0]
+        # (as wide as the page stores it, for the cache and the window launch alike)
+        k_rope = jnp.pad(k_rope, ((0, 0), (0, kr_pages.shape[-1] - k_rope.shape[-1])))
         ck_pages, kr_pages = _write_latents(
             ck_pages, kr_pages, c_kv, k_rope, at.slots(token_slot))
         w_uk, w_uv = _up_projections(w, cfg)
         q_lat, q_wide = _absorb(w_uk, q_nope, q_rope, kr_pages.shape[-1], cfg.dtype)
         tables = at.blocks(block_tables)
         with jax.named_scope("attn"):
-            if attention.startswith("pallas"):
-                from dynamo_tpu.ops.pallas import ragged_mla_attention
-
-                ctx = ragged_mla_attention(
-                    q_lat, q_wide, ck_pages, kr_pages, token_lane, token_pos,
-                    tables, span_lane, span_first, span_count, kv_steps,
-                    scale=scale, tb_tokens=tb_tokens,
-                    interpret=attention == "pallas_interpret",
-                )
-            else:
+            if not pallas:
                 from dynamo_tpu.ops.attention import ragged_mla_paged_attention
 
                 ctx = ragged_mla_paged_attention(
                     q_lat, q_wide, ck_pages, kr_pages, tables, token_lane,
                     token_pos, scale=scale,
                 )
-        return _decompress(w, w_uv, ctx, cfg), ck_pages, kr_pages
+                return _decompress(w, w_uv, ctx, cfg), ck_pages, kr_pages
+            interpret = attention == "pallas_interpret"
+            ctx, lse_resident = ragged_mla_attention(
+                q_lat, q_wide, ck_pages, kr_pages, token_lane, resident_pos,
+                tables, span_lane, span_first, span_count, kv_steps,
+                scale=scale, tb_tokens=tb_tokens, interpret=interpret,
+                with_lse=True,
+            )
+            up = lambda w_u: jnp.einsum(  # noqa: E731 — the rows' own keys / values
+                "tr,rhd->thd", c_kv, w_u, preferred_element_type=jnp.float32
+            ).astype(cfg.dtype)
+            out, lse_window = ragged_mla_attention_window(
+                q_nope, q_wide, up(w_uk), k_rope, up(w_uv), token_lane, token_pos,
+                lanes=lanes, scale=scale, interpret=interpret,
+            )
+            out = _merge_parts(ctx, lse_resident, out, lse_window, w_uv).astype(cfg.dtype)
+        return mm(out, w["wo"]), ck_pages, kr_pages
 
     x, new_cache = _forward(params, cfg, x, kv_cache, attend, valid=live)
     rows = x[sample_rows]  # [lanes, h] — junk for hole lanes, caller-gated

@@ -57,6 +57,12 @@ class ModelFamily:
     # and decode tokens, ops/pallas/ragged_attention.py); None = the engine
     # keeps the split prefill/decode step for this family
     forward_unified: Callable | None = None
+    # ``forward_unified`` attends the keys of the window's own rows itself
+    # (from its activations) and walks only the pages RESIDENT before the
+    # window: the engine then hands ``pack_spans`` each row's last resident
+    # position of its lane in place of the row's own
+    # (ops/pallas/mla_attention.py ``last_resident_pos``)
+    unified_attends_window: bool = False
     # param-tree leaf names eligible for weight-only int8 (ops/quant.py);
     # empty = the family's forwards don't route matmuls through quant.mm
     quant_leaves: tuple[str, ...] = ()
@@ -313,6 +319,7 @@ def _deepseek_family() -> ModelFamily:
         ),
         forward_verify=deepseek.deepseek_forward_verify,
         forward_unified=deepseek.deepseek_forward_unified,
+        unified_attends_window=True,
     )
 
 

@@ -104,6 +104,12 @@ class ModelCost:
     # row passes, and the bytes a perfect mixing moves for a row of one
     mhc_sublayers: int = 0
     mhc_stream_bytes_per_row: int = 0
+    # a latent family's unified step attends the keys of its own window
+    # DECOMPRESSED (ops/pallas/mla_attention.py ragged_mla_attention_window;
+    # else 0): the products of one (query, key) pair there, and the bytes the
+    # launch moves for one row (its q, k, v and output once), every layer
+    window_attn_flops_per_ctx_token: int = 0
+    window_attn_bytes_per_row: int = 0
 
     def attn_flops(self, attn_ctx_tokens: int) -> float:
         """FLOPs of attention's own products over ``attn_ctx_tokens``
@@ -164,12 +170,15 @@ def model_cost(
 
     attn_flops_per_ctx_token = 4 * layers * heads * head_dim
     kv_values_per_token = 2 * layers * kv_heads * head_dim
+    window = (0, 0)     # (products a pair, bytes a row) of a latent window launch
     if getattr(model, "kv_lora_rank", 0):
         latent = _latent_cost(model)
         attn_params = latent["attn_params"]
         attn_flops_per_ctx_token = layers * latent["attn_flops_per_ctx_token"]
         kv_values_per_token = layers * latent["page_row"]
         mlp_params_total, mlp_params_active = latent["mlp_params"]
+        window = (layers * latent["window_flops_per_ctx_token"],
+                  layers * latent["window_values_per_row"] * _dtype_bytes(getattr(model, "dtype", None)))
     streams = _stream_cost(model)
 
     embed = vocab * h
@@ -199,6 +208,8 @@ def model_cost(
         kv_bytes_per_token=kv_values_per_token * kv_dtype_bytes,
         mhc_sublayers=streams["sublayers"],
         mhc_stream_bytes_per_row=streams["bytes_per_row"],
+        window_attn_flops_per_ctx_token=window[0],
+        window_attn_bytes_per_row=window[1],
     )
 
 
@@ -242,7 +253,8 @@ def _latent_cost(model) -> dict:
 
     h, heads, layers = model.hidden_size, model.num_heads, model.num_layers
     r, rope = model.kv_lora_rank, model.qk_rope_head_dim
-    q_out = heads * (model.qk_nope_head_dim + rope)
+    nope, v_dim = model.qk_nope_head_dim, model.v_head_dim
+    q_out = heads * (nope + rope)
     q_params = (
         h * model.q_lora_rank + model.q_lora_rank * q_out if model.q_lora_rank else h * q_out
     )
@@ -258,6 +270,12 @@ def _latent_cost(model) -> dict:
         + r * heads * (model.qk_nope_head_dim + model.v_head_dim)
         + heads * model.v_head_dim * h,
         "attn_flops_per_ctx_token": 2 * heads * (r + rope) + 2 * heads * r,
+        # a key of the row's own window, decompressed (unpadded widths: the
+        # floor is the model's): every head's own 192-wide key and 128-wide
+        # value; and what that launch reads and writes for a row: the heads'
+        # queries, keys less the one rotated part, values and outputs
+        "window_flops_per_ctx_token": 2 * heads * (nope + rope) + 2 * heads * v_dim,
+        "window_values_per_row": heads * (2 * nope + rope + 2 * v_dim) + rope,
         "page_row": r + rope_page_width(model),
         "mlp_params": (mean(model.num_experts), mean(model.experts_per_token)),
     }

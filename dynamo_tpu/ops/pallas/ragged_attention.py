@@ -180,6 +180,7 @@ def walk_live_pages(
     tb_tokens: int,
     pages_per_step: int,
     step_body,          # (slot, lane, first ordinal of the step) -> None
+    first_block=None,   # whether this is the launch's first token block
 ):
     """Loop skeleton of the ragged kernels: visit every live page of one
     token block — spans in order, ordinals ascending, ``pages_per_step``
@@ -191,7 +192,8 @@ def walk_live_pages(
     only the pages the span still has: the places behind them keep what an
     earlier step left there (zeros before the launch's first), and the
     body's causal mask hides them, since their positions lie past every
-    query of the span's lane."""
+    query of the span's lane.  ``first_block``: from a caller that walks
+    under a condition, where the grid position cannot be asked for."""
     pps = pages_per_step
 
     def copies(slot, s, o, go):
@@ -212,7 +214,7 @@ def walk_live_pages(
         jax.lax.fori_loop(0, jnp.minimum(pps, end - o), one, None)
 
     if pps > 1:
-        @pl.when(pl.program_id(0) == 0)
+        @pl.when(pl.program_id(0) == 0 if first_block is None else first_block)
         def _no_stale_bits():
             for _, buf in streams:
                 buf[...] = jnp.zeros_like(buf)

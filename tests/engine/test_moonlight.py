@@ -246,22 +246,37 @@ async def test_counters_read_zero_from_the_start_and_equal_a_hand_count(served):
     finally:
         engine.stop()
     r, rope, stored = 32, 8, 32 + 128          # the page row: latent + the key's tile
+    nope = v_dim = 16
     pair = 2 * HEADS * (r + rope) + 2 * HEADS * r
+    # a key that is a row of the same window: decompressed, every head's own
+    own_pair = 2 * HEADS * (nope + rope) + 2 * HEADS * v_dim
     tri = lambda a, b: (b * (b + 1) - a * (a + 1)) // 2  # noqa: E731
     # ragged launches: prompt 0 alone, then prompt 1 beside lane 0's decode;
     # decode launches: whatever ran with no prompt in the window
     windows = stats["decode_windows_unified_total"]
     assert windows >= 1
-    ragged_ctx = stats["ragged_attn_flops_total"] // (LAYERS * pair)
+    # the unified steps' own keys: both prompts whole (no resident part), and
+    # the decode row that rode beside prompt 1 (if lane 0 still ran), itself
+    own_ctx, attended_ctx = stats["mla_window_ctx_total"], stats["mla_attended_ctx_total"]
+    rode = own_ctx - tri(0, 41) - tri(0, 27)
+    assert rode in (0, 1)
+    resident_flops = stats["ragged_attn_flops_total"] - LAYERS * own_pair * own_ctx
+    resident_ctx = resident_flops // (LAYERS * pair)
+    assert resident_flops == LAYERS * pair * resident_ctx
+    assert own_ctx + resident_ctx == attended_ctx
+    assert resident_ctx in ([0] if not rode else range(41, 46))   # that row's context
     decode_ctx = stats["decode_attn_flops_total"] // (LAYERS * pair)
-    assert stats["ragged_attn_flops_total"] == LAYERS * pair * ragged_ctx
-    # every attended (query, key) pair is in one launch or the other: both
+    # every attended (query, key) pair is in one launch or another: both
     # prompts' triangles and each decode token's context
     served_ctx = tri(0, 41) + tri(0, 27) + sum(41 + i for i in range(1, 6)) + 28
-    assert ragged_ctx + decode_ctx == served_ctx
+    assert attended_ctx + decode_ctx == served_ctx
     page_bytes = BLOCK * stored * 4             # float32 pages here
-    assert stats["ragged_kv_read_bytes_total"] == LAYERS * page_bytes * stats["ragged_live_pages_total"]
-    assert stats["ragged_kv_read_bytes_total"] > 0
+    # the pages the walk copied (the decode row's context, nothing of a whole
+    # prompt) and the window launch's q, k, v and output once a row
+    own_row = 4 * (HEADS * (2 * nope + rope + 2 * v_dim) + rope)
+    assert stats["ragged_kv_read_bytes_total"] == LAYERS * (
+        page_bytes * stats["ragged_live_pages_total"] + own_row * (41 + 27 + rode))
+    assert stats["ragged_live_pages_total"] == -(-(resident_ctx) // BLOCK)
     assert stats["ragged_page_slots_total"] == stats["ragged_kv_steps_total"] * kv_step_pages(BLOCK)
     # the expert layers: every token of every step, k assignments each, all held
     tokens = 41 + 27 + 5 + 1
@@ -313,26 +328,144 @@ def test_step_programs_hold_no_pad_relayout_or_copy_of_the_latent_cache(served, 
     ever made by a reshape (the flat pages' view and back: leading axes
     merged), by the scatter that writes a step's latents, or carried by the
     layer loop: no pad, slice, concatenate, transpose, gather, convert or
-    copy of it; each kernel launch takes the two flat leaves whole; and the
-    compiled program (kernels interpreted) aliases both leaves to its
-    outputs."""
+    copy of it; each kernel launch that reads the cache takes the two flat
+    leaves whole (the unified step's window launch reads none of it: the
+    rows' own keys, from the activations); and the compiled program (kernels
+    interpreted) aliases both leaves to its outputs."""
     cfg, params, _, _ = served
     fn, args, cache = _step_program(program, cfg, attention="pallas")
     sizes = {int(np.prod(cache[k].shape)): k for k in ("k", "v")}
     jaxpr = jax.make_jaxpr(fn)(params, cache, *args)
-    makers, launches = set(), 0
+    makers, launches, off_cache = set(), 0, 0
+    pages = [(shape[0] * shape[1], *shape[2:]) for shape in (cache[k].shape for k in ("k", "v"))]
     for eqn in _equations(jaxpr.jaxpr):
         if any(int(np.prod(v.aval.shape)) in sizes for v in eqn.outvars):
             makers.add(eqn.primitive.name)
         if eqn.primitive.name == "pallas_call":
-            launches += 1
             flat = {tuple(v.aval.shape) for v in eqn.invars}
-            for leaf in ("k", "v"):
-                layers, blocks, *page = cache[leaf].shape
-                assert (layers * blocks, *page) in flat, leaf
+            if not any(int(np.prod(shape)) in sizes for shape in flat):
+                off_cache += 1
+                continue
+            launches += 1
+            assert all(page in flat for page in pages), flat
     assert launches == 2     # one a run of layers (dense, sparse), inside its scan
+    assert off_cache == (2 if program == "unified" else 0)    # the window's own keys
     assert makers <= {"reshape", "scatter", "scan", "while", "pjit"}, makers
     fn, args, cache = _step_program(program, cfg, attention="pallas_interpret")
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(params, cache, *args).compile()
     pages = sum(int(np.prod(cache[k].shape)) * cache[k].dtype.itemsize for k in ("k", "v"))
     assert compiled.memory_analysis().alias_size_in_bytes >= pages
+
+
+def _watch_windows(engine, monkeypatch):
+    """Record, for every unified window the engine dispatches: its spans
+    ``(start, end)``, the positions ``pack_spans`` was handed beside the rows'
+    lanes, and what the window added to the kernels' counters."""
+    import dynamo_tpu.ops.pallas as kernels
+
+    seen, packed = [], []
+    run, pack = engine._run_unified, kernels.pack_spans
+
+    def spy_pack(token_lane, token_pos, **kw):
+        packed.append((np.array(token_lane), np.array(token_pos)))
+        return pack(token_lane, token_pos, **kw)
+
+    def spy_run(spans, decodes, bucket, overlap):
+        before = dict(engine._kernel_work)
+        done = run(spans, decodes, bucket, overlap)
+        seen.append({
+            "spans": [(a, b) for _, a, b in spans], "decodes": len(decodes),
+            "packed": packed[-1],
+            "work": {k: v - before[k] for k, v in engine._kernel_work.items()},
+        })
+        return done
+
+    monkeypatch.setattr(kernels, "pack_spans", spy_pack)
+    engine._run_unified = spy_run
+    return seen
+
+
+async def test_chunked_prompts_serve_the_same_tokens_on_both_routes_and_count_their_windows(
+        served, monkeypatch):
+    """(vi) the 41-token prompt in windows of 16, then the 27-token one beside
+    its decode: the Pallas route (window keys decompressed, resident pages
+    absorbed, one softmax) serves the tokens and log-probabilities of the XLA
+    route (absorbed in one piece).  Each window's counters by hand:
+    ``mla_attended_ctx_total`` is ``_attended_ctx`` over the window's spans
+    plus its decode rows' contexts, ``mla_window_ctx_total`` the part that lay
+    in the window (a span's own triangle, a decode row itself), and
+    ``ragged_attn_flops_total`` the two rates' sum; the walk was handed each
+    lane's last RESIDENT position."""
+    results = {}
+    for attention in ("jax", "pallas_interpret"):
+        engine = make_engine(
+            served, attention_impl=attention, prefill_chunk_tokens=16, max_batch_size=2,
+            decode_overlap=False)
+        windows = _watch_windows(engine, monkeypatch) if attention != "jax" else None
+        try:
+            results[attention] = await serve_staggered(engine, PROMPTS[:2], max_tokens=8)
+            stats = await idle_stats(engine)
+        finally:
+            engine.stop()
+    for (tokens, lps), (twin, twin_lps) in zip(results["pallas_interpret"], results["jax"]):
+        assert tokens == twin
+        np.testing.assert_allclose(lps, twin_lps, atol=2e-4)
+    r, rope, nope, v_dim = 32, 8, 16, 16
+    pair = 2 * HEADS * (r + rope) + 2 * HEADS * r
+    own_pair = 2 * HEADS * (nope + rope) + 2 * HEADS * v_dim
+    # (a window's budget is the chunk: the first two are the long prompt's alone)
+    assert [w["spans"] for w in windows[:2]] == [[(0, 16)], [(16, 32)]]
+    attended = own = 0
+    for w in windows:
+        lane, walk = w["packed"]
+        n_own = w["decodes"] + sum((b - a) * (b - a + 1) // 2 for a, b in w["spans"])
+        # decode rows are packed first, each walking up to the position before
+        # its own; then each span, all its rows up to the one before the span
+        cursor = w["decodes"]
+        for a, b in w["spans"]:
+            assert walk[cursor:cursor + b - a].tolist() == [a - 1] * (b - a)
+            cursor += b - a
+        assert (walk[cursor:] == -1).all()
+        resident = int((walk[:cursor] + 1).sum())
+        work = w["work"]
+        assert work["mla_window_ctx_total"] == n_own
+        assert work["mla_attended_ctx_total"] == n_own + resident
+        assert work["mla_attended_ctx_total"] == sum(
+            engine._attended_ctx(a, b)[0] for a, b in w["spans"]
+        ) + int((walk[: w["decodes"]] + 2).sum())
+        assert work["ragged_attn_flops_total"] == LAYERS * (own_pair * n_own + pair * resident)
+        attended += n_own + resident
+        own += n_own
+    # the second window of the 41-token prompt, by hand: 16 rows, each 16
+    # resident keys and its own triangle of 136
+    assert windows[1]["work"]["ragged_attn_flops_total"] == LAYERS * (own_pair * 136 + pair * 256)
+    assert (stats["mla_attended_ctx_total"], stats["mla_window_ctx_total"]) == (attended, own)
+    assert stats["mla_window_ctx_total"] < stats["mla_attended_ctx_total"]
+
+
+@pytest.mark.parametrize("family", ["llama", "deepseek_v3"])
+async def test_the_walk_is_told_resident_positions_only_by_a_family_that_attends_its_window(
+        served, monkeypatch, family):
+    """(vii) ``pack_spans`` is handed the rows' own positions, the very array
+    the step program gets, for a family without ``unified_attends_window``
+    (every llama-family cell: byte for byte what it was), and each lane's
+    last resident position for one with it."""
+    from dynamo_tpu.models.registry import get_family
+    from tests.engine.test_jax_engine import make_engine as make_llama_engine
+
+    assert get_family(family).unified_attends_window == (family != "llama")
+    kw = dict(attention_impl="pallas_interpret", prefill_chunk_tokens=16, decode_overlap=False)
+    engine = (make_engine(served, **kw) if family != "llama"
+              else make_llama_engine(unified_batch=True, block_size=8, num_blocks=32, **kw))
+    windows = _watch_windows(engine, monkeypatch)
+    try:
+        await collect(engine, request(PROMPTS[1], max_tokens=3, ignore_eos=True))
+    finally:
+        engine.stop()
+    assert [w["spans"] for w in windows] == [[(0, 16)], [(16, 27)]]
+    first, second = (w["packed"][1] for w in windows)
+    if family == "llama":
+        assert first[:16].tolist() == list(range(16)) and second[:11].tolist() == list(range(16, 27))
+    else:
+        assert first[:16].tolist() == [-1] * 16 and second[:11].tolist() == [15] * 11
+    assert (first[16:] == -1).all() and (second[11:] == -1).all()

@@ -280,3 +280,68 @@ def test_v3_config_roundtrip_and_forward():
         jnp.int32(8), jnp.int32(0), cos, sin,
     )
     assert bool(jnp.all(jnp.isfinite(logits)))
+
+
+def unified_in_windows(params, cfg, ids, cuts, *, attention, block_size=4, num_blocks=32,
+                       lanes=3, tb=8, blocks=None):
+    """A prompt served through ``deepseek_forward_unified`` in the windows
+    ``[0, cuts[0]), [cuts[0], cuts[1]), ...`` on lane 1 of ``lanes``, the
+    spans packed as the engine packs them for a family that attends its own
+    window (the walk told each lane's last RESIDENT position).  Returns the
+    last window's logits of the prompt's last token and the cache."""
+    from dynamo_tpu.models.deepseek import deepseek_forward_unified
+    from dynamo_tpu.ops.pallas.mla_attention import last_resident_pos
+    from dynamo_tpu.ops.pallas.ragged_attention import pack_spans
+
+    i32 = lambda x: jnp.asarray(x, jnp.int32)  # noqa: E731
+    cos, sin = make_rope_tables(cfg)
+    cache = init_kv_cache(cfg, num_blocks, block_size)
+    max_blocks = -(-len(ids) // block_size)
+    blocks = np.asarray(blocks if blocks is not None else range(3, 3 + max_blocks), np.int32)
+    tables = np.zeros((lanes, max_blocks), np.int32)
+    tables[1] = blocks
+    oob = num_blocks * block_size
+    logits = None
+    for start, end in zip([0, *cuts], [*cuts, len(ids)]):
+        n = end - start
+        t = -(-n // tb) * tb
+        lane, pos, slot = np.full(t, lanes, np.int32), np.full(t, -1, np.int32), np.full(t, oob, np.int32)
+        tokens = np.zeros(t, np.int32)
+        lane[:n], pos[:n], tokens[:n] = 1, np.arange(start, end), ids[start:end]
+        slot[:n] = blocks[pos[:n] // block_size] * block_size + pos[:n] % block_size
+        walk = last_resident_pos(lane, pos, lanes) if attention.startswith("pallas") else pos
+        spans = pack_spans(lane, walk, lanes=lanes, tb_tokens=tb, block_size=block_size)
+        ctx = np.zeros(lanes, np.int32)
+        ctx[1] = end
+        rows = np.zeros(lanes, np.int32)
+        rows[1] = n - 1
+        logits, cache = deepseek_forward_unified(
+            params, cfg, i32(tokens), cache, i32(tables), i32(ctx), i32(pos), i32(slot),
+            i32(lane), *(i32(s) for s in spans), i32(rows), cos, sin,
+            attention=attention, tb_tokens=tb)
+    return logits[1], cache
+
+
+def test_a_prompt_in_two_unified_windows_gives_the_split_forwards_logits():
+    """A 23-token prompt served in two windows of the unified step (Pallas
+    kernels, interpreted), cut at 14, so that the second window continues a
+    resident prefix that ends mid-page (pages of 4): the first window's rows
+    attend nothing but their own window, decompressed; the second's attend
+    the resident 14 absorbed and their own 9 decompressed, merged under one
+    softmax.  The logits are the whole-prompt split prefill's, and the XLA
+    route's (absorbed in one piece) in the same two windows; the pages
+    written are the same."""
+    params = init_params(CFG, jax.random.PRNGKey(4))
+    cos, sin = make_rope_tables(CFG)
+    ids = [int(t) for t in np.random.default_rng(7).integers(2, 500, size=23)]
+    blocks = jnp.arange(3, 9, dtype=jnp.int32)
+    want, want_cache = deepseek_forward_prefill(
+        params, CFG, jnp.asarray(ids + [0], jnp.int32), init_kv_cache(CFG, NUM_BLOCKS, BLOCK_SIZE),
+        blocks, jnp.int32(len(ids)), jnp.int32(0), cos, sin)
+    got, cache = unified_in_windows(params, CFG, ids, [14], attention="pallas_interpret")
+    twin, _ = unified_in_windows(params, CFG, ids, [14], attention="jax")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(twin), rtol=2e-4, atol=2e-4)
+    for leaf in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(cache[leaf]), np.asarray(want_cache[leaf]), rtol=1e-5, atol=1e-5)

@@ -312,3 +312,25 @@ def test_the_family_is_bound_and_refuses_a_checkpoint_it_cannot_read(tmp_path):
     save_file({"model.norm.weight": np.ones(4, np.float32)}, str(tmp_path / "model.safetensors"))
     with pytest.raises(NotImplementedError, match="hc_phi.*seeded weights"):
         family.load_weights(cfg, tmp_path)
+
+
+@pytest.mark.parametrize("cuts", [[], [14], [6, 17]], ids=["whole", "two_windows", "three_windows"])
+def test_a_prompt_in_unified_windows_gives_the_split_forwards_logits(served, cuts):
+    """``tiny_xing``'s 23-token prompt through the unified step's Pallas route
+    (interpreted) whole, in two windows cut at 14 and in three cut at 6 and 17
+    (pages of 4: every continued window's resident prefix ends mid-page): the
+    window's own keys decompressed, the resident pages absorbed, one softmax.
+    The last token's logits are the split prefill's and the reference's."""
+    from tests.models.test_deepseek import unified_in_windows
+
+    params, weights = served
+    n = 23
+    blocks = list(range(5, 5 + MAX_BLOCKS))
+    got, _ = unified_in_windows(
+        params, CFG, IDS[:n], cuts, attention="pallas_interpret", block_size=BS,
+        num_blocks=BLOCKS, lanes=LANES, tb=TB, blocks=blocks[: -(-n // BS)])
+    split, _ = _prefill(params, CFG, IDS[:n], blocks)
+    want = _want(weights, IDS[:n], [n - 1])[0]
+    spread = want.std()
+    assert float(np.max(np.abs(np.asarray(got) - np.asarray(split)))) / spread < TOL
+    assert float(np.max(np.abs(np.asarray(got) - want))) / spread < TOL

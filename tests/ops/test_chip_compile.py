@@ -363,6 +363,13 @@ def _expert_cell_program(one_chip, config, program):
     return compiled, sum(math.prod(a.shape) * a.dtype.itemsize for a in jax.tree.leaves(cache))
 
 
+# a latent family's launches by step program: the unified step walks the
+# resident pages absorbed and attends its window's own keys decompressed
+# (PR 52), both under names the benchmark's ``^%?ragged_mla_attention`` reads
+MLA_KERNELS = {"decode": {"mla_paged_attention_decode"},
+               "unified_t8192": {"ragged_mla_attention", "ragged_mla_attention_window"}}
+
+
 @pytest.mark.parametrize("program", ["decode", "unified_t8192"])
 def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one_chip, program):
     """``moonlight-16b-l9``'s whole decode and 8,192-token step programs at
@@ -372,8 +379,7 @@ def test_moonlight_step_programs_compile_and_write_the_latent_pages_in_place(one
     products of the walk), less than 1 GB of temporaries (a copy, a pad or a
     relayout of the rope leaf alone would be 0.41 GB, of the latent 1.62)."""
     compiled, pools = _expert_cell_program(one_chip, "moonlight-16b-l9", program)
-    kernel = "mla_paged_attention_decode" if program == "decode" else "ragged_mla_attention"
-    assert _kernel_names(compiled) == {kernel, "gmm"}
+    assert _kernel_names(compiled) == {*MLA_KERNELS[program], "gmm"}
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pools - 64
     assert memory.temp_size_in_bytes < 1e9
@@ -428,8 +434,7 @@ def test_xing_step_programs_compile_beside_their_weights(one_chip, program):
     235 MB in bf16, 470 MB in float32) small enough that arguments and
     temporaries together leave the chip's 16 GB a margin."""
     compiled, pools = _expert_cell_program(one_chip, "xing4-29b-l8", program)
-    kernel = "mla_paged_attention_decode" if program == "decode" else "ragged_mla_attention"
-    assert _kernel_names(compiled) == {kernel, "gmm"}
+    assert _kernel_names(compiled) == {*MLA_KERNELS[program], "gmm"}
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pools - 64
     assert 13.1e9 < memory.argument_size_in_bytes < 13.3e9
@@ -455,6 +460,51 @@ def test_the_6144_bucket_of_the_8192_context_cells_compiles_between_its_neighbou
         assert memory.alias_size_in_bytes >= pools - 64, tokens
         temps[tokens] = memory.temp_size_in_bytes
     assert temps[4096] <= temps[6144] <= temps[8192] < limit, temps
+
+
+# every unified bucket of an 8,192 context (engine._token_buckets)
+LONG_DOC_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 6144, 8192)
+
+
+@pytest.mark.parametrize("heads", [16, XING_HEADS])
+@pytest.mark.parametrize("bucket", LONG_DOC_BUCKETS)
+def test_the_window_launch_compiles_at_every_bucket_of_both_latent_cells(one_chip, bucket, heads):
+    """``ragged_mla_attention_window`` (PR 52: a unified window's own keys,
+    decompressed) at the published head (128 + the rotated part 128 wide,
+    values 128), bf16, 16 heads (``moonlight-16b-l9``) and 32
+    (``xing4-29b-l8``), at every bucket the two cells' engines build: one
+    kernel under a name the benchmark's ``^%?ragged_mla_attention`` reads, a
+    head's keys and values whole in VMEM (12.6 MB at 8,192 rows), and beside
+    it only the relayouts of its operands (a quarter GB at 32 heads: in the
+    step program they fuse into the projections that make them)."""
+    from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention_window
+
+    s = _sds(one_chip)
+    head = lambda: s((bucket, heads, 128), jnp.bfloat16)  # noqa: E731
+    tok = s((bucket,), jnp.int32)
+    compiled = ragged_mla_attention_window.lower(
+        head(), head(), head(), s((bucket, 128), jnp.bfloat16), head(), tok, tok,
+        lanes=MLA["lanes"], scale=0.07).compile()
+    assert _kernel_names(compiled) == {"ragged_mla_attention_window"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * max(bucket, 128) * heads * 128 * 2
+
+
+def test_xings_unified_programs_fit_beside_their_weights_at_the_three_long_buckets(one_chip):
+    """``xing4-29b-l8``'s ``unified_4096 / 6144 / 8192`` with both latent
+    launches (32 heads: the window launch's q, k, v and output are 67 MB each
+    at 8,192 rows, the merge's float32 halves 134 MB): the temporaries grow
+    with the bucket and, with 13.15 GB of arguments, stay inside the 16.9 GB
+    the runtime offers with 2 GB to spare (1.22 GB at 8,192; 1.10 before the
+    window launch, my chip runs, PR 51)."""
+    temps = {}
+    for tokens in (4096, 6144, 8192):
+        compiled, pools = _expert_cell_program(one_chip, "xing4-29b-l8", f"unified_t{tokens}")
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= pools - 64, tokens
+        assert _kernel_names(compiled) == {*MLA_KERNELS["unified_t8192"], "gmm"}
+        temps[tokens] = memory.temp_size_in_bytes
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16.9e9 - 2e9
+    assert temps[4096] <= temps[6144] <= temps[8192] < 1.5e9, temps
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "fp8"])

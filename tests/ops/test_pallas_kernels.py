@@ -1,6 +1,8 @@
 """Pallas kernels vs pure-JAX references (interpret mode on the CPU mesh;
 the same kernels compile for TPU)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -828,3 +830,226 @@ def test_ragged_attention_group_sizes(h, kvh, q_dtype):
     atol = 2e-5 if q_dtype == jnp.float32 else BF16_ATOL
     np.testing.assert_allclose(out[valid], ref[valid], rtol=0, atol=atol)
     assert np.isfinite(out).all()
+
+
+# --- the unified step's two-part latent attention (PR 52): the window's own
+# keys decompressed (ragged_mla_attention_window), the resident pages absorbed
+# (ragged_mla_attention told each lane's last resident position), one softmax
+
+
+def _dense_window_reference(qn, qr, kn, kr, v, lane, pos, scale):
+    """float32, dense: row i attends the live rows of its lane at flat index
+    <= i.  Returns (out [t, H, v], lse [t, H]; a pad: zeros, -inf)."""
+    lane, pos = np.asarray(lane), np.asarray(pos)
+    t = lane.shape[0]
+    idx = np.arange(t)
+    live = pos >= 0
+    mask = ((lane[:, None] == lane[None, :]) & (idx[None, :] <= idx[:, None])
+            & live[:, None] & live[None, :])
+    s = (jnp.einsum("thn,shn->hts", qn, kn) + jnp.einsum("thp,sp->hts", qr, kr)) * scale
+    s = jnp.where(mask[None], s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    w = jnp.where(mask[None], jnp.exp(s - jnp.where(jnp.isfinite(lse), lse, 0.0)[..., None]), 0.0)
+    return np.asarray(jnp.einsum("hts,shv->thv", w, v)), np.asarray(lse.T)
+
+
+@pytest.mark.parametrize("block,t_pad", [(512, 40), (128, 384), (128, 640)],
+                         ids=["one_block", "three_blocks", "five_blocks"])
+def test_mla_window_launch_matches_a_dense_float32_reference(block, t_pad):
+    """The flash launch over a window's own keys at a 192/128-shaped head
+    (128 + a 64-wide rotated part stored 128 wide, values 128), a mixed
+    batch as the engine packs it: three decode rows, a span that continues
+    at position 5, a whole prompt from 0, pads behind.  One block of rows,
+    and three blocks of 128 (the second span crosses a block edge; the first
+    key block a query block visits comes from its earliest lane's first
+    row), and five (the whole prompt, 450 rows, fills two blocks alone)."""
+    from dynamo_tpu.ops.pallas.mla_attention import NEG_INF, ragged_mla_attention_window
+
+    lanes, h, n, p, vd = 6, 2, 128, 128, 128
+    long = t_pad > 128
+    spans = [(0, 40, 1), (1, 7, 1), (2, 0, 1), (3, 5, 150 if long else 20),
+             (4, 0, {40: 13, 384: 200, 640: 450}[t_pad])]
+    token_lane, token_pos, _ = ragged_meta(spans, lanes, t_pad=t_pad)
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    qn, kn = (jax.random.normal(k, (t_pad, h, n), jnp.float32) for k in keys[:2])
+    v = jax.random.normal(keys[2], (t_pad, h, vd), jnp.float32)
+    # the rotated parts: 64 live lanes, zeros behind (the page's layout)
+    qr = jnp.pad(jax.random.normal(keys[3], (t_pad, h, 64), jnp.float32), ((0, 0), (0, 0), (0, 64)))
+    kr = jnp.pad(jax.random.normal(keys[4], (t_pad, 64), jnp.float32), ((0, 0), (0, 64)))
+    out, lse = ragged_mla_attention_window(
+        qn, qr, kn, kr, v, token_lane, token_pos, lanes=lanes, scale=0.07,
+        interpret=True, block=block)
+    out = np.asarray(out).reshape(t_pad, h, vd)     # [T, H x V], head-major columns
+    want, want_lse = _dense_window_reference(qn, qr, kn, kr, v, token_lane, token_pos, 0.07)
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    live = np.asarray(token_pos) >= 0
+    np.testing.assert_allclose(np.asarray(lse)[live], want_lse[live], rtol=2e-5, atol=2e-5)
+    assert (out[~live] == 0).all() and (np.asarray(lse)[~live] == NEG_INF).all()
+    # a decode row is a window of one: its own value, its own score
+    np.testing.assert_allclose(out[0], np.asarray(v)[0], rtol=1e-6, atol=1e-6)
+
+
+def _latent_batch():
+    """A unified window with every kind of row of the contract over a small
+    latent cache (pages of 8, lane i's pages 4 i .. 4 i + 3): lane 0 a decode
+    row at position 20; lane 1 a whole 11-token prompt; lane 2 a chunk of 9
+    that continues a resident prefix of 13 (which ends mid-page); lane 3
+    EMPTY (pages, no row); pads behind.  Returns everything both routes
+    need and the one-piece absorbed result."""
+    from dynamo_tpu.ops.attention import ragged_mla_paged_attention
+    from dynamo_tpu.ops.pallas.mla_attention import last_resident_pos
+
+    h, r, p, n, vd, bs, lanes = 4, 32, 128, 16, 16, 8, 4
+    spans = [(0, 20, 1), (1, 0, 11), (2, 13, 9)]
+    token_lane, token_pos, _ = ragged_meta(spans, lanes, t_pad=24)
+    lane, pos = np.asarray(token_lane), np.asarray(token_pos)
+    t = lane.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(23), 6)
+    tables = jnp.arange(lanes * 4, dtype=jnp.int32).reshape(lanes, 4)
+    ck = jax.random.normal(keys[0], (lanes * 4, bs, r), jnp.float32)
+    kr = jnp.pad(jax.random.normal(keys[1], (lanes * 4, bs, 8), jnp.float32),
+                 ((0, 0), (0, 0), (0, p - 8)))
+    w_uk = jax.random.normal(keys[2], (r, h, n), jnp.float32) / np.sqrt(r)
+    w_uv = jax.random.normal(keys[3], (r, h, vd), jnp.float32) / np.sqrt(r)
+    q_nope = jax.random.normal(keys[4], (t, h, n), jnp.float32)
+    q_rope = jnp.pad(jax.random.normal(keys[5], (t, h, 8), jnp.float32), ((0, 0), (0, 0), (0, p - 8)))
+    q_lat = jnp.einsum("thn,rhn->thr", q_nope, w_uk)
+    # the rows' own latents: what the step wrote at their slots before attention
+    live = pos >= 0
+    page = np.asarray(tables)[np.clip(lane, 0, lanes - 1), np.maximum(pos, 0) // bs]
+    c_kv = jnp.where(live[:, None], ck[page, np.maximum(pos, 0) % bs], 0.0)
+    k_rope = jnp.where(live[:, None], kr[page, np.maximum(pos, 0) % bs], 0.0)
+    scale = 0.2
+    ctx = ragged_mla_paged_attention(q_lat, q_rope, ck, kr, tables, token_lane, token_pos, scale=scale)
+    want = np.asarray(jnp.einsum("thr,rhv->thv", ctx, w_uv))
+    resident = last_resident_pos(lane, pos, lanes)
+    return dict(
+        lanes=lanes, bs=bs, scale=scale, lane=lane, pos=pos, resident=resident, tables=tables,
+        ck=ck, kr=kr, w_uk=w_uk, w_uv=w_uv, q_nope=q_nope, q_rope=q_rope, q_lat=q_lat,
+        c_kv=c_kv, k_rope=k_rope, want=want)
+
+
+@functools.cache
+def _two_parts():
+    """``_latent_batch`` through the two launches and the merge."""
+    from dynamo_tpu.models.deepseek import _merge_parts
+    from dynamo_tpu.ops.pallas.mla_attention import (
+        ragged_mla_attention,
+        ragged_mla_attention_window,
+    )
+
+    b = _latent_batch()
+    ctx, lse_resident = ragged_mla_attention(
+        b["q_lat"], b["q_rope"], b["ck"], b["kr"],
+        *span_args(b["lane"], b["resident"], b["tables"], 8, b["bs"]),
+        scale=b["scale"], tb_tokens=8, interpret=True, with_lse=True)
+    out, lse_window = ragged_mla_attention_window(
+        b["q_nope"], b["q_rope"], jnp.einsum("tr,rhn->thn", b["c_kv"], b["w_uk"]), b["k_rope"],
+        jnp.einsum("tr,rhv->thv", b["c_kv"], b["w_uv"]), jnp.asarray(b["lane"]),
+        jnp.asarray(b["pos"]), lanes=b["lanes"], scale=b["scale"], interpret=True)
+    merged = _merge_parts(ctx, lse_resident, out, lse_window, b["w_uv"])
+    by_head = lambda a: a.reshape(a.shape[0], 4, -1)  # noqa: E731 — [T, H x v] -> [T, H, v]
+    return b, {k: np.asarray(a) for k, a in dict(
+        ctx=ctx, lse_resident=lse_resident, out=by_head(out), lse_window=lse_window,
+        merged=by_head(merged)).items()}
+
+
+@pytest.mark.parametrize("kind,rows,has_resident,has_window", [
+    ("decode_row", slice(0, 1), True, True),
+    ("whole_prompt", slice(1, 12), False, True),
+    ("chunk_after_a_prefix_that_ends_mid_page", slice(12, 21), True, True),
+    ("pad", slice(21, 24), False, False),
+    ("empty_lane", None, False, False),
+])
+def test_the_merged_parts_equal_one_piece_absorbed_attention(kind, rows, has_resident, has_window):
+    """Window keys decompressed + resident pages absorbed, merged under one
+    softmax, against ``ops/attention.py:ragged_mla_paged_attention`` over all
+    of a row's keys at once, on each kind of row of the contract; and which
+    part was empty where (its log-sum-exp reads ``NEG_INF``, and the merge
+    then takes the other unscaled)."""
+    from dynamo_tpu.ops.pallas import pack_spans
+    from dynamo_tpu.ops.pallas.mla_attention import NEG_INF
+
+    b, got = _two_parts()
+    if kind == "empty_lane":
+        # lane 3 has pages and no row: no span names it, nothing is walked
+        # for it, and every row's result stands as the other cases hold it
+        span_lane, _, count, steps = pack_spans(
+            b["lane"], b["resident"], lanes=b["lanes"], tb_tokens=8, block_size=b["bs"])
+        assert 3 not in span_lane.tolist()
+        # the decode row's 3 pages, and the chunk's 2 resident ones for each
+        # of the two token blocks its rows lie in; nothing of the whole prompt
+        assert sorted(count[count > 0].tolist()) == [2, 2, 3] and steps.tolist() == [1, 1, 1]
+        assert b["resident"].tolist() == [19] + [-1] * 11 + [12] * 9 + [-1] * 3
+        return
+    if kind == "pad":     # (the twin's softmax over nothing is uniform: no reference)
+        assert (got["merged"][rows] == 0).all()
+    else:
+        np.testing.assert_allclose(got["merged"][rows], b["want"][rows], rtol=2e-5, atol=2e-5)
+    assert ((got["lse_resident"][rows] > NEG_INF).all() if has_resident
+            else (got["lse_resident"][rows] == NEG_INF).all())
+    assert ((got["lse_window"][rows] > NEG_INF).all() if has_window
+            else (got["lse_window"][rows] == NEG_INF).all())
+    if not has_resident and has_window:
+        decompressed = got["out"][rows]
+        np.testing.assert_array_equal(got["merged"][rows], decompressed)   # unscaled
+
+
+def test_the_ragged_mla_launchs_log_sum_exp_is_the_xla_twins():
+    """``with_lse``: the rows' log-sum-exp from the running max and sum the
+    kernel keeps, against the gathered scores of the XLA twin's arithmetic
+    (every key of the lane up to the row's position), on the mixed batch; the
+    context is the launch's without the flag, to the bit."""
+    from dynamo_tpu.ops.pallas.mla_attention import ragged_mla_attention
+
+    b = _latent_batch()
+    args = (b["q_lat"], b["q_rope"], b["ck"], b["kr"],
+            *span_args(b["lane"], b["pos"], b["tables"], 8, b["bs"]))
+    kw = dict(scale=b["scale"], tb_tokens=8, interpret=True)
+    ctx, lse = ragged_mla_attention(*args, **kw, with_lse=True)
+    np.testing.assert_array_equal(np.asarray(ctx), np.asarray(ragged_mla_attention(*args, **kw)))
+    length = 4 * b["bs"]
+    for i in np.flatnonzero(b["pos"] >= 0):
+        pages = np.asarray(b["tables"])[b["lane"][i]]
+        ck = np.asarray(b["ck"])[pages].reshape(length, -1)
+        kr = np.asarray(b["kr"])[pages].reshape(length, -1)
+        s = (np.asarray(b["q_lat"])[i] @ ck.T + np.asarray(b["q_rope"])[i] @ kr.T) * b["scale"]
+        s = s[:, : b["pos"][i] + 1]
+        want = np.log(np.exp(s - s.max(-1, keepdims=True)).sum(-1)) + s.max(-1)
+        np.testing.assert_allclose(np.asarray(lse)[i], want, rtol=2e-5, atol=2e-5)
+
+
+# sha256 of ``str(jax.make_jaxpr(launch))`` (the kernel's body included, no
+# source locations in it) at the two latent cells' shapes, read on the commit
+# before PR 52 (dee43df) and again after it, jax 0.9.0
+LANE_LAUNCH_PROGRAMS = {
+    ("decode", 16): "eb2e781dd1714fd67bd3c86ec901add6beb6d07ef01cb62a2df982192db44bb5",
+    ("decode", 32): "b4e4377054992970b6fea3af29c2b4590d79e310a32fb7ae8ae902244846f820",
+    ("verify_w5", 16): "9bbe1d7eb5f4625617ecc7c33951971196b03a45e76e2cb4a54e19a8cf14e493",
+    ("verify_w5", 32): "b1fc3b9b4f21d19423201e179251aefde6ab06ccd72baded49105ef9112c02a6",
+}
+
+
+@pytest.mark.parametrize("launch,heads", sorted(LANE_LAUNCH_PROGRAMS))
+def test_the_decode_and_verify_launches_trace_to_the_program_they_were(launch, heads):
+    """The ragged launch alone learned to return a log-sum-exp and to pass by a
+    token block with nothing resident (a static flag of ``_launch``): the
+    decode and verify launches trace, kernel body and all, to the text they
+    traced to before (so ``step_ms_decode`` and ``mla_decode_roofline`` have
+    nothing to move by)."""
+    import hashlib
+
+    from dynamo_tpu.ops.pallas.mla_attention import (
+        mla_paged_attention_decode,
+        mla_paged_window_attention_decode,
+    )
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jax 0.9.0's printer")
+    fn, lead = ((mla_paged_attention_decode, (24,)) if launch == "decode"
+                else (mla_paged_window_attention_decode, (24, 5)))
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype)  # noqa: E731
+    text = str(jax.make_jaxpr(lambda *a: fn(*a, scale=0.07))(
+        s((*lead, heads, 512)), s((*lead, heads, 128)), s((9 * 11008, 16, 512)),
+        s((9 * 11008, 16, 128)), s((24, 512), jnp.int32), s((24,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest() == LANE_LAUNCH_PROGRAMS[launch, heads]
