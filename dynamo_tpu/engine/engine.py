@@ -140,6 +140,7 @@ MOE_STAT_KEYS = (
     "moe_expert_rows_max_total",
     "moe_expert_layers_total",
     "moe_rows_walked_total",
+    "moe_rows_multiplied_total",
 )
 
 

@@ -43,6 +43,7 @@ MOE_STATS = (
     "expert_rows_max",      # rows of the busiest held expert (summed over layers)
     "expert_layers",        # expert layers run (the divisor of the two above)
     "rows_walked",          # chunks walked x their rows: what the products were given
+    "rows_multiplied",      # row tiles the products' kernel visits x a tile's rows
 )
 
 # rows a trip of ``moe_experts``' walk holds.  A step of fewer assignments
@@ -108,6 +109,65 @@ def moe_router_sigmoid_noaux(
     return top_ids.astype(jnp.int32), top_scores
 
 
+# The scoped VMEM a Mosaic kernel is granted on a v5e unless it asks for more
+# (megablox does not) is 16 MiB; the blocks ``gmm_tiling`` chooses may take this
+# much of it by ``gmm_block_bytes``' count, the rest is left to the kernel's own
+# temporaries (the loaded operands, the masked store): the chip's compiler
+# refused 16.75 MiB where the count read 15.5 (tests/ops/test_chip_compile.py).
+GMM_BLOCK_BYTES = 14 * 2**20
+
+
+def gmm_block_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What the megablox kernel holds in VMEM at a tiling: two buffers each of
+    the ``tm x tk`` rows, the ``tk x tn`` bank block and the ``tm x tn``
+    result, and the float32 accumulator."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def tile_rows(m: int) -> int:
+    """Rows of a row tile of the grouped products over ``m`` rows: 128 for a
+    decode step's rows, 256 for a prompt's chunk.  The chip kept both at every
+    width and at 1 to 4,096 rows a group (PERF.md section 5, PR 53): 128 and
+    512 lose at a chunk whatever the groups, 16 to 64 gain nothing at a
+    decode step, whose time is the banks' bytes."""
+    return 128 if m <= 1024 else 256
+
+
+def _whole_tiles(width: int) -> list[int]:
+    """The multiples of 128 that divide ``width``, widest first."""
+    return [d for d in range(width, 0, -128) if width % d == 0] if width % 128 == 0 else []
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` of the megablox product ``[m, k] @ [groups, k, n]``,
+    from the shapes alone.  In this order:
+
+    - no masked work: ``tk`` divides ``k`` and ``tn`` divides ``n`` in whole
+      128-lane tiles (the kernel multiplies a tile's every column, and masks
+      the last ``k`` tile's operands elementwise in float32);
+    - a bank block at least 256 columns wide for a prompt's chunk, whose time
+      is the MXU's, and at least 1,024 (rows of 2 KB) for a decode step's
+      rows, whose time is the banks' bytes: narrower blocks were 12% slower
+      there at 6,144 x 2,048 (PERF.md section 6, PR 53);
+    - ``k`` whole where the blocks fit ``GMM_BLOCK_BYTES``: the bank block's
+      index then stays put over a group's consecutive row tiles and the
+      pipeline fetches a group's bank once, not once a row tile; else the
+      deepest ``tk`` of at most 1,024;
+    - the widest ``tn`` that fits: each narrower one is another pass over the
+      rows.
+
+    A width no whole tile divides keeps the tiling every width had before."""
+    tm = tile_rows(m)
+    columns = min(n, 1024 if m <= 1024 else 256)
+    depths = _whole_tiles(k)
+    # k whole, else its deepest tile of at most 1,024
+    for tk in depths[:1] + [d for d in depths[1:] if d <= 1024][:1]:
+        for tn in _whole_tiles(n):
+            if tn >= columns and gmm_block_bytes(tm, tk, tn, itemsize) <= GMM_BLOCK_BYTES:
+                return tm, tk, tn
+    return tm, min(k, 1024), min(n, 1024)
+
+
 def grouped_matmul(
     lhs: jnp.ndarray,          # [M, K] rows sorted by group
     rhs,                       # [G, K, N] one matrix a group (may be quantized),
@@ -156,14 +216,14 @@ def grouped_matmul(
             jnp.zeros((rhs.shape[0],), jnp.int32), group_sizes, (layer * groups,)
         )
     k, n = rhs.shape[1:]
-    tm = 128 if m <= 1024 else 256
-    pad = -m % tm
+    tiling = gmm_tiling(m, k, n, lhs.dtype.itemsize)
+    pad = -m % tiling[0]
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = gmm(
         lhs, rhs.astype(lhs.dtype), group_sizes,
         preferred_element_type=lhs.dtype,
-        tiling=(tm, min(k, 1024), min(n, 1024)),
+        tiling=tiling,
         interpret=impl == "pallas_interpret",
     )[:m]
     # the kernel leaves the tiles it never visits unwritten
@@ -230,6 +290,12 @@ def moe_experts(
     combined = jax.lax.fori_loop(
         0, chunks, jax.jit(chunk), jnp.zeros((t, h // lanes, lanes), jnp.float32)
     ).reshape(t, h).astype(x.dtype)
+    # the row tiles the products' kernel visits, chunk by chunk ([chunks,
+    # experts]): a tile is multiplied once for each expert with a row in it
+    tm = tile_rows(c)
+    lo = jnp.arange(-(-(t * k) // c))[:, None] * c
+    first, last = jnp.clip(starts, lo, lo + c) - lo, jnp.clip(ends, lo, lo + c) - lo
+    tiles_visited = jnp.sum(jnp.where(last > first, (last + tm - 1) // tm - first // tm, 0))
     stats = jnp.stack([
         jnp.sum(real) * k,
         live_rows,
@@ -238,6 +304,7 @@ def moe_experts(
         jnp.max(group_sizes),
         jnp.int32(1),
         chunks * c,
+        tiles_visited * tm,
     ]).astype(jnp.int32)
     return combined, stats
 

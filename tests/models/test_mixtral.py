@@ -59,8 +59,9 @@ def test_no_token_dropped_when_all_route_to_one_expert():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(jax.nn.silu(x) * x), rtol=1e-5, atol=1e-5
     )
-    # routed, held, experts touched, busiest expert's rows, layers, rows walked
-    assert stats.tolist() == [t, t, 1, t, 1, t]
+    # routed, held, experts touched, busiest expert's rows, layers, rows walked,
+    # rows multiplied (one row tile of 128)
+    assert stats.tolist() == [t, t, 1, t, 1, t, 128]
 
 
 def test_mixtral_prefill_decode_consistency():

@@ -562,28 +562,42 @@ def test_a_layers_expert_banks_are_read_where_they_lie(one_chip):
     assert 10.3e9 < memory.argument_size_in_bytes < 10.7e9
 
 
-@pytest.mark.parametrize("tokens", [16, 8192])
-def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, tokens):
-    """``moe_experts`` at ``k-exaone-236b-l8``'s widths (16 of 128 experts
-    held, 8 choices a token, banks stacked over 7 layers), a decode step's
-    16 lanes and the 8,192 bucket: the three grouped products are the Pallas
-    kernel inside the walk's loop, over ONE chunk's rows, and nothing of
-    ``tokens x 8`` rows but indices is left (the sorted buffers were 805 MB
-    each at 8,192; the program holds the tokens' float32 sum, 201 MB, and
-    a chunk).  The products keep the instruction name ``gmm*`` that
-    ``benchmark/metrics/moe_*.json`` look for, with locations as short as
-    the serving process makes them (``utils/compile_cache.py``): lowered
-    inline in the loop's body they were ``tpu_custom_call.<n>`` on the chip
-    and both metrics fell silent."""
+# the routed cells' expert layers as a chip holds them (benchmark/configs/):
+# choices a token, hidden, expert width, experts held, the first held, sparse
+# layers in the stack, decode lanes
+EXPERT_LAYERS = {
+    "k-exaone-236b-l8": (8, 6144, 2048, 16, 32, 7, 16),
+    "moonlight-16b-l9": (6, 2048, 1408, 64, 0, 8, 24),
+    "xing4-29b-l8": (4, 3584, 1024, 64, 0, 6, 24),
+}
+
+
+@pytest.mark.parametrize("rows", ["decode", "8192"])
+@pytest.mark.parametrize("config", sorted(EXPERT_LAYERS))
+def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, config, rows):
+    """``moe_experts`` at a routed cell's widths (``k-exaone-236b-l8``: 16 of
+    128 experts held, 8 choices a token, banks stacked over 7 layers; the two
+    latent models: 64 of 64 held, 1,408 and 1,024 wide), a decode step's lanes
+    and the 8,192 bucket: the three grouped products are the Pallas kernel
+    inside the walk's loop, over ONE chunk's rows, at the tiles
+    ``moe.gmm_tiling`` gives those widths (an over-full tiling is refused
+    here, not on the chip), and nothing of ``tokens x choices`` rows but
+    indices is left (the sorted buffers were 805 MB each at 8,192; the program
+    holds the tokens' float32 sum, 201 MB, and a chunk).  The products keep
+    the instruction name ``gmm*`` that ``benchmark/metrics/moe_*.json`` look
+    for, with locations as short as the serving process makes them
+    (``utils/compile_cache.py``): lowered inline in the loop's body they were
+    ``tpu_custom_call.<n>`` on the chip and both metrics fell silent."""
     from dynamo_tpu.ops import moe
 
     s = _sds(one_chip)
-    k, h, i, e, layers = 8, 6144, 2048, 16, 7
+    k, h, i, e, first, layers, lanes = EXPERT_LAYERS[config]
+    tokens = lanes if rows == "decode" else int(rows)
     bank = lambda a, b: (s((layers, e, a, b), jnp.bfloat16), s((), jnp.int32))  # noqa: E731
 
     def fn(x, ids, probs, gate, up, down, valid):
         return moe.moe_experts(
-            x, ids, probs, gate, up, down, first_expert=32, valid=valid, impl="pallas")
+            x, ids, probs, gate, up, down, first_expert=first, valid=valid, impl="pallas")
 
     full = jax.config.jax_include_full_tracebacks_in_locations
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
@@ -595,14 +609,16 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, tokens):
     finally:
         jax.config.update("jax_include_full_tracebacks_in_locations", full)
     hlo = compiled.as_text()
-    rows = min(tokens * k, moe.CHUNK_ROWS)
+    chunk = min(tokens * k, moe.CHUNK_ROWS)
+    # (a chunk's rows are padded to whole row tiles for the kernel)
+    padded = -(-chunk // (tm := moe.tile_rows(chunk))) * tm
     products = re.findall(
         r"%([\w.\-]+) = bf16\[(\d+),\d+\][^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     assert len(products) == 3, products
-    assert all(re.match(r"gmm(\.\d+)?$", name) and int(m) == rows for name, m in products), products
-    # (the count by expert compares [tokens x 8, 16] inside a fusion)
-    wide = {m for m in re.findall(rf"\w+\[{tokens * k},(\d+)\]", hlo) if int(m) >= i}
-    assert tokens * k == rows or not wide, wide
+    assert all(re.match(r"gmm(\.\d+)?$", name) and int(m) == padded for name, m in products), products
+    # (the count by expert compares [tokens x choices, experts held] inside a fusion)
+    wide = {m for m in re.findall(rf"\w+\[{tokens * k},(\d+)\]", hlo) if int(m) >= min(h, i)}
+    assert tokens * k == chunk or not wide, wide
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 

@@ -5,6 +5,9 @@ an expert's rows and row counts that are no multiple of a chunk; and the
 lowered program holds no array of ``tokens x k`` rows of the hidden or the
 expert width, so the buffer the walk replaced cannot come back unseen."""
 
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -121,3 +124,105 @@ def test_no_array_of_tokens_x_k_rows_of_the_hidden_or_the_expert_width():
     assert any(p == "while" for _, p in shapes)
     assert (moe.CHUNK_ROWS, i) in [s for s, _ in shapes]
     assert (moe.CHUNK_ROWS, h) in [s for s, _ in shapes]
+
+
+def _routed_widths():
+    """(hidden, expert width, choices a token, lanes) of every routed
+    configuration under ``benchmark/configs/`` and of ``mixtral-8x7b-l4``
+    (``tests/ops/test_chip_compile.py``: no cell serves it)."""
+    out = {"mixtral-8x7b-l4": (4096, 14336, 2, 8)}
+    for path in sorted((Path(__file__).parents[2] / "benchmark" / "configs").glob("*.json")):
+        cfg = json.loads(path.read_text())
+        if "moe_intermediate_size" in cfg:
+            args = cfg["serving"]["args"]
+            out[path.stem] = (
+                cfg["hidden_size"], cfg["moe_intermediate_size"],
+                cfg["num_experts_per_tok"], args[args.index("--max-batch-size") + 1])
+    return out
+
+
+ROUTED = _routed_widths()
+
+
+def test_the_benchmarks_routed_configurations_are_all_here():
+    assert sorted(ROUTED) == ["k-exaone-236b-l8", "mixtral-8x7b-l4", "moonlight-16b-l9", "xing4-29b-l8"]
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("rows", ["decode", "prompt"])
+@pytest.mark.parametrize("config", sorted(ROUTED))
+def test_the_tiling_divides_the_widths_and_fits_fast_memory(config, rows, product):
+    """``gmm_tiling`` at a decode step's rows (lanes x choices) and at a
+    prompt's 2,048-row chunk: no masked column (``tk | k``, ``tn | n``, whole
+    128-lane tiles), the row tile a multiple of the bf16 sublane tile, the
+    blocks within the budget the rule states, a decode step's bank blocks at
+    least 1,024 columns wide, and at 6,144 x 2,048 the tilings the chip kept
+    (PERF.md sections 5 and 6, PR 53)."""
+    h, width, choices, lanes = ROUTED[config]
+    m = min((lanes if rows == "decode" else 8192) * choices, moe.CHUNK_ROWS)
+    k, n = (h, width) if product == "up" else (width, h)
+    tm, tk, tn = moe.gmm_tiling(m, k, n, 2)
+    assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0, (tm, tk, tn)
+    assert tm % 16 == 0 and tm == moe.tile_rows(m)
+    assert moe.gmm_block_bytes(tm, tk, tn, 2) <= moe.GMM_BLOCK_BYTES < 16 * 2**20
+    assert rows == "prompt" or tn >= 1024
+    if config == "k-exaone-236b-l8":
+        assert (tm, tk, tn) == {
+            ("decode", "up"): (128, 1024, 2048), ("decode", "down"): (128, 2048, 1024),
+            ("prompt", "up"): (256, 6144, 256), ("prompt", "down"): (256, 2048, 1024),
+        }[rows, product]
+
+
+def test_a_width_no_tile_divides_keeps_the_old_tiling():
+    """The CPU tests' widths (16 x 24) and any width that is no multiple of
+    128: correctness never depends on the rule."""
+    assert moe.gmm_tiling(128, 16, 24, 4) == (128, 16, 24)
+    assert moe.gmm_tiling(2048, 2048, 1400, 2) == (256, 1024, 1024)
+
+
+@pytest.mark.parametrize("widths", [(2048, 1408), (3584, 1024)], ids=["2048x1408", "3584x1024"])
+def test_the_products_at_the_cells_widths_are_xlas(monkeypatch, widths):
+    """The kernel at the tiling the rule gives ``moonlight-16b-l9``'s and
+    ``xing4-29b-l8``'s widths (bf16, interpreted) against ``ragged_dot``: 75
+    tokens x 3 choices over 4 experts of which one gets nothing, in chunks of
+    128 rows: groups of 75 rows straddle row tiles and chunks, and the last
+    chunk is part empty."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 128)
+    h, i = widths
+    t, k = 75, 3
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    x = jax.random.normal(keys[0], (t, h), jnp.bfloat16)
+    banks = [(b * h ** -0.5).astype(jnp.bfloat16) for b in _banks(keys[1], E_HELD, h, i)]
+    ids = FIRST + jnp.asarray([0, 1, 3], jnp.int32)[
+        jnp.stack([jax.random.permutation(kk, 3) for kk in jax.random.split(keys[2], t)])]
+    probs = jax.nn.softmax(jax.random.normal(keys[3], (t, k)), axis=-1)
+    tm = moe.tile_rows(128)
+    assert moe.gmm_tiling(128, i, h, 2)[1] == i and h % moe.gmm_tiling(128, h, i, 2)[1] == 0
+    got, stats = moe_experts(x, ids, probs, *banks, first_expert=FIRST, impl="pallas_interpret")
+    want, _ = moe_experts(x, ids, probs, *banks, first_expert=FIRST, impl="xla")
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=2e-2, atol=2e-2)
+    stats = dict(zip(MOE_STATS, stats.tolist()))
+    assert stats["expert_rows_max"] == 75 and stats["rows_walked"] == 2 * 128
+    # [0, 75) and [75, 128) of the first chunk, [0, 22) and [22, 97) of the second
+    assert stats["rows_multiplied"] == tm * sum(
+        -(-end // tm) - start // tm for start, end in ((0, 75), (75, 128), (0, 22), (22, 97)))
+
+
+def test_rows_multiplied_is_the_row_tiles_the_kernel_visits(monkeypatch):
+    """Ten tokens x 2 choices over four held experts: 5, 0, 9 and 6 rows, in
+    chunks of 16 at row tiles of 8.  Chunk 0 holds rows [0, 5) of expert 0 (one
+    tile), [5, 14) of expert 2 (tiles 0 and 1) and [14, 16) of expert 3 (tile
+    1): four visits; chunk 1 the last four rows of expert 3: one.  Five visits
+    x 8 rows for 20 held."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 16)
+    monkeypatch.setattr(moe, "tile_rows", lambda m: 8)
+    experts = np.repeat([0, 2, 3], [5, 9, 6])
+    ids = jnp.asarray(FIRST + np.random.default_rng(3).permutation(experts).reshape(10, 2), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(13), 2)
+    x = jax.random.normal(keys[0], (10, 16))
+    _, stats = moe_experts(
+        x, ids, jnp.full((10, 2), 0.5), *_banks(keys[1], E_HELD, 16, 24), first_expert=FIRST, impl="xla")
+    stats = dict(zip(MOE_STATS, stats.tolist()))
+    assert stats["assignments_held"] == 20 and stats["rows_walked"] == 32
+    assert stats["rows_multiplied"] == 5 * 8
