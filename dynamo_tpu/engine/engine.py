@@ -21,7 +21,6 @@ Architecture:
 from __future__ import annotations
 
 import asyncio
-import math
 import os
 import queue as thread_queue
 import threading
@@ -202,16 +201,6 @@ def _token_buckets(configured: tuple[int, ...], max_len: int) -> list[int]:
         buckets |= {octave * 3 // 2, octave * 2}
         octave *= 2
     return sorted(b for b in buckets if b < max_len) + [max_len]
-
-
-def _kernel_perf_path() -> str:
-    """DYN_KERNEL_PERF override or the repo-root KERNEL_PERF.json."""
-    import os
-
-    return knobs.get("DYN_KERNEL_PERF") or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-        "KERNEL_PERF.json",
-    )
 
 
 @dataclass
@@ -825,64 +814,20 @@ class JaxLlmEngine:
         self._prompt_window_live_tokens = 0
         self._prompt_window_bucket_tokens = 0
         self._admission_drains = 0    # pipeline drains forced by admission
-        # ragged kernel tunable, precedence: explicit knob > tuned
-        # KERNEL_PERF.json row (ops/autotune.py) > the default the kernel
-        # derives from the head geometry.
-        # tb: the LARGEST token block; a bucket's flat token axis is cut
-        # into blocks of gcd(tb, bucket) tokens (_tb_for), so every bucket
-        # packs whole blocks.  Lanes PACK within a block (per-row routing).
-        # The kernel's loop runs each block's live KV steps, so there is no
-        # width to configure and ONE program per token bucket whatever the
-        # batch composition.
+        # query rows of one token in one of the ragged kernel's products: the
+        # heads that share a KV head (every head, over a latent cache).  A
+        # bucket's flat token axis is cut into blocks of bucket_tb_tokens
+        # (_tb_for), so every bucket packs whole blocks and there is ONE
+        # program per token bucket whatever the batch composition.
         from dynamo_tpu.ops.pallas.ragged_attention import default_tb_tokens
 
         heads = int(getattr(cfg, "num_heads", 0) or 1)
-        # query rows of one token in one of the kernel's products: the heads
-        # that share a KV head (every head, over a latent cache)
-        rows_per_token = heads if getattr(cfg, "kv_lora_rank", 0) else max(
+        self._rows_per_token = heads if getattr(cfg, "kv_lora_rank", 0) else max(
             1, heads // int(getattr(cfg, "num_kv_heads", 0) or heads)
         )
-        tb_default = default_tb_tokens(rows_per_token, config.block_size)
-        tuned = self._resolve_tuned_kernel_config(cfg)
-        knob_tb = knobs.get("DYN_AUTOTUNE_TB")
-        # a tb that cannot pack every unified bucket would split-fallback
-        # every window: validate tuned/knob choices against the prospective
-        # bucket set (chunk + mixed buckets are added below, after this)
-        prospective = set(self.buckets)
-        if (
-            config.prefill_chunk_tokens is not None
-            and self.family.forward_prefill_with_prefix is not None
-        ):
-            ct = _round_chunk_tokens(
-                config.prefill_chunk_tokens, config.block_size
-            )
-            if ct < self.max_len:
-                prospective.add(ct)
-                mixed_b = -(-(ct + config.max_batch_size) // 8) * 8
-                if mixed_b < self.max_len:
-                    prospective.add(mixed_b)
-        tb = int(knob_tb or (tuned or {}).get("tb_tokens") or tb_default)
-        if tb != tb_default and any(b % tb for b in prospective):
-            logger.warning(
-                "kernel tb_tokens=%d does not divide unified buckets %s; "
-                "using heuristic default %d",
-                tb, sorted(prospective), tb_default,
-            )
-            tb = tb_default
-        self._unified_tb = tb
-        if knob_tb:
-            source = "knob"
-        elif tuned is not None and int(tuned["tb_tokens"]) == tb:
-            source = "tuned"
-        else:
-            source = "default"
-        self._kernel_config = {
-            "tb_tokens": tb,
-            "source": source,
-            "geometry": getattr(self, "_kernel_geometry", None),
-        }
-        if source != "default":
-            logger.info("unified kernel config (%s): tb_tokens=%d", source, tb)
+        self._unified_tb = default_tb_tokens(
+            self._rows_per_token, config.block_size
+        )
         self._fb_zero = None          # resident all-zero feedback tokens
         self._seed_none = None        # resident no-op seed scatter args
         # Per-lane block-table host rows, rewritten only for lanes whose
@@ -1134,52 +1079,12 @@ class JaxLlmEngine:
         return quantize_params(raw_params, self.family.quant_leaves)
 
     def _tb_for(self, bucket: int) -> int:
-        """Token block of one unified bucket's program and packing: the
-        largest divisor of the bucket in the resolved block (a 64-token
-        block serves buckets 64 ... 4,096 whole, a 32-token bucket as one
-        block, a 528-token chunk + lanes bucket as blocks of 16)."""
-        return math.gcd(self._unified_tb, bucket)
+        """Token block of one unified bucket's program and packing."""
+        from dynamo_tpu.ops.pallas.ragged_attention import bucket_tb_tokens
 
-    def _resolve_tuned_kernel_config(self, cfg) -> dict | None:
-        """Look up the autotuned ragged-kernel row for this engine's
-        (geometry, device_kind, kv dtype) in the kernel-perf table
-        (DYN_KERNEL_PERF or repo-root KERNEL_PERF.json).  Advisory like the
-        attention-impl lookup: anything malformed degrades to None (the
-        heuristic defaults), never to a startup crash.  DYN_AUTOTUNE=0
-        disables the lookup entirely."""
-        self._kernel_geometry = None
-        if knobs.get("DYN_AUTOTUNE") is False:
-            return None
-        try:
-            from dynamo_tpu.ops import autotune as _autotune
-
-            heads = int(getattr(cfg, "num_heads", 0) or 1)
-            geom = _autotune.Geometry(
-                num_heads=heads,
-                num_kv_heads=int(getattr(cfg, "num_kv_heads", 0) or heads),
-                head_dim=int(
-                    getattr(cfg, "head_dim", 0)
-                    or getattr(cfg, "kv_lora_rank", 0)
-                    or 128
-                ),
-                block_size=self.config.block_size,
-                lanes=self.config.max_batch_size,
-                max_blocks_per_seq=self.max_blocks_per_seq,
-            )
-            kv_dtype = resolve_kv_cache_dtype(self.config.kv_cache_dtype)
-            if kv_dtype is None:
-                kv_dtype = jnp.dtype(cfg.dtype)
-            kind = self._device_info["kind"]
-            self._kernel_geometry = geom.key
-            return _autotune.resolve(
-                _autotune.load_table(_kernel_perf_path()),
-                geometry_key=geom.key,
-                device_kind=kind,
-                dtype=str(jnp.dtype(kv_dtype)),
-            )
-        except Exception as err:  # noqa: BLE001
-            logger.warning("autotune table resolution failed: %s", err)
-            return None
+        return bucket_tb_tokens(
+            self._rows_per_token, self.config.block_size, bucket
+        )
 
     # -- guided decoding ---------------------------------------------------
     def enable_guided_json(self, tokenizer) -> None:
@@ -2378,8 +2283,8 @@ class JaxLlmEngine:
             # reason-slug → count of windows (or the engine init) that fell
             # back from the unified step; each reason also logged once
             "unified_fallbacks": dict(self._unified_fallbacks),
-            # resolved ragged-kernel token block (source: knob / tuned / default)
-            "kernel_config": dict(self._kernel_config),
+            # the ragged kernel's largest token block (default_tb_tokens)
+            "kernel_config": {"tb_tokens": self._unified_tb},
             "attention_impl": self.attention_impl,
             "device": dict(self._device_info),
             # programs requested from the compiler / answered by the

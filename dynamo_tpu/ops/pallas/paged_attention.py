@@ -152,7 +152,7 @@ def paged_window_attention_decode(
     """Pallas multi-query paged attention for speculative verification
     (pure-JAX twin: ops/attention.py paged_window_attention).
     ``pages_per_step`` widens each grid step to DMA that many block-table
-    pages (autotuned; past-the-end indices clamp to the last block)."""
+    pages (past-the-end indices clamp to the last block)."""
     b, w, h, d = q.shape
     n, bs, kvh, _ = k_cache.shape
     maxb = block_tables.shape[1]

@@ -515,3 +515,12 @@ def ragged_paged_attention(
     )
     out = out.reshape(num_tb, kvh, groups, tb_tokens, d)
     return out.transpose(0, 3, 1, 2, 4).reshape(t_pad, h, d)
+
+
+def bucket_tb_tokens(rows_per_token: int, block_size: int, bucket: int) -> int:
+    """Token block one token bucket's program is packed and launched with:
+    the largest divisor of the bucket in ``default_tb_tokens`` (a 64-token
+    block serves buckets 64 ... 4,096 whole, a 32-token bucket as one block,
+    a 528-token chunk + lanes bucket as blocks of 16).  Kept below the
+    kernel so that no line of its body moves."""
+    return math.gcd(default_tb_tokens(rows_per_token, block_size), bucket)

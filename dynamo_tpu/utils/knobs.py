@@ -235,21 +235,6 @@ K_UNIFIED_BATCH = register(
     doc="override `EngineConfig.unified_batch` (unset defers to config, "
         "which defaults ON for every family with a unified forward; `0` "
         "forces the split prefill/decode step)", section=PERF)
-K_KERNEL_PERF = register(
-    "DYN_KERNEL_PERF", type="str", default=None,
-    doc="explicit path to a KERNEL_PERF.json kernel-choice table (default: "
-        "the repo-root artifact, purely advisory)", section=PERF)
-K_AUTOTUNE = register(
-    "DYN_AUTOTUNE", type="bool", default=True,
-    doc="consult KERNEL_PERF.json's measured autotune rows for the ragged "
-        "kernel's token-block size at engine init; `0` keeps the default "
-        "from the head geometry",
-    section=PERF)
-K_AUTOTUNE_TB = register(
-    "DYN_AUTOTUNE_TB", type="int", default=None,
-    doc="force the ragged kernel's largest token block (overrides tuned rows; "
-        "must divide every serving bucket or it falls back with a warning)",
-    section=PERF)
 
 # -- predictive prefetch (docs/performance.md) -------------------------------
 K_PREFETCH = register(

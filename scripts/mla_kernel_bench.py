@@ -57,7 +57,7 @@ def main() -> int:
         ragged_mla_attention,
         ragged_mla_attention_window,
     )
-    from dynamo_tpu.ops.pallas.ragged_attention import default_tb_tokens, pack_spans
+    from dynamo_tpu.ops.pallas.ragged_attention import bucket_tb_tokens, pack_spans
 
     if jax.default_backend() != "tpu":
         print("mla_kernel_bench: no TPU", file=sys.stderr)
@@ -98,10 +98,10 @@ def main() -> int:
 
     def case(name, heads, spans):
         """``spans``: (lane, first position, tokens) packed in order."""
-        tb = default_tb_tokens(heads, BLOCK)
         lane = np.concatenate([np.full(n, ln) for ln, _, n in spans])
         pos = np.concatenate([np.arange(a, a + n) for _, a, n in spans])
-        bucket = 1 << int(np.ceil(np.log2(max(len(lane), tb))))
+        bucket = 1 << int(np.ceil(np.log2(len(lane))))
+        tb = bucket_tb_tokens(heads, BLOCK, bucket)
         pad = bucket - len(lane)
         lane = np.concatenate([lane, np.full(pad, LANES)]).astype(np.int32)
         pos = np.concatenate([pos, np.full(pad, -1)]).astype(np.int32)

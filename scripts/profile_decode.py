@@ -1,6 +1,6 @@
 """Decode hot-loop phase profile on the current accelerator.
 
-Builds a const-init engine (same construction as bench.py's rungs), drives
+Builds a const-init engine (constant weights, no checkpoint), drives
 a fixed batch of greedy requests, and prints one JSON line with per-phase
 wall time from the engine's always-on host-phase accounting
 (schedule / pack / upload / dispatch / readback / post) plus ITL

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import math
 import os
 import statistics
 import sys
@@ -49,7 +48,7 @@ def _params(family, cfg):
 
 def _inputs(cfg, program, lanes, num_blocks):
     """The forward's arguments behind (params, cache), as numpy."""
-    from dynamo_tpu.ops.pallas.ragged_attention import default_tb_tokens, pack_spans
+    from dynamo_tpu.ops.pallas.ragged_attention import bucket_tb_tokens, pack_spans
 
     rng = np.random.default_rng(0)
     max_blocks = MAX_LEN // BLOCK
@@ -62,7 +61,7 @@ def _inputs(cfg, program, lanes, num_blocks):
         tok = rng.integers(0, cfg.vocab_size, lanes).astype(np.int32)
         return {}, (tok, tables, lens, slots.astype(np.int32))
     t = int(program.removeprefix("unified_t"))
-    tb = math.gcd(default_tb_tokens(cfg.num_heads // cfg.num_kv_heads, BLOCK), t)
+    tb = bucket_tb_tokens(cfg.num_heads // cfg.num_kv_heads, BLOCK, t)
     pos = np.arange(t, dtype=np.int32)
     lane = np.zeros(t, np.int32)
     slot = (tables[0, pos // BLOCK] * BLOCK + pos % BLOCK).astype(np.int32)

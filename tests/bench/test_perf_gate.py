@@ -34,9 +34,9 @@ def _edit_json(path: Path, mutate) -> None:
 # -- the tier-1 gate itself ---------------------------------------------------
 def test_committed_pile_passes_the_gate():
     """THE gate: the repo's committed artifacts vs the committed baseline.
-    A failure here means a PR regressed a headline metric (fix it) or
-    legitimately moved one (rerun scripts/perfgate.py --write-baseline and
-    commit the new baseline with the artifacts)."""
+    A failure here means a PR moved a count or a boolean the artifacts
+    record (fix it) or legitimately changed one (rerun scripts/perfgate.py
+    --write-baseline and commit the new baseline with the artifacts)."""
     findings = perfgate.check(REPO_ROOT)
     assert findings == [], "\n".join(str(f) for f in findings)
 
@@ -50,29 +50,33 @@ def test_every_schema_metric_is_extractable_and_baselined():
 
 
 # -- regression detection -----------------------------------------------------
-def test_degraded_profile_decode_metric_fails_with_named_finding(tmp_path):
+def _scale_prefetch_hits(factor: float):
+    """prefetch_bench.prefetch_hits: higher is better, 10% band."""
+    def mutate(d):
+        d["prefetch"]["prefetch_hits_total"] *= factor
+    return mutate
+
+
+def test_degraded_metric_fails_with_named_finding(tmp_path):
     _copy_pile(tmp_path)
-    _edit_json(
-        tmp_path / "PROFILE_DECODE.json",
-        lambda d: d.update(overlap_speedup_steps_s=d["overlap_speedup_steps_s"] * 0.5),
-    )
+    _edit_json(tmp_path / "PREFETCH_BENCH.json", _scale_prefetch_hits(0.5))
     findings = perfgate.check(tmp_path)
     assert len(findings) == 1
     f = findings[0]
     assert f.kind == "regression"
-    assert f.metric == "profile_decode.overlap_speedup_steps_s"
-    assert "PROFILE_DECODE.json" in f.detail
+    assert f.metric == "prefetch_bench.prefetch_hits"
+    assert "PREFETCH_BENCH.json" in f.detail
     assert "baseline" in f.detail
 
 
 def test_improvement_and_in_band_drift_pass(tmp_path):
     _copy_pile(tmp_path)
 
-    def mutate(d):
-        d["overlap_speedup_steps_s"] *= 1.5           # improvement
-        d["tiny_ab"]["overlap_speedup_tok_s"] *= 0.95  # within the 10% band
-
-    _edit_json(tmp_path / "PROFILE_DECODE.json", mutate)
+    _edit_json(tmp_path / "PREFETCH_BENCH.json", _scale_prefetch_hits(1.5))  # improvement
+    _edit_json(  # within migration_bench.committed's 25% band
+        tmp_path / "MIGRATION_BENCH.json",
+        lambda d: d["migrations"].update(committed=d["migrations"]["committed"] * 0.95),
+    )
     assert perfgate.check(tmp_path) == []
 
 
@@ -102,12 +106,12 @@ def test_stale_baseline_entry_fails(tmp_path):
 def test_no_longer_extractable_entry_is_stale(tmp_path):
     _copy_pile(tmp_path)
     _edit_json(
-        tmp_path / "PROFILE_DECODE.json",
-        lambda d: d.pop("overlap_speedup_steps_s"),
+        tmp_path / "PREFETCH_BENCH.json",
+        lambda d: d["prefetch"].pop("prefetch_hits_total"),
     )
     findings = perfgate.check(tmp_path)
     assert [(f.kind, f.metric) for f in findings] == [
-        ("stale", "profile_decode.overlap_speedup_steps_s")
+        ("stale", "prefetch_bench.prefetch_hits")
     ]
 
 
@@ -171,36 +175,33 @@ def committed_pile(tmp_path):
 
 def test_write_baseline_refuses_dirty_pile(committed_pile, capsys):
     _edit_json(
-        committed_pile / "PROFILE_DECODE.json",
-        lambda d: d.update(overlap_speedup_steps_s=42.0),
+        committed_pile / "PREFETCH_BENCH.json",
+        lambda d: d["prefetch"].update(prefetch_hits_total=4242.0),
     )
-    assert perfgate.dirty_artifacts(committed_pile) == ["PROFILE_DECODE.json"]
+    assert perfgate.dirty_artifacts(committed_pile) == ["PREFETCH_BENCH.json"]
     rc = perfgate_main(["--root", str(committed_pile), "--write-baseline"])
     assert rc == 1
     out = capsys.readouterr().out
     assert "refusing --write-baseline" in out
-    assert "PROFILE_DECODE.json" in out
+    assert "PREFETCH_BENCH.json" in out
 
 
 def test_write_baseline_over_clean_pile_then_gate_passes(committed_pile, capsys):
     _edit_json(
-        committed_pile / "PROFILE_DECODE.json",
-        lambda d: d.update(overlap_speedup_steps_s=42.0),
+        committed_pile / "PREFETCH_BENCH.json",
+        lambda d: d["prefetch"].update(prefetch_hits_total=4242.0),
     )
     _git(committed_pile, "add", "-A")
     _git(committed_pile, "commit", "-q", "-m", "legit perf change")
     assert perfgate_main(["--root", str(committed_pile), "--write-baseline"]) == 0
     baseline = perfgate.load_baseline(committed_pile / perfgate.BASELINE_NAME)
-    assert baseline["metrics"]["profile_decode.overlap_speedup_steps_s"] == 42.0
+    assert baseline["metrics"]["prefetch_bench.prefetch_hits"] == 4242.0
     assert perfgate_main(["--root", str(committed_pile)]) == 0
 
 
 def test_cli_exit_code_and_findings_output(tmp_path, capsys):
     _copy_pile(tmp_path)
-    _edit_json(
-        tmp_path / "PROFILE_DECODE.json",
-        lambda d: d.update(overlap_speedup_steps_s=d["overlap_speedup_steps_s"] * 0.5),
-    )
+    _edit_json(tmp_path / "PREFETCH_BENCH.json", _scale_prefetch_hits(0.5))
     rc = perfgate_main(["--root", str(tmp_path)])
     assert rc == 1
-    assert "[regression] profile_decode.overlap_speedup_steps_s" in capsys.readouterr().out
+    assert "[regression] prefetch_bench.prefetch_hits" in capsys.readouterr().out

@@ -663,19 +663,9 @@ def test_resident_arrays_sit_uncommitted_on_the_default_device():
     assert all(x.devices() == {jax.devices()[0]} for x in leaves)
 
 
-def test_no_table_routes_a_tpu_engine_off_the_kernel(monkeypatch, tmp_path):
-    """attention_impl=auto on a TPU IS the Pallas path.  A kernel-perf table
-    — however it claims the kernel loses, from whatever device — cannot
-    switch it off (it only ever feeds the ragged kernel's autotune rows)."""
-    import json
-
-    table = tmp_path / "perf.json"
-    table.write_text(json.dumps({
-        "platform": "tpu", "interpret": False, "device_kind": "TPU v5 lite",
-        "rows": [{"bench": "paged_attention_decode", "batch": 4, "ctx": 128,
-                  "pallas_speedup": 0.5}],
-    }))
-    monkeypatch.setenv("DYN_KERNEL_PERF", str(table))
+def test_no_table_routes_a_tpu_engine_off_the_kernel(monkeypatch):
+    """attention_impl=auto on a TPU IS the Pallas path: the backend decides
+    it, and the engine reads no table that could switch the kernel off."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     engine = make_engine()
     assert engine.attention_impl == "pallas"

@@ -371,6 +371,18 @@ def recount(engine, spans, bucket):
     }
 
 
+def test_the_token_block_is_the_head_geometrys_and_a_bucket_is_cut_to_what_it_holds():
+    """No table and no knob: the tiny geometry (two query heads a KV head,
+    pages of 4) gets the default block, and each bucket its divisor of it."""
+    engine = idle_engine(prefill_buckets=(16, 32, 64))
+    # 64 tokens keep a product's score rows under 256; the buckets pack to
+    # blocks of 16 / 32 / 64
+    assert engine.stats()["kernel_config"] == {"tb_tokens": 64}
+    assert [engine._tb_for(b) for b in (16, 32, 64)] == [16, 32, 64]
+    # the worklist width and its overflow counter went with the static worklists
+    assert "unified_ps_overflows_total" not in engine.stats()
+
+
 async def test_the_engines_ragged_counters_equal_a_recount_from_the_request():
     """Pallas (interpreted) unified engine, one request of 21 prompt tokens:
     the window's kernel work recounted from the request's length."""

@@ -28,7 +28,7 @@ from dynamo_tpu.ops.pallas.paged_attention import (
     paged_window_attention_decode,
 )
 from dynamo_tpu.ops.pallas.ragged_attention import (
-    default_tb_tokens,
+    bucket_tb_tokens,
     ragged_paged_attention,
 )
 
@@ -57,7 +57,7 @@ CELL_SHAPES = {"lanes8": 8, "lanes16": 16}
 
 def _tb(model, bucket):
     h, kvh, _ = GEOMETRY[model]
-    return math.gcd(default_tb_tokens(h // kvh, BLOCK), bucket)
+    return bucket_tb_tokens(h // kvh, BLOCK, bucket)
 
 
 @pytest.fixture(scope="module")
@@ -343,7 +343,7 @@ def _expert_cell_program(one_chip, config, program):
             return family.forward_decode(p, cfg, tok, c, bt, cl, sl, cos, sin, attention="pallas")
         args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
     elif kind == "unified":
-        tb = math.gcd(default_tb_tokens(rows, BLOCK), t)
+        tb = bucket_tb_tokens(rows, BLOCK, t)
         def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
             return family.forward_unified(
                 p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
@@ -411,7 +411,7 @@ def test_mla_launches_compile_at_32_heads_on_one_latent(one_chip, launch):
     tables = s((lanes, MLA["max_blocks"]), jnp.int32)
     if launch == "ragged_t8192":
         bucket = 8192
-        tb = math.gcd(default_tb_tokens(XING_HEADS, BLOCK), bucket)
+        tb = bucket_tb_tokens(XING_HEADS, BLOCK, bucket)
         tok = s((bucket,), jnp.int32)
         fn = ragged_mla_attention
         compiled = fn.lower(*q(bucket), *cache, tok, tok, tables, tok, tok, tok,
@@ -645,8 +645,7 @@ def _compile_forward(one_chip, config, program):
         args = (i32(lanes), tables, i32(lanes), i32(lanes), rope, rope)
     else:
         t = int(program.removeprefix("unified_t"))
-        tb = math.gcd(
-            default_tb_tokens(cfg.num_heads // cfg.num_kv_heads, BLOCK), t)
+        tb = bucket_tb_tokens(cfg.num_heads // cfg.num_kv_heads, BLOCK, t)
         def fn(p, c, tok, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows, cos, sin):
             return family.forward_unified(
                 p, cfg, tok, c, bt, cl, pos, slot, lane, sl, sf, sc, pt, rows,
@@ -902,7 +901,4 @@ def test_engine_constructs_at_a_context_the_worklists_refused():
         max_model_len=32768, attention_impl="pallas_interpret",
     ))
     assert engine.unified_batch
-    assert engine.stats()["kernel_config"] == {
-        "tb_tokens": 64, "source": "default",
-        "geometry": engine.stats()["kernel_config"]["geometry"],
-    }
+    assert engine.stats()["kernel_config"] == {"tb_tokens": 64}
