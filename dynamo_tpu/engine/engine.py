@@ -120,13 +120,29 @@ KERNEL_WORK_KEYS = (
     # layers full (ragged spans and decode walks alike, one layer's count)
     "window_pages_visited_total",
     "window_pages_full_total",
+    # the part of the two ``*_kv_read_bytes_total`` that layers read from
+    # pages they never wrote (a cross-decoder's; 0 otherwise)
+    "decode_cross_kv_read_bytes_total",
+    "ragged_cross_kv_read_bytes_total",
+    # state-space and memory layers (0 for a model without them): live rows
+    # through them (each row passes every such layer), and what a perfect
+    # implementation moves and computes for those rows (perf.ModelCost)
+    "ssm_rows_total",
+    "ssm_state_bytes_total",
+    "ssm_flops_total",
+    "gmu_rows_total",
 )
+
+# cache leaves that are not pages: the expert layers' counters, and a
+# state-space family's recurrent state and taps a LANE (models/phi4flash.py)
+_NOT_PAGES = ("moe_stats", "ssm", "conv")
+
 
 def _pages(cache: dict) -> dict:
     """The cache's page leaves (``[layers, blocks, ...]``: what block ids
-    index, what extract / inject / offload move), without the expert layers'
-    counters that ride the same pytree."""
-    return {name: leaf for name, leaf in cache.items() if name != "moe_stats"}
+    index, what extract / inject / offload move), without what else rides
+    the same pytree (``_NOT_PAGES``)."""
+    return {name: leaf for name, leaf in cache.items() if name not in _NOT_PAGES}
 
 
 # The expert layers' counters (a routed model; absent otherwise): what the
@@ -519,6 +535,11 @@ class JaxLlmEngine:
             # expert layer runs XLA's ragged dot, which GSPMD partitions
             cfg = dataclasses.replace(cfg, grouped_matmul="xla")
             config = self.config = dataclasses.replace(config, model=cfg)
+        if self.family.lane_state and self.mesh is not None:
+            raise ValueError(
+                f"family {config.model_family!r} keeps a recurrent state a "
+                "lane and is built for one chip: no mesh is served for it"
+            )
         # a model with window layers: their second pool (models/registry.py)
         self._window_blocks = 0
         if self.family.window_pool_blocks is not None:
@@ -582,6 +603,7 @@ class JaxLlmEngine:
                 cfg, config.num_blocks, config.block_size,
                 resolve_kv_cache_dtype(config.kv_cache_dtype),
                 **({"window_blocks": self._window_blocks} if self._window_blocks else {}),
+                **({"lanes": config.max_batch_size} if self.family.lane_state else {}),
             )
             cos, sin = self.family.rope_tables(cfg)
             # families build tables out to max_position_embeddings (131k for
@@ -1139,6 +1161,7 @@ class JaxLlmEngine:
             and self.family.supports_sp
         ):
             prefill_kwargs["sp_mesh"] = self.mesh
+        lane_state = self.family.lane_state
 
         # cos/sin ride as arguments, not closure constants: a closed-over
         # concrete array is baked into the HLO as a constant (observed:
@@ -1148,7 +1171,7 @@ class JaxLlmEngine:
                  greedy, pres, freq, rep, bias_ids, bias_vals, grow, cos, sin):
             logits, cache = self.family.forward_prefill(
                 params, cfg, token_ids, cache, block_ids, seq_len, start_pos,
-                cos, sin, **prefill_kwargs,
+                cos, sin, **prefill_kwargs, **({"lane": lane} if lane_state else {}),
             )
             # (re)seed this lane's sampling state.  ``gen_row`` is the count
             # of already-generated tokens (nonzero only on preemption
@@ -2997,6 +3020,12 @@ class JaxLlmEngine:
             if self._window_blocks:
                 work["window_pages_visited_total"] += window_pages
                 work["window_pages_full_total"] += live_pages
+            work["ragged_cross_kv_read_bytes_total"] += (
+                live_pages * cost.cross_layers * bs * cost.kv_bytes_per_token // layers_n
+            )
+        self._book_state_rows(
+            len(decodes), sum(end - start for _, start, end in spans), len(spans)
+        )
 
         if not overlap:
             _, opened, samples = self._take_unwaited(KIND_PROMPT, opened)
@@ -3709,6 +3738,7 @@ class JaxLlmEngine:
         self._step_prefill_tokens += end - start
         self._step_attn_ctx += (end * (end + 1) - start * (start + 1)) // 2
         self._step_weight_streams += 1
+        self._book_state_rows(0, end - start, 1)
         if not final:
             # intermediate chunk: KV written, no token sampled; publish the
             # completed blocks so routers (and future prompts) can hit them
@@ -4181,6 +4211,7 @@ class JaxLlmEngine:
         self._step_decode_tokens += lanes * steps
         self._step_attn_ctx += ctx_sum * steps
         self._step_weight_streams += steps
+        self._book_state_rows(lanes * steps, 0, 0)
         if not self.attention_impl.startswith("pallas"):
             return
         bs = self.config.block_size
@@ -4206,6 +4237,27 @@ class JaxLlmEngine:
         if self._window_blocks:
             work["window_pages_visited_total"] += pages * steps
             work["window_pages_full_total"] += full_pages * steps
+        work["decode_cross_kv_read_bytes_total"] += (
+            full_pages * cost.cross_layers * steps * bs * cost.kv_bytes_per_token // layers_n
+        )
+
+    def _book_state_rows(self, decode_rows: int, prompt_rows: int, spans: int) -> None:
+        """Book a dispatch's rows through the state-space and memory layers
+        (nothing for a model without them): decode rows, rows of prompt
+        spans, and the spans (each writes its lane's state out once)."""
+        cost = self.utilization.cost
+        if not cost.ssm_layers:
+            return
+        work = self._kernel_work
+        rows = decode_rows + prompt_rows
+        work["ssm_rows_total"] += rows
+        work["gmu_rows_total"] += rows
+        work["ssm_flops_total"] += rows * cost.ssm_flops_per_row
+        work["ssm_state_bytes_total"] += (
+            decode_rows * 2 * cost.ssm_state_bytes_per_lane
+            + prompt_rows * cost.ssm_prompt_bytes_per_row
+            + spans * cost.ssm_state_bytes_per_lane
+        )
 
     @property
     def _phase_name(self) -> str | None:

@@ -60,19 +60,40 @@ class LayerKind:
 
     window: int | None      # its attention's sliding window (None = full causal)
     rope: bool              # whether q and k are rotated
-    pool: str               # "kv": the full-length cache pool | "window": the window pool
+    # "kv": the full-length cache pool | "window": the window pool | None: a
+    # mixer that keeps no pages (a state-space layer, a gated memory unit)
+    pool: str | None
     group: str              # the stacked weights its layers lie in (``params[group]``)
+    # WHICH MIXER the layer runs, for a family whose block has several
+    # (models/phi4flash.py); the shared block knows only "attn"
+    mixer: str = "attn"
+    # whether the layer WRITES its pool's pages; one that does not reads the
+    # pages of the pool's layer ``pool_start`` (its run's every step the same)
+    writes: bool = True
 
 
 @dataclass(frozen=True)
 class LayerRun:
     """``count`` consecutive layers of one kind: rows ``start ...`` of
-    ``params[kind.group]``, layers ``pool_start ...`` of their cache pool."""
+    ``params[kind.group]``, layers ``pool_start ...`` of their cache pool.
 
-    kind: LayerKind
-    start: int
+    Where a model's pattern ALTERNATES (state-space, attention, state-space,
+    ...), a run is ``count`` PERIODS: ``kind``, ``start`` and ``pool_start``
+    are then tuples, one entry a layer of the period, and one scan step runs
+    the period's layers in order (32 runs of one layer would compile 32
+    bodies; three runs of pairs compile three).  A period touches ONE pool."""
+
+    kind: LayerKind | tuple[LayerKind, ...]
+    start: int | tuple[int, ...]
     count: int
-    pool_start: int
+    pool_start: int | tuple[int, ...]
+
+    @property
+    def period(self) -> tuple[tuple[LayerKind, int, int], ...]:
+        """``(kind, start, pool_start)`` of each layer of one step."""
+        if isinstance(self.kind, LayerKind):
+            return ((self.kind, self.start, self.pool_start),)
+        return tuple(zip(self.kind, self.start, self.pool_start))
 
 
 class KvPools(NamedTuple):
@@ -581,6 +602,11 @@ class _LayerOf(Mapping):
     def __init__(self, leaves: dict, index):
         self._leaves, self._index = leaves, index
 
+    @property
+    def index(self):
+        """The layer's row in its group's stacks."""
+        return self._index
+
     def __getitem__(self, name):
         return jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, self._index, keepdims=False),
@@ -608,7 +634,10 @@ def _scan_layer_runs(layer, x, params: dict, kv_cache: dict, runs):
     """The layer loop of a model whose layers differ in kind: one scan a run
     (``LayerRun``), each over the layers' indices, its body taking layer
     ``i``'s weights out of ``params[kind.group]`` (``_LayerOf``) and writing and reading
-    the pages of the run's pool (``POOL_LEAVES``) as ``_scan_layers`` does:
+    the pages of the run's pool (``POOL_LEAVES``) as ``_scan_layers`` does
+    (a run of PERIODS runs the period's layers in order in one body, each of
+    its own kind and group; ``x`` may be any pytree a family's ``layer``
+    carries beside the activations):
     a pool's flat pages are the carry of each of its runs' scans, one buffer
     from the donated argument to the result.  Counters an FFN returns
     (``_block``) are added into ``kv_cache["moe_stats"]``."""
@@ -626,29 +655,35 @@ def _scan_layer_runs(layer, x, params: dict, kv_cache: dict, runs):
     }
     stats = kv_cache.get("moe_stats")
     for run in runs:
-        kind = run.kind
-        num_layers, num_blocks, block_size = pools[kind.pool][:3]
-        weights = params[kind.group]
+        period = run.period
+        pool = next(kind.pool for kind, _, _ in period if kind.pool is not None)
+        num_layers, num_blocks, block_size = pools[pool][:3]
 
-        def body(carry, index, kind=kind, weights=weights,
+        def body(carry, index, period=period,
                  num_layers=num_layers, num_blocks=num_blocks, block_size=block_size):
             x, k_pages, v_pages, stats = carry
-            w = _LayerOf(weights, index[0])
-            at = _LayerPages(
-                index[1] * num_blocks, num_blocks, block_size, num_layers, kind
-            )
-            x, k_pages, v_pages, *counted = layer(x, w, k_pages, v_pages, at)
-            if counted and stats is not None:
-                stats = stats + counted[0]
+            for j, (kind, _, _) in enumerate(period):
+                w = _LayerOf(params[kind.group], index[2 * j])
+                at = _LayerPages(
+                    index[2 * j + 1] * num_blocks, num_blocks, block_size, num_layers, kind
+                )
+                x, k_pages, v_pages, *counted = layer(x, w, k_pages, v_pages, at)
+                if counted and stats is not None:
+                    stats = stats + counted[0]
             return (x, k_pages, v_pages, stats), None
 
-        steps = jnp.arange(run.count, dtype=jnp.int32)[:, None] + jnp.asarray(
-            [run.start, run.pool_start], jnp.int32
-        )
+        # a step's (weights row, pool layer) of each layer of the period; a
+        # layer that writes nothing stays on the pool layer it reads
+        first = [n for _, start, pool_start in period for n in (start, pool_start)]
+        stride = [n for kind, _, _ in period for n in (1, int(kind.writes))]
+        steps = jnp.arange(run.count, dtype=jnp.int32)[:, None]
+        if any(n != 1 for n in stride):
+            steps = steps * jnp.asarray(stride, jnp.int32)
+        steps = steps + jnp.asarray(first, jnp.int32)
         (x, *pages, stats), _ = jax.lax.scan(
-            body, (x, *flat[kind.pool], stats), steps
+            body, (x, *flat[pool], stats), steps
         )
-        flat[kind.pool] = tuple(pages)
+        flat[pool] = tuple(pages)
     out = dict(kv_cache)
     for name in pools:
         for leaf, pages in zip(POOL_LEAVES[name], flat[name]):

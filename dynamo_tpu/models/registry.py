@@ -72,6 +72,10 @@ class ModelFamily:
     # engine then hands ``init_kv_cache`` a ``window_blocks`` keyword,
     # serves no prefix cache and no KV transfer for the family)
     window_pool_blocks: Callable | None = None
+    # the cache holds leaves a LANE beside its pages (a recurrent state):
+    # the engine hands ``init_kv_cache`` a ``lanes`` keyword and
+    # ``forward_prefill`` the prompt's ``lane``
+    lane_state: bool = False
 
     def cache_init(self, cfg, num_blocks: int, block_size: int, dtype=None, **pools):
         if self.init_kv_cache is not None:
@@ -296,6 +300,30 @@ def _exaone_moe_family() -> ModelFamily:
     )
 
 
+def _phi4flash_family() -> ModelFamily:
+    # Phi-4-flash = state-space layers (a recurrent state a lane), window and
+    # full differential attention, and a cross-decoder of gated memory units
+    # and layers that read ONE layer's pages (models/phi4flash.py).  The
+    # state at a block boundary is not kept, so: no continued prefill (prefix
+    # cache, chunked prefill), no verify, no pipelined decode, no loader.
+    from dynamo_tpu.models import phi4flash
+
+    return ModelFamily(
+        name="phi4flash",
+        config_from_hf=phi4flash.Phi4FlashConfig.from_hf_config,
+        init_params=phi4flash.init_params,
+        param_specs=phi4flash.param_specs,
+        forward_prefill=phi4flash.phi4flash_forward_prefill,
+        forward_decode=phi4flash.phi4flash_forward_decode,
+        init_kv_cache=phi4flash.init_kv_cache,
+        kv_cache_specs=phi4flash.kv_cache_specs,
+        make_rope_tables=phi4flash.make_rope_tables,
+        forward_unified=phi4flash.phi4flash_forward_unified,
+        window_pool_blocks=phi4flash.window_pool_blocks,
+        lane_state=True,
+    )
+
+
 def _deepseek_family() -> ModelFamily:
     from dynamo_tpu.models import deepseek
 
@@ -342,6 +370,7 @@ _FAMILIES: dict[str, Callable[[], ModelFamily]] = {
     # DeepSeek-MoE ("deepseek") uses conventional attention and would need
     # its own family
     "exaone_moe": _exaone_moe_family,
+    "phi4flash": _phi4flash_family,
     "deepseek_v2": _deepseek_family,
     "deepseek_v3": _deepseek_family,
     # the same latent attention and expert layers inside hc_mult residual

@@ -110,6 +110,17 @@ class ModelCost:
     # launch moves for one row (its q, k, v and output once), every layer
     window_attn_flops_per_ctx_token: int = 0
     window_attn_bytes_per_row: int = 0
+    # state-space and memory layers (a config with ``d_state``; else 0), what
+    # a PERFECT implementation moves and computes, every such layer together:
+    # a decode row reads and writes its lane's state and taps; a prompt row
+    # reads ``a``, ``delta``, ``B``, ``C``, ``z`` and writes ``y`` (in the
+    # model's dtype); a span's last row also writes the state and taps out
+    ssm_layers: int = 0
+    ssm_prompt_bytes_per_row: int = 0
+    ssm_state_bytes_per_lane: int = 0    # a decode row reads it and writes it
+    ssm_flops_per_row: int = 0
+    # attention layers that read pages they never wrote (of ``full`` kind)
+    cross_layers: int = 0
 
     def attn_flops(self, attn_ctx_tokens: int) -> float:
         """FLOPs of attention's own products over ``attn_ctx_tokens``
@@ -140,6 +151,8 @@ def model_cost(
     shared geometry fields (LlamaConfig and friends).  Never raises: absent
     fields fall back to conservative defaults, so an exotic family gets an
     approximation instead of no utilization signal."""
+    if getattr(model, "d_state", 0):
+        return _state_space_cost(model, quantize, kv_cache_dtype)
     h = int(getattr(model, "hidden_size", 0) or 1)
     layers = int(getattr(model, "num_layers", 0) or 1)
     heads = int(getattr(model, "num_heads", 0) or 1)
@@ -210,6 +223,43 @@ def model_cost(
         mhc_stream_bytes_per_row=streams["bytes_per_row"],
         window_attn_flops_per_ctx_token=window[0],
         window_attn_bytes_per_row=window[1],
+    )
+
+
+def _state_space_cost(model, quantize, kv_cache_dtype) -> ModelCost:
+    """The cost of ``models.phi4flash.Phi4FlashConfig``: state-space layers,
+    window and full differential attention, memory units and cross layers.
+
+    The attention numbers are over the layers that WALK pages (window, full
+    and cross), as the kernels execute them (``num_heads`` queries against
+    ``head_dim``-wide cache heads: the paired form, zeros included), so the
+    engine's per-kind split (``_attn_layers``) prices each by its own
+    context; ``kv_bytes_per_token`` is therefore what those layers READ for a
+    token of context, of which only the window layers and the one full layer
+    hold pages of their own."""
+    from dynamo_tpu.models.phi4flash import param_counts
+
+    act = _dtype_bytes(getattr(model, "dtype", None))
+    dtype_bytes = 1 if quantize == "int8" else act
+    kv_bytes = _dtype_bytes(kv_cache_dtype, default=act)
+    counts = param_counts(model)
+    walking = model.window_layers + model.full_layers
+    di, n, taps, s = model.d_inner, model.d_state, model.d_conv, model.ssm_layers
+    state = 4 * n * di + act * (taps - 1) * di       # one layer's, one lane
+    return ModelCost(
+        param_count=counts["params"],
+        weight_bytes=counts["matrix"] * dtype_bytes + counts["float32"] * 4,
+        linear_flops_per_token=2 * counts["matrix"],
+        attn_flops_per_ctx_token=4 * walking * model.num_heads * model.head_dim,
+        kv_bytes_per_token=2 * walking * model.num_kv_heads * model.head_dim * kv_bytes,
+        ssm_layers=s,
+        ssm_prompt_bytes_per_row=s * act * (4 * di + 2 * n),
+        ssm_state_bytes_per_lane=s * state,
+        # the recurrence (decay's product and exp, the update's two products
+        # and sum, the read-out's product and sum: 7 a state element) and the
+        # convolution; the projections are linear work
+        ssm_flops_per_row=s * (7 * n * di + 2 * taps * di),
+        cross_layers=model.cross_layers,
     )
 
 

@@ -308,31 +308,44 @@ def _xing4_29b_l8():
     return "xing4_0", cfg, 11008, MLA["lanes"], cfg.num_heads, cfg.qk_rope_head_dim
 
 
+def _phi4_mini_flash():
+    import json
+    from pathlib import Path
+
+    from dynamo_tpu.models.phi4flash import Phi4FlashConfig
+
+    hf = json.loads((Path(__file__).parents[2] / "benchmark/configs/phi4-mini-flash.json").read_text())
+    cfg = Phi4FlashConfig.from_hf_config(hf)
+    return "phi4flash", cfg, 4160, 16, cfg.num_heads // cfg.num_kv_heads, 2, 4096
+
+
 EXPERT_CELLS = {"moonlight-16b-l9": _moonlight_16b_l9, "k-exaone-236b-l8": _k_exaone_236b_l8}
 # (the cells whose 6,144 bucket PR 45 held between its neighbours; a later
 # configuration's programs are built by the same helper from here)
-CELL_PROGRAMS = {**EXPERT_CELLS, "xing4-29b-l8": _xing4_29b_l8}
+CELL_PROGRAMS = {**EXPERT_CELLS, "xing4-29b-l8": _xing4_29b_l8,
+                 "phi4-mini-flash": _phi4_mini_flash}
 
 
 @functools.cache
 def _expert_cell_program(one_chip, config, program):
-    """A step forward of an 8,192-context expert cell at the cell's shapes
-    (``decode``, ``unified_t<tokens>``, ``prefill_t<tokens>``), the cache
-    donated, compiled for the described chip (once a module), and the bytes
-    of its cache."""
+    """A step forward of a cell at the cell's shapes (``decode``,
+    ``unified_t<tokens>``, ``prefill_t<tokens>``; context 8,192 unless the
+    cell says another), the cache donated, compiled for the described chip
+    (once a module), and the bytes of its cache."""
     from dynamo_tpu.models.llama import KvPools
 
-    name, cfg, blocks, lanes, rows, rope_dim = CELL_PROGRAMS[config]()
+    name, cfg, blocks, lanes, rows, rope_dim, *context = CELL_PROGRAMS[config]()
     family = get_family(name)
     s = _sds(one_chip)
-    context = 8192
+    context = context[0] if context else 8192
     window = (family.window_pool_blocks(cfg, lanes, context, BLOCK)
               if family.window_pool_blocks else 0)
     pools = (lambda a: KvPools(a, a)) if window else (lambda a: a)
     shaped = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)  # noqa: E731
     params = shaped(jax.eval_shape(lambda: family.init_params(cfg, jax.random.PRNGKey(0))))
     cache = shaped(jax.eval_shape(lambda: family.cache_init(
-        cfg, blocks, BLOCK, None, **({"window_blocks": window} if window else {}))))
+        cfg, blocks, BLOCK, None, **({"window_blocks": window} if window else {}),
+        **({"lanes": lanes} if family.lane_state else {}))))
     i32 = lambda *shape: s(shape, jnp.int32)  # noqa: E731
     rope = s((context, rope_dim // 2), jnp.float32)
     tables = pools(i32(lanes, context // BLOCK))
@@ -439,6 +452,27 @@ def test_xing_step_programs_compile_beside_their_weights(one_chip, program):
     assert memory.alias_size_in_bytes >= pools - 64
     assert 13.1e9 < memory.argument_size_in_bytes < 13.3e9
     assert memory.temp_size_in_bytes < 2.0e9
+
+
+@pytest.mark.parametrize("program", ["decode", "unified_t4096"])
+def test_phi4_flash_step_programs_compile_with_the_state_beside_the_pools(one_chip, program):
+    """``phi4-mini-flash``'s whole decode and 4,096-token step programs at
+    the cell's shapes (7.71 GB of weights, two pools and the lanes' state):
+    every cache leaf aliased to the outputs (no copy of a pool, none of the
+    state), the two paged kernels and no other, heads of 64 served as pairs
+    of 128, and temporaries that hold no ``[rows, d_state, d_inner]`` array
+    (4,096 rows of it would be 1.34 GB in float32)."""
+    compiled, cache = _expert_cell_program(one_chip, "phi4-mini-flash", program)
+    kernel = ("paged_window_attention_decode" if program == "decode"
+              else "ragged_paged_attention")
+    assert _kernel_names(compiled) == {kernel}
+    memory = compiled.memory_analysis()
+    assert cache == 921927680       # benchmark/configs/phi4-mini-flash.json serving.kv_bytes
+    assert memory.alias_size_in_bytes >= cache - 64
+    assert 8.6e9 < memory.argument_size_in_bytes < 8.7e9
+    print(program, "temporaries", memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < (0.05e9 if program == "decode" else 0.6e9)
+    assert "f32[4096,16,5120]" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("kind", ["unified", "prefill"])
