@@ -156,6 +156,7 @@ MOE_STAT_KEYS = (
     "moe_expert_layers_total",
     "moe_rows_walked_total",
     "moe_rows_multiplied_total",
+    "moe_rows_gathered_total",
 )
 
 

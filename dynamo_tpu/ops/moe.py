@@ -11,13 +11,31 @@ EXAONE-MoE), told which experts it holds:
               all the token's choices), summed back per token
 
 No token is dropped at any skew, and there is no capacity and no capacity
-factor: only the ORDER (``tokens x k`` int32 indices) has a place for every
+factor: the ORDER (``tokens x k`` int32 indices) has a place for every
 assignment.  The rows themselves are walked ``CHUNK_ROWS`` at a time by a
 loop whose trip count is the rows HELD, read on the device: a trip gathers
-its rows of ``x``, runs the three grouped products over them, gates, and adds
-each row, weighted, into the tokens' float32 sum.  So the time of everything
-here but route and sort follows the assignments held, and the program holds
-no array of ``tokens x k`` rows of the hidden or the expert width.
+its rows of ``x``, runs the three grouped products over them and gates.  So
+the time of everything here but route and sort follows the assignments held,
+and no array of ``tokens x k`` rows of the EXPERT width exists on any path.
+
+Summing the rows back per token has two prices, one by the rows HELD (a
+scatter-add, 0.09-0.17 us a row on the chip) and one by the rows ROUTED (a
+gather, 0.03 us a row), and the layer takes the smaller by what it is told of
+its holding, which is static (PERF.md section 6, PRs 41 and 56):
+
+- a SHARE of the router's experts held (``k-exaone-236b-l8``: 16 of 128, so
+  an eighth of the routed rows): each trip adds its rows, weighted, into the
+  tokens' float32 sum, the loop's carry (``[tokens, hidden / 128, 128]``,
+  201 MB at 8,192 tokens of 6,144); no array of ``tokens x k`` rows of the
+  hidden width exists (it would be 805 MB for 101 MB of live rows);
+- EVERY expert held (``moonlight-16b-l9``, ``xing4-29b-l8``, Mixtral,
+  Qwen3-MoE, any ``ep`` mesh; held and routed are then the same count): each
+  trip writes its down product's rows where they lie in sorted order, into
+  ONE buffer ``[tokens x k, hidden]`` in ``x``'s dtype that the loop carries
+  (201 MB at 8,192 tokens x 6 of 2,048; 235 MB at x 4 of 3,584), and after
+  the loop ONE gather by each assignment's place sums a token's ``k`` rows,
+  weighted, in float32.  No float32 sum rides the loop, nothing is
+  scatter-added.
 
 Expert parallelism: a chip that holds experts ``[first, first + E_held)`` of
 ``E`` computes its own experts' part of each token's sum; the parts of all
@@ -44,6 +62,7 @@ MOE_STATS = (
     "expert_layers",        # expert layers run (the divisor of the two above)
     "rows_walked",          # chunks walked x their rows: what the products were given
     "rows_multiplied",      # row tiles the products' kernel visits x a tile's rows
+    "rows_gathered",        # held rows the one gather after the walk summed (every expert held)
 )
 
 # rows a trip of ``moe_experts``' walk holds.  A step of fewer assignments
@@ -239,6 +258,7 @@ def moe_experts(
     w_down,                   # [E_held, I, H], or its stack and the layer
     *,
     first_expert: int = 0,
+    experts_routed: int | None = None,  # the router's width (``moe_ffn`` knows it)
     valid: jnp.ndarray | None = None,   # [T] bool: rows that are real tokens
     impl: str = "auto",
 ):
@@ -246,10 +266,24 @@ def moe_experts(
     ``MOE_STATS``.  Assignments to experts outside ``[first_expert,
     first_expert + E_held)`` and those of rows that are not ``valid`` (a
     bucket's padding, an empty lane) sort behind the live rows, where the
-    walk does not go."""
+    walk does not go.
+
+    How the walk's rows are summed back per token follows the holding, which
+    is static.  A SHARE of the router's experts held (or a caller that does
+    not say how wide the router is): each chunk's rows are scatter-added,
+    weighted, into the tokens' float32 sum ``[T, H / 128, 128]``, the loop's
+    carry; its cost follows the rows held, and no array of ``T x k`` rows
+    exists.  EVERY expert held (``first_expert`` 0 and ``E_held ==
+    experts_routed``): rows held and rows routed are the same count, so the
+    loop carries the down products' rows in sorted order, ``[T x k padded to
+    whole chunks, H]`` in ``x``'s dtype (a chunk written in place where it
+    lies), and ONE gather by each assignment's place sums a token's ``k`` rows
+    in float32 after the loop: a gathered row costs a third of a row
+    scatter-added (28 ns against 86 at 2,048 wide, PERF.md section 5, PR 56)."""
     t, h = x.shape
     e = w_gate[0].shape[1] if isinstance(w_gate, tuple) else w_gate.shape[0]
     k = expert_ids.shape[1]
+    gathers = first_expert == 0 and e == experts_routed
     local = expert_ids.astype(jnp.int32) - first_expert
     real = jnp.ones((t, 1), bool) if valid is None else valid[:, None]
     held = (local >= 0) & (local < e) & real
@@ -261,14 +295,25 @@ def moe_experts(
     starts, live_rows = ends - group_sizes, ends[-1]
     c = min(t * k, CHUNK_ROWS)
     chunks = (live_rows + c - 1) // c
-    order = jnp.pad(order, (0, -(t * k) % c))               # the last chunk's slice is whole
-    weight = jnp.where(held, probs, 0.0).reshape(-1)
+    weight = jnp.where(held, probs, 0.0)
     # a token's sum is kept as whole (8, 128) float32 tiles, so that adding a
     # row into it rewrites 6 tiles and not one sublane of 48 (on the chip a
     # chunk of 2,048 rows of 6,144: 0.46 ms where [T, H] took 2.8)
     lanes = 128 if h % 128 == 0 else h
+    if gathers:
+        # where each assignment landed in the sorted order, by choice ([k, T]):
+        # the permutation's inverse as a second sort (0.08 ms at 49,152 places
+        # on the chip; as a scatter of an arange 0.28, PERF.md section 5, PR 56)
+        place = jnp.argsort(order).reshape(t, k).T
+        # zeros: an assignment that is not live points past the live rows,
+        # into a chunk's zero rows or a chunk the walk never wrote
+        carry = jnp.zeros((-(-(t * k) // c) * c, h), x.dtype)
+    else:
+        carry = jnp.zeros((t, h // lanes, lanes), jnp.float32)
+    order = jnp.pad(order, (0, -(t * k) % c))               # the last chunk's slice is whole
+    flat_weight = weight.reshape(-1)
 
-    def chunk(i, acc):
+    def chunk(i, carry):
         """Rows ``[lo, lo + c)`` of the sorted order: each expert's rows
         clipped to them are the chunk's groups."""
         lo = i * c
@@ -280,16 +325,22 @@ def moe_experts(
             grouped_matmul(rows, w_gate, sizes, impl=impl)
         ) * grouped_matmul(rows, w_up, sizes, impl=impl)
         out = grouped_matmul(hidden, w_down, sizes, impl=impl)      # zeros past the live rows
-        scale = jnp.where(lo + jnp.arange(c) < live_rows, weight[at], 0.0)
+        if gathers:
+            return jax.lax.dynamic_update_slice(carry, out, (lo, 0))
+        scale = jnp.where(lo + jnp.arange(c) < live_rows, flat_weight[at], 0.0)
         tiles = out.reshape(c, -1, lanes).astype(jnp.float32)
-        return acc.at[token].add(tiles * scale[:, None, None])
+        return carry.at[token].add(tiles * scale[:, None, None])
 
     # (a chunk is a function of its own in the lowered program: JAX stamps what
     # it lowers inline in a loop's body with the loop's own location, and the
     # trace finds the grouped products by the name theirs gives them)
-    combined = jax.lax.fori_loop(
-        0, chunks, jax.jit(chunk), jnp.zeros((t, h // lanes, lanes), jnp.float32)
-    ).reshape(t, h).astype(x.dtype)
+    carry = jax.lax.fori_loop(0, chunks, jax.jit(chunk), carry)
+    if gathers:
+        combined = sum(
+            carry[place[j]].astype(jnp.float32) * weight[:, j, None] for j in range(k))
+    else:
+        combined = carry.reshape(t, h)
+    combined = combined.astype(x.dtype)
     # the row tiles the products' kernel visits, chunk by chunk ([chunks,
     # experts]): a tile is multiplied once for each expert with a row in it
     tm = tile_rows(c)
@@ -305,6 +356,7 @@ def moe_experts(
         jnp.int32(1),
         chunks * c,
         tiles_visited * tm,
+        live_rows if gathers else 0,
     ]).astype(jnp.int32)
     return combined, stats
 
@@ -342,6 +394,6 @@ def moe_ffn(
         ids, probs = moe_router(x, w_router, top_k, norm_topk_prob=norm_topk_prob)
     out, stats = moe_experts(
         x, ids, probs, w_gate, w_up, w_down,
-        first_expert=first_expert, valid=valid, impl=impl,
+        first_expert=first_expert, experts_routed=e_all, valid=valid, impl=impl,
     )
     return (out, stats) if with_stats else out

@@ -7,9 +7,19 @@ experts of which 16 are held, banks stacked over 2 layers).
         [--hidden H --width I --experts E --held E_HELD --choices K --layers L]
         [--tiling rule tm,tk,tn tm,tk,tn/tm,tk,tn ...]
 
+The routed widths the tree's users run (PERF.md section 5 has their tables):
+
+    moonlight-16b-l9   --hidden 2048 --width 1408 --experts 64 --held 64 --choices 6
+    xing4-29b-l8       --hidden 3584 --width 1024 --experts 64 --held 64 --choices 4
+    k-exaone-236b-l8   the defaults (16 of 128 held; both routings)
+    mixtral-8x7b-l4    --hidden 4096 --width 14336 --experts 8 --held 8 --choices 2
+
 Prints one JSON line per (tokens, chunk, tiling, routing): ``ms`` a call of
 the layer (median of ``--iters`` timed calls, each ended by
-block_until_ready), the layer's ``MOE_STATS``, and ``up_ms`` / ``down_ms``: one
+block_until_ready), the layer's ``MOE_STATS``, ``combine``: how the tree
+summed the walk's rows per token (``gather`` where ``rows_gathered`` counts
+them: every expert of the router held; else ``scatter_add``; a tree is told
+the router's width if it asks for it), and ``up_ms`` / ``down_ms``: one
 grouped product over the walk's first chunk (``[rows, hidden] @ [hidden,
 width]`` and back), timed ``REPS`` launches a call so the host's part of a
 call is a sixteenth.  Routing ``even`` draws the choices among all experts;
@@ -29,6 +39,7 @@ inputs (the parent commit's: one process a tree, both in one chip call);
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import statistics
 import sys
@@ -99,9 +110,13 @@ def main() -> int:
     has_rule = hasattr(moe, "gmm_tiling")
     rule = (moe.gmm_tiling, moe.tile_rows) if has_rule else None
 
+    # (the parent of PR 56 takes no router's width: every tree scatter-added)
+    told = ({"experts_routed": e_all}
+            if "experts_routed" in inspect.signature(moe.moe_experts).parameters else {})
+
     def layer(x, ids, probs, *b):
         return moe.moe_experts(
-            x, ids, probs, *[(bank, jnp.int32(1)) for bank in b], first_expert=first)
+            x, ids, probs, *[(bank, jnp.int32(1)) for bank in b], first_expert=first, **told)
 
     def products(rows, bank, sizes):
         """``REPS`` launches of one product, a layer's banks in turn."""
@@ -121,6 +136,7 @@ def main() -> int:
             "ms": round(_median_ms(lambda: run(x, ids, probs, *banks), args.iters), 4),
             "stats": dict(zip(moe.MOE_STATS, stats.tolist())),
         }
+        line["combine"] = "gather" if line["stats"].get("rows_gathered") else "scatter_add"
         c = min(t * k, getattr(moe, "CHUNK_ROWS", t * k))
         local = ids.reshape(-1) - first
         sizes = jnp.sum(local[:, None] == jnp.arange(e_held), axis=0, dtype=jnp.int32)

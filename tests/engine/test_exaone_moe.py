@@ -140,6 +140,7 @@ async def test_prefill_then_decode_through_both_pools_equals_reference(
     # 2 of 8 experts are held: a quarter of the assignments, give or take
     routed, held = stats["moe_assignments_routed_total"], stats["moe_assignments_held_total"]
     assert routed > 0 and 0.1 < held / routed < 0.45
+    assert stats["moe_rows_gathered_total"] == 0        # a share held: the walk scatter-adds
     assert stats["moe_expert_layers_total"] % 7 == 0
     assert stats["moe_gmm_flops_total"] == 2 * 3 * 64 * 32 * held
     if attention != "jax":      # the kernels' work is counted where they run

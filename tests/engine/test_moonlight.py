@@ -288,6 +288,8 @@ async def test_counters_read_zero_from_the_start_and_equal_a_hand_count(served):
     assert stats["moe_gmm_bytes_total"] == 4 * (
         stats["moe_experts_touched_total"] * 3 * 64 * 32 + routed * 3 * (64 + 32))
     assert stats["moe_rows_walked_total"] >= stats["moe_assignments_held_total"]
+    # every expert of the router is held: one gather summed every held row
+    assert stats["moe_rows_gathered_total"] == stats["moe_assignments_held_total"]
     assert stats["moe_experts_touched_total"] <= 8 * stats["moe_expert_layers_total"]
 
 

@@ -46,22 +46,25 @@ def test_moe_matches_per_token_dense():
     np.testing.assert_allclose(np.asarray(out), expected, rtol=2e-4, atol=2e-4)
 
 
-def test_no_token_dropped_when_all_route_to_one_expert():
+@pytest.mark.parametrize("told", [None, 2], ids=["router_unknown", "every_expert_held"])
+def test_no_token_dropped_when_all_route_to_one_expert(told):
     """Every token routed to ONE expert still gets that expert's result
-    (the capacity path zeroed all but the first ``capacity`` of them)."""
+    (the capacity path zeroed all but the first ``capacity`` of them), told
+    the router's width (both experts held: the gather sums the rows) or not."""
     rng = jax.random.PRNGKey(1)
     t, h, i = 8, 8, 8
     x = jax.random.normal(rng, (t, h), jnp.float32)
     ids = jnp.zeros((t, 1), jnp.int32)
     probs = jnp.ones((t, 1), jnp.float32)
     w = jnp.stack([jnp.eye(h, i), 2 * jnp.eye(h, i)])
-    out, stats = moe_experts(x, ids, probs, w, w, jnp.stack([jnp.eye(i, h)] * 2))
+    out, stats = moe_experts(
+        x, ids, probs, w, w, jnp.stack([jnp.eye(i, h)] * 2), experts_routed=told)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(jax.nn.silu(x) * x), rtol=1e-5, atol=1e-5
     )
     # routed, held, experts touched, busiest expert's rows, layers, rows walked,
-    # rows multiplied (one row tile of 128)
-    assert stats.tolist() == [t, t, 1, t, 1, t, 128]
+    # rows multiplied (one row tile of 128), rows gathered
+    assert stats.tolist() == [t, t, 1, t, 1, t, 128, t if told else 0]
 
 
 def test_mixtral_prefill_decode_consistency():

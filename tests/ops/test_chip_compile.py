@@ -598,12 +598,31 @@ def test_a_layers_expert_banks_are_read_where_they_lie(one_chip):
 
 # the routed cells' expert layers as a chip holds them (benchmark/configs/):
 # choices a token, hidden, expert width, experts held, the first held, sparse
-# layers in the stack, decode lanes
+# layers in the stack, decode lanes, the router's width
 EXPERT_LAYERS = {
-    "k-exaone-236b-l8": (8, 6144, 2048, 16, 32, 7, 16),
-    "moonlight-16b-l9": (6, 2048, 1408, 64, 0, 8, 24),
-    "xing4-29b-l8": (4, 3584, 1024, 64, 0, 6, 24),
+    "k-exaone-236b-l8": (8, 6144, 2048, 16, 32, 7, 16, 128),
+    "moonlight-16b-l9": (6, 2048, 1408, 64, 0, 8, 24, 64),
+    "xing4-29b-l8": (4, 3584, 1024, 64, 0, 6, 24, 64),
 }
+# where every expert is held, the layer's temporaries at the 8,192 bucket by
+# ``memory_analysis()`` (PR 56: 472 / 589 MB): the sorted rows' buffer (201 /
+# 235 MB) and the rows the gather reads out of it, a choice's at a time (the
+# same again); a share held keeps the float32 sum and a chunk (221 MB)
+EXPERT_LAYER_TEMP_BYTES = {"moonlight-16b-l9": 520e6, "xing4-29b-l8": 650e6}
+
+
+def _computations(hlo: str, root: str) -> list[str]:
+    """The text of computation ``root`` of a compiled module and of every
+    computation it calls, however deep (a fusion's, a nested loop's)."""
+    blocks = {m.group(1): m.group(0) for m in re.finditer(
+        r"^%?([\w.\-]+) \([^\n]*\{\n.*?^\}", hlo, re.M | re.S)}
+    seen, todo = [], [root]
+    while todo:
+        name = todo.pop()
+        if name in blocks and blocks[name] not in seen:
+            seen.append(blocks[name])
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", blocks[name])
+    return seen
 
 
 @pytest.mark.parametrize("rows", ["decode", "8192"])
@@ -615,9 +634,14 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, config, rows):
     and the 8,192 bucket: the three grouped products are the Pallas kernel
     inside the walk's loop, over ONE chunk's rows, at the tiles
     ``moe.gmm_tiling`` gives those widths (an over-full tiling is refused
-    here, not on the chip), and nothing of ``tokens x choices`` rows but
-    indices is left (the sorted buffers were 805 MB each at 8,192; the program
-    holds the tokens' float32 sum, 201 MB, and a chunk).  The products keep
+    here, not on the chip).  Where a SHARE is held nothing of ``tokens x
+    choices`` rows but indices is left (the sorted buffers were 805 MB each at
+    8,192; the program holds the tokens' float32 sum, 201 MB, and a chunk).
+    Where EVERY expert is held the program holds the walk's rows in sorted
+    order, ONE ``bf16[tokens x choices, hidden]`` buffer that the loop's body
+    writes in place (no ``copy`` of its shape there) and that stays in HBM,
+    nothing of that many rows as wide as an expert, and no float32 sum
+    ``[tokens, hidden / 128, 128]`` with its ``scatter``.  The products keep
     the instruction name ``gmm*`` that ``benchmark/metrics/moe_*.json`` look
     for, with locations as short as the serving process makes them
     (``utils/compile_cache.py``): lowered inline in the loop's body they were
@@ -625,13 +649,14 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, config, rows):
     from dynamo_tpu.ops import moe
 
     s = _sds(one_chip)
-    k, h, i, e, first, layers, lanes = EXPERT_LAYERS[config]
+    k, h, i, e, first, layers, lanes, routed = EXPERT_LAYERS[config]
     tokens = lanes if rows == "decode" else int(rows)
     bank = lambda a, b: (s((layers, e, a, b), jnp.bfloat16), s((), jnp.int32))  # noqa: E731
 
     def fn(x, ids, probs, gate, up, down, valid):
         return moe.moe_experts(
-            x, ids, probs, gate, up, down, first_expert=first, valid=valid, impl="pallas")
+            x, ids, probs, gate, up, down, first_expert=first, experts_routed=routed,
+            valid=valid, impl="pallas")
 
     full = jax.config.jax_include_full_tracebacks_in_locations
     jax.config.update("jax_include_full_tracebacks_in_locations", False)
@@ -652,8 +677,28 @@ def test_the_expert_layers_walk_holds_a_chunks_rows(one_chip, config, rows):
     assert all(re.match(r"gmm(\.\d+)?$", name) and int(m) == padded for name, m in products), products
     # (the count by expert compares [tokens x choices, experts held] inside a fusion)
     wide = {m for m in re.findall(rf"\w+\[{tokens * k},(\d+)\]", hlo) if int(m) >= min(h, i)}
-    assert tokens * k == chunk or not wide, wide
-    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    memory = compiled.memory_analysis()
+    if e < routed:
+        assert tokens * k == chunk or not wide, wide
+        assert memory.temp_size_in_bytes < 300e6
+        return
+    assert tokens * k == chunk or wide == {str(h)}, wide
+    buffer = rf"bf16\[{tokens * k},{h}\]"
+    # the walk's loop is the one that carries the buffer
+    (loop,) = {name for line in hlo.splitlines() if " while(" in line and re.search(buffer, line)
+               for name in re.findall(r"body=%([\w.\-]+)", line)}
+    body = _computations(hlo, loop)
+    assert not [line for text in body for line in text.splitlines()
+                if re.search(buffer + r"\S* copy(-start)?\(", line)]
+    if tokens * k > chunk:      # (a decode step's one chunk IS the buffer)
+        assert any(re.search(buffer + r"[^\n]* dynamic-update-slice\(", text) for text in body)
+        # in HBM, not ferried through fast memory round every chunk ("S(1)")
+        assert not re.search(buffer + r"\{[^}]*S\(1\)\}", "\n".join(body))
+    # (the kernel's own group metadata is an int32 scatter of a few hundred places)
+    assert f"f32[{tokens},{h // 128},128]" not in hlo
+    assert not re.search(r"= f32\[\d+,\d+,128\][^\n]* scatter\(", hlo)
+    assert memory.temp_size_in_bytes < (
+        EXPERT_LAYER_TEMP_BYTES[config] if rows == "8192" else 10e6)
 
 
 @functools.cache

@@ -1,9 +1,12 @@
 """The expert layer's walk (``ops/moe.py:moe_experts``): the rows its experts
-hold, a chunk at a time.  Against a plain loop over tokens and their chosen
-experts, at the skews a router can produce, with chunk ends that fall inside
+hold, a chunk at a time, summed back per token by a scatter-add (a share of
+the router's experts held) or by one gather after the loop (every expert
+held).  Against a plain loop over tokens and their chosen experts, on both
+combines, at the skews a router can produce, with chunk ends that fall inside
 an expert's rows and row counts that are no multiple of a chunk; and the
-lowered program holds no array of ``tokens x k`` rows of the hidden or the
-expert width, so the buffer the walk replaced cannot come back unseen."""
+lowered program holds no array of ``tokens x k`` rows of the expert width, and
+of the hidden width only the one buffer of the gather's path, so the buffers
+the walk replaced cannot come back unseen."""
 
 import json
 from pathlib import Path
@@ -34,36 +37,46 @@ def _route(skew, key, t, k, e_held=E_HELD):
 
 SKEWS = ("even_an_eighth_held", "all_to_one_held", "none_held", "all_held")
 
+# what the layer is told of its holding: experts [FIRST, FIRST + E_HELD) of a
+# router E_ALL wide (the walk's scatter-add), or all of a router E_HELD wide
+# (the gather after the loop; ids outside it stand for what a router cannot
+# produce and the layer must not care about)
+HOLDINGS = {"a_share": (FIRST, E_ALL), "every_expert": (0, E_HELD)}
+
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
 @pytest.mark.parametrize("masked", [False, True], ids=["every_row_a_token", "valid_rows"])
 @pytest.mark.parametrize("skew", SKEWS)
-def test_the_walk_is_the_per_token_sum_at_any_skew(monkeypatch, skew, masked, impl):
+@pytest.mark.parametrize("holding", sorted(HOLDINGS))
+def test_the_walk_is_the_per_token_sum_at_any_skew(monkeypatch, holding, skew, masked, impl):
     """75 tokens x 4 choices = 300 rows, in chunks of 128: no multiple of a
     chunk; with every assignment on ONE expert its rows straddle three
     chunks (two when 61 of the rows are tokens), with none held the walk
-    makes no trip, and ``rows_walked`` is the chunks that hold a live row."""
+    makes no trip, and ``rows_walked`` is the chunks that hold a live row.
+    On both combines: ``rows_gathered`` is every held row or none."""
     chunk = 128
     monkeypatch.setattr(moe, "CHUNK_ROWS", chunk)
+    first, routed = HOLDINGS[holding]
     t, h, i, k = 75, 16, 24, 4
     keys = jax.random.split(jax.random.PRNGKey(7), 4)
     x = jax.random.normal(keys[0], (t, h))
     banks = _banks(keys[1], E_HELD, h, i)
-    ids = _route(skew, keys[2], t, k).astype(jnp.int32)
+    ids = (_route(skew, keys[2], t, k) - FIRST + first).astype(jnp.int32)
     probs = jax.nn.softmax(jax.random.normal(keys[3], (t, k)), axis=-1)
     valid = np.arange(t) < 61 if masked else np.ones(t, bool)
     out, stats = moe_experts(
-        x, ids, probs, *banks, first_expert=FIRST,
+        x, ids, probs, *banks, first_expert=first, experts_routed=routed,
         valid=jnp.asarray(valid) if masked else None, impl=impl,
     )
     np.testing.assert_allclose(
-        np.asarray(out), _per_token(x, ids, probs, banks, FIRST, valid), rtol=2e-4, atol=2e-4)
+        np.asarray(out), _per_token(x, ids, probs, banks, first, valid), rtol=2e-4, atol=2e-4)
     assert not np.asarray(out)[~valid].any()
     stats = dict(zip(MOE_STATS, stats.tolist()))
-    local = np.asarray(ids)[valid] - FIRST
+    local = np.asarray(ids)[valid] - first
     held = int(((local >= 0) & (local < E_HELD)).sum())
     assert stats["assignments_routed"] == int(valid.sum()) * k
     assert stats["assignments_held"] == held
+    assert stats["rows_gathered"] == (held if holding == "every_expert" else 0)
     assert stats["rows_walked"] == -(-held // chunk) * chunk
     # an expert counts once a chunk that visits it: its banks are read in each
     sizes = np.bincount(local[(local >= 0) & (local < E_HELD)], minlength=E_HELD)
@@ -96,12 +109,19 @@ def test_a_prompt_buckets_rows_at_the_chunk_the_tree_ships():
     assert 0 < stats["assignments_held"] < lanes * k and stats["rows_walked"] == lanes * k
 
 
-def _shapes(jaxpr):
+def _eqns(jaxpr):
     for eqn in jaxpr.eqns:
-        for var in eqn.outvars:
-            yield tuple(var.aval.shape), eqn.primitive.name
+        yield eqn
         for sub in jax_core.jaxprs_in_params(eqn.params):
-            yield from _shapes(sub)
+            yield from _eqns(sub)
+
+
+def _outvars(jaxpr):
+    return ((eqn.primitive.name, var) for eqn in _eqns(jaxpr) for var in eqn.outvars)
+
+
+def _shapes(jaxpr):
+    return ((tuple(var.aval.shape), name) for name, var in _outvars(jaxpr))
 
 
 def test_no_array_of_tokens_x_k_rows_of_the_hidden_or_the_expert_width():
@@ -124,6 +144,62 @@ def test_no_array_of_tokens_x_k_rows_of_the_hidden_or_the_expert_width():
     assert any(p == "while" for _, p in shapes)
     assert (moe.CHUNK_ROWS, i) in [s for s, _ in shapes]
     assert (moe.CHUNK_ROWS, h) in [s for s, _ in shapes]
+
+
+def test_with_every_expert_held_the_program_has_one_buffer_of_rows_and_no_scatter_add():
+    """Traced at 2,048 tokens x 8 choices with all 16 of the router's experts
+    held: the walk's loop carries ONE ``[16,384, hidden]`` buffer in ``x``'s
+    dtype and writes a chunk's rows into it where they lie; nothing of 16,384
+    rows is as wide as the EXPERT width, of the hidden width there is the
+    buffer and nothing else (the gather reads a choice's 2,048 rows at a
+    time), no float32 ``[tokens, hidden]`` sum rides the loop and no
+    ``scatter-add`` is in the program at all."""
+    t, k, h, i, e = 2048, 8, 256, 128, 16
+    bf16 = jnp.bfloat16
+    args = (
+        jax.ShapeDtypeStruct((t, h), bf16), jax.ShapeDtypeStruct((t, k), jnp.int32),
+        jax.ShapeDtypeStruct((t, k), jnp.float32), jax.ShapeDtypeStruct((e, h, i), bf16),
+        jax.ShapeDtypeStruct((e, h, i), bf16), jax.ShapeDtypeStruct((e, i, h), bf16),
+    )
+    jaxpr = jax.make_jaxpr(lambda *a: moe_experts(*a, experts_routed=e, impl="xla"))(*args)
+    rows = t * k
+    outs = [(tuple(v.aval.shape), v.aval.dtype, p) for p, v in _outvars(jaxpr.jaxpr)]
+    wide = {(s, str(d)) for s, d, _ in outs if rows in s and np.prod(s) >= rows * min(h, i)}
+    assert wide == {((rows, h), "bfloat16")}, wide
+    assert not [p for _, _, p in outs if p.startswith("scatter")]
+    loops = [eqn for eqn in _eqns(jaxpr.jaxpr) if eqn.primitive.name == "while"]
+    assert len(loops) == 1
+    carried = [(tuple(v.aval.shape), str(v.aval.dtype)) for v in loops[0].outvars]
+    assert ((rows, h), "bfloat16") in carried
+    assert not [c for c in carried if c[1] == "float32" and np.prod(c[0]) >= t * h], carried
+    body = [p for p, _ in _outvars(loops[0].params["body_jaxpr"].jaxpr)]
+    assert "dynamic_update_slice" in body and "ragged_dot_general" in body
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_padding_rows_that_point_at_unwritten_chunks_leave_the_sum_finite_and_exact(monkeypatch, impl):
+    """A bucket a quarter full, every expert held: 64 of 256 rows are tokens,
+    so 192 of the 768 assignments are live and the walk writes two chunks of
+    the buffer's six.  The other 576 point past them, at rows no trip wrote:
+    what they read is zeros (not whatever the memory held), a padding row's
+    sum is exactly 0 and a token's is the per-token reference's."""
+    monkeypatch.setattr(moe, "CHUNK_ROWS", 128)
+    t, h, i, k = 256, 16, 24, 3
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    x = jax.random.normal(keys[0], (t, h))
+    banks = _banks(keys[1], E_HELD, h, i)
+    ids = jnp.stack([jax.random.permutation(kk, E_HELD)[:k] for kk in jax.random.split(keys[2], t)])
+    probs = jax.nn.softmax(jax.random.normal(keys[3], (t, k)), axis=-1)
+    valid = np.arange(t) < t // 4
+    out, stats = jax.jit(lambda valid, *a: moe_experts(
+        *a, experts_routed=E_HELD, valid=valid, impl=impl))(
+            jnp.asarray(valid), x, ids.astype(jnp.int32), probs, *banks)
+    out = np.asarray(out)
+    assert np.isfinite(out).all() and not out[~valid].any()
+    np.testing.assert_allclose(out, _per_token(x, ids, probs, banks, 0, valid), rtol=2e-4, atol=2e-4)
+    stats = dict(zip(MOE_STATS, stats.tolist()))
+    assert stats["rows_gathered"] == stats["assignments_held"] == 192
+    assert stats["rows_walked"] == 2 * 128
 
 
 def _routed_widths():
