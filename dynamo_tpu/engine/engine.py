@@ -2296,6 +2296,8 @@ class JaxLlmEngine:
             "iterations_total": self._iterations,
             "prefix_hits_total": self.allocator.prefix_hits_total,
             "prefix_cached_tokens_total": self.allocator.prefix_cached_tokens_total,
+            "kv_publish_blocks_hashed_total": self.allocator.publish_blocks_hashed_total,
+            "kv_publish_blocks_stored_total": self.allocator.publish_blocks_stored_total,
             "spec_drafted_tokens_total": self._spec_drafted,
             "spec_accepted_tokens_total": self._spec_accepted,
             "decode_windows_overlapped_total": self._overlap_windows,
@@ -2366,6 +2368,7 @@ class JaxLlmEngine:
         # of it that hands tokens to the event loop
         out["engine_post_time_total_s"] = self.loop_account.rows["post"][0]
         out["engine_post_emit_time_total_s"] = self._emit_row[0]
+        out["engine_post_publish_time_total_s"] = self._publish_row[0]
         # request-path spans of this process, aggregated (count / total /
         # max / duration histogram per component and name): recorder.py
         out["spans"] = get_recorder().aggregate()
@@ -2808,7 +2811,7 @@ class JaxLlmEngine:
                 self.max_len,
             )
             pos = dev_ctx - 1
-            token_ids[cursor] = seq.all_token_ids[-1]
+            token_ids[cursor] = seq.last_token_id
             # the host's last token lags the device while a window holding
             # this lane is in flight: read the feedback array instead
             use_fb[cursor] = overlap and seq.inflight_tokens > 0
@@ -4036,7 +4039,7 @@ class JaxLlmEngine:
             if steps <= 1:
                 slot_ids[lane] = slots[seq.seq_id]
             if token_ids is not None:
-                token_ids[lane] = seq.all_token_ids[-1]
+                token_ids[lane] = seq.last_token_id
         self._part("tables")
         tables = self._decode_tables(active)
         self._phase("upload")
@@ -4296,7 +4299,7 @@ class JaxLlmEngine:
         for seq in active:
             self._prep_decode_seq(seq)
             lane = seq.lane
-            token_ids[lane] = seq.all_token_ids[-1]
+            token_ids[lane] = seq.last_token_id
             context_lens[lane] = seq.context_len
             if steps <= 1:
                 slot_ids[lane] = slots[seq.seq_id]
@@ -4422,11 +4425,10 @@ class JaxLlmEngine:
         for seq in active:
             self._prep_decode_seq(seq)
             lane = seq.lane
-            all_tokens = seq.all_token_ids
             draft = drafts.get(seq.seq_id) or []
             if draft:
                 spec_ok[lane] = True
-            row = [all_tokens[-1]] + draft
+            row = [seq.last_token_id] + draft
             row = (row + [row[-1]] * w)[:w]  # pad: never accepted unless equal
             token_mat[lane] = row
             blocks = self.allocator.block_ids(seq.seq_id)
@@ -4529,9 +4531,9 @@ class JaxLlmEngine:
         elif seq.context_len % self.config.block_size == 0 and seq.mm_embeds is None:
             # (multimodal blocks never publish: text-token hashes cannot
             # describe patch-embedding content)
-            self._publish_stored(seq.seq_id, seq.all_token_ids)
+            self._publish_stored(seq.seq_id, seq.tokens)
 
-    def _publish_stored(self, seq_id: str, tokens: list[int]) -> None:
+    def _publish_stored(self, seq_id: str, tokens) -> None:
         """``allocator.publish_stored``, booked as the part `publish` of
         `post` (a block's worth of tokens a lane: measured where it runs)."""
         t0 = time.perf_counter()

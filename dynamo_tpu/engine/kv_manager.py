@@ -190,6 +190,10 @@ class BlockAllocator:
         # observability
         self.prefix_cached_tokens_total = 0
         self.prefix_hits_total = 0
+        # blocks hashed by publish_stored / blocks in its stored events:
+        # equal while a completed block is hashed once
+        self.publish_blocks_hashed_total = 0
+        self.publish_blocks_stored_total = 0
 
     # -- capacity ----------------------------------------------------------
     @property
@@ -646,29 +650,33 @@ class BlockAllocator:
             return cleared
 
     # -- events ------------------------------------------------------------
-    def publish_stored(self, seq_id: str, token_ids: list[int]) -> None:
+    def publish_stored(self, seq_id: str, token_ids) -> None:
         """Emit stored events for newly-completed full blocks of ``seq_id``
-        and register them for prefix reuse."""
+        and register them for prefix reuse.  Only the blocks past the
+        published ones are hashed, chained from the last published hash;
+        ``token_ids`` is a list or a ``TokenView`` of the sequence so far."""
         with self._lock:
             seq = self._sequences.get(seq_id)
             if seq is None:
                 return
-            hashes = compute_block_hashes(token_ids, self.block_size)
-            new = hashes[len(seq.published_hashes):]
+            published = seq.published_hashes
+            new = compute_block_hashes(token_ids, self.block_size, published)
             if not new:
                 return
-            parent = seq.published_hashes[-1] if seq.published_hashes else None
+            self.publish_blocks_hashed_total += len(new)
+            parent = published[-1] if published else None
             if self.enable_prefix_caching:
-                for idx in range(len(seq.published_hashes), len(hashes)):
+                for idx, h in enumerate(new, len(published)):
                     if idx >= len(seq.block_ids):
                         break
-                    h, bid = hashes[idx], seq.block_ids[idx]
+                    bid = seq.block_ids[idx]
                     # first writer wins: a hash already resident elsewhere keeps
                     # its mapping; this block simply stays unregistered
                     if h not in self._hash_to_block and bid not in self._block_hash:
                         self._hash_to_block[h] = bid
                         self._block_hash[bid] = h
-            seq.published_hashes = hashes
+            published.extend(new)
+            self.publish_blocks_stored_total += len(new)
             if self.event_sink:
                 self.event_sink(
                     KvEvent(

@@ -9,6 +9,27 @@ from dataclasses import dataclass, field
 from dynamo_tpu.llm.protocols.common import FinishReason, PreprocessedRequest
 
 
+class TokenView:
+    """Prompt and output read as one list without joining them: ``len()``
+    and a slice, which is all a block's hash asks of its tokens."""
+
+    __slots__ = ("_prompt", "_output")
+
+    def __init__(self, prompt: list[int], output: list[int]):
+        self._prompt = prompt
+        self._output = output
+
+    def __len__(self) -> int:
+        return len(self._prompt) + len(self._output)
+
+    def __getitem__(self, s: slice) -> list[int]:
+        start, stop, _ = s.indices(len(self))
+        n = len(self._prompt)
+        if start >= n:
+            return self._output[start - n : stop - n]
+        return self._prompt[start:stop] + self._output[: max(stop - n, 0)]
+
+
 class SeqStatus(enum.Enum):
     WAITING = "waiting"         # queued for prefill
     PREFILLING = "prefilling"   # chunked prefill in progress (holds a lane)
@@ -93,7 +114,17 @@ class Sequence:
 
     @property
     def all_token_ids(self) -> list[int]:
+        """A fresh list of the whole context: for a caller that needs all of
+        it (a prompt's upload, a match at admission), not one a step."""
         return self.request.token_ids + self.output_ids
+
+    @property
+    def tokens(self) -> TokenView:
+        return TokenView(self.request.token_ids, self.output_ids)
+
+    @property
+    def last_token_id(self) -> int:
+        return (self.output_ids or self.request.token_ids)[-1]
 
     def hit_stop(self, token_id: int) -> FinishReason | None:
         stop = self.request.stop

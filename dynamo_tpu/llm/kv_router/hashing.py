@@ -13,13 +13,15 @@ import xxhash
 HASH_SEED = 1337
 
 
-def compute_block_hashes(token_ids: list[int], block_size: int) -> list[int]:
-    """Hash each FULL block; each hash chains its parent, so a hash uniquely
-    identifies the whole prefix ending at that block."""
+def compute_block_hashes(token_ids, block_size: int, have=()) -> list[int]:
+    """Hash each FULL block past the ``len(have)`` already hashed; each hash
+    chains its parent (``have[-1]``, 0 for block 0), so a hash uniquely
+    identifies the whole prefix ending at that block.  ``token_ids`` needs
+    ``len()`` and slices only."""
     hashes: list[int] = []
-    parent = 0
+    parent = have[-1] if have else 0
     full = len(token_ids) - len(token_ids) % block_size
-    for start in range(0, full, block_size):
+    for start in range(len(have) * block_size, full, block_size):
         block = token_ids[start : start + block_size]
         h = xxhash.xxh3_64(
             parent.to_bytes(8, "little")

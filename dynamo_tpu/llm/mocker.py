@@ -148,6 +148,8 @@ class MockerEngine:
             "tokens_emitted_total": self._tokens_emitted_total,
             "prefill_tokens_total": self._prefill_tokens_total,
             "decode_tokens_total": self._decode_tokens_total,
+            "kv_publish_blocks_hashed_total": self.allocator.publish_blocks_hashed_total,
+            "kv_publish_blocks_stored_total": self.allocator.publish_blocks_stored_total,
             **self.flight.stats(),
         }
 
@@ -236,7 +238,7 @@ class MockerEngine:
             for seq in decision.prefills:
                 if seq.status == SeqStatus.FINISHED:  # cancelled mid-sleep
                     continue
-                self.allocator.publish_stored(seq.seq_id, seq.all_token_ids)
+                self.allocator.publish_stored(seq.seq_id, seq.tokens)
                 self._emit_next(seq)
             decode_before = self._tokens_emitted_total
             for seq in decodes:
@@ -284,7 +286,7 @@ class MockerEngine:
 
     def _emit_next(self, seq: Sequence) -> None:
         # deterministic "generation": next token = (last + 1) mod 1000
-        token = (seq.all_token_ids[-1] + 1) % 1000 if seq.all_token_ids else 0
+        token = (seq.last_token_id + 1) % 1000 if seq.context_len else 0
         seq.output_ids.append(token)
         self._tokens_emitted_total += 1
         finish = seq.hit_stop(token)
@@ -293,4 +295,4 @@ class MockerEngine:
         if finish is not None:
             self.scheduler.finish(seq)
         elif seq.context_len % self.config.block_size == 0:
-            self.allocator.publish_stored(seq.seq_id, seq.all_token_ids)
+            self.allocator.publish_stored(seq.seq_id, seq.tokens)

@@ -19,7 +19,8 @@ FLAT_KEYS = (
     "device_starved_time_total_s", "device_starved_slack_time_total_s",
     "device_starved_dispatches_total", "engine_no_work_time_total_s",
     "engine_host_offcpu_time_total_s", "engine_post_time_total_s",
-    "engine_post_emit_time_total_s",
+    "engine_post_emit_time_total_s", "engine_post_publish_time_total_s",
+    "kv_publish_blocks_hashed_total", "kv_publish_blocks_stored_total",
 )
 
 
@@ -366,6 +367,8 @@ async def test_a_served_window_keeps_the_accounts_invariants(overlap, fresh_reco
     assert stats["engine_post_time_total_s"] == pytest.approx(post["total_ms"] / 1e3, abs=1e-5)
     assert stats["engine_post_emit_time_total_s"] == pytest.approx(
         post["parts"]["emit"]["total_ms"] / 1e3, abs=1e-5)
+    assert stats["engine_post_publish_time_total_s"] == pytest.approx(
+        post["parts"]["publish"]["total_ms"] / 1e3, abs=1e-5)
     # the duration series: a busy step a sample, an episode a sample
     series = stats["spans"]["series"]["engine"]
     busy = series["engine.step.decode"]["count"] + series["engine.step.prompt"]["count"]
